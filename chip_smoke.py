@@ -1,0 +1,656 @@
+"""Prove that the served path starts and answers correctly on a TPU.
+
+One process, one chip (``--chips 4``: one process, the four-chip
+mesh path and its single-shard reference, and nothing else).  In
+order, with no arguments:
+
+1. the device JAX found, printed first — anything but a TPU can never
+   end in exit 0 or ``"ok": true``;
+2. ``native/`` built from source in this run, all halves loaded;
+3. ONE ``full_step_donate`` at the full shape, K=64, timed to
+   ``block_until_ready``: compile seconds and step milliseconds;
+4. ``svcnode.serve`` + ``ServiceClient`` over localhost TCP: load the
+   keys, a few hundred of each verb, every reply checked against a
+   dict model, every acked write read back from a device round and
+   again after a restore of the same ``data_dir``;
+5. one fused step with the Pallas quorum kernel on and off, bit-equal.
+
+Earlier lines carry what the run observed (compile events, flush
+counts, peak device bytes, donation, seconds per phase).  They are
+smoke observations, not benchmark results.  The LAST line of stdout is
+``{"ok": true, "device": {...}}`` and the exit code 0 only when every
+phase passed on a TPU.
+
+Sizes can be overridden to rehearse on a CPU
+(``JAX_PLATFORMS=cpu python chip_smoke.py --n-ens 64 --n-slots 16
+--keys 500``): the phases run, the first and last lines say that this
+was no chip, and the exit code is non-zero.
+"""
+
+from __future__ import annotations
+
+import argparse
+import asyncio
+import json
+import os
+import shutil
+import subprocess
+import sys
+import time
+import traceback
+
+import numpy as np
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+
+#: the 2026-07-31 question: launches then never finished.  The served
+#: phases below make dozens of launches, most narrower than the timed
+#: K=64 one; past this they cannot fit the smoke's 20 minutes, so the
+#: run stops at the step and the step is the finding.  Under it a
+#: slow step is printed as a finding and the run goes on.
+STEP_MS_LIMIT = 5000.0
+#: per-request client timeout: a first flush compiles for ~a minute
+CALL_TIMEOUT_S = 1200.0
+#: the server's batching tick (``svcnode --tick``).  A flush blocks
+#: the serving loop for as long as its device round takes (~0.4 s on
+#: the v5e as the step stands), and requests are only parsed between
+#: flushes: at the 5 ms default each flush carried ~33 ensembles'
+#: requests and the smoke made ~1,500 launches (13 minutes, PR 22's
+#: first chip run).  A tick of the order of the round lets a flush
+#: carry what arrived meanwhile.
+SERVE_TICK_S = 0.1
+#: requests the client keeps outstanding: under the server's
+#: per-connection in-flight budget (``svcnode._MAX_INFLIGHT`` = 1024).
+#: PR 22's chip runs pipelined all 10,000 read-back requests at once
+#: and the server answered ~6 per flush, 0.41 s apart (710 s); 1,000
+#: at a time took 0.6 s.
+WINDOW = 1000
+N_EACH = 300          # ops of each single-key verb
+VALUE_BYTES = 24
+
+
+class SmokeFailure(Exception):
+    """A phase observed something wrong; the run ends non-zero."""
+
+
+def check(cond: bool, msg: str) -> None:
+    if not cond:
+        raise SmokeFailure(msg)
+
+
+def say(msg: str) -> None:
+    print(msg, flush=True)
+
+
+# -- phase: native libraries -------------------------------------------------
+
+NATIVE_TARGETS = ("libretpu_native.so", "_retpu_resolve.so",
+                  "_retpu_wire.so")
+
+
+def build_native(out_dir: str) -> dict:
+    """Compile ``native/`` from source and load every half.  The build
+    runs in a scratch copy and each library is moved into place
+    atomically: whatever .so sat in ``native/`` before (git-ignored
+    leftovers) is replaced, and a process that loads concurrently
+    never sees a missing or half-written file."""
+    src = os.path.join(HERE, "native")
+    work = os.path.join(out_dir, "native_build")
+    shutil.rmtree(work, ignore_errors=True)
+    os.makedirs(work)
+    for name in os.listdir(src):
+        if name.endswith((".cc", ".h")) or name == "Makefile":
+            shutil.copy2(os.path.join(src, name), work)
+    proc = subprocess.run(
+        ["make", "-C", work, "all", "_retpu_resolve.so",
+         "_retpu_wire.so"], capture_output=True, text=True, timeout=600)
+    check(proc.returncode == 0,
+          f"native build failed:\n{proc.stdout}\n{proc.stderr}")
+    for so in NATIVE_TARGETS:
+        os.replace(os.path.join(work, so), os.path.join(src, so))
+
+    from riak_ensemble_tpu import wire
+    from riak_ensemble_tpu.utils import native
+    base, resolve = native.load(), native.load_resolve()
+    halves = {
+        "clock+treestore": base is not None,
+        "resolve": resolve is not None,
+        "enqueue": resolve is not None
+        and hasattr(resolve, "retpu_enqueue_pack")
+        and hasattr(resolve, "retpu_enqueue_gather"),
+        "wire": wire._native_codec() is not None,
+    }
+    check(all(halves.values()),
+          f"native halves fell back to Python: {halves}")
+    return halves
+
+
+# -- phase: one timed fused step ---------------------------------------------
+
+def timed_step(n_ens: int, n_peers: int, n_slots: int, k: int) -> dict:
+    """Compile, then run, ONE donated full step: every ensemble elects
+    and commits K puts.  Compile and run are timed apart (AOT)."""
+    import jax
+    import jax.numpy as jnp
+
+    from riak_ensemble_tpu.ops import engine as eng
+
+    state = eng.init_state(n_ens, n_peers, n_slots)
+    elect = jnp.ones((n_ens,), bool)
+    cand = jnp.zeros((n_ens,), jnp.int32)
+    rounds = np.arange(k, dtype=np.int32)[:, None]
+    kind = jnp.full((k, n_ens), eng.OP_PUT, jnp.int32)
+    slot = jnp.asarray(np.broadcast_to(rounds % n_slots, (k, n_ens)))
+    val = jnp.asarray(np.broadcast_to(rounds + 1, (k, n_ens)))
+    lease = jnp.ones((k, n_ens), bool)
+    up = jnp.ones((n_ens, n_peers), bool)
+    zeros = jnp.zeros((k, n_ens), jnp.int32)
+    args = (elect, cand, kind, slot, val, lease, up)
+    jax.block_until_ready((state, args))
+
+    t0 = time.perf_counter()
+    compiled = eng.full_step_donate.lower(
+        state, *args, exp_epoch=zeros, exp_seq=zeros).compile()
+    compile_s = time.perf_counter() - t0
+    step_ms = []
+    for _ in range(3):
+        t0 = time.perf_counter()
+        state, won, res = compiled(state, *args, exp_epoch=zeros,
+                                   exp_seq=zeros)
+        jax.block_until_ready((state, won, res))
+        step_ms.append((time.perf_counter() - t0) * 1e3)
+        # the first launch elects; later ones find their leader
+        elect = jnp.zeros((n_ens,), bool)
+        args = (elect,) + args[1:]
+    check(bool(np.asarray(res.committed).all()),
+          "timed step: not every put committed")
+    mem = compiled.memory_analysis()
+    return {"compile_s": compile_s, "step_ms": step_ms,
+            "temp_bytes": mem.temp_size_in_bytes,
+            "argument_bytes": mem.argument_size_in_bytes}
+
+
+# -- phase: the served path --------------------------------------------------
+
+class Traffic:
+    """The seeded request stream and the dict model its replies are
+    held to.  ``transcript`` keeps every reply in issue order so two
+    servers given the same seed can be compared reply for reply."""
+
+    def __init__(self, seed: int, n_ens: int, n_slots: int,
+                 keys: int) -> None:
+        self.rng = np.random.default_rng(seed)
+        self.n_ens = n_ens
+        self.per_ens = -(-keys // n_ens)
+        check(self.per_ens + 2 <= n_slots,
+              f"{keys} keys over {n_ens} ensembles need "
+              f"{self.per_ens + 2} slots each, have {n_slots}")
+        self.keys = [f"k{j}" for j in range(self.per_ens)]
+        self.model: dict = {}      # (ens, key) -> value
+        self.vsn: dict = {}        # (ens, key) -> (epoch, seq)
+        self.transcript: list = []
+        self.t_first_reply = None
+
+    def _picks(self, n: int):
+        """n distinct (ens, key) pairs of loaded keys."""
+        n = min(n, self.n_ens)
+        ens = self.rng.choice(self.n_ens, size=n, replace=False)
+        return [(int(e), self.keys[int(self.rng.integers(self.per_ens))])
+                for e in ens]
+
+    def _values(self, n: int):
+        blob = self.rng.bytes(n * VALUE_BYTES)
+        return [blob[i:i + VALUE_BYTES]
+                for i in range(0, len(blob), VALUE_BYTES)]
+
+    def _replied(self, _task) -> None:
+        if self.t_first_reply is None:
+            self.t_first_reply = time.time()
+
+    async def _gather(self, calls):
+        """Replies in call order, ``WINDOW`` requests at a time."""
+        replies = []
+        for i in range(0, len(calls), WINDOW):
+            tasks = [asyncio.ensure_future(c)
+                     for c in calls[i:i + WINDOW]]
+            tasks[0].add_done_callback(self._replied)
+            replies += await asyncio.gather(*tasks)
+        return replies
+
+    async def load(self, c) -> int:
+        per = self.per_ens
+        vals = self._values(self.n_ens * per)
+        replies = await self._gather(
+            [c.kput_many(e, self.keys, vals[e * per:(e + 1) * per],
+                         timeout=CALL_TIMEOUT_S)
+             for e in range(self.n_ens)])
+        for e, rep in enumerate(replies):
+            check(isinstance(rep, list) and len(rep) == per
+                  and all(r[0] == "ok" for r in rep),
+                  f"kput_many({e}) -> {rep!r}")
+            for j, r in enumerate(rep):
+                self.model[(e, self.keys[j])] = vals[e * per + j]
+                self.vsn[(e, self.keys[j])] = tuple(r[1])
+            self.transcript.extend(rep)
+        return self.n_ens * per
+
+    async def verbs(self, c) -> None:
+        from riak_ensemble_tpu import funref
+        from riak_ensemble_tpu.types import NOTFOUND
+
+        T = dict(timeout=CALL_TIMEOUT_S)
+        # kget
+        picks = self._picks(N_EACH)
+        replies = await self._gather([c.kget(e, k, **T)
+                                      for e, k in picks])
+        for (e, k), r in zip(picks, replies):
+            check(tuple(r) == ("ok", self.model[(e, k)]),
+                  f"kget({e},{k}) -> {r!r}")
+        self.transcript.extend(replies)
+        # kget_many, one never-written key among the loaded ones
+        ens = [e for e, _ in self._picks(N_EACH)]
+        replies = await self._gather(
+            [c.kget_many(e, self.keys + ["absent"], **T) for e in ens])
+        for e, rep in zip(ens, replies):
+            want = [("ok", self.model[(e, k)]) for k in self.keys] \
+                + [("ok", NOTFOUND)]
+            check([tuple(r) for r in rep] == want,
+                  f"kget_many({e}) -> {rep!r}")
+            self.transcript.extend(rep)
+        # kupdate: a CAS on the current version wins, a second CAS on
+        # the now-stale version must fail and change nothing
+        picks = self._picks(N_EACH)
+        new = self._values(len(picks))
+        stale = {p: self.vsn[p] for p in picks}
+        replies = await self._gather(
+            [c.kupdate(e, k, self.vsn[(e, k)], v, **T)
+             for (e, k), v in zip(picks, new)])
+        for (e, k), v, r in zip(picks, new, replies):
+            check(r[0] == "ok", f"kupdate({e},{k}) -> {r!r}")
+            self.model[(e, k)] = v
+            self.vsn[(e, k)] = tuple(r[1])
+        self.transcript.extend(replies)
+        replies = await self._gather(
+            [c.kupdate(e, k, stale[(e, k)], b"stale", **T)
+             for e, k in picks])
+        for (e, k), r in zip(picks, replies):
+            check(r == "failed", f"stale kupdate({e},{k}) -> {r!r}")
+        self.transcript.extend(replies)
+        # table kmodify: two adds on a counter key
+        ens = [e for e, _ in self._picks(N_EACH)]
+        adds = self.rng.integers(1, 1000, size=(len(ens), 2))
+        for col in range(2):
+            replies = await self._gather(
+                [c.kmodify(e, "ctr",
+                           funref.ref("rmw:add", int(adds[i, col])),
+                           0, **T) for i, e in enumerate(ens)])
+            for e, r in zip(ens, replies):
+                check(r[0] == "ok", f"kmodify({e}) -> {r!r}")
+            self.transcript.extend(replies)
+        for i, e in enumerate(ens):
+            self.model[(e, "ctr")] = int(adds[i].sum())
+        # kdelete, then delete of a key that never existed
+        picks = self._picks(N_EACH)
+        replies = await self._gather([c.kdelete(e, k, **T)
+                                      for e, k in picks])
+        for (e, k), r in zip(picks, replies):
+            check(r[0] == "ok" and r[1] != NOTFOUND,
+                  f"kdelete({e},{k}) -> {r!r}")
+            self.model[(e, k)] = NOTFOUND
+        self.transcript.extend(replies)
+        r = await c.kdelete(picks[0][0], "absent", **T)
+        check(tuple(r) == ("ok", NOTFOUND), f"kdelete(absent) -> {r!r}")
+        self.transcript.extend([r])
+
+    async def read_back(self, c) -> int:
+        """Every key the model knows, one kget_many per ensemble."""
+        by_ens: dict = {}
+        for (e, k) in self.model:
+            by_ens.setdefault(e, []).append(k)
+        ens = sorted(by_ens)
+        replies = await self._gather(
+            [c.kget_many(e, by_ens[e], timeout=CALL_TIMEOUT_S)
+             for e in ens])
+        n = 0
+        for e, rep in zip(ens, replies):
+            want = [("ok", self.model[(e, k)]) for k in by_ens[e]]
+            check([tuple(r) for r in rep] == want,
+                  f"read-back of ensemble {e}: {rep!r} != {want!r}")
+            n += len(rep)
+            self.transcript.extend(rep)
+        return n
+
+
+async def with_server(serve_kw: dict, body):
+    """Start a svcnode through its normal entry point, run ``body(
+    server, client)`` against it over localhost TCP, tear both down."""
+    from riak_ensemble_tpu import svcnode
+
+    server = await svcnode.serve(**serve_kw)
+    client = svcnode.ServiceClient(server.host, server.port)
+    await client.connect()
+    try:
+        return await body(server, client)
+    finally:
+        await client.close()
+        await server.stop()
+
+
+def service_facts(svc) -> dict:
+    st = svc.stats()
+    return {
+        "flushes": st["flushes"], "ops_served": st["ops_served"],
+        "grid_occupancy": round(st["grid_occupancy"], 4),
+        "read_fastpath_hits": st["read_fastpath_hits"],
+        "read_fastpath_misses": st["read_fastpath_misses"],
+        "read_fastpath_miss_reasons": st["read_fastpath_miss_reasons"],
+        "donate": st["donate"],
+        "native_resolve_flushes": st["native_resolve"]["flushes"],
+        "native_enqueue_flushes": st["native_enqueue"]["flushes"],
+        "wal_records": st["wal"]["records"],
+        "wal_sync": st["wal"]["sync_mode"],
+        # where a flush's wall time went (the service's own marks,
+        # median ms over its recent flushes)
+        "flush_p50_ms": {k: round(v["p50_ms"], 2)
+                         for k, v in svc.latency_breakdown().items()},
+    }
+
+
+async def drive(traffic: Traffic, serve_kw: dict, inspect=None) -> dict:
+    """Load + verbs + health/metrics + a read-back of every acked
+    write with the read fast path OFF (a device round, not the host
+    mirror).  ``inspect(svc)`` runs while the loaded service is up."""
+    async def body(server, c):
+        svc = server.svc
+        t0 = time.perf_counter()
+
+        def lap(what: str) -> None:
+            # printed as each step ends: a run that is cut still shows
+            # how far it got (wall seconds, flushes so far)
+            nonlocal t0
+            now = time.perf_counter()
+            say(f"  {what}: {now - t0:.1f} s, flushes={svc.flushes}")
+            t0 = now
+
+        loaded = await traffic.load(c)
+        lap(f"load of {loaded} keys")
+        await traffic.verbs(c)
+        lap("single-key verbs and kget_many")
+        h = await c.health(timeout=CALL_TIMEOUT_S)
+        check(h["n_ens"] == traffic.n_ens
+              and h["ensembles_with_leader"] == traffic.n_ens
+              and h["corrupt_rows"] == 0 and "donate" in h,
+              f"health: {h!r}")
+        m = await c.metrics(timeout=CALL_TIMEOUT_S)
+        check(isinstance(m, dict) and "retpu_flushes_total" in m,
+              f"metrics verb: {type(m).__name__}")
+        svc.set_fast_reads(False)
+        before = svc.stats()
+        n = await traffic.read_back(c)
+        after = svc.stats()
+        check(after["read_fastpath_hits"] == before["read_fastpath_hits"]
+              and after["flushes"] > before["flushes"],
+              "read-back did not ride device rounds")
+        lap(f"device read-back of {n} keys")
+        facts = service_facts(svc)
+        # (a mesh engine's per-shard payload is unpacked in Python by
+        # design, so only the single-shard service owes resolve runs)
+        check(facts["native_enqueue_flushes"] > 0
+              and (facts["native_resolve_flushes"] > 0
+                   or serve_kw.get("engine") is not None),
+              f"native kernels did not run: {facts}")
+        facts.update(keys_loaded=loaded, read_back_device=n)
+        if inspect is not None:
+            facts.update(inspect(svc))
+        return facts
+    return await with_server(serve_kw, body)
+
+
+async def restored_read_back(traffic: Traffic, serve_kw: dict) -> int:
+    """A second server over the SAME data_dir: ``serve`` finds the
+    prior state and goes through ``BatchedEnsembleService.restore``
+    (checkpoint + WAL replay); every acked write must be there."""
+    async def body(server, c):
+        server.svc.set_fast_reads(False)
+        return await traffic.read_back(c)
+    return await with_server(serve_kw, body)
+
+
+def compile_report(t_first_reply) -> None:
+    from riak_ensemble_tpu.obs.compilewatch import COMPILE_EVENTS
+
+    evs = list(COMPILE_EVENTS)
+    for phase, sel in (
+            ("before_first_reply",
+             [e for e in evs if e["t_unix"] <= t_first_reply]),
+            ("during_serving",
+             [e for e in evs if e["t_unix"] > t_first_reply])):
+        say(f"compiles {phase}: {len(sel)} events, "
+            f"{sum(e['compile_ms'] for e in sel) / 1e3:.1f} s")
+    for e in sorted(evs, key=lambda e: -e["compile_ms"])[:12]:
+        say(f"  compile {e['fn']} {e['compile_ms'] / 1e3:.1f} s "
+            f"{e['shapes']}")
+
+
+# -- phase: Pallas quorum kernel on/off --------------------------------------
+
+def pallas_bit_equal() -> None:
+    """One fused step at E=1,024 with the quorum reduce as the Mosaic
+    kernel and as the jnp chain: results must be bit-equal.  Fresh
+    jits — the gate is read when a program is traced."""
+    import jax
+    import jax.numpy as jnp
+
+    from riak_ensemble_tpu.ops import engine as eng
+
+    e, m, s, k = 1024, 5, 128, 4
+    rng = np.random.default_rng(0)
+    args = (jnp.ones((e,), bool), jnp.zeros((e,), jnp.int32),
+            jnp.asarray(rng.integers(0, 3, (k, e)), jnp.int32),
+            jnp.asarray(rng.integers(0, s, (k, e)), jnp.int32),
+            jnp.asarray(rng.integers(1, 99, (k, e)), jnp.int32),
+            jnp.ones((k, e), bool),
+            jnp.asarray(rng.random((e, m)) < 0.8))
+    outs = {}
+    was = eng.PALLAS_QUORUM
+    try:
+        for gate in (True, False):
+            eng.PALLAS_QUORUM = gate
+            state = eng.init_state(e, m, s)
+            step = jax.jit(eng._full_step_body).lower(
+                state, *args).compile()
+            if gate:
+                check("tpu_custom_call" in step.as_text(),
+                      "gate on, but no Mosaic kernel in the program")
+            outs[gate] = jax.block_until_ready(step(state, *args))
+    finally:
+        eng.PALLAS_QUORUM = was
+    for a, b in zip(jax.tree.leaves(outs[True]),
+                    jax.tree.leaves(outs[False])):
+        check(bool((np.asarray(a) == np.asarray(b)).all()),
+              "pallas quorum on/off results differ")
+
+
+# -- the four-chip path ------------------------------------------------------
+
+def mesh_placement(svc) -> dict:
+    """Where the loaded mesh service's state really sits: every leaf
+    sharded over four devices along 'ens', and (where the backend
+    keeps allocator stats) bytes in use on each device."""
+    import jax
+
+    from riak_ensemble_tpu.parallel.batched_host import (
+        _backend_mem_bytes_per_device)
+
+    for name, leaf in zip(svc.state._fields, svc.state):
+        shards = leaf.addressable_shards
+        check(len({s.device for s in shards}) == 4
+              and all(s.data.shape[0] * 4 == leaf.shape[0]
+                      for s in shards),
+              f"state.{name} is not split four ways along 'ens': "
+              f"{leaf.sharding}")
+    per_dev = _backend_mem_bytes_per_device()
+    total = sum(per_dev.values())
+    if not np.isnan(total):  # the backend keeps allocator stats
+        check(len(per_dev) >= 4 and all(
+            b >= total / 5 for b in per_dev.values()),
+            f"state bytes are not spread over the devices: {per_dev}")
+    else:
+        check(jax.devices()[0].platform != "tpu",
+              "no allocator stats on a TPU")
+        per_dev = "not measured"
+    return {"bytes_in_use_per_device": per_dev}
+
+
+# -- main --------------------------------------------------------------------
+
+def cache_entries(path: str) -> int:
+    try:
+        return sum(1 for n in os.listdir(path) if not n.endswith("-atime"))
+    except FileNotFoundError:
+        return 0
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                    help="4 = only the mesh-sharded service against "
+                         "its single-shard reference")
+    ap.add_argument("--n-ens", type=int, default=None,
+                    help="default 10,000 (10,240 with --chips 4)")
+    ap.add_argument("--n-slots", type=int, default=128)
+    ap.add_argument("--keys", type=int, default=100_000)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--out", default=os.path.join(HERE, "chiprun_out",
+                                                  "chip_smoke"))
+    args = ap.parse_args(argv)
+    full_shape = (args.n_ens is None and args.n_slots == 128
+                  and args.keys == 100_000)
+    n_ens = args.n_ens or (10_240 if args.chips == 4 else 10_000)
+    n_peers = 5
+
+    import jax
+
+    devs = jax.devices()
+    device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+              "count": len(devs)}
+    on_chip = device["platform"] == "tpu"
+    no_chip = ("" if on_chip else
+               "  ** NOT A TPU: a rehearsal, this run cannot pass **")
+    say(f"chip_smoke: device {json.dumps(device)}{no_chip}")
+    if not on_chip and full_shape:
+        say("chip_smoke: no TPU, nothing run (rehearse on a CPU with "
+            "--n-ens 64 --n-slots 16 --keys 500)")
+        return 2
+    if len(devs) < args.chips:
+        say(f"chip_smoke: --chips {args.chips} but jax sees {len(devs)}")
+        return 2
+
+    from riak_ensemble_tpu.utils.jaxcache import setup_compile_cache
+    cache_dir = setup_compile_cache()
+    say(f"compile cache: {cache_dir} entries_before="
+        f"{cache_entries(cache_dir)}")
+    os.makedirs(args.out, exist_ok=True)
+
+    def data_dir(name: str) -> str:
+        d = os.path.join(args.out, name)
+        shutil.rmtree(d, ignore_errors=True)
+        return d
+
+    traffic = Traffic(args.seed, n_ens, args.n_slots, args.keys)
+    kw = dict(n_ens=n_ens, n_peers=n_peers, n_slots=args.n_slots,
+              tick=SERVE_TICK_S, warm=False)
+
+    def native():
+        say("native built from source, loaded: "
+            f"{build_native(args.out)}")
+
+    def step():
+        r = timed_step(n_ens, n_peers, args.n_slots, 64)
+        say(f"full_step_donate E={n_ens} M={n_peers} S={args.n_slots} "
+            f"K=64: compile_s={r['compile_s']:.1f}")
+        say("full_step_donate step_ms="
+            + " ".join(f"{x:.2f}" for x in r["step_ms"])
+            + f" temp_bytes={r['temp_bytes']} "
+            f"argument_bytes={r['argument_bytes']}")
+        best = min(r["step_ms"])
+        check(best < STEP_MS_LIMIT,
+              f"a fused step takes {best:.0f} ms: that is the finding, "
+              "nothing after it was run")
+        if best >= 1000.0:
+            say(f"FINDING: one fused step takes {best:.0f} ms "
+                f"({best / 64:.1f} ms per K/V round) — seconds, not "
+                "milliseconds; the served phases still fit, so the run "
+                "goes on")
+
+    def serve():
+        facts = asyncio.run(drive(
+            traffic, dict(kw, data_dir=data_dir("data"))))
+        say(f"served: {json.dumps(facts)}")
+
+    def restore():
+        n = asyncio.run(restored_read_back(
+            traffic, dict(kw, data_dir=os.path.join(args.out, "data"))))
+        say(f"restore: {n} keys read back after restore(), all equal "
+            "to the model")
+
+    def pallas():
+        pallas_bit_equal()
+        say("pallas quorum kernel: compiled by Mosaic, step bit-equal "
+            "to the jnp path")
+
+    reference = Traffic(args.seed, n_ens, args.n_slots, args.keys)
+
+    def mesh():
+        from riak_ensemble_tpu.parallel.mesh import mesh_engine
+        facts = asyncio.run(drive(
+            traffic, dict(kw, data_dir=data_dir("data_mesh"),
+                          engine=mesh_engine(4)),
+            inspect=mesh_placement))
+        say(f"mesh service (4 devices): {json.dumps(facts)}")
+
+    def single():
+        facts = asyncio.run(drive(
+            reference, dict(kw, data_dir=data_dir("data_single"))))
+        say(f"single-shard service: {json.dumps(facts)}")
+        check(traffic.transcript == reference.transcript,
+              "mesh and single-shard replies differ")
+        say("mesh replies identical to single-shard: "
+            f"{len(traffic.transcript)} replies")
+
+    phases = ((native, mesh, single) if args.chips == 4
+              else (native, step, serve, restore, pallas))
+    failed = None
+    for phase in phases:
+        t0 = time.perf_counter()
+        try:
+            phase()
+        except Exception:
+            traceback.print_exc()
+            failed = phase.__name__
+            say(f"phase {failed}: FAILED (traceback on stderr)")
+            break
+        say(f"phase {phase.__name__}: ok "
+            f"{time.perf_counter() - t0:.1f} s")
+
+    if traffic.t_first_reply is not None:
+        compile_report(traffic.t_first_reply)
+    say(f"model: {len(traffic.transcript)} replies checked against the "
+        f"dict model, {len(traffic.model)} keys")
+    stats = devs[0].memory_stats() or {}
+    say(f"peak device bytes: "
+        f"{stats.get('peak_bytes_in_use', 'not measured')}")
+    say(f"compile cache: {cache_dir} entries_after="
+        f"{cache_entries(cache_dir)}")
+    if failed is not None:
+        say(f"chip_smoke: FAILED in phase {failed}{no_chip}")
+        return 1
+    if not on_chip:
+        say("chip_smoke: every phase ran, but NOT ON A TPU: no result")
+        return 1
+    say(json.dumps({"ok": True, "device": device}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

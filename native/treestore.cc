@@ -82,6 +82,13 @@ uint32_t read_u32(const uint8_t* p) {
 struct Store {
   std::string path;        // snapshot file; log is path + ".log"
   std::map<std::string, std::string> data;
+  // retpu_store_key_at's resume point: a scan asks for index 0, 1,
+  // 2, ... (each twice: size, then copy), and walking from begin()
+  // every time made it quadratic — minutes for a 100k-record WAL
+  // replay.  Any mutation shifts indices, so it drops the cursor.
+  std::map<std::string, std::string>::iterator cursor;
+  uint64_t cursor_index = 0;
+  bool cursor_valid = false;
   FILE* log = nullptr;
   uint64_t log_records = 0;
   int refcount = 1;
@@ -278,6 +285,7 @@ int retpu_store_put(void* h, const uint8_t* key, uint32_t klen,
   std::string k(reinterpret_cast<const char*>(key), klen);
   std::string v(reinterpret_cast<const char*>(val), vlen);
   s->data[k] = v;
+  s->cursor_valid = false;
   s->append_record(1, k, v);
   if (s->log_records >= kCompactThreshold) {
     s->compact();
@@ -294,6 +302,7 @@ int retpu_store_put_many(void* h, const uint8_t* arena,
                          const int64_t* idx, int64_t n) {
   auto* s = static_cast<Store*>(h);
   std::lock_guard<std::mutex> g(s->mu);
+  s->cursor_valid = false;
   for (int64_t i = 0; i < n; i++) {
     const int64_t klen = idx[i * 4 + 1];
     if (klen <= 0) {
@@ -339,6 +348,7 @@ int retpu_store_delete(void* h, const uint8_t* key, uint32_t klen) {
   std::lock_guard<std::mutex> g(s->mu);
   std::string k(reinterpret_cast<const char*>(key), klen);
   s->data.erase(k);
+  s->cursor_valid = false;
   s->append_record(2, k, std::string());
   return 0;
 }
@@ -359,8 +369,14 @@ int64_t retpu_store_key_at(void* h, uint64_t index, uint8_t* buf,
   if (index >= s->data.size()) {
     return -1;
   }
-  auto it = s->data.begin();
-  std::advance(it, index);
+  if (!s->cursor_valid || index < s->cursor_index) {
+    s->cursor = s->data.begin();
+    s->cursor_index = 0;
+    s->cursor_valid = true;
+  }
+  std::advance(s->cursor, index - s->cursor_index);
+  s->cursor_index = index;
+  const auto& it = s->cursor;
   if (buf != nullptr && buflen >= it->first.size()) {
     memcpy(buf, it->first.data(), it->first.size());
   }

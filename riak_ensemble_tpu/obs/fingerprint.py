@@ -10,7 +10,10 @@ before it is believed.
 Static fields (host, cpu count, versions, knobs) are cached;
 load-dependent fields (loadavg) are re-read per call.  jax/jaxlib
 versions come from package metadata, NOT ``import jax`` — the
-fingerprint must never be the thing that initializes a backend.
+fingerprint must never be the thing that initializes a backend (a
+launcher parent has to stay off the chip its children need).  The
+device fields therefore read only a backend that is ALREADY up, and
+are None in a process that never touched one.
 """
 
 from __future__ import annotations
@@ -32,6 +35,19 @@ def _pkg_version(name: str) -> str:
         return version(name)
     except Exception:
         return "unknown"
+
+
+def _device() -> Dict[str, Any]:
+    """What jax actually runs on in this process — the env var only
+    says what was asked for."""
+    jax = sys.modules.get("jax")
+    if jax is None or not jax._src.xla_bridge.backends_are_initialized():
+        return {"device_platform": None, "device_kind": None,
+                "device_count": None}
+    devs = jax.devices()
+    return {"device_platform": devs[0].platform,
+            "device_kind": devs[0].device_kind,
+            "device_count": len(devs)}
 
 
 def box_fingerprint() -> Dict[str, Any]:
@@ -57,6 +73,7 @@ def box_fingerprint() -> Dict[str, Any]:
     except (OSError, AttributeError):
         out["loadavg"] = None
     out["jax_platforms_env"] = os.environ.get("JAX_PLATFORMS")
+    out.update(_device())
     out["retpu_knobs"] = {k: v for k, v in os.environ.items()
                           if k.startswith("RETPU_")}
     return out

@@ -476,14 +476,16 @@ def _quorum_met(ack: jax.Array, heard: jax.Array, view_mask: jax.Array,
     this path.
     """
     if PALLAS_QUORUM and axis_name is None and ack.ndim == 2:
-        from riak_ensemble_tpu.ops.pallas_quorum import quorum_met_epallas
-        res = quorum_met_epallas(ack, heard & ~ack, view_mask)
+        from riak_ensemble_tpu.ops import pallas_quorum
+        res = pallas_quorum.quorum_met_epallas(
+            ack, heard & ~ack, view_mask,
+            interpret=pallas_quorum.INTERPRET)
         return res == quorum_lib.MET
     if PALLAS_QUORUM and axis_name is None and ack.ndim == 3:
         # Wide-round shape [E, W, Ml] (every K/V round since the lane
         # refactor — W=1 included): flatten the lane axis into the
         # ensemble axis for the kernel, whose contract is [E', Ml].
-        from riak_ensemble_tpu.ops.pallas_quorum import quorum_met_epallas
+        from riak_ensemble_tpu.ops import pallas_quorum
         e, w, ml = ack.shape
         # Broadcast BOTH a 3-dim [E, V, Ml] and an already-widened
         # 4-dim [E, W, V, Ml] view_mask to the full lane shape: a
@@ -492,9 +494,10 @@ def _quorum_met(ack: jax.Array, heard: jax.Array, view_mask: jax.Array,
         vm = jnp.broadcast_to(
             view_mask if view_mask.ndim == 4 else view_mask[:, None],
             (e, w) + view_mask.shape[-2:])
-        res = quorum_met_epallas(
+        res = pallas_quorum.quorum_met_epallas(
             ack.reshape(e * w, ml), (heard & ~ack).reshape(e * w, ml),
-            vm.reshape(e * w, *vm.shape[-2:]))
+            vm.reshape(e * w, *vm.shape[-2:]),
+            interpret=pallas_quorum.INTERPRET)
         return (res == quorum_lib.MET).reshape(e, w)
     res = quorum_met_batch(
         ack, heard & ~ack, view_mask,
@@ -1567,28 +1570,23 @@ def lowered_cost_analysis(fn, *args, **kwargs):
     — WITHOUT a backend compile (``Lowered.cost_analysis`` runs the
     HLO cost model on the lowering, a few ms even for the full step).
     Returns ``{"flops": f, "bytes_accessed": b}`` with whatever keys
-    the backend reports, or None when the lowering/analysis is
-    unsupported (mesh placements, older jaxlibs) — telemetry capture
-    must degrade, never raise into a warmup.
+    the backend reports, or None for a program that cannot be lowered
+    from here (mesh placements: the sharded step is a plain method,
+    not a jitted callable).
 
     Used by ``BatchedEnsembleService.warmup`` to record per-(K, A)-
     bucket cost gauges next to the compile-event log, so a bucket's
     device cost and its compile cost live on the same surface.
     """
-    try:
-        lower = getattr(fn, "lower", None)
-        if lower is None:
-            return None
-        ca = lower(*args, **kwargs).cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else None
-        if not isinstance(ca, dict):
-            return None
-        out = {}
-        if "flops" in ca:
-            out["flops"] = float(ca["flops"])
-        if "bytes accessed" in ca:
-            out["bytes_accessed"] = float(ca["bytes accessed"])
-        return out or None
-    except Exception:
+    lower = getattr(fn, "lower", None)
+    if lower is None:
         return None
+    ca = lower(*args, **kwargs).cost_analysis()
+    if not ca:  # a backend without an HLO cost model answers None
+        return None
+    out = {}
+    if "flops" in ca:
+        out["flops"] = float(ca["flops"])
+    if "bytes accessed" in ca:
+        out["bytes_accessed"] = float(ca["bytes accessed"])
+    return out or None

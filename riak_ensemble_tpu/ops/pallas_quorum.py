@@ -19,14 +19,17 @@ and the implicit self vote (folded in as a +1 on the votes matrix
 before the matmul, which is literally what ``heard = n_valid +
 self_in_view`` computes).
 
-On non-TPU platforms the kernel runs in interpreter mode (tests); the
-jnp reference implementation remains the portable path.
+The kernel compiles for the TPU (Mosaic) unless the caller asks for
+the interpreter: direct calls pass ``interpret=True``, and the engine
+gate (``ops/engine.py:_quorum_met``) passes the module-level
+:data:`INTERPRET`, which only tests set.  Nothing here looks at the
+backend, so a served path can never end up on the interpreter by
+itself; the jnp reference implementation remains the portable path.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -36,6 +39,11 @@ from jax.experimental import pallas as pl
 from riak_ensemble_tpu.ops.quorum import MET, NACK, REQUIRED_MODES, UNDECIDED
 
 LANE = 128
+
+#: ``interpret=`` the engine gate hands both kernels.  False in every
+#: deployment; tests/test_pallas_quorum.py flips it in a fixture so
+#: the CPU suite can run the gated engine paths.
+INTERPRET = False
 
 
 def _resolve(heard, n_nack, members, thresh, is_active, out_ref):
@@ -83,7 +91,7 @@ def _kernel(votes_ref, nacks_ref, vmt_ref, members_ref, thresh_ref,
 def quorum_met_pallas(valid: jax.Array, nack: jax.Array,
                       view_mask: jax.Array, self_idx: jax.Array,
                       required: str = "quorum", block_e: int = 256,
-                      interpret: Optional[bool] = None) -> jax.Array:
+                      interpret: bool = False) -> jax.Array:
     """Drop-in for ``quorum_met_batch(..., axis_name=None)`` on a 2-D
     ``[E, M]`` batch with shared or per-ensemble ``view_mask``
     (``[V, M]`` or ``[E, V, M]`` — the latter reduces to the shared
@@ -93,8 +101,6 @@ def quorum_met_pallas(valid: jax.Array, nack: jax.Array,
     Returns int8 ``[E]`` of MET / UNDECIDED / NACK.
     """
     assert required in REQUIRED_MODES, required
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     e, m = valid.shape
     assert view_mask.ndim == 2, "pallas path takes a shared [V, M] mask"
     v = view_mask.shape[0]
@@ -171,15 +177,13 @@ def _ekernel(votes_ref, nacks_ref, mask_ref, out_ref):
                    static_argnames=("block_e", "interpret"))
 def quorum_met_epallas(valid: jax.Array, nack: jax.Array,
                        view_mask: jax.Array, block_e: int = 512,
-                       interpret: Optional[bool] = None) -> jax.Array:
+                       interpret: bool = False) -> jax.Array:
     """Pallas form of the ENGINE's quorum predicate: ``required=
     "quorum"``, no self term (the leader's vote is already folded into
     ``valid``), per-ensemble ``view_mask [E, V, M]``.  Drop-in for
     ``quorum_met_batch(valid, nack, view_mask, self_idx=-1,
     required="quorum", axis_name=None)``; returns int8 ``[E]``.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     e, m = valid.shape
     assert view_mask.ndim == 3 and view_mask.shape[0] == e \
         and view_mask.shape[2] == m, view_mask.shape

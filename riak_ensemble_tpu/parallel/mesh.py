@@ -33,15 +33,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.5 exports shard_map at top level
-    _shard_map = jax.shard_map
-except AttributeError:  # 0.4.x: experimental home, check_rep spelling
-    from jax.experimental.shard_map import shard_map as _shard_map_04
-
-    def _shard_map(fn, *, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map_04(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=check_vma)
-
 from riak_ensemble_tpu.ops import engine as eng
 
 
@@ -114,7 +105,7 @@ class ShardedEngine:
         ax = "peer" if mesh.shape["peer"] > 1 else None
 
         def smap(fn, in_specs, out_specs, donate=False):
-            return jax.jit(_shard_map(
+            return jax.jit(jax.shard_map(
                 fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
                 check_vma=False),
                 donate_argnums=(0,) if donate else ())

@@ -151,6 +151,7 @@ from riak_ensemble_tpu.parallel.batched_host import (
     BatchedEnsembleService, WallRuntime, _PendingBatch,
     warmup_kernels)
 from riak_ensemble_tpu.types import NOTFOUND
+from riak_ensemble_tpu.utils.jaxcache import setup_compile_cache
 
 _HDR = struct.Struct(">I")
 #: install frames carry full engine-state snapshots
@@ -5366,6 +5367,7 @@ def main(argv=None) -> int:
 
     from riak_ensemble_tpu.config import fast_test_config
 
+    setup_compile_cache()
     peers = []
     for spec in args.peer:
         h, p = spec.rsplit(":", 1)

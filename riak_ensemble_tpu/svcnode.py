@@ -134,6 +134,7 @@ from riak_ensemble_tpu import faults, wire
 from riak_ensemble_tpu.config import Config, fast_test_config
 from riak_ensemble_tpu.netruntime import NetRuntime
 from riak_ensemble_tpu.parallel.batched_host import BatchedEnsembleService
+from riak_ensemble_tpu.utils.jaxcache import setup_compile_cache
 
 _HDR = struct.Struct(">I")
 _MAX_FRAME = 16 << 20
@@ -887,6 +888,8 @@ def main(argv=None) -> int:
                          "(docs/ARCHITECTURE.md §14; audit via the "
                          "('controller',) verb)")
     args = ap.parse_args(argv)
+
+    setup_compile_cache()
 
     engine = None
     if args.mesh_devices:

@@ -10,10 +10,18 @@ import pytest
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
+from riak_ensemble_tpu.ops import pallas_quorum  # noqa: E402
 from riak_ensemble_tpu.ops.pallas_quorum import quorum_met_pallas  # noqa: E402
 from riak_ensemble_tpu.ops.quorum import (  # noqa: E402
     REQUIRED_MODES, quorum_met_batch, views_to_mask,
 )
+
+
+@pytest.fixture
+def interpreted_engine_gate(monkeypatch):
+    """The engine gate compiles the kernel for the TPU unless told
+    otherwise; these CPU tests run it in the Pallas interpreter."""
+    monkeypatch.setattr(pallas_quorum, "INTERPRET", True)
 
 
 @pytest.mark.parametrize("required", REQUIRED_MODES)
@@ -37,8 +45,7 @@ def test_pallas_matches_reference(required, seed):
                                       required=required))
     got = np.asarray(quorum_met_pallas(valid, nack, mask, self_idx,
                                        required=required,
-                                       interpret=jax.default_backend()
-                                       != "tpu"))
+                                       interpret=True))
     np.testing.assert_array_equal(got, ref)
 
 
@@ -49,7 +56,8 @@ def test_pallas_singleton_and_edge_cases():
     nack = jnp.zeros((4, 1), bool)
     self_idx = jnp.asarray([0, 0, -1, -1], jnp.int32)
     ref = np.asarray(quorum_met_batch(valid, nack, mask, self_idx))
-    got = np.asarray(quorum_met_pallas(valid, nack, mask, self_idx))
+    got = np.asarray(quorum_met_pallas(valid, nack, mask, self_idx,
+                                       interpret=True))
     np.testing.assert_array_equal(got, ref)
 
 
@@ -63,7 +71,7 @@ def test_pallas_block_padding():
     self_idx = jnp.zeros((e,), jnp.int32)
     ref = np.asarray(quorum_met_batch(valid, nack, mask, self_idx))
     got = np.asarray(quorum_met_pallas(valid, nack, mask, self_idx,
-                                       block_e=256))
+                                       block_e=256, interpret=True))
     np.testing.assert_array_equal(got, ref)
 
 
@@ -89,11 +97,11 @@ def test_epallas_matches_reference(seed):
     ref = quorum_met_batch(valid, nack, mask,
                            jnp.full((e,), -1, jnp.int32),
                            required="quorum")
-    got = quorum_met_epallas(valid, nack, mask)
+    got = quorum_met_epallas(valid, nack, mask, interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
 
-def test_engine_flag_gated_pallas_equivalence():
+def test_engine_flag_gated_pallas_equivalence(interpreted_engine_gate):
     """RETPU_PALLAS_QUORUM=1 must not change any engine result: run a
     full protocol slice (elect, puts/gets with a down peer, reconfig)
     with the flag off and on and compare everything."""
@@ -136,7 +144,7 @@ def test_engine_flag_gated_pallas_equivalence():
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_quorum_met_wide_pallas_3dim_view_mask():
+def test_quorum_met_wide_pallas_3dim_view_mask(interpreted_engine_gate):
     """Regression (round-5 ADVICE): the wide Pallas branch of
     engine._quorum_met must accept a 3-dim [E, V, Ml] view_mask with
     W > 1 — broadcasting it per lane — not just a caller-pre-widened
