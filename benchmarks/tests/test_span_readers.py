@@ -1,0 +1,137 @@
+"""The readers of PR 25's per-layer metrics on synthetic ``facts``
+(the window filter, the 5 x median rule, a reduction of ``None``), and
+a rehearsal on the CPU that prints the four of them a CPU can read."""
+
+import importlib.util
+import json
+import os
+import types
+
+import pytest
+
+from test_rehearsal import BENCH, rehearse
+
+SPAN_METRICS = ("frontend_p50_ms", "wal_fsync_p50_ms",
+                "flush_stall_ms_per_s", "gc_pause_ms_per_s")
+
+
+def reader(name):
+    spec = importlib.util.spec_from_file_location(
+        "reader_" + name, os.path.join(BENCH, "readers", name + ".py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+def facts(records, reduction=None, seconds=10.0, end=1000.0, t0=None):
+    dump = {"lat_records": records}
+    if reduction is not None:
+        dump["trace"] = {"reduction": reduction}
+    out = {"dump": dump, "seconds": seconds, "window_end_unix": end}
+    if t0 is not None:
+        out["log"] = types.SimpleNamespace(t0=t0)
+    return out
+
+
+def rec(unix, total, **marks):
+    return {"clock": [5.0, unix], "total": total, "starts": {}, **marks}
+
+
+WINDOW = [rec(990.5 + i, 0.010, gc=0.001) for i in range(9)]
+
+
+@pytest.mark.parametrize("extra,expect", [
+    ([], (0.0, 9)),
+    # one flush of 300 ms is over 5 x the median of 10 ms
+    ([rec(995.0, 0.300)], (30.0, 10)),
+    # 50 ms is exactly 5 x the median: not over it
+    ([rec(995.0, 0.050)], (0.0, 10)),
+    # a stall before the window (the warm-up's) or after it (the
+    # read-back's) is not the window's
+    ([rec(989.9, 0.300), rec(1000.1, 0.300)], (0.0, 9)),
+    # records without a stamp (the parent's) or without a total
+    # (a compaction event) are not flush records of the window
+    ([{"total": 0.3}, {"clock": [1.0, 995.0], "svc_compaction": 0.3}],
+     (0.0, 9)),
+])
+def test_stall_rate(extra, expect):
+    assert reader("stall_rate")(facts(WINDOW + extra)) == \
+        pytest.approx(expect)
+
+
+@pytest.mark.parametrize("records,marks,expect", [
+    (WINDOW, ["gc"], (0.9, 9)),
+    (WINDOW, ["gc", "wal_fsync"], (0.9, 9)),        # a mark no record has
+    (WINDOW + [rec(980.0, 0.01, gc=5.0)], ["gc"], (0.9, 9)),
+    (WINDOW + [rec(999.0, 0.01, gc=0.25)], ["gc"], (25.9, 10)),
+    ([{"total": 0.01, "gc": 1.0}], ["gc"], None),   # the parent's records
+    ([], ["gc"], None),
+])
+def test_mark_rate(records, marks, expect):
+    got = reader("mark_rate")(facts(records), marks=marks)
+    assert got == (pytest.approx(expect) if expect else None)
+
+
+@pytest.mark.parametrize("t0,expect", [
+    # the phase returned 20 s after the window (the tracer wrote its
+    # trace out): the generator's own start of the window, 965.0 on
+    # the wall clock through a record's pair (perf 5.0 = its unix),
+    # puts the window where it was
+    (5.0 + (965.0 - 990.5), (0.9, 9)),
+    # a start that cannot be one (after end - seconds; long before):
+    # the rule without a log
+    (5.0 + 100.0, (0.0, 1)),
+    (5.0 - 5000.0, (0.0, 1)),
+    (None, (0.0, 1)),
+])
+def test_window_is_the_generators_when_the_phase_returned_late(
+        t0, expect):
+    early = [rec(965.5 + i, 0.010, gc=0.001) for i in range(9)]
+    read_back = [rec(990.5, 0.010)]
+    # every record's pair says perf 5.0 was that record's unix stamp;
+    # the first record's (965.5) is the one used
+    for r in early + read_back:
+        r["clock"] = [5.0 + (r["clock"][1] - 990.5), r["clock"][1]]
+    got = reader("mark_rate")(facts(early + read_back, t0=t0),
+                              marks=["gc"])
+    assert got == pytest.approx(expect)
+    stall = reader("stall_rate")(facts(early + read_back, t0=t0))
+    assert stall == pytest.approx((0.0, expect[1]))
+
+
+@pytest.mark.parametrize("reduction,expect", [
+    (None, None),                                   # no device: a CPU
+    ({"idle_gaps": []}, None),
+    ({"idle_gaps": [["unattributed", 0.3], ["unattributed", 0.1]]},
+     (0.0, 2)),
+    ({"idle_gaps": [["svc.wal_fsync", 0.3], ["unattributed", 0.1]]},
+     (75.0, 2)),
+    ({"idle_gaps": [["svc.between_flushes", 0.02], ["py.gc", 0.02]]},
+     (100.0, 2)),
+])
+def test_idle_named(reduction, expect):
+    got = reader("idle_named")(facts([], reduction))
+    assert got == (pytest.approx(expect) if expect else None)
+
+
+def test_layer_files_name_readers_that_exist():
+    with open(os.path.join(os.path.dirname(BENCH),
+                           "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    names = [m["name"] for m in bench["per_layer"]]
+    assert names[-5:] == [*SPAN_METRICS, "idle_named_share"]
+    for name in names[-5:]:
+        with open(os.path.join(BENCH, "layers", name + ".json")) as f:
+            spec = json.load(f)
+        assert callable(reader(spec["reader"]))
+
+
+def test_rehearsal_prints_the_span_metrics_a_cpu_can_read():
+    by = rehearse("ycsb-a.ring64-n3-deep")
+    layer = by["per_layer"]
+    assert set(SPAN_METRICS) <= set(layer)
+    assert "idle_named_share" not in layer
+    assert layer["frontend_p50_ms"]["value"] > 0.0
+    assert layer["wal_fsync_p50_ms"]["value"] > 0.0
+    assert layer["flush_stall_ms_per_s"]["samples"] > 0
+    assert layer["gc_pause_ms_per_s"]["value"] >= 0.0

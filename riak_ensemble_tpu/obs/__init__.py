@@ -20,6 +20,22 @@ stack reports into:
   enqueue/step/d2h/unpack/WAL/delta-build spans and replica-side
   validate/scatter/rebuild/WAL spans join into ONE causal timeline
   per flush (the Dapper propagation model, scoped to the flush).
+  It also holds the SPAN PRIMITIVE (``SpanRecorder.span``), the one
+  way the served path times host work: each span stamps its start
+  and duration (``perf_counter``) into the flush's record —
+  ``rec[mark]`` seconds, ``rec["starts"][mark]``, one
+  ``rec["clock"] = (perf_counter, time.time())`` pair per record —
+  and, with ``RETPU_OBS`` on, is a ``jax.profiler.TraceAnnotation``
+  ``svc.<mark>`` on the device trace's own clock.  The flush's marks,
+  the inside of the WAL barrier (``wal_encode``/``wal_append``/
+  ``wal_fsync``), the front end (``fe_decode``/``fe_dispatch``/
+  ``fe_reply``/``fe_reply_direct``: three spans a request, so taken
+  in one loop cycle in eight and in every cycle of a profiler
+  session), the loop's ``between_flushes``, the collector's
+  pauses (``GcWatch``: mark ``gc``, annotation ``py.gc``,
+  ``stats()["gc"]``) and the plane's own cost (``obs``) all go
+  through it; ``stats()["frontend"]`` counts the wire's frames and
+  bytes.  docs/ARCHITECTURE.md §11 has the span table.
 - :mod:`.flightrec` — a flight recorder: bounded ring of complete
   per-flush records (marks, batch shape, active-set occupancy,
   payload bytes, queue depths) with an anomaly trigger — any flush

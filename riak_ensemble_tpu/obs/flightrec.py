@@ -72,7 +72,20 @@ DUMP_SCHEMA = "retpu-flight-dump-v4"
 #: can never be additive in one place and excluded in the other (it
 #: would dominate every tail attribution).
 DERIVED_MARKS = ("enqueue", "resolve_native", "resolve_fallback",
-                 "enqueue_native", "enqueue_fallback")
+                 "enqueue_native", "enqueue_fallback",
+                 # the span primitive's subdivisions (obs.spans): the
+                 # pack stage of flush() (inside queue_wait), the
+                 # inside of the WAL barrier (inside wal), the front
+                 # end's reply (inside resolve) and its decode,
+                 # dispatch and direct replies (between flushes), the
+                 # loop thread's
+                 # stretch between flushes, the collector's pauses
+                 # (inside whichever mark they interrupted) and the
+                 # obs plane's own cost
+                 "pack", "wal_encode", "wal_append", "wal_fsync",
+                 "fe_decode", "fe_dispatch", "fe_reply",
+                 "fe_reply_direct",
+                 "between_flushes", "gc", "obs")
 
 #: per-flush record fields that are shape/identity metadata or
 #: derived marks, not additive latency components — shared with
@@ -80,7 +93,10 @@ DERIVED_MARKS = ("enqueue", "resolve_native", "resolve_fallback",
 #: never drift apart
 META_FIELDS = ("k", "total") + DERIVED_MARKS + (
     "flush_id", "t", "a_width", "payload_bytes", "queued_rounds",
-    "in_flight")
+    "in_flight",
+    # obs.spans: {mark: first start, perf_counter} and the record's
+    # (perf_counter, time.time()) anchor
+    "starts", "clock")
 
 
 class FlightRecorder:
@@ -265,9 +281,3 @@ class FlightRecorder:
                 os.unlink(p)
             except OSError:
                 pass  # a racing rotator already took it
-
-    def marks_tail(self, n: int) -> List[Dict[str, Any]]:
-        """The newest ``n`` records (oldest first) — the bench's
-        tail-attribution source."""
-        recs = list(self.records)
-        return recs[-n:] if n else []

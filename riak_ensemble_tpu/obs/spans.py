@@ -22,16 +22,255 @@ smoke shape) share this store with their leader, so the join is
 immediate.  Subprocess replicas record into their own process's
 store; the leader's id still names their spans, and the join happens
 wherever both exports land.
+
+The span primitive
+------------------
+
+:class:`SpanRecorder` is the ONE way the served path times a stretch
+of host work (one recorder per service).  ``with spans.span("wal",
+rec):`` stamps the stretch's START and DURATION on
+``time.perf_counter()`` into the flush's record — ``rec["wal"]`` is
+the mark (float seconds, summed over repeats), ``rec["starts"]
+["wal"]`` the first start stamp — and, when the recorder annotates
+(``RETPU_OBS`` on), holds a ``jax.profiler.TraceAnnotation`` named
+``svc.wal`` open over the same stretch, so that in a profiler session
+the program's spans lie in the ``/host:CPU`` plane on the device
+trace's own clock.  A record is born in :meth:`SpanRecorder.begin`
+with ``rec["clock"] = (perf_counter, time.time())``, one pair read
+back to back, so a reader in another process places every stamp on
+the wall clock: ``unix = clock[1] + (start - clock[0])``.
+
+Code that times work for a flush but has no record at hand (the WAL
+barrier's inside, the front end's reply, the collector's pauses)
+passes no record and lands in :attr:`SpanRecorder.open` — the record
+of the launch being settled, else :attr:`SpanRecorder.loop`, which
+collects what the loop thread did between flushes (front-end decode
+and dispatch, ``between_flushes``, pauses of the collector) until
+:meth:`SpanRecorder.close` folds it into the record of the flush that
+settles next.
 """
 
 from __future__ import annotations
 
+import contextlib
+import gc
 import itertools
 import threading
-from collections import OrderedDict
-from typing import Any, Dict, List, Tuple
+import time
+from collections import OrderedDict, deque
+from typing import Any, Dict, List, Optional, Tuple
 
-__all__ = ["next_flush_id", "SpanStore", "SPANS", "timeline"]
+__all__ = ["next_flush_id", "SpanStore", "SPANS", "timeline",
+           "SpanRecorder", "Span", "NULL_SPAN", "GcWatch"]
+
+_now = time.perf_counter
+
+
+class Span:
+    """One timed stretch: a context manager, or ``begin()`` /
+    ``end()`` where the stretch spans loop callbacks.  A span that
+    names no record may be kept and entered again (one stretch at a
+    time): the front end times every request through four of them
+    and makes no object per request."""
+
+    __slots__ = ("_owner", "_rec", "name", "seconds", "_label",
+                 "_ann", "_t0")
+
+    def __init__(self, owner: "SpanRecorder", rec: Optional[dict],
+                 name: str, label: Optional[str]) -> None:
+        self._owner = owner
+        self._rec = rec
+        #: the mark; may be set before the end, where only the
+        #: stretch itself tells which arm ran
+        self.name = name
+        self.seconds = 0.0
+        self._label = label or "svc." + name
+        self._ann = None
+
+    def __enter__(self) -> "Span":
+        # a TraceAnnotation decides at CONSTRUCTION whether a profiler
+        # session records it, so one is made per stretch, and only
+        # while a session is on (the check costs a tenth of making one)
+        make = self._owner._annotation
+        if make is not None and make.is_enabled():
+            self._ann = make(self._label)
+            self._ann.__enter__()
+        self._t0 = _now()
+        return self
+
+    def end(self) -> float:
+        """Close the stretch; its seconds."""
+        t0 = self._t0
+        dt = _now() - t0
+        rec = self._rec
+        if rec is None:
+            rec = self._owner.open
+        self.seconds = dt
+        name = self.name
+        rec[name] = rec.get(name, 0.0) + dt
+        starts = rec.get("starts")
+        if starts is None:
+            starts = rec["starts"] = {}
+        if name not in starts:
+            starts[name] = t0
+        ann = self._ann
+        if ann is not None:
+            self._ann = None
+            ann.__exit__(None, None, None)
+        return dt
+
+    def __exit__(self, *exc) -> bool:
+        self.end()
+        return False
+
+    begin = __enter__
+
+
+#: what a call site holds where spans are off (``RETPU_OBS=0``)
+NULL_SPAN = contextlib.nullcontext()
+
+
+class SpanRecorder:
+    """Per-service span recorder (module docstring, "The span
+    primitive").  ``annotate`` is the service's ``RETPU_OBS`` gate:
+    off, spans still stamp the record (the marks every reader of
+    ``lat_records`` expects) and open no profiler annotation."""
+
+    #: The front end's spans run three to a REQUEST, not a handful to
+    #: a flush, and at 2,000 requests a second that showed end to end
+    #: (``read_p50_ms`` of ``ring64-n3-deep`` +10%, PERF.md §6), so
+    #: they are taken in one loop cycle in this many, whole (a sampled
+    #: cycle's record carries its front end complete, the others carry
+    #: none), and in every cycle of a profiler session.
+    DETAIL_EVERY = 8
+
+    def __init__(self, annotate: bool = False) -> None:
+        self._annotation = None
+        if annotate:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
+        #: what the loop thread did outside any flush record
+        self.loop: Dict[str, Any] = {"starts": {}}
+        #: where a span that names no record lands
+        self.open: Dict[str, Any] = self.loop
+        self._between: Optional[Span] = None
+        #: whether the cycle under way is one the front end is timed in
+        self.detail = False
+        self._cycles = 0
+
+    @staticmethod
+    def begin() -> Dict[str, Any]:
+        """A new flush record, anchored on both clocks."""
+        return {"starts": {}, "clock": (_now(), time.time())}
+
+    def span(self, name: str, rec: Optional[dict] = None,
+             label: Optional[str] = None) -> Span:
+        return Span(self, rec, name, label)
+
+    @property
+    def is_settling(self) -> bool:
+        """Whether a launch's record is open (a span that names no
+        record then belongs to that flush, not to the loop)."""
+        return self.open is not self.loop
+
+    def settling(self, rec: Optional[dict]) -> None:
+        """Point :attr:`open` at the launch being settled (None:
+        back at the loop's own record)."""
+        self.open = self.loop if rec is None else rec
+
+    def close(self, rec: dict) -> None:
+        """Fold what the loop did since the last close into ``rec``,
+        the record of the flush that settles now."""
+        loop = self.loop
+        if len(loop) == 1:
+            return
+        starts = rec["starts"]
+        for name, t0 in loop.pop("starts").items():
+            starts.setdefault(name, t0)
+        for name, dt in loop.items():
+            rec[name] = rec.get(name, 0.0) + dt
+        loop.clear()
+        loop["starts"] = {}
+
+    def between_begin(self) -> None:
+        """The loop thread leaves a flush: everything until
+        :meth:`between_end` is ``between_flushes`` (the front end's
+        spans nest inside it)."""
+        if self._between is None:
+            self._cycles += 1
+            make = self._annotation
+            self.detail = (self._cycles % self.DETAIL_EVERY == 0
+                           or (make is not None and make.is_enabled()))
+            self._between = self.span("between_flushes", self.loop)
+            self._between.begin()
+
+    def between_end(self) -> None:
+        sp, self._between = self._between, None
+        if sp is not None:
+            sp.end()
+
+
+class GcWatch:
+    """The collector's pauses, through ``gc.callbacks``: each pause is
+    a span ``gc`` (annotation ``py.gc``) into the recorder's open
+    record, a count and seconds per generation, and, at 1 ms or more,
+    an entry ``(generation, start_unix, ms)`` in :attr:`recent`."""
+
+    SLOW_MS = 1.0
+
+    def __init__(self, recorder: SpanRecorder) -> None:
+        self._recorder = recorder
+        self._span = recorder.span("gc", label="py.gc")
+        self._collecting = False
+        self.pauses = [0, 0, 0]
+        self.seconds = [0.0, 0.0, 0.0]
+        self.longest_ms = 0.0
+        self.recent: "deque[Tuple[int, float, float]]" = deque(maxlen=64)
+        self.installed = False
+
+    def install(self) -> None:
+        if not self.installed:
+            gc.callbacks.append(self._on_gc)
+            self.installed = True
+
+    def remove(self) -> None:
+        if self.installed:
+            self.installed = False
+            try:
+                gc.callbacks.remove(self._on_gc)
+            except ValueError:
+                pass
+
+    def _on_gc(self, phase: str, info: Dict[str, int]) -> None:
+        if phase == "start":
+            self._collecting = True
+            self._span.begin()
+            return
+        if not self._collecting:    # installed inside a collection
+            return
+        self._collecting = False
+        dt = self._span.end()
+        gen = min(int(info.get("generation", 2)), 2)
+        self.pauses[gen] += 1
+        self.seconds[gen] += dt
+        ms = dt * 1e3
+        if ms > self.longest_ms:
+            self.longest_ms = ms
+        if ms >= self.SLOW_MS:
+            self.recent.append((gen, time.time() - dt, round(ms, 3)))
+
+    def stats(self) -> Dict[str, Any]:
+        return {
+            "installed": self.installed,
+            "pauses": sum(self.pauses),
+            "seconds": sum(self.seconds),
+            "by_generation": {
+                str(g): {"pauses": self.pauses[g],
+                         "seconds": self.seconds[g]}
+                for g in range(3)},
+            "longest_ms": self.longest_ms,
+            "recent": [list(p) for p in self.recent],
+        }
 
 #: process-wide monotonic flush ids — shared by every service in the
 #: process so leader and in-process replica launches never collide

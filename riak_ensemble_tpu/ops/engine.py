@@ -151,6 +151,15 @@ def merge_vals(cur: jax.Array, mcls: jax.Array,
 TREE_WIDTH = 16
 
 
+#: ``jax.named_scope`` names on the step's phases (metadata only: they
+#: reach the HLO's ``op_name`` and from there a profiler trace, where
+#: ``tools/trace_scopes.py`` groups the device's time by them).
+#: ``result_pack`` also wraps the packers of ``parallel.batched_host``.
+SCOPES = ("elect", "quorum", "slot_gather", "merkle_verify", "apply",
+          "merkle_write", "slot_scatter", "slice_columns",
+          "scatter_columns", "result_pack")
+
+
 class EngineState(NamedTuple):
     """Ballot + replicated-store + integrity state for E ensembles x M
     peers.
@@ -621,8 +630,9 @@ def _kv_context(state: EngineState, up: jax.Array,
                              axis_name) > 0
     # Epoch-check acks: shared by put replication and non-leased reads.
     ack = heard & (state.epoch == lead_epoch[:, None])
-    epoch_ok = (_quorum_met(ack, heard, state.view_mask, axis_name)
-                & has_leader & leader_up)
+    with jax.named_scope("quorum"):
+        epoch_ok = (_quorum_met(ack, heard, state.view_mask, axis_name)
+                    & has_leader & leader_up)
     n_member = reduce_peers(member.astype(jnp.int32), axis_name)
     return _KvCtx(heard=heard, leader_up=leader_up & has_leader,
                   lead_epoch=lead_epoch, epoch_ok=epoch_ok,
@@ -680,8 +690,9 @@ def _kv_round(state: EngineState, ctx: _KvCtx, kind: jax.Array,
     # Per-replica object at each lane's slot: ONE gather per plane
     # (invalid slots read the absent object).
     def at_slot(plane):
-        return jnp.take_along_axis(
-            plane, slot_c[:, None, :], axis=2)               # [E, Ml, W]
+        with jax.named_scope("slot_gather"):
+            return jnp.take_along_axis(
+                plane, slot_c[:, None, :], axis=2)           # [E, Ml, W]
     sv = slot_valid[:, None, :]
     pe = jnp.where(sv, at_slot(state.obj_epoch), 0)
     ps = jnp.where(sv, at_slot(state.obj_seq), 0)
@@ -689,10 +700,12 @@ def _kv_round(state: EngineState, ctx: _KvCtx, kind: jax.Array,
 
     # Integrity gate (tree-is-truth, synctree.erl:44-73): the object
     # must match its leaf, and the slot's root-ward path must verify.
-    leaf = jnp.take_along_axis(
-        state.tree_leaf, slot_c[:, None, :, None], axis=2)   # [E,Ml,W,L]
-    leaf_ok = (leaf == hashk.obj_leaf_hash(pe, ps, pv)).all(-1)
-    path_bad = _verify_path(state.tree_leaf, state.tree_node, slot_c)
+    with jax.named_scope("merkle_verify"):
+        leaf = jnp.take_along_axis(
+            state.tree_leaf, slot_c[:, None, :, None], axis=2)  # [E,Ml,W,L]
+        leaf_ok = (leaf == hashk.obj_leaf_hash(pe, ps, pv)).all(-1)
+        path_bad = _verify_path(state.tree_leaf, state.tree_node,
+                                slot_c)
     replica_ok = heard3 & leaf_ok & ~path_bad                # [E, Ml, W]
     tree_corrupt = ((path_bad | ~leaf_ok) & heard3
                     & (active & slot_valid)[:, None, :]).any(-1)
@@ -708,11 +721,12 @@ def _kv_round(state: EngineState, ctx: _KvCtx, kind: jax.Array,
     # (they win/lose by (epoch, seq) and replicate like any object)
     # but read back as notfound, exactly like the reference's notfound
     # obj (peer.erl:1568-1584).
-    rd_epoch, rd_seq, rd_val, obj_found = _latest_among(
-        pe.transpose(0, 2, 1), ps.transpose(0, 2, 1),
-        pv.transpose(0, 2, 1), ok_t, axis_name)              # each [E, W]
+    with jax.named_scope("quorum"):
+        rd_epoch, rd_seq, rd_val, obj_found = _latest_among(
+            pe.transpose(0, 2, 1), ps.transpose(0, 2, 1),
+            pv.transpose(0, 2, 1), ok_t, axis_name)          # each [E, W]
+        n_ok = reduce_peers(ok_t.astype(jnp.int32), axis_name)  # [E, W]
     found = obj_found & (rd_val != 0)
-    n_ok = reduce_peers(ok_t.astype(jnp.int32), axis_name)   # [E, W]
     all_ok = n_ok == ctx.n_member[:, None]
 
     get_gate = is_get & leader_up & (lease_ok | epoch_ok)
@@ -733,88 +747,92 @@ def _kv_round(state: EngineState, ctx: _KvCtx, kind: jax.Array,
     # would let a single GET tombstone over a committed object.
     # Out-of-range slots never held data: plain notfound.
     nf = get_gate & ~obj_found
-    nf_quorum = _quorum_met(
-        ok_t, jnp.broadcast_to(heard[:, None, :], ok_t.shape),
-        jnp.broadcast_to(state.view_mask[:, None],
-                         (e, w) + state.view_mask.shape[1:]),
-        axis_name)                                           # [E, W]
-    nf_write = nf & slot_valid & ~all_ok & epoch_ok & nf_quorum
-    get_ok = ((get_gate & obj_found & (~stale | rewrite))
-              | (nf & (all_ok | ~slot_valid | nf_write)))
+    with jax.named_scope("quorum"):
+        nf_quorum = _quorum_met(
+            ok_t, jnp.broadcast_to(heard[:, None, :], ok_t.shape),
+            jnp.broadcast_to(state.view_mask[:, None],
+                             (e, w) + state.view_mask.shape[1:]),
+            axis_name)                                       # [E, W]
+    # (scope "apply": the decision what commits, with which value
+    # and seq, and which replicas a read repairs)
+    with jax.named_scope("apply"):
+        nf_write = nf & slot_valid & ~all_ok & epoch_ok & nf_quorum
+        get_ok = ((get_gate & obj_found & (~stale | rewrite))
+                  | (nf & (all_ok | ~slot_valid | nf_write)))
 
-    # Commit path (shared by put, CAS, rewrite and notfound
-    # tombstone).  CAS compares the expected version against the
-    # slot's CURRENT stored version atomically within this round (the
-    # do_kupdate (epoch, seq) equality, peer.erl:259-270 — atomic
-    # because no other lane in the round touches this slot);
-    # expecting (0, 0) on an absent slot is create-if-missing
-    # (do_kput_once, :278-284).  A tombstone counts as an existing
-    # version for the compare (ksafe_delete reads the tombstone's vsn)
-    # but val 0 still reads back notfound.
-    put_commit = is_put & epoch_ok & slot_valid
-    exp_absent = (exp_epoch == 0) & (exp_seq == 0)
-    # (0, 0) matches a tombstone as well as true absence — put-once
-    # succeeds over a notfound-valued object (do_kput_once,
-    # peer.erl:278-284) — and TRUE absence additionally needs a quorum
-    # of hash-valid notfound answers (same nf_quorum guard as the GET
-    # tombstone path): without it, corrupting every holder's leaves
-    # would let a (0,0) CAS overwrite committed data the integrity
-    # gate excluded.
-    vsn_match = ((obj_found & (rd_epoch == exp_epoch)
-                  & (rd_seq == exp_seq))
-                 | (exp_absent & obj_found & (rd_val == 0))
-                 | (exp_absent & ~obj_found & nf_quorum))
-    cas_commit = is_cas & epoch_ok & slot_valid & vsn_match
+        # Commit path (shared by put, CAS, rewrite and notfound
+        # tombstone).  CAS compares the expected version against the
+        # slot's CURRENT stored version atomically within this round (the
+        # do_kupdate (epoch, seq) equality, peer.erl:259-270 — atomic
+        # because no other lane in the round touches this slot);
+        # expecting (0, 0) on an absent slot is create-if-missing
+        # (do_kput_once, :278-284).  A tombstone counts as an existing
+        # version for the compare (ksafe_delete reads the tombstone's vsn)
+        # but val 0 still reads back notfound.
+        put_commit = is_put & epoch_ok & slot_valid
+        exp_absent = (exp_epoch == 0) & (exp_seq == 0)
+        # (0, 0) matches a tombstone as well as true absence — put-once
+        # succeeds over a notfound-valued object (do_kput_once,
+        # peer.erl:278-284) — and TRUE absence additionally needs a quorum
+        # of hash-valid notfound answers (same nf_quorum guard as the GET
+        # tombstone path): without it, corrupting every holder's leaves
+        # would let a (0,0) CAS overwrite committed data the integrity
+        # gate excluded.
+        vsn_match = ((obj_found & (rd_epoch == exp_epoch)
+                      & (rd_seq == exp_seq))
+                     | (exp_absent & obj_found & (rd_val == 0))
+                     | (exp_absent & ~obj_found & nf_quorum))
+        cas_commit = is_cas & epoch_ok & slot_valid & vsn_match
 
-    # Device RMW (OP_RMW): fn(cur, operand) committed in THIS round —
-    # the fused kmodify.  ``cur`` is the round's own latest-object
-    # read (tombstones and verified absence read as 0, the engine's
-    # notfound value), so concurrent RMWs of one slot serialize
-    # through round order with no conflict window.  Absence must be
-    # VERIFIED (the same nf_quorum guard as the (0,0)-CAS create):
-    # treating not-found-because-every-holder-is-corrupt as 0 would
-    # overwrite committed data the integrity gate excluded.
-    fn = exp_epoch                                           # [E, W]
-    cur = jnp.where(obj_found, rd_val, 0)
-    new_rmw = jnp.select(
-        [fn == RMW_ADD, fn == RMW_SUB, fn == RMW_MAX, fn == RMW_MIN,
-         fn == RMW_SET, fn == RMW_BAND, fn == RMW_BOR,
-         fn == RMW_BXOR],
-        [cur + val, cur - val, jnp.maximum(cur, val),
-         jnp.minimum(cur, val), val, cur & val, cur | val, cur ^ val],
-        default=val)                  # RMW_PIA commits the operand
-    rmw_absent = ((obj_found & (rd_val == 0))
-                  | (~obj_found & nf_quorum))
-    rmw_known = obj_found | nf_quorum
-    rmw_commit = (is_rmw & epoch_ok & slot_valid
-                  & jnp.where(fn == RMW_PIA, rmw_absent, rmw_known))
+        # Device RMW (OP_RMW): fn(cur, operand) committed in THIS round —
+        # the fused kmodify.  ``cur`` is the round's own latest-object
+        # read (tombstones and verified absence read as 0, the engine's
+        # notfound value), so concurrent RMWs of one slot serialize
+        # through round order with no conflict window.  Absence must be
+        # VERIFIED (the same nf_quorum guard as the (0,0)-CAS create):
+        # treating not-found-because-every-holder-is-corrupt as 0 would
+        # overwrite committed data the integrity gate excluded.
+        fn = exp_epoch                                           # [E, W]
+        cur = jnp.where(obj_found, rd_val, 0)
+        new_rmw = jnp.select(
+            [fn == RMW_ADD, fn == RMW_SUB, fn == RMW_MAX, fn == RMW_MIN,
+             fn == RMW_SET, fn == RMW_BAND, fn == RMW_BOR,
+             fn == RMW_BXOR],
+            [cur + val, cur - val, jnp.maximum(cur, val),
+             jnp.minimum(cur, val), val, cur & val, cur | val, cur ^ val],
+            default=val)                  # RMW_PIA commits the operand
+        rmw_absent = ((obj_found & (rd_val == 0))
+                      | (~obj_found & nf_quorum))
+        rmw_known = obj_found | nf_quorum
+        rmw_commit = (is_rmw & epoch_ok & slot_valid
+                      & jnp.where(fn == RMW_PIA, rmw_absent, rmw_known))
 
-    commit = (put_commit | cas_commit | rewrite | nf_write
-              | rmw_commit)                                  # [E, W]
-    wval = jnp.where(is_put | is_cas, val,
-                     jnp.where(is_rmw, new_rmw,
-                               jnp.where(rewrite, rd_val, 0)))
+        commit = (put_commit | cas_commit | rewrite | nf_write
+                  | rmw_commit)                                  # [E, W]
+        wval = jnp.where(is_put | is_cas, val,
+                         jnp.where(is_rmw, new_rmw,
+                                   jnp.where(rewrite, rd_val, 0)))
 
-    # Commit seqs advance in lane order (obj_sequence, peer.erl:1776-
-    # 1791): lane w's seq is ctr + (commits among lanes <= w), exactly
-    # the values W sequential rounds would assign.
-    ranks = jnp.cumsum(commit.astype(jnp.int32), axis=1)     # [E, W]
-    new_seq = state.obj_seq_ctr[:, None] + ranks
+        # Commit seqs advance in lane order (obj_sequence, peer.erl:1776-
+        # 1791): lane w's seq is ctr + (commits among lanes <= w), exactly
+        # the values W sequential rounds would assign.
+        ranks = jnp.cumsum(commit.astype(jnp.int32), axis=1)     # [E, W]
+        new_seq = state.obj_seq_ctr[:, None] + ranks
 
-    # Read repair (maybe_repair, peer.erl:1518-1536): a successful
-    # current-epoch read heals reachable replicas that lag the winning
-    # version or failed the integrity gate (re-writing the slot also
-    # recomputes their hash path, healing tree corruption).
-    plain_read = get_ok & obj_found & ~rewrite               # [E, W]
-    divergent = heard3 & ((pe != rd_epoch[:, None, :])
-                          | (ps != rd_seq[:, None, :])
-                          | ~leaf_ok | path_bad)
-    repair = plain_read[:, None, :] & divergent              # [E, Ml, W]
+        # Read repair (maybe_repair, peer.erl:1518-1536): a successful
+        # current-epoch read heals reachable replicas that lag the winning
+        # version or failed the integrity gate (re-writing the slot also
+        # recomputes their hash path, healing tree corruption).
+        plain_read = get_ok & obj_found & ~rewrite               # [E, W]
+        divergent = heard3 & ((pe != rd_epoch[:, None, :])
+                              | (ps != rd_seq[:, None, :])
+                              | ~leaf_ok | path_bad)
+        repair = plain_read[:, None, :] & divergent              # [E, Ml, W]
 
-    w_epoch = jnp.where(commit, lead_epoch, rd_epoch)        # [E, W]
-    w_seq = jnp.where(commit, new_seq, rd_seq)
-    w_val = jnp.where(commit, wval, rd_val)
-    do_write = (commit[:, None, :] & heard3) | repair        # [E, Ml, W]
+        w_epoch = jnp.where(commit, lead_epoch, rd_epoch)        # [E, W]
+        w_seq = jnp.where(commit, new_seq, rd_seq)
+        w_val = jnp.where(commit, wval, rd_val)
+        do_write = (commit[:, None, :] & heard3) | repair        # [E, Ml, W]
 
     # Scatter, not full-plane where: per round only the touched slot
     # columns move through HBM (in place inside the kv scan's carry).
@@ -825,8 +843,10 @@ def _kv_round(state: EngineState, ctx: _KvCtx, kind: jax.Array,
     sl2 = jnp.where(do_write, slot_c[:, None, :], s)         # [E, Ml, W]
 
     def set_slot(plane, new):
-        return plane.at[eidx, midx, sl2].set(
-            jnp.broadcast_to(new[:, None, :], (e, ml, w)), mode="drop")
+        with jax.named_scope("slot_scatter"):
+            return plane.at[eidx, midx, sl2].set(
+                jnp.broadcast_to(new[:, None, :], (e, ml, w)),
+                mode="drop")
 
     obj_epoch = set_slot(state.obj_epoch, w_epoch)
     obj_seq = set_slot(state.obj_seq, w_seq)
@@ -836,9 +856,11 @@ def _kv_round(state: EngineState, ctx: _KvCtx, kind: jax.Array,
     # Synchronous tree maintenance: leaves + root-ward paths, same
     # round.  Lanes sharing a path parent recompute it identically
     # from the post-scatter children, so duplicate targets agree.
-    new_leaf = hashk.obj_leaf_hash(w_epoch, w_seq, w_val)    # [E, W, L]
-    tree_leaf, tree_node = _write_path(
-        state.tree_leaf, state.tree_node, slot_c, new_leaf, do_write)
+    with jax.named_scope("merkle_write"):
+        new_leaf = hashk.obj_leaf_hash(w_epoch, w_seq, w_val)  # [E, W, L]
+        tree_leaf, tree_node = _write_path(
+            state.tree_leaf, state.tree_node, slot_c, new_leaf,
+            do_write)
 
     # Version reported for any served object INCLUDING tombstones —
     # the reference's kget hands back the notfound obj with its vsn,
@@ -1088,10 +1110,11 @@ def gather_result_columns(res: KvResult,
     """
     def take(x):
         return jnp.take(x, active_idx, axis=1)
-    return res._replace(
-        committed=take(res.committed), get_ok=take(res.get_ok),
-        found=take(res.found), value=take(res.value),
-        obj_vsn=take(res.obj_vsn))
+    with jax.named_scope("result_pack"):
+        return res._replace(
+            committed=take(res.committed), get_ok=take(res.get_ok),
+            found=take(res.found), value=take(res.value),
+            obj_vsn=take(res.obj_vsn))
 
 
 # ---------------------------------------------------------------------------
@@ -1399,7 +1422,9 @@ def _full_step_body(state: EngineState, elect: jax.Array, cand: jax.Array,
     ensembles need elections (failure detection is host-side), the
     device does all the protocol math.
     """
-    state, won = elect_step(state, elect, cand, up, axis_name=axis_name)
+    with jax.named_scope("elect"):
+        state, won = elect_step(state, elect, cand, up,
+                                axis_name=axis_name)
     state, res = kv_step_scan(state, kind, slot, val, lease_ok, up,
                               axis_name=axis_name, exp_epoch=exp_epoch,
                               exp_seq=exp_seq)
@@ -1435,7 +1460,9 @@ def _full_step_wide_body(state: EngineState, elect: jax.Array,
     Carries :func:`kv_step_scan_wide`'s precondition: valid slots must
     be distinct within every ``[g, e]`` row (see its docstring;
     :func:`validate_wide_plane` checks concrete planes)."""
-    state, won = elect_step(state, elect, cand, up, axis_name=axis_name)
+    with jax.named_scope("elect"):
+        state, won = elect_step(state, elect, cand, up,
+                                axis_name=axis_name)
     state, res = kv_step_scan_wide(
         state, kind, slot, val, lease_ok, up, axis_name=axis_name,
         exp_epoch=exp_epoch, exp_seq=exp_seq)
@@ -1464,9 +1491,10 @@ def _slice_columns(state: EngineState, active_idx: jax.Array,
     lanes are NOOP/elect-False so they never write, and the scatter
     drops them."""
     e = state.epoch.shape[0]
-    idx_c = jnp.clip(active_idx, 0, e - 1)
-    sub = jax.tree.map(lambda x: jnp.take(x, idx_c, axis=0), state)
-    return sub, jnp.take(up, idx_c, axis=0)
+    with jax.named_scope("slice_columns"):
+        idx_c = jnp.clip(active_idx, 0, e - 1)
+        sub = jax.tree.map(lambda x: jnp.take(x, idx_c, axis=0), state)
+        return sub, jnp.take(up, idx_c, axis=0)
 
 
 def _scatter_columns(state: EngineState, sub: EngineState,
@@ -1476,9 +1504,10 @@ def _scatter_columns(state: EngineState, sub: EngineState,
     real indices are distinct, so the scatter is conflict-free.
     With the full state donated, this lowers to an in-place update
     of the A touched rows instead of a full-plane copy."""
-    return jax.tree.map(
-        lambda full, s: full.at[active_idx].set(s, mode="drop"),
-        state, sub)
+    with jax.named_scope("scatter_columns"):
+        return jax.tree.map(
+            lambda full, s: full.at[active_idx].set(s, mode="drop"),
+            state, sub)
 
 
 def _full_step_sliced_body(state: EngineState, active_idx: jax.Array,

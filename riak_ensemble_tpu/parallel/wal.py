@@ -40,6 +40,7 @@ import zlib
 from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
 from riak_ensemble_tpu import faults
+from riak_ensemble_tpu.obs.spans import SpanRecorder
 from riak_ensemble_tpu.save import fsync_dir
 
 #: sync modes: "fsync" forces records to stable storage before the ack
@@ -305,6 +306,12 @@ class ServiceWAL:
         #: active ``RETPU_FAULT_FSYNC_MS``/programmatic plan);
         #: assign a callable for a WAL-local override.
         self.sync_hook: Callable[[], None] = faults.fsync_sleep
+        #: where the barrier's inside is timed (obs.spans): the spans
+        #: ``wal_append`` and ``wal_fsync`` go to the open record of
+        #: this recorder.  The owning service assigns its own, so they
+        #: land in the record of the flush that waits on the barrier;
+        #: a WAL on its own times into a recorder nobody reads.
+        self.spans = SpanRecorder()
         # The underlying stores are not thread-safe; a replica host's
         # promise grants (connection threads) and its apply/campaign
         # writes (other threads) share one WAL.
@@ -316,8 +323,15 @@ class ServiceWAL:
         complete before the writes it covers are acked."""
         with self._lock:
             faults.crashpoint("wal_append")
-            for key, value in records:
-                self._store.store(key, value)
+            with self.spans.span("wal_append"):
+                for key, value in records:
+                    self._store.store(key, value)
+            self._barrier()
+
+    def _barrier(self) -> None:
+        """Make what was appended durable per the sync mode (call
+        under the lock): the span ``wal_fsync``."""
+        with self.spans.span("wal_fsync"):
             if self.sync_mode == "fsync":
                 faults.crashpoint("wal_fsync_pre")
                 self.sync_hook()
@@ -346,23 +360,18 @@ class ServiceWAL:
         native/fallback equivalence contract)."""
         with self._lock:
             faults.crashpoint("wal_append")
-            st = self._store
-            put_many = getattr(st, "put_many_raw", None)
-            if put_many is not None:
-                put_many(arena, index)
-            else:
-                for koff, klen, voff, vlen in index.tolist():
-                    st.store_raw(bytes(arena[koff:koff + klen]),
-                                 bytes(arena[voff:voff + vlen]))
-            for key, value in extra_records:
-                st.store(key, value)
-            if self.sync_mode == "fsync":
-                faults.crashpoint("wal_fsync_pre")
-                self.sync_hook()
-                self._store.sync()
-                faults.crashpoint("wal_fsync_post")
-            else:
-                self._flush_store()
+            with self.spans.span("wal_append"):
+                st = self._store
+                put_many = getattr(st, "put_many_raw", None)
+                if put_many is not None:
+                    put_many(arena, index)
+                else:
+                    for koff, klen, voff, vlen in index.tolist():
+                        st.store_raw(bytes(arena[koff:koff + klen]),
+                                     bytes(arena[voff:voff + vlen]))
+                for key, value in extra_records:
+                    st.store(key, value)
+            self._barrier()
 
     def delete(self, keys: List[Any]) -> None:
         """Remove records (e.g. a destroyed ensemble's kv entries)
@@ -371,18 +380,12 @@ class ServiceWAL:
             faults.crashpoint("wal_append")
             for key in keys:
                 self._store.delete(key)
-            if self.sync_mode == "fsync":
-                faults.crashpoint("wal_fsync_pre")
-                self.sync_hook()
-                self._store.sync()
-                faults.crashpoint("wal_fsync_post")
-            else:
-                # Mirror log(): buffer mode still promises
-                # process-crash durability, and a destroy's kv
-                # deletions sitting in the userspace stdio buffer
-                # would die with the process — the destroyed tenant's
-                # records would replay into a recycled row (ADVICE r3).
-                self._flush_store()
+            # Mirror log(): buffer mode still promises process-crash
+            # durability, and a destroy's kv deletions sitting in the
+            # userspace stdio buffer would die with the process — the
+            # destroyed tenant's records would replay into a recycled
+            # row (ADVICE r3).
+            self._barrier()
 
     def records(self) -> List[Tuple[Any, Any]]:
         with self._lock:

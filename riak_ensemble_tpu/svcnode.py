@@ -133,6 +133,7 @@ from typing import Any, Dict, Optional, Tuple
 from riak_ensemble_tpu import faults, wire
 from riak_ensemble_tpu.config import Config, fast_test_config
 from riak_ensemble_tpu.netruntime import NetRuntime
+from riak_ensemble_tpu.obs.spans import NULL_SPAN
 from riak_ensemble_tpu.parallel.batched_host import BatchedEnsembleService
 from riak_ensemble_tpu.utils.jaxcache import setup_compile_cache
 
@@ -194,6 +195,17 @@ class ServiceServer:
         self.svc = svc
         self.host, self.port = host, port
         self._server: Optional[asyncio.AbstractServer] = None
+        # The front end's spans (obs.spans), taken in the loop cycles
+        # the recorder samples (``spans.detail``; none with
+        # RETPU_OBS=0): ``fe_decode``, ``fe_dispatch`` and
+        # ``fe_reply_direct`` (a reply written at once: a leased read,
+        # a verb) land in the loop's record and are taken by the flush
+        # that settles next; ``fe_reply`` (a reply written from a
+        # settling flush) runs inside that flush's ``resolve`` and
+        # lands in its record.  One span object per mark, entered
+        # again for every request.
+        self._spans = [svc.spans.span(name) for name in (
+            "fe_decode", "fe_dispatch", "fe_reply", "fe_reply_direct")]
 
     async def start(self) -> Tuple[str, int]:
         self._server = await asyncio.start_server(
@@ -300,6 +312,9 @@ class ServiceServer:
         # VERDICT/advisor backpressure finding).
         inflight = asyncio.Semaphore(_MAX_INFLIGHT)
         bp = self.svc.svc_backpressure
+        fe = self.svc.frontend
+        spans = self.svc.spans
+        fe_decode, fe_dispatch, fe_reply, fe_reply_direct = self._spans
 
         def send(req_id: Any, result: Any) -> None:
             # Responses are written from flush-context future waiters
@@ -308,11 +323,15 @@ class ServiceServer:
             # is only awaited on the request path).
             if writer.is_closing():
                 return
-            try:
-                payload = wire.encode((req_id, result))
-            except wire.WireError:
-                payload = wire.encode((req_id, "failed"))
-            writer.write(_HDR.pack(len(payload)) + payload)
+            with ((fe_reply if spans.is_settling else fe_reply_direct)
+                  if spans.detail else NULL_SPAN):
+                try:
+                    payload = wire.encode((req_id, result))
+                except wire.WireError:
+                    payload = wire.encode((req_id, "failed"))
+                writer.write(_HDR.pack(len(payload)) + payload)
+            fe["frames_out"] += 1
+            fe["bytes_out"] += _HDR.size + len(payload)
             transport = writer.transport
             if (transport is not None
                     and transport.get_write_buffer_size()
@@ -331,10 +350,13 @@ class ServiceServer:
                 if length > _MAX_FRAME:
                     break  # hostile length: drop the connection
                 frame = await reader.readexactly(length)
+                fe["frames_in"] += 1
+                fe["bytes_in"] += _HDR.size + length
                 try:
-                    msg = wire.decode(frame)
-                    req_id, op = msg[0], msg[1]
-                    args = tuple(msg[2:])
+                    with fe_decode if spans.detail else NULL_SPAN:
+                        msg = wire.decode(frame)
+                        req_id, op = msg[0], msg[1]
+                        args = tuple(msg[2:])
                 except (wire.WireError, IndexError, TypeError):
                     break  # malformed: drop the connection
                 if op == "stats":
@@ -426,7 +448,8 @@ class ServiceServer:
                     bp["inflight_stalls"] += 1
                 await inflight.acquire()
                 try:
-                    fut = self._dispatch(op, args)
+                    with fe_dispatch if spans.detail else NULL_SPAN:
+                        fut = self._dispatch(op, args)
                 except Exception:
                     # wrong arity / types from a hostile or buggy
                     # client: answer, don't let the task die with an

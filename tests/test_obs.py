@@ -503,3 +503,423 @@ def test_svcnode_metrics_verb():
             await server.stop()
 
     asyncio.run(run())
+
+
+# -- the span primitive (obs.spans; docs/ARCHITECTURE.md §11) ---------------
+
+ADDITIVE_D1 = ("h2d", "dispatch", "device_d2h", "unpack", "wal",
+               "resolve", "queue_wait")
+WAL_PARTS = ("wal_encode", "wal_append", "wal_fsync")
+
+
+@pytest.mark.parametrize("form", ["with", "begin_end", "open_record"])
+def test_span_stamps_start_and_duration(form):
+    """A span puts its seconds under ``rec[name]`` and its start on
+    ``perf_counter`` under ``rec["starts"][name]``; a record carries
+    one (perf_counter, time.time()) pair; a span that names no record
+    lands in the recorder's open one."""
+    sp = obs.spans.SpanRecorder()
+    before = time.perf_counter(), time.time()
+    rec = sp.begin()
+    if form == "with":
+        with sp.span("wal", rec):
+            pass
+    elif form == "begin_end":
+        s = sp.span("wal", rec).begin()
+        assert s.end() == s.seconds == rec["wal"]
+    else:
+        sp.settling(rec)
+        with sp.span("wal"):
+            pass
+        sp.settling(None)
+        with sp.span("fe_decode"):
+            pass
+        assert "fe_decode" in sp.loop and "fe_decode" not in rec
+    after = time.perf_counter(), time.time()
+    assert isinstance(rec["wal"], float) and rec["wal"] >= 0.0
+    assert before[0] <= rec["clock"][0] <= rec["starts"]["wal"] \
+        <= after[0]
+    assert before[1] <= rec["clock"][1] <= after[1]
+
+
+def test_span_accumulates_repeats_and_renames():
+    """A mark that runs twice sums its seconds and keeps its FIRST
+    start; a span may be named at its end (the arm that ran)."""
+    sp = obs.spans.SpanRecorder()
+    rec = sp.begin()
+    with sp.span("resolve_fallback", rec) as arm:
+        pass
+    first = rec["starts"]["resolve_fallback"]
+    with sp.span("resolve_fallback", rec) as arm:
+        arm.name = "resolve_native"
+    with sp.span("resolve_fallback", rec) as again:
+        pass
+    assert rec["starts"]["resolve_fallback"] == first
+    assert rec["resolve_native"] == arm.seconds
+    assert rec["resolve_fallback"] >= again.seconds
+
+
+def test_loop_record_is_taken_by_the_next_close():
+    sp = obs.spans.SpanRecorder()
+    sp.between_begin()
+    with sp.span("fe_decode"):
+        pass
+    sp.between_end()
+    rec = sp.begin()
+    rec["wal"] = 1.0
+    sp.close(rec)
+    assert {"fe_decode", "between_flushes"} <= set(rec["starts"])
+    assert rec["fe_decode"] <= rec["between_flushes"]
+    assert sp.loop == {"starts": {}}
+    rec2 = sp.begin()
+    sp.close(rec2)              # nothing happened since: nothing taken
+    assert set(rec2) == {"starts", "clock"}
+
+
+@pytest.fixture(scope="module", params=[1, 2])
+def served_records(request, tmp_path_factory):
+    """The records of a few durable batch flushes (tick=None)."""
+    d = tmp_path_factory.mktemp(f"spans{request.param}")
+    svc = BatchedEnsembleService(
+        WallRuntime(), 8, 3, 8, tick=None, config=fast_test_config(),
+        data_dir=str(d), pipeline_depth=request.param,
+        max_ops_per_tick=2)
+    svc.flush()                 # elections
+    futs = [svc.kput_many(e, ["a", "b"], [b"1", b"2"])
+            for e in range(4)]
+    futs += [svc.kget_many(e, ["a", "b"]) for e in range(4)]
+    for _ in range(8):
+        svc.flush()
+    assert all(f.done for f in futs)
+    recs = [r for r in svc.lat_records if r.get("k") and "wal" in r]
+    assert recs
+    flight = list(svc.flight.records)
+    bd = svc.latency_breakdown()
+    svc.stop()
+    return request.param, recs, flight, bd
+
+
+@pytest.mark.parametrize("mark", ADDITIVE_D1 + (
+    "enqueue", "total", "k", "resolve_arm", "enqueue_arm"))
+def test_served_flush_keeps_every_mark(served_records, mark):
+    """Every mark a served flush's record carried before the primitive
+    is still there, a float of seconds under its own name."""
+    depth, recs, _flight, bd = served_records
+    if mark == "device_d2h" and depth > 1:
+        mark = "inflight_wait"
+    names = {"resolve_arm": ("resolve_native", "resolve_fallback"),
+             "enqueue_arm": ("enqueue_native", "enqueue_fallback")
+             }.get(mark, (mark,))
+    for r in recs:
+        got = [r[n] for n in names if n in r]
+        assert got, (mark, sorted(r))
+        assert all(isinstance(v, (float, int)) for v in got)
+    if mark != "k":
+        assert any(n in bd for n in names)
+
+
+def test_served_flush_total_is_the_sum_of_the_same_marks(
+        served_records):
+    depth, recs, flight, _bd = served_records
+    wait = "device_d2h" if depth == 1 else "inflight_wait"
+    additive = set(ADDITIVE_D1) - {"device_d2h"} | {wait}
+    for r in recs:
+        marks = {c for c in r if c not in obs.flightrec.META_FIELDS}
+        assert marks == additive, marks ^ additive
+        assert r["total"] == pytest.approx(sum(r[c] for c in marks))
+        # the subdivisions lie inside their parents
+        assert sum(r.get(p, 0.0) for p in WAL_PARTS) <= r["wal"]
+        assert r["obs"] > 0.0 and r["pack"] >= 0.0
+        # every span has a start stamp, the record a wall-clock anchor
+        timed = {c for c in r
+                 if c not in ("k", "total", "enqueue", "starts",
+                              "clock")
+                 and not c.startswith(("enqueue_", ))}
+        assert timed <= set(r["starts"]), timed - set(r["starts"])
+        assert len(r["clock"]) == 2
+        if "wal_fsync" in r:    # a flush that wrote
+            assert r["starts"]["wal"] <= r["starts"]["wal_fsync"] \
+                <= r["starts"]["resolve"]
+    # the flight ring and the span store saw the same records
+    assert {f["flush_id"] for f in flight if f.get("k")} and all(
+        "starts" in f and "clock" in f for f in flight)
+    wal_recs = [r for r in recs if "wal_fsync" in r]
+    assert wal_recs and all(set(WAL_PARTS) <= set(r) for r in wal_recs)
+
+
+def test_gc_pause_lands_in_the_open_record_and_hook_goes_with_stop():
+    """A collection forced inside a flush is that flush's mark ``gc``
+    (a derived mark: it overlaps whichever mark it interrupted), is
+    counted in ``stats()["gc"]``, and the ``gc.callbacks`` hook is
+    the service's own: installed with the flush timer, removed by
+    ``stop()``."""
+    import gc
+
+    from riak_ensemble_tpu.runtime import Runtime
+
+    rt = Runtime(seed=11)
+    hooks = len(gc.callbacks)
+    svc = BatchedEnsembleService(rt, 4, 3, 8, tick=0.005,
+                                 config=fast_test_config())
+    assert len(gc.callbacks) == hooks + 1
+    assert svc.stats()["gc"]["installed"] is True
+    rt.run_for(0.05)
+    fetch = svc._fetch_packed
+
+    def fetch_and_collect(fl):
+        gc.collect()
+        return fetch(fl)
+    svc._fetch_packed = fetch_and_collect
+    fut = svc.kput(0, "k", b"v")
+    rt.run_until(lambda: fut.done, 60)
+    svc._fetch_packed = fetch
+    recs = [r for r in svc.lat_records if r.get("k")]
+    assert recs and recs[-1]["gc"] > 0.0
+    assert "gc" in recs[-1]["starts"]
+    assert recs[-1]["total"] == pytest.approx(sum(
+        v for c, v in recs[-1].items()
+        if c not in obs.flightrec.META_FIELDS))
+    g = svc.stats()["gc"]
+    assert g["by_generation"]["2"]["pauses"] >= 1
+    assert g["seconds"] >= recs[-1]["gc"]
+    svc.stop()
+    assert len(gc.callbacks) == hooks
+    assert svc.stats()["gc"]["installed"] is False
+
+
+@pytest.mark.parametrize("obs_on", [True, False])
+def test_frontend_counts_frames_and_times_the_loop(monkeypatch,
+                                                   obs_on, tmp_path):
+    """N requests over loopback: frames in = frames out = N, bytes
+    counted both ways, the ``retpu_frontend_*`` families exported;
+    with obs on the records carry ``fe_decode``/``fe_dispatch``
+    (what the loop spent on requests since the previous flush),
+    ``fe_reply`` inside ``resolve`` and ``between_flushes``; with
+    ``RETPU_OBS=0`` the counters still count and no ``fe_*`` mark is
+    taken."""
+    import asyncio
+
+    from riak_ensemble_tpu import svcnode
+
+    monkeypatch.setenv("RETPU_OBS", "1" if obs_on else "0")
+    n = 25
+
+    async def run():
+        server = await svcnode.serve(4, 3, 8, port=0, tick=0.002,
+                                     config=fast_test_config(),
+                                     data_dir=str(tmp_path))
+        svc = server.svc
+        svc.spans.DETAIL_EVERY = 1      # every cycle, not one in 8
+        client = svcnode.ServiceClient(server.host, server.port)
+        await client.connect()
+        try:
+            rs = await asyncio.gather(*[
+                client.kput(i % 4, f"k{i}", b"v%d" % i)
+                for i in range(12)])
+            rs += await asyncio.gather(*[
+                client.kget(i % 4, f"k{i}") for i in range(12)])
+            # one more write: its flush takes what the loop did for
+            # the reads (a leased read is answered at once)
+            rs.append(await client.kput(0, "last", b"w"))
+            assert all(r[0] == "ok" for r in rs)
+            fe = dict(svc.frontend)
+            assert fe["frames_in"] == fe["frames_out"] == n
+            assert fe["bytes_in"] > 4 * n and fe["bytes_out"] > 4 * n
+            assert svc.stats()["frontend"] == fe
+            snap = svc.obs_registry.snapshot()
+            assert snap["retpu_frontend_frames_total"] == {
+                "in": n, "out": n}
+            assert snap["retpu_frontend_bytes_total"]["in"] \
+                == fe["bytes_in"]
+            assert set(snap["retpu_gc_pause_seconds_total"]) == {
+                "0", "1", "2"}
+            recs = [r for r in svc.lat_records if r.get("k")]
+            assert recs
+            marks = {c for r in recs for c in r}
+            fe_marks = {"fe_decode", "fe_dispatch", "fe_reply",
+                        "between_flushes"}
+            if not obs_on:
+                assert not fe_marks & marks
+                return
+            assert fe_marks <= marks
+            # a reply written at once is not a flush's: its own mark
+            assert ("fe_reply_direct" in marks) == (
+                svc.read_fastpath_hits > 0)
+            for r in recs:
+                assert r.get("fe_reply", 0.0) <= r["resolve"]
+                assert r["total"] == pytest.approx(sum(
+                    v for c, v in r.items()
+                    if c not in obs.flightrec.META_FIELDS))
+        finally:
+            await client.close()
+            await server.stop()
+
+    asyncio.run(run())
+
+
+def test_frontend_is_timed_in_one_cycle_in_eight(tmp_path):
+    """Outside a profiler session the front end's spans are taken in
+    one loop cycle in ``DETAIL_EVERY``: a sampled cycle's record
+    carries its decode, dispatch and replies whole, the others none,
+    so a median over the records that have them is unbiased and the
+    three spans a request cost an eighth."""
+    import asyncio
+
+    from riak_ensemble_tpu import svcnode
+
+    async def run():
+        server = await svcnode.serve(4, 3, 8, port=0, tick=0.002,
+                                     config=fast_test_config(),
+                                     data_dir=str(tmp_path))
+        svc = server.svc
+        assert svc.spans.DETAIL_EVERY == 8
+        client = svcnode.ServiceClient(server.host, server.port)
+        await client.connect()
+        try:
+            for i in range(40):     # one flush each
+                assert (await client.kput(i % 4, f"k{i % 24}", b"v"))[0] \
+                    == "ok"
+            recs = [r for r in svc.lat_records if r.get("k")]
+            timed = [r for r in recs if "fe_decode" in r]
+            assert len(recs) >= 40
+            assert 0 < len(timed) <= len(recs) // 4
+            for r in timed:
+                assert {"fe_dispatch", "fe_reply"} <= set(r)
+                assert r["fe_reply"] <= r["resolve"]
+            assert all("between_flushes" in r for r in recs[1:])
+        finally:
+            await client.close()
+            await server.stop()
+
+    asyncio.run(run())
+
+
+SESSION_SPANS = ("svc.wal", "svc.wal_encode", "svc.wal_append",
+                 "svc.wal_fsync", "svc.resolve", "svc.between_flushes",
+                 "svc.h2d", "svc.dispatch", "svc.device_d2h",
+                 "svc.unpack", "svc.pack", "svc.obs", "svc.fe_decode",
+                 "svc.fe_dispatch", "svc.fe_reply", "py.gc")
+
+
+@pytest.fixture(scope="module")
+def session_host_events(tmp_path_factory):
+    """A CPU profiler session (Python tracer off, as the benchmark's
+    ``server.py`` starts it) around a few served flushes, reduced by
+    the benchmark's own unchanged ``trace_reduce.load``."""
+    import asyncio
+    import gc
+    import sys
+
+    from riak_ensemble_tpu import svcnode
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, os.path.join(root, "benchmarks"))
+    import trace_reduce
+    trace_dir = str(tmp_path_factory.mktemp("session"))
+
+    async def run():
+        server = await svcnode.serve(
+            4, 3, 8, port=0, tick=0.002, config=fast_test_config(),
+            data_dir=str(tmp_path_factory.mktemp("session_data")))
+        client = svcnode.ServiceClient(server.host, server.port)
+        await client.connect()
+        try:
+            await client.kput_many(0, ["w"], [b"warm"])
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(trace_dir, profiler_options=opts)
+            try:
+                for j in range(4):
+                    r = await client.kput_many(
+                        j, ["a", "b"], [b"1", b"2"])
+                    assert all(x[0] == "ok" for x in r)
+                    gc.collect()
+                    await asyncio.sleep(0.01)
+            finally:
+                jax.profiler.stop_trace()
+        finally:
+            await client.close()
+            await server.stop()
+
+    asyncio.run(run())
+    return trace_reduce.load(trace_dir)["host"]
+
+
+@pytest.mark.parametrize("name", SESSION_SPANS)
+def test_profiler_session_host_plane_holds_the_span(
+        session_host_events, name):
+    assert any(n == name and dur > 0
+               for n, _start, dur in session_host_events), sorted(
+        {n for n, _s, _d in session_host_events
+         if n.startswith(("svc.", "py."))})
+
+
+def test_profiler_session_nests_the_barrier_inside_wal(
+        session_host_events):
+    """On the trace's own clock every ``svc.wal_fsync`` lies inside a
+    ``svc.wal``, and one held open across loop callbacks
+    (``svc.between_flushes``) is recorded whole."""
+    by = {}
+    for n, start, dur in session_host_events:
+        by.setdefault(n, []).append((start, start + dur))
+    for lo, hi in by["svc.wal_fsync"]:
+        assert any(a <= lo and hi <= b for a, b in by["svc.wal"])
+    for lo, hi in by["svc.fe_reply"]:
+        assert any(a <= lo and hi <= b for a, b in by["svc.resolve"])
+    assert any(a <= lo and hi <= b
+               for lo, hi in by["svc.fe_decode"]
+               for a, b in by["svc.between_flushes"])
+
+
+def _lowered(program):
+    import jax.numpy as jnp
+
+    from riak_ensemble_tpu.ops import engine as eng
+    from riak_ensemble_tpu.parallel import batched_host
+
+    e, m, s, k, a = 512, 3, 16, 2, 16
+    st = eng.init_state(e, m, s)
+    up = jnp.ones((e, m), bool)
+    if program == "step_sliced":
+        z = jnp.zeros((k, a), jnp.int32)
+        low = eng.full_step_sliced.lower(
+            st, jnp.arange(a, dtype=jnp.int32), jnp.zeros((a,), bool),
+            jnp.zeros((a,), jnp.int32), z, z, z,
+            jnp.zeros((k, a), bool), up, exp_epoch=z, exp_seq=z)
+    else:
+        z = jnp.zeros((k, e), jnp.int32)
+        args = (st, jnp.zeros((e,), bool), jnp.zeros((e,), jnp.int32),
+                z, z, z, jnp.zeros((k, e), bool), up)
+        if program == "step":
+            low = eng.full_step.lower(*args, exp_epoch=z, exp_seq=z)
+        else:
+            _st, won, res = jax.eval_shape(
+                lambda *xs: eng.full_step(*xs), *args)
+            low = batched_host._pack_results.lower(
+                won, res, want_vsn=True,
+                active_idx=jnp.arange(a, dtype=jnp.int32))
+    return low.as_text(debug_info=True)
+
+
+STEP_SCOPES = ("elect", "quorum", "slot_gather", "merkle_verify",
+               "apply", "merkle_write", "slot_scatter")
+
+
+@pytest.mark.parametrize("program,scope", [
+    *[("step_sliced", s) for s in STEP_SCOPES + ("slice_columns",
+                                                 "scatter_columns")],
+    *[("step", s) for s in STEP_SCOPES],
+    ("pack", "result_pack"),
+])
+def test_lowered_step_names_every_scope(program, scope):
+    """``jax.named_scope`` on the step's phases reaches the lowered
+    text's locations (and from there the HLO's ``op_name`` and a
+    profiler trace): metadata only."""
+    from riak_ensemble_tpu.ops import engine as eng
+
+    import re
+
+    assert scope in eng.SCOPES
+    # an operation inside the scope is located as "<scope>/<op>"
+    assert re.search(r'loc\("(?:[^"]*/)?%s/' % scope,
+                     _lowered(program))

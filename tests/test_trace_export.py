@@ -2,7 +2,8 @@
 (tools/trace_export.py, docs/ARCHITECTURE.md §14).
 
 Unit round trip on a canned store, the documented timeline semantics
-(per-flush spans sequential, cross-flush ordinal), the flight-dump
+(stamped records laid out by their start stamps; records without
+stamps sequential per flush, ordinal across flushes), the flight-dump
 CLI path, and the acceptance round trip: a timeline RECORDED on a
 live 3-host replication group (leader + two in-process replica
 lanes) exports to a JSON every span of which matches the store."""
@@ -15,10 +16,12 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
+import pytest  # noqa: E402
+
 from riak_ensemble_tpu import obs  # noqa: E402
 from riak_ensemble_tpu.config import fast_test_config  # noqa: E402
 from riak_ensemble_tpu.parallel.batched_host import (  # noqa: E402
-    WallRuntime)
+    BatchedEnsembleService, WallRuntime)
 from tools import trace_export  # noqa: E402
 
 
@@ -74,6 +77,89 @@ def test_unit_round_trip_canned_store(tmp_path):
     # the never-recorded fid contributed nothing (skipped, not fake)
     assert not [e for e in evs
                 if e.get("args", {}).get("flush_id") == 12345]
+
+
+def _stamped(fid, t0, k=1):
+    """A record as the span primitive leaves it: durations under their
+    names, starts on the recorder's clock, the subdivisions of
+    ``wal`` inside it."""
+    starts = {"h2d": t0, "dispatch": t0 + 0.003,
+              "device_d2h": t0 + 0.004, "wal": t0 + 0.006,
+              "wal_fsync": t0 + 0.0065, "resolve": t0 + 0.009}
+    return {"flush_id": fid, "k": k, "t": 1.7e9 + t0,
+            "clock": [t0, 1.7e9 + t0], "starts": starts,
+            "h2d": 0.003, "dispatch": 0.001, "device_d2h": 0.002,
+            "wal": 0.003, "wal_fsync": 0.002, "resolve": 0.001,
+            "enqueue": 0.004, "total": 0.010}
+
+
+@pytest.mark.parametrize("source", ["store", "flight_dump"])
+def test_stamped_records_lay_out_by_their_start_stamps(source):
+    """Spans sit where they ran: zero is the earliest stamp exported,
+    the gap between two flushes is the real one (18 ms here, not the
+    sum of the first one's spans), and an unstamped replica role
+    stacks from its flush's base."""
+    recs = [_stamped(7, 100.0), _stamped(9, 100.018)]
+    names = ("h2d", "dispatch", "device_d2h", "wal", "resolve")
+    if source == "store":
+        store = obs.SpanStore()
+        for r in recs:
+            store.record(r["flush_id"], "leader",
+                         [(n, r[n]) for n in names],
+                         starts=r["starts"])
+        store.record(9, "replica@h:1", [("validate", 0.001),
+                                        ("apply", 0.002)])
+        evs = trace_export.trace_events([7, 9], store=store)
+    else:
+        evs = trace_export.flight_dump_events({"ring": recs})
+    by = {(e["args"]["flush_id"], e["tid"], e["name"]): e
+          for e in evs if e["ph"] == "X"}
+    assert by[7, "leader", "h2d"]["ts"] == 0.0
+    assert by[7, "leader", "wal"]["ts"] == pytest.approx(6000.0)
+    assert by[7, "leader", "wal"]["dur"] == pytest.approx(3000.0)
+    assert by[9, "leader", "h2d"]["ts"] == pytest.approx(18000.0)
+    assert by[9, "leader", "resolve"]["ts"] == pytest.approx(27000.0)
+    marks = {e["name"]: e["ts"] for e in evs if e["tid"] == "flush"}
+    assert marks["flush 9"] == pytest.approx(18000.0)
+    if source == "store":
+        rep = by[9, "replica@h:1", "validate"]
+        assert rep["ts"] == pytest.approx(18000.0)
+        assert by[9, "replica@h:1", "apply"]["ts"] == \
+            pytest.approx(19000.0)
+    else:
+        # a dump carries the subdivisions: on their own track, inside
+        # their parent's extent
+        sub = by[7, "leader/sub", "wal_fsync"]
+        wal = by[7, "leader", "wal"]
+        assert wal["ts"] <= sub["ts"] and \
+            sub["ts"] + sub["dur"] <= wal["ts"] + wal["dur"]
+        assert (7, "leader", "wal_fsync") not in by
+
+
+def test_served_flushes_export_at_their_stamps(tmp_path):
+    """End to end: the records of a served service carry stamps, and
+    the export places each flush's ``wal`` after its ``h2d`` by the
+    measured distance."""
+    svc = BatchedEnsembleService(WallRuntime(), 4, 3, 8, tick=None,
+                                 config=fast_test_config(),
+                                 data_dir=str(tmp_path))
+    before = set(obs.SPANS.flush_ids())
+    svc.flush()
+    fut = svc.kput_many(0, ["a", "b"], [b"1", b"2"])
+    svc.flush()
+    svc.flush()
+    assert fut.done
+    fids = [f for f in obs.SPANS.flush_ids() if f not in before]
+    evs = [e for e in trace_export.trace_events(fids)
+           if e["ph"] == "X" and e["tid"] == "leader"]
+    rec = [r for r in svc.flight.records if r.get("k")][-1]
+    mine = {e["name"]: e for e in evs
+            if e["args"]["flush_id"] == rec["flush_id"]}
+    assert mine["wal"]["ts"] - mine["h2d"]["ts"] == pytest.approx(
+        (rec["starts"]["wal"] - rec["starts"]["h2d"]) * 1e6)
+    # zero is the earliest stamp exported (the first 'pack')
+    assert 0.0 <= min(e["ts"] for e in evs) < 1e6
+    svc.stop()
 
 
 def test_flight_dump_cli_path(tmp_path, capsys):

@@ -9,17 +9,21 @@ so "where did the last N flushes' time go, and when did the
 controller move a knob" becomes a picture instead of a dict-reading
 exercise.
 
-Timeline semantics (honest, documented): span records carry
-DURATIONS, not absolute start stamps — the store is
-allocation-free on the hot path by design.  The export therefore
-lays each flush's spans out SEQUENTIALLY per role from a per-flush
-base tick, and advances the base by the flush's widest role before
-the next flush: within a flush, every span's extent is
-measurement-accurate and roles align at the flush base; ACROSS
-flushes the spacing is ordinal (flush order), not wall-clock.
-Controller journal events render as instant events on a
-``controller`` track at the base tick of the flush they were
-journaled against.
+Timeline semantics: a record of the span primitive (``obs.spans``)
+carries, beside each mark's duration, its START stamp
+(``starts[mark]``, ``time.perf_counter()`` of the recording process),
+and such a record is laid out by its stamps: every span sits where it
+ran, flushes are spaced as they ran, and the derived subdivisions
+(``wal_fsync`` inside ``wal``, ``fe_reply`` inside ``resolve``, ...)
+ride a ``<role>/sub`` track under their parents; the trace's zero is
+the earliest stamp exported.  A mark that ran in several stretches
+(``between_flushes`` over idle ticks, ``gc``) is one bar from its
+first start, as long as their sum.  A role WITHOUT stamps (a replica
+lane, a flight dump older than the primitive) keeps the sequential
+layout: its spans stacked from the flush's base in record order,
+extents exact, the base of an unstamped flush ordinal.  Controller
+journal events render as instant events on a ``controller`` track at
+the base of the flush they were journaled against.
 
 Two entry points:
 
@@ -60,18 +64,55 @@ _US = 1e6  # seconds -> trace microseconds
 
 
 def _span_events(role: str, spans, base_us: float, fid: int,
-                 pid: str) -> List[Dict[str, Any]]:
-    """One role's spans as complete ("X") events stacked
-    sequentially from the flush base."""
+                 pid: str, starts: Optional[Dict[str, float]] = None,
+                 origin_s: float = 0.0) -> List[Dict[str, Any]]:
+    """One role's spans as complete ("X") events: each at its start
+    stamp where ``starts`` has one (seconds on the recorder's clock,
+    ``origin_s`` the trace's zero), the rest stacked sequentially
+    from the flush base."""
     out: List[Dict[str, Any]] = []
     t = base_us
     for name, dur_s in spans:
         dur_us = max(float(dur_s), 0.0) * _US
-        out.append({"name": str(name), "ph": "X", "ts": t,
+        at = (starts or {}).get(name)
+        ts = t if at is None else (float(at) - origin_s) * _US
+        out.append({"name": str(name), "ph": "X", "ts": ts,
                     "dur": dur_us, "pid": pid, "tid": str(role),
                     "args": {"flush_id": fid}})
-        t += dur_us
+        if at is None:
+            t += dur_us
     return out
+
+
+def _origin(all_starts: Iterable[Optional[Dict[str, float]]]) -> float:
+    """The earliest start stamp of the records to export (0.0 where
+    none is stamped)."""
+    firsts = [min(st.values()) for st in all_starts if st]
+    return min(firsts) if firsts else 0.0
+
+
+def _flush_extent(sides: List[Dict[str, Any]], origin_s: float,
+                  cursor_us: float):
+    """(base, cursor after) of one flush, in trace microseconds, from
+    its roles' ``{"spans", "starts"}`` sides, the leader's first.  A
+    flush whose leader is stamped starts at its earliest stamp, else
+    at the cursor; it ends with its latest stamped span, or past its
+    widest unstamped role (times 1.25: the ordinal spacing)."""
+    starts = sides[0].get("starts") if sides else None
+    base = ((min(starts.values()) - origin_s) * _US if starts
+            else cursor_us)
+    after = cursor_us
+    for side in sides:
+        st, spans = side.get("starts") or {}, side.get("spans", [])
+        for name, dur in spans:
+            if name in st:
+                after = max(after, (st[name] - origin_s
+                                    + max(float(dur), 0.0)) * _US)
+        width = sum(max(float(d), 0.0) for n, d in spans
+                    if n not in st) * _US
+        if width or not st:
+            after = max(after, base + max(width, 1.0) * 1.25)
+    return base, after
 
 
 def trace_events(flush_ids: Iterable[int],
@@ -87,30 +128,35 @@ def trace_events(flush_ids: Iterable[int],
     store = store if store is not None else obs.SPANS
     events: List[Dict[str, Any]] = []
     base_of: Dict[int, float] = {}
-    base = 0.0
+    tls = []
     for fid in sorted(set(int(f) for f in flush_ids)):
         tl = store.timeline(fid)
-        if not tl or tl.get("miss"):
-            continue  # evicted/unknown fid: a structured miss, not
-            #           a record (the store counted it)
+        if tl and not tl.get("miss"):
+            tls.append((fid, tl))
+        # else evicted/unknown fid: a structured miss, not a record
+        # (the store counted it)
+    origin = _origin((tl.get("leader") or {}).get("starts")
+                     for _fid, tl in tls)
+    cursor = 0.0
+    for fid, tl in tls:
+        sides = sorted(((role, side) for role, side in tl.items()
+                        if role != "flush_id"),
+                       key=lambda rs: rs[0] != "leader")
+        base, after = _flush_extent([side for _r, side in sides],
+                                    origin, cursor)
         base_of[fid] = base
-        widest = 0.0
-        for role, side in tl.items():
-            if role == "flush_id":
-                continue
-            spans = side.get("spans", [])
-            events.extend(_span_events(role, spans, base, fid, pid))
-            widest = max(widest,
-                         sum(max(float(d), 0.0) for _n, d in spans))
+        for role, side in sides:
+            events.extend(_span_events(
+                role, side.get("spans", []), base, fid, pid,
+                side.get("starts"), origin))
         # one metadata marker per flush so the viewer can jump by id
         events.append({"name": f"flush {fid}", "ph": "i", "s": "t",
                        "ts": base, "pid": pid, "tid": "flush",
                        "args": {k: v for k, v in
                                 (tl.get("leader") or {}).items()
-                                if k != "spans"}})
-        # next flush starts past this one's widest role (µs), with
-        # breathing room — the ordinal cross-flush spacing
-        base += max(widest * _US, 1.0) * 1.25
+                                if k not in ("spans", "starts")}})
+        cursor = after
+    base = cursor
     for ev in decisions:
         ts = base_of.get(int(ev.get("flush_id", 0)), base)
         knob = ev.get("knob") or ev.get("actuator", "decision")
@@ -129,21 +175,31 @@ def flight_dump_events(dump: Dict[str, Any],
 
     events: List[Dict[str, Any]] = []
     base_of: Dict[int, float] = {}
-    base = 0.0
-    for rec in dump.get("ring", []):
+    ring = dump.get("ring", [])
+    origin = _origin(rec.get("starts") for rec in ring)
+    cursor = 0.0
+    for rec in ring:
         fid = int(rec.get("flush_id", 0))
+        starts = rec.get("starts")
         spans = [(c, v) for c, v in rec.items()
                  if isinstance(v, (int, float))
                  and c not in flightrec.META_FIELDS]
+        # the stamped subdivisions of those marks, on their own track
+        sub = [(c, rec[c]) for c in flightrec.DERIVED_MARKS
+               if c in rec and c in (starts or {})]
+        base, cursor = _flush_extent(
+            [{"spans": spans, "starts": starts}], origin, cursor)
         base_of[fid] = base
-        events.extend(_span_events("leader", spans, base, fid, pid))
+        events.extend(_span_events("leader", spans, base, fid, pid,
+                                   starts, origin))
+        events.extend(_span_events("leader/sub", sub, base, fid, pid,
+                                   starts, origin))
         events.append({"name": f"flush {fid}", "ph": "i", "s": "t",
                        "ts": base, "pid": pid, "tid": "flush",
                        "args": {k: rec.get(k) for k in
                                 ("k", "a_width", "payload_bytes",
                                  "queued_rounds", "in_flight")}})
-        base += max(sum(max(float(d), 0.0) for _n, d in spans),
-                    1e-6) * _US * 1.25
+    base = cursor
     for ev in dump.get("controller_decisions", []):
         ts = base_of.get(int(ev.get("flush_id", 0)), base)
         knob = ev.get("knob") or ev.get("actuator", "decision")
@@ -203,8 +259,9 @@ def export(path: str, flush_ids: Iterable[int],
         "otherData": {
             "source": "riak_ensemble_tpu tools/trace_export.py",
             "timeline_semantics":
-                "per-flush spans sequential from a per-flush base; "
-                "cross-flush spacing is ordinal, not wall-clock",
+                "stamped spans at their start stamps (zero = the "
+                "earliest exported); unstamped roles sequential "
+                "from their flush's base",
         },
     }
     with open(path, "w", encoding="utf-8") as fh:
