@@ -5868,7 +5868,8 @@ class BatchedEnsembleService:
         WAL appends it verbatim (:meth:`ServiceWAL.log_arena`) —
         byte-identical store contents to the Python path below, which
         remains the oracle and the fallback (scalar write ops, exotic
-        key/payload types, RETPU_NATIVE_RESOLVE=0)."""
+        key/payload types, RETPU_NATIVE_RESOLVE=0) and reads the
+        planes at the flush's own lanes only."""
         committed, _get_ok, _found, value, vsn = planes
         if committed is None:
             return
@@ -5876,10 +5877,14 @@ class BatchedEnsembleService:
                 and self._log_wal_native(taken, planes, rec)):
             return
         encode = self.spans.span("wal_encode", rec).begin()
-        committed_l = committed.tolist()
-        vsn_l = vsn.tolist()
-        puts = (eng.OP_PUT, eng.OP_CAS)
+        writes = (eng.OP_PUT, eng.OP_CAS, eng.OP_RMW)
         recs = []
+        #: the flush's scalar write lanes: where in recs, which op,
+        #: and the (j, e) to read the planes at
+        lane_at: List[int] = []
+        lane_op: List[_PendingOp] = []
+        lane_j: List[int] = []
+        lane_e: List[int] = []
         for e, ops in taken:
             j = -1
             for op in ops:
@@ -5910,21 +5915,42 @@ class BatchedEnsembleService:
                     j += op.n
                     continue
                 j += 1
-                if op.kind in puts and committed_l[j][e]:
+                if op.kind in writes:
+                    # hold the record's place in the walk's order
+                    # (scalar and batch records of one (ens, slot)
+                    # interleave: latest-per-key within the flush)
+                    lane_at.append(len(recs))
+                    lane_op.append(op)
+                    lane_j.append(j)
+                    lane_e.append(e)
+                    recs.append(None)
+        if lane_at:
+            # The planes are [K, E(, 2)] whatever the flush wrote, so
+            # all three are read at the flush's own lanes only: one
+            # gather per plane, then tolist() of those tens of elements
+            # for the Python ints the store pickles.  The barrier costs
+            # O(ops in the flush) at every E.  Listing a plane instead
+            # builds and frees E lists a round: at E = 10,000 that was
+            # 3.0 of the barrier's 3.6 ms and what tripped a 300 ms
+            # full collector pass every 3.6 s (PERF.md §6, PR 26).
+            lanes = (np.asarray(lane_j), np.asarray(lane_e))
+            comm_l = committed[lanes].tolist()
+            vsn_l = vsn[lanes].tolist()
+            value_l = value[lanes].tolist()
+            for at, op, e, comm, (ve, vs), val in zip(
+                    lane_at, lane_op, lane_e, comm_l, vsn_l, value_l):
+                if not comm:
+                    continue
+                if op.kind == eng.OP_RMW:
+                    recs[at] = (("kv", e, op.slot),
+                                (op.key, val, ve, vs, None, True))
+                else:
                     payload = (self.values.get(op.handle)
                                if op.handle else None)
-                    ve, vs = vsn_l[j][e]
-                    recs.append((("kv", e, op.slot),
-                                 (op.key, op.handle, ve, vs, payload,
-                                  False)))
-                elif op.kind == eng.OP_RMW and committed_l[j][e]:
-                    # direct ndarray index: RMW scalar ops are rare
-                    # enough that a full value.tolist() per flush
-                    # would tax the pure put/get WAL hot path
-                    ve, vs = vsn_l[j][e]
-                    recs.append((("kv", e, op.slot),
-                                 (op.key, int(value[j, e]), ve, vs,
-                                  None, True)))
+                    recs[at] = (("kv", e, op.slot),
+                                (op.key, op.handle, ve, vs, payload,
+                                 False))
+            recs = [r for r in recs if r is not None]
         encode.end()
         if recs:
             self._wal.log(recs + self._wal_extra_records())

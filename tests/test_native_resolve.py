@@ -9,6 +9,7 @@ sections.  The Python implementations are the oracle; the kernel is
 an optimization, never a semantic.
 """
 
+import contextlib
 import os
 import pickle
 import zlib
@@ -23,7 +24,8 @@ from riak_ensemble_tpu import funref
 from riak_ensemble_tpu.ops import engine as eng
 from riak_ensemble_tpu.parallel import repgroup, resolve_native
 from riak_ensemble_tpu.parallel.batched_host import (
-    BatchedEnsembleService, WallRuntime, unpack_results,
+    BatchedEnsembleService, Future, WallRuntime, _PendingBatch,
+    _PendingOp, unpack_results,
 )
 
 needs_kernel = pytest.mark.skipif(
@@ -388,6 +390,213 @@ def test_wal_encode_pickle_byte_identity():
         assert pickle.loads(raw[vo:vo + vl]) == (
             keys[i], f2, int(vsn[j, e, 0]), int(vsn[j, e, 1]),
             pays[i], bool(lane_inline[i]))
+
+
+# -- 5b) the Python WAL arm: the arena's oracle ------------------------------
+#
+# _log_wal's Python arm reads the result planes at the flush's own
+# write lanes only.  What it hands ServiceWAL.log is compared with a
+# reference built the plain way, from the planes' own tolist().
+
+
+class _WalRecorder:
+    """Stands in for ServiceWAL: keeps what each log() was handed."""
+
+    def __init__(self):
+        self.calls = []
+
+    def log(self, records):
+        self.calls.append(records)
+
+
+def _scalar(kind, slot, handle=0, key=None):
+    return _PendingOp(kind, slot, handle, Future(), key=key)
+
+
+def _batch(kind, slots, handles, keys=None):
+    return _PendingBatch(kind, list(slots), list(handles), Future(),
+                         pos=list(range(len(slots))), keys=keys,
+                         n=len(slots))
+
+
+def _planes(rng, taken, n_ens, p_commit):
+    k = max(sum(op.n for op in ops) for _e, ops in taken)
+    committed = rng.random((k, n_ens)) < p_commit
+    value = rng.integers(-2**31, 2**31, (k, n_ens)).astype(np.int32)
+    vsn = rng.integers(0, 2**31, (k, n_ens, 2)).astype(np.int32)
+    return committed, value, vsn
+
+
+def _reference_records(svc, taken, committed, value, vsn):
+    """Every committed write lane of the walk, in the walk's order,
+    from full listings of the planes."""
+    comm_l, value_l, vsn_l = (committed.tolist(), value.tolist(),
+                              vsn.tolist())
+    recs = []
+    for e, ops in taken:
+        j = -1
+        for op in ops:
+            batch = isinstance(op, _PendingBatch)
+            for i in range(op.n):
+                j += 1
+                if op.kind == eng.OP_GET or not comm_l[j][e]:
+                    continue
+                key = op.keys[i] if batch else op.key
+                slot = op.slot[i] if batch else op.slot
+                ve, vs = vsn_l[j][e]
+                if op.kind == eng.OP_RMW:
+                    recs.append((("kv", e, slot),
+                                 (key, value_l[j][e], ve, vs, None,
+                                  True)))
+                    continue
+                h = op.handle[i] if batch else op.handle
+                recs.append((("kv", e, slot),
+                             (key, h, ve, vs,
+                              svc.values.get(h) if h else None, False)))
+    return recs
+
+
+def _taken_scalars():
+    """Scalar put / cas / rmw / get lanes over three ensembles, K=4."""
+    P, C, R, G = eng.OP_PUT, eng.OP_CAS, eng.OP_RMW, eng.OP_GET
+    return [
+        (1, [_scalar(P, 0, 1, "a"), _scalar(G, 0, key="a"),
+             _scalar(C, 1, 2, "b"), _scalar(R, 2, 7, "c")]),
+        (5, [_scalar(R, 3, -4, "d"), _scalar(P, 3, 0, "d")]),
+        (6, [_scalar(G, 1, key="e")]),
+    ]
+
+
+def _taken_interleaved():
+    """Scalar and batch writes on the SAME (ens, slot), interleaved:
+    latest-per-key rests on the walk's order."""
+    P, C, R, G = eng.OP_PUT, eng.OP_CAS, eng.OP_RMW, eng.OP_GET
+    return [
+        (2, [_scalar(P, 3, 1, "k3"),
+             _batch(P, [3, 4], [2, 0], ["k3", "k4"]),
+             _scalar(R, 3, 9, "k3"),
+             _batch(R, [3, 5], [1, 1], ["k3", "k5"]),
+             _batch(G, [3, 4], [0, 0]),
+             _scalar(C, 4, 3, ("tuple", "key")),
+             _batch(C, [4], [1], [("tuple", "key")]),
+             _scalar(P, 4, 0, "k4")]),
+        (0, [_batch(P, [0, 1, 2], [3, 1, 2], ["x", "y", "z"]),
+             _scalar(G, 0, key="x"), _scalar(P, 0, 3, "x")]),
+    ]
+
+
+def _taken_reads_only():
+    G = eng.OP_GET
+    return [(0, [_scalar(G, 0, key="x"), _batch(G, [1, 2], [0, 0])]),
+            (3, [_scalar(G, 1, key="y")])]
+
+
+@contextlib.contextmanager
+def _python_wal_arm(native=False):
+    """A service whose WAL is a recorder; ``native`` leaves the arena
+    arm in place (it hands a flush with a scalar write lane back)."""
+    svc = BatchedEnsembleService(WallRuntime(), 8, 3, 8, tick=None)
+    try:
+        if not native:
+            svc._native_resolve = None
+        svc._wal = wal = _WalRecorder()
+        svc.values.update({1: b"one", 2: b"", 3: b"three" * 40})
+        yield svc, wal
+    finally:
+        svc._wal = None
+        svc.stop()
+
+
+_WAL_ARM_CASES = {
+    # name: (taken, share of lanes committed, extra records, native)
+    "scalars": (_taken_scalars, 0.7, [], False),
+    "scalars_all_committed": (_taken_scalars, 1.0, [], False),
+    "interleaved": (_taken_interleaved, 0.7, [], False),
+    "interleaved_all_committed": (_taken_interleaved, 1.0, [], False),
+    "nothing_committed": (_taken_interleaved, 0.0, [], False),
+    "reads_only": (_taken_reads_only, 1.0, [], False),
+    "extra_records": (_taken_interleaved, 0.7,
+                      [(("grp", "meta"), (7, 1, 42, None))], False),
+    # the native arm hands a flush with a scalar write lane back
+    "native_hands_back": (_taken_interleaved, 0.7, [], True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_WAL_ARM_CASES))
+def test_python_wal_arm_matches_full_listing(case, monkeypatch):
+    """The list handed to ServiceWAL.log: same records, same order,
+    same Python types (each key and value pickles to the same bytes,
+    as the store pickles them) as the full-listing reference, then
+    the subclass's extra records, in ONE call; none when nothing
+    committed."""
+    make_taken, p_commit, extra, native = _WAL_ARM_CASES[case]
+    if native and resolve_native.get() is None:
+        pytest.skip("native resolve kernel unavailable")
+    with _python_wal_arm(native) as (svc, wal):
+        monkeypatch.setattr(svc, "_wal_extra_records",
+                            lambda: list(extra))
+        for seed in range(4):
+            taken = make_taken()
+            committed, value, vsn = _planes(
+                np.random.default_rng(seed), taken, 8, p_commit)
+            ref = _reference_records(svc, taken, committed, value, vsn)
+            del wal.calls[:]
+            svc._log_wal(taken, (committed, None, None, value, vsn))
+            if not ref:
+                assert wal.calls == []
+                continue
+            assert len(wal.calls) == 1
+            got = wal.calls[0]
+            assert got == ref + extra
+            for (gk, gv), (rk, rv) in zip(got, ref + extra):
+                assert pickle.dumps(gk, 4) == pickle.dumps(rk, 4)
+                assert pickle.dumps(gv, 4) == pickle.dumps(rv, 4)
+
+
+class _WatchedPlane(np.ndarray):
+    """A result plane that notes the size of everything derived from
+    it (views, gathers, copies) and of every listing or nonzero
+    taken, while ``seen`` is a list."""
+
+    seen = None
+
+    def _note(self):
+        if _WatchedPlane.seen is not None:
+            _WatchedPlane.seen.append(self.size)
+
+    def __array_finalize__(self, obj):
+        self._note()
+
+    def tolist(self):
+        self._note()
+        return super().tolist()
+
+    def nonzero(self):
+        self._note()
+        return super().nonzero()
+
+
+def test_python_wal_arm_never_materialises_a_plane(monkeypatch):
+    """At E = 4,096 the arm touches nothing wider than the flush: no
+    listing, nonzero, gather, view or copy larger than the flush's
+    write lanes (x2: a lane's version is a pair)."""
+    taken = [(e + 1000, ops)
+             for e, ops in _taken_interleaved() + _taken_scalars()]
+    write_lanes = sum(op.n for _e, ops in taken for op in ops
+                      if op.kind != eng.OP_GET)
+    with _python_wal_arm() as (svc, wal):
+        committed, value, vsn = _planes(np.random.default_rng(3),
+                                        taken, 4096, 0.8)
+        ref = _reference_records(svc, taken, committed, value, vsn)
+        watched = [a.view(_WatchedPlane) for a in (committed, value, vsn)]
+        monkeypatch.setattr(_WatchedPlane, "seen", [])
+        svc._log_wal(taken, (watched[0], None, None, watched[1],
+                             watched[2]))
+        seen = _WatchedPlane.seen
+    assert wal.calls == [ref]
+    assert seen and max(seen) <= 2 * write_lanes, (max(seen),
+                                                   write_lanes)
+    assert committed.size > 100 * write_lanes
 
 
 # -- 6) degradation ----------------------------------------------------------
