@@ -10,6 +10,13 @@ against the plain reference (``check.py``) and prints the result as the
 last line of stdout.  ``--sweep`` (one set-up, a ladder of rates) and
 ``--rehearse`` (a CPU, cut shapes, exit 3, no result line) are for
 builders; the driver passes neither.
+
+No process this run starts outlives it.  ``server.py`` asks the kernel
+for SIGKILL at this process's death (so SIGKILL here, which runs no
+handler, leaves nothing either); SIGTERM, SIGINT and SIGHUP print one
+``"what": "cut"`` line that names the phase, kill the child, wait for
+it and exit 128 + the signal; and a run that ends by itself looks for
+what it started (``processes_left``) before it says ``checked``.
 """
 
 from __future__ import annotations
@@ -21,6 +28,7 @@ import importlib.util
 import json
 import os
 import shutil
+import signal
 import sys
 import time
 
@@ -43,6 +51,27 @@ TRACE_S = 5.0
 UNTOUCHED_SAMPLE = 10_000
 SWEEP_STEP_S = 10.0
 WARM_ATTEMPTS = 3
+#: every process of a run carries this in its environment, so that what
+#: the run started can be found when the processes between are gone
+RUN_TAG = "RETPU_BENCH_RUN"
+CUT_SIGNALS = (signal.SIGTERM, signal.SIGINT, signal.SIGHUP)
+
+
+def tagged(tag: str) -> list:
+    """The pids alive now, but for this one, of the processes whose
+    environment carries a run's tag that begins with ``tag`` (a whole
+    tag finds that run's; a zombie has no environment)."""
+    want, out = f"{RUN_TAG}={tag}".encode(), []
+    for pid in os.listdir("/proc"):
+        if not pid.isdigit() or int(pid) == os.getpid():
+            continue
+        try:
+            with open(f"/proc/{pid}/environ", "rb") as f:
+                if any(v.startswith(want) for v in f.read().split(b"\0")):
+                    out.append(int(pid))
+        except OSError:
+            continue        # gone meanwhile, or another user's
+    return out
 
 
 class Run:
@@ -71,6 +100,8 @@ class Run:
         self.recordcount = self.cfg["records_per_ens"] * self.cfg["n_ens"]
         self.out = os.path.join(ROOT, ".bench_out", self.cell["name"])
         self.device: dict = {}
+        self.tag = f"{os.getpid()}.{time.time_ns()}"
+        self.phase = "starting"    # the last ``what`` said
 
     @staticmethod
     def _json(kind: str, name: str) -> dict:
@@ -78,8 +109,54 @@ class Run:
             return json.load(f)
 
     def say(self, what: str, **fields) -> None:
+        self.phase = " ".join(
+            str(x) for x in (what, fields.get("phase")) if x is not None)
         print(json.dumps(dict(what=what, cell=self.cell["name"],
                               **fields, **self.device)), flush=True)
+
+    def started(self) -> list:
+        """The pids alive now of the processes this run started."""
+        return tagged(self.tag)
+
+    def end_started(self) -> int:
+        """Kill what :meth:`started` finds until it finds nothing; how
+        many it found at first."""
+        found = pids = self.started()
+        deadline = time.monotonic() + 10.0
+        while pids and time.monotonic() < deadline:
+            for pid in pids:
+                try:
+                    os.kill(pid, signal.SIGKILL)
+                except OSError:
+                    pass
+            time.sleep(0.05)
+            pids = self.started()
+        return len(found)
+
+    def cut(self, signum: int, _frame) -> None:
+        """SIGTERM, SIGINT or SIGHUP: say where the run stood, as its
+        last line, leave nothing behind, exit 128 + the signal.  (Not
+        through the loop: it may be in a long step of this process's
+        own, and the child holds the chip meanwhile.)"""
+        line = json.dumps(dict(
+            what="cut", cell=self.cell["name"],
+            signal=signal.Signals(signum).name, signum=signum,
+            phase=self.phase,
+            seconds_since_start=time.perf_counter() - T_START,
+            **self.device))
+        try:
+            sys.stdout.flush()
+        except Exception:
+            pass                # cut inside a print: its buffer is lost
+        os.write(1, (line + "\n").encode())
+        self.end_started()
+        try:
+            while True:
+                os.waitpid(-1, 0)
+        except ChildProcessError:
+            pass                # every child of this process is reaped
+        shutil.rmtree(self.out, ignore_errors=True)
+        os._exit(128 + signum)
 
 
 class Child:
@@ -100,9 +177,13 @@ class Child:
             cmd.append("--rehearse")
         if r.args.control:
             cmd += ["--control", r.args.control]
+        # (from this thread, the main one: the kernel ties the child's
+        # request to the thread that forked it)
+        cmd += ["--parent-pid", str(os.getpid())]
         self.proc = await asyncio.create_subprocess_exec(
             *cmd, stdin=asyncio.subprocess.PIPE,
-            stdout=asyncio.subprocess.PIPE, limit=256 << 20, cwd=ROOT)
+            stdout=asyncio.subprocess.PIPE, limit=256 << 20, cwd=ROOT,
+            env=dict(os.environ, **{RUN_TAG: r.tag}))
 
     async def event(self, want: str) -> dict:
         """The next line of the child, which must be ``want``."""
@@ -229,9 +310,12 @@ async def warm_up(run: Run, child: Child, client, records, next_wid: int,
     of active columns), and a launch that meets a bucket for the first
     time stalls every request behind it, which deepens the next flush.
     So, first, ``warm_grid``: for every depth and width of the traffic
-    file whose product is at most ``max_ops``, one burst that is that
-    deep and that wide (``loadgen.depth_burst``), each drained before
-    the next, ``rounds`` times over.  Second, ``warm_pileups``: that
+    file whose product is at most ``max_ops``, and then for every
+    ``[depth, width]`` pair of its ``bursts`` (a band of the grid where
+    the whole product would cost set-up for programs no backlog of the
+    mix can meet), one burst that is that deep and that wide
+    (``loadgen.depth_burst``), each drained before the next, ``rounds``
+    times over.  Second, ``warm_pileups``: that
     many requests of the mix itself, all due at once, as they pile up
     behind a stalled flush (the widest flushes come only from many
     small frames parsed in one turn of the server's loop).  Then the
@@ -242,10 +326,22 @@ async def warm_up(run: Run, child: Child, client, records, next_wid: int,
 
     async def quiet(what: str, **fields) -> bool:
         events = (await child.ask("dump"))["compile_events"]
+        by_fn: dict = {}
+        for e in events:
+            n, s = by_fn.get(e["fn"], (0, 0.0))
+            by_fn[e["fn"]] = (n + 1, s + e["compile_ms"] / 1e3)
         run.say("warmed", phase=what, programs_first_met=len(events),
                 first_met_seconds=sum(e["compile_ms"] for e in events) / 1e3,
+                first_met_by_fn=by_fn,
                 seconds_since_loaded=time.perf_counter() - t_loaded,
                 **fields)
+        if run.args.keep:       # which program, at which shapes
+            os.makedirs(run.args.keep, exist_ok=True)
+            with open(os.path.join(
+                    run.args.keep, f"{run.cell['name']}.{run.args.seed}"
+                    ".programs.jsonl"), "a") as f:
+                for e in events:
+                    f.write(json.dumps(dict(phase=what, **e)) + "\n")
         return not events
 
     grid = t.get("warm_grid")
@@ -254,18 +350,24 @@ async def warm_up(run: Run, child: Child, client, records, next_wid: int,
     first_of = np.full(run.cfg["n_ens"], -1, np.int64)
     first_of[records.ens[::-1]] = np.arange(run.recordcount)[::-1]
     aimable = first_of[first_of >= 0]
+    await quiet("load")     # what the load itself met, since the start
+    bursts = []
+    if grid:
+        bursts = [(d, w) for d in grid.get("depths", ())
+                  for w in grid.get("widths", ())
+                  if d * w <= grid["max_ops"]]
+        bursts += [(d, w) for d, w in grid.get("bursts", ())]
     for rnd in range(grid["rounds"] if grid else 0):
         await child.ask("mark")
-        for depth in grid["depths"]:
-            for width in grid["widths"]:
-                if depth * width > grid["max_ops"] or width > aimable.size:
-                    continue
-                log = await loadgen.depth_burst(
-                    client, records, depth,
-                    rng.choice(aimable, width, replace=False), next_wid,
-                    PHASE_TIMEOUT_S)
-                next_wid += log.due.size
-                logs.append(log)
+        for depth, width in bursts:
+            if width > aimable.size:
+                continue
+            log = await loadgen.depth_burst(
+                client, records, depth,
+                rng.choice(aimable, width, replace=False), next_wid,
+                PHASE_TIMEOUT_S)
+            next_wid += log.due.size
+            logs.append(log)
         await quiet(f"grid {rnd}")
     # (which bucket a pile-up lands in is chance: in a fresh checkout the
     # ladder is walked again, other draws, while it still meets programs,
@@ -324,9 +426,12 @@ async def main_async(run: Run) -> int:
     args, t = run.args, run.traffic
     shutil.rmtree(run.out, ignore_errors=True)
     os.makedirs(run.out)
+    for sig in CUT_SIGNALS:
+        signal.signal(sig, run.cut)
     child = Child(run)
     await child.start()
     client = None
+    processes_left = 0
     try:
         dev = await child.event("device")
         run.device = {"platform": dev["platform"],
@@ -363,6 +468,7 @@ async def main_async(run: Run) -> int:
 
         await child.ask("mark")
         setup_s = time.perf_counter() - T_START
+        run.say("measuring", seconds=args.seconds, setup_s=setup_s)
         log = await phase(run, client, records, 2, args.seconds, next_wid,
                           child, trace=bool(args.trace))
         logs.append(log)
@@ -405,11 +511,16 @@ async def main_async(run: Run) -> int:
         if client is not None:
             client.close()
         await child.stop()
+        # the driver's own check, made here first: nothing this run
+        # started is alive once the child has been stopped
+        processes_left = run.end_started()
         shutil.rmtree(run.out, ignore_errors=True)
 
     v = check.verdict(run.recordcount, load_vsn, logs, got, dump,
                       run.device, mesh=run.cfg.get("engine") == "mesh")
-    run.say("checked", **v)
+    run.say("checked", **v, processes_left=processes_left)
+    if processes_left:
+        return 1                # killed by now, but it was there
     facts = {"dump": dump, "log": log, "cfg": run.cfg,
              "seconds": args.seconds, "here": HERE,
              "window_end_unix": window_end_unix}

@@ -8,6 +8,12 @@ native halves, built from source when stale), ``serving`` (host, port,
 compile cache).  Commands: ``mark``, ``trace_start``, ``trace_stop``,
 ``fast_reads_off``, ``dump``, ``quit`` — see ``README.md``.
 
+This process cannot outlive the ``run.py`` that started it: before it
+imports JAX it asks the kernel for SIGKILL at its parent's death
+(:func:`die_with_parent`), and the ``make`` of ``native/`` runs under
+``make_native.py``, in a process group that the same request ends
+whole.
+
 ``--control <name>`` (never passed by the driver) serves with one
 stated guarantee broken, to show that the check fails such a run:
 ``stale_read``, ``lost_write``, ``wal_buffer``, ``python_resolve``.
@@ -17,9 +23,11 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import ctypes
 import json
 import os
 import shutil
+import signal
 import subprocess
 import sys
 import time
@@ -30,6 +38,25 @@ NATIVE_TARGETS = ("libretpu_native.so", "_retpu_resolve.so",
                   "_retpu_wire.so")
 COUNTERS = ("flushes", "ops_served", "read_fastpath_hits",
             "read_fastpath_misses")
+
+
+PR_SET_PDEATHSIG = 1    # <linux/prctl.h>
+
+
+def die_with_parent(sig: int, parent_pid: int = 0) -> None:
+    """Ask the kernel to send this process ``sig`` when the thread that
+    started it ends, however it ends: no ``finally`` of the parent has
+    to run.  The request is made after the fork, so a parent that died
+    in between is missed: where the starter's pid is given and it is
+    no longer the parent, leave at once.  Linux only (where the chip
+    is); elsewhere nothing is asked."""
+    if not sys.platform.startswith("linux"):
+        return
+    libc = ctypes.CDLL(None, use_errno=True)
+    if libc.prctl(PR_SET_PDEATHSIG, int(sig), 0, 0, 0) != 0:
+        raise OSError(ctypes.get_errno(), "prctl(PR_SET_PDEATHSIG)")
+    if parent_pid and os.getppid() != parent_pid:
+        os._exit(1)
 
 
 def say(event: str, **fields) -> None:
@@ -60,10 +87,10 @@ def build_native(work: str) -> dict:
         os.makedirs(work)
         for name in sources:
             shutil.copy2(os.path.join(src, name), work)
+        # (make_native.py: make in a group that dies with this process)
         proc = subprocess.run(
-            ["make", "-C", work, "all", "_retpu_resolve.so",
-             "_retpu_wire.so"], capture_output=True, text=True,
-            timeout=600)
+            [sys.executable, os.path.join(HERE, "make_native.py"), work,
+             str(os.getpid())], capture_output=True, text=True)
         if proc.returncode != 0:
             raise RuntimeError(
                 f"native build failed:\n{proc.stdout}\n{proc.stderr}")
@@ -277,10 +304,14 @@ def main(argv=None) -> int:
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
     ap.add_argument("--out", required=True)
     ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--parent-pid", type=int, default=0,
+                    help="run.py's pid: leave at once if it is no "
+                         "longer the parent")
     ap.add_argument("--control", default=None,
                     choices=("stale_read", "lost_write", "wal_buffer",
                              "python_resolve"))
     args = ap.parse_args(argv)
+    die_with_parent(signal.SIGKILL, args.parent_pid)    # before JAX
     sys.path.insert(0, ROOT)
     if args.control == "python_resolve":
         os.environ["RETPU_NATIVE_RESOLVE"] = "0"

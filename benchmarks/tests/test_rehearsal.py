@@ -3,8 +3,8 @@ with the look for a chip skipped.  Sound, it ends with every comparison
 inside its limit; with the timed path broken underneath (an answer
 altered where it is produced, an acknowledged write undone, the WAL not
 synced, a native half replaced by its Python fallback) the same run
-comes out not correct.  And the four-device mesh deployment is a file,
-not a code path."""
+comes out not correct.  And the four-device mesh cell is files and an
+entry, not a code path."""
 
 import json
 import os
@@ -38,6 +38,7 @@ def rehearse(cell, *extra, seed=3_000_000_017, devices=1):
     # no result line in the contract's form, no device metric
     assert not any("correct" in x and "metrics" in x for x in lines)
     by = {x["what"]: x for x in lines}
+    by["warmed_all"] = [x for x in lines if x["what"] == "warmed"]
     assert not any(m in by["per_layer"] for m in DEVICE_METRICS)
     assert {k for k in by["end_to_end"] if k.endswith(("_ms", "_s"))} == {
         "update_p50_ms", "read_p50_ms", "committed_ops_per_s", "setup_s"}
@@ -81,20 +82,20 @@ def test_weaker_guarantee_is_not_correct(control, guarantee):
     assert not by["checked"]["correct"]
 
 
-def test_mesh_deployment_is_a_file(tmp_path):
-    """``ring40k-n5-mesh4`` (engine mesh, chips 4) is in no cell yet: a
-    BENCHMARK.json that names it is all it takes."""
+def test_mesh_deployment_is_a_file():
+    """``ycsb-a.ring40k-n5-mesh4`` (engine mesh, chips 4) is a
+    configuration file, a traffic file and an entry of BENCHMARK.json:
+    the real cell, rehearsed on four virtual devices."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    cell = {"name": "ycsb-a.ring40k-n5-mesh4", "config": "ring40k-n5-mesh4",
-            "traffic": bench["workloads"][0]["traffic"], "chips": 4,
-            "why": "rehearsal"}
-    bench["workloads"].append(cell)
-    for m in bench["per_layer"]:
-        if "workloads" in m:
-            m["workloads"].append(cell["name"])
-    path = tmp_path / "BENCHMARK.json"
-    path.write_text(json.dumps(bench))
-    by = rehearse(cell["name"], "--benchmark", str(path), devices=4)
+    cell = next(w for w in bench["workloads"] if w["chips"] == 4)
+    assert cell["name"] == "ycsb-a.ring40k-n5-mesh4"
+    assert (cell["config"], cell["traffic"]) == ("ring40k-n5-mesh4",
+                                                 "ycsb-a-r400")
+    by = rehearse(cell["name"], devices=4)
     assert by["serving"]["count"] == 4
     assert by["rehearsed"]["correct_but_for_the_device"] is True
+    assert by["checked"]["processes_left"] == 0
+    assert by["per_layer"]["rounds_per_flush"]["value"] >= 1.0
+    grid = next(x for x in by["warmed_all"] if x["phase"] == "grid 0")
+    assert set(grid["first_met_by_fn"]) <= {"step", "pack"}

@@ -114,13 +114,54 @@ def test_idle_named(reduction, expect):
     assert got == (pytest.approx(expect) if expect else None)
 
 
+def recorded():
+    """PR 27's traced run of the mesh cell on four chips: the records
+    of the window, its drain and the read-back behind it."""
+    with open(os.path.join(BENCH, "testdata",
+                           "mesh4_window_records.json")) as f:
+        rec = json.load(f)
+    return rec, {"dump": {"lat_records": rec["lat_records"]},
+                 "seconds": rec["seconds"],
+                 "window_end_unix": rec["window_end_unix"],
+                 "log": types.SimpleNamespace(t0=rec["t0"])}
+
+
+def test_rounds_per_flush_reads_the_windows_records_only():
+    rec, f = recorded()
+    ks = [r["k"] for r in rec["lat_records"] if "k" in r]
+    assert len(ks) == 773 and sum(ks) / len(ks) == pytest.approx(2.0492,
+                                                                 abs=1e-4)
+    # the window's 629 flushes launched 1.496 rounds each; the drain's
+    # and the read-back's 144 were 4.5 deep
+    assert reader("window_mean")(f, field="k") == \
+        pytest.approx((1.4960254372019077, 629))
+    # without the generator's own start the window is placed by the
+    # phase's return, which the tracer delayed by 19 s here
+    del f["log"]
+    assert reader("window_mean")(f, field="k") == \
+        pytest.approx((1.5248091603053435, 524))
+
+
+@pytest.mark.parametrize("records,expect", [
+    ([], None),
+    ([{"total": 0.01, "k": 4}], None),              # no stamp
+    ([rec(995.0, 0.01)], None),                     # no such field
+    ([rec(995.0, 0.01, k=1), rec(996.0, 0.01, k=4),
+      rec(1000.5, 0.01, k=64)], (2.5, 2)),          # the read-back's
+])
+def test_window_mean(records, expect):
+    got = reader("window_mean")(facts(records), field="k")
+    assert got == (pytest.approx(expect) if expect else None)
+
+
 def test_layer_files_name_readers_that_exist():
     with open(os.path.join(os.path.dirname(BENCH),
                            "BENCHMARK.json")) as f:
         bench = json.load(f)
     names = [m["name"] for m in bench["per_layer"]]
-    assert names[-5:] == [*SPAN_METRICS, "idle_named_share"]
-    for name in names[-5:]:
+    assert names[-6:] == [*SPAN_METRICS, "idle_named_share",
+                          "rounds_per_flush"]
+    for name in names[-6:]:
         with open(os.path.join(BENCH, "layers", name + ".json")) as f:
             spec = json.load(f)
         assert callable(reader(spec["reader"]))
