@@ -1573,19 +1573,131 @@ def _full_step_wide_sliced_body(state: EngineState,
     return _scatter_columns(state, sub, active_idx), won, res
 
 
-full_step_sliced = jax.jit(_full_step_sliced_body,
-                           static_argnames=("axis_name",))
-
-#: donated-state variant (see :data:`full_step_donate`): the scatter
-#: back into the donated full planes is an in-place A-row update.
-full_step_sliced_donate = jax.jit(_full_step_sliced_body,
-                                  static_argnames=("axis_name",),
-                                  donate_argnums=(0,))
+# (the scalar sliced step is jitted in its op-slab form below:
+# full_step_sliced_slab, the one form the service launches)
 
 full_step_wide_sliced = jax.jit(_full_step_wide_sliced_body,
                                 static_argnames=("axis_name",))
 
 full_step_wide_sliced_donate = jax.jit(_full_step_wide_sliced_body,
+                                       static_argnames=("axis_name",),
+                                       donate_argnums=(0,))
+
+
+# ---------------------------------------------------------------------------
+# The op slab: one scalar launch's host-built operands as ONE array
+#
+# THE layout (the only statement of it; the host packs with
+# :func:`pack_op_slab`, the step programs take it apart with
+# :func:`split_op_slab`).  int32 ``[R, W]``, W the launch's column
+# width (the active bucket on a sliced launch, E otherwise):
+#
+#   row 0            elect       [W]   bool as 0/1
+#   row 1            cand        [W]
+#   row 2            lease_ok    [W]   bool as 0/1, ONE row: the program
+#                                      broadcasts it to [K, W]
+#   row 3            active_idx  [W]   SLICED launches only (pad = E)
+#   then K rows each kind, slot, val, exp_epoch, exp_seq   ([K, W] each)
+#
+# R = head + 5 K with head 3 (4 sliced), so the shape alone carries K
+# and W and a (K, A) bucket stays one program.  Under ``shard_map`` the
+# slab is sharded ``P(None, 'ens')`` like the op planes it replaces: a
+# row of the local block is that shard's ``P('ens')`` vector.
+
+SLAB_ELECT, SLAB_CAND, SLAB_LEASE, SLAB_ACTIVE_IDX = 0, 1, 2, 3
+#: the per-round planes, K rows each, in slab order
+SLAB_PLANES = ("kind", "slot", "val", "exp_epoch", "exp_seq")
+
+
+def _slab_head(sliced: bool) -> int:
+    return SLAB_ACTIVE_IDX + 1 if sliced else SLAB_ACTIVE_IDX
+
+
+def pack_op_slab(width: int, k: int, elect, cand, lease_ok, planes,
+                 active=None, active_idx=None) -> np.ndarray:
+    """Host half of the op slab: a FRESH ``[R, width]`` int32 array
+    (an upload may still be reading the previous launch's).
+
+    ``planes`` are the five ``[K, E]`` host planes in
+    :data:`SLAB_PLANES` order (None = all zero, the absent CAS
+    versions).  Full width: ``width`` is E and everything is copied
+    whole.  Sliced (``active_idx`` given, ``[width]``, pad = E):
+    ``active`` names the real columns, gathered to the slab's first
+    ``len(active)`` columns; padding columns stay NOOP/zero."""
+    sliced = active_idx is not None
+    head = _slab_head(sliced)
+    slab = np.zeros((head + len(SLAB_PLANES) * k, width), np.int32)
+    n = len(active) if sliced else width
+
+    def cols(x):
+        x = np.asarray(x)
+        return x[..., active] if sliced else x
+
+    slab[SLAB_ELECT, :n] = cols(elect)
+    slab[SLAB_CAND, :n] = cols(cand)
+    slab[SLAB_LEASE, :n] = cols(lease_ok)
+    if sliced:
+        slab[SLAB_ACTIVE_IDX] = active_idx
+    if k:
+        body = slab[head:].reshape(len(SLAB_PLANES), k, width)
+        for i, p in enumerate(planes):
+            if p is not None:
+                body[i, :, :n] = cols(p)
+    return slab
+
+
+def split_op_slab(slab: jax.Array, sliced: bool = False):
+    """Program half: ``(elect, cand, lease_ok[K, W], kind, slot, val,
+    exp_epoch, exp_seq)`` — the step bodies' operands in their call
+    order — at static offsets of the slab (a sliced slab's index row
+    is read by the caller)."""
+    head = _slab_head(sliced)
+    n_p = len(SLAB_PLANES)
+    w = slab.shape[1]
+    k = (slab.shape[0] - head) // n_p
+    body = slab[head:].reshape(n_p, k, w)
+    lease = jnp.broadcast_to(slab[SLAB_LEASE] != 0, (k, w))
+    return (slab[SLAB_ELECT] != 0, slab[SLAB_CAND], lease,
+            *(body[i] for i in range(n_p)))
+
+
+def _full_step_slab_body(state: EngineState, slab: jax.Array,
+                         up: jax.Array,
+                         axis_name: Optional[str] = None
+                         ) -> Tuple[EngineState, jax.Array, KvResult]:
+    """:func:`_full_step_body` fed by one op slab (layout above)."""
+    elect, cand, lease, kind, slot, val, xe, xs = split_op_slab(slab)
+    return _full_step_body(state, elect, cand, kind, slot, val, lease,
+                           up, axis_name=axis_name, exp_epoch=xe,
+                           exp_seq=xs)
+
+
+def _full_step_sliced_slab_body(state: EngineState, slab: jax.Array,
+                                up: jax.Array,
+                                axis_name: Optional[str] = None
+                                ) -> Tuple[EngineState, jax.Array,
+                                           KvResult]:
+    """:func:`_full_step_sliced_body` fed by one A-width op slab whose
+    row :data:`SLAB_ACTIVE_IDX` is the active-column index vector."""
+    elect, cand, lease, kind, slot, val, xe, xs = split_op_slab(
+        slab, sliced=True)
+    return _full_step_sliced_body(
+        state, slab[SLAB_ACTIVE_IDX], elect, cand, kind, slot, val,
+        lease, up, axis_name=axis_name, exp_epoch=xe, exp_seq=xs)
+
+
+#: the served step programs: ``(state, slab, up)``, plain and donated
+#: (see :data:`full_step_donate` for the aliasing contract; the sliced
+#: step's scatter back into the donated full planes is an in-place
+#: A-row update)
+full_step_slab = jax.jit(_full_step_slab_body,
+                         static_argnames=("axis_name",))
+full_step_slab_donate = jax.jit(_full_step_slab_body,
+                                static_argnames=("axis_name",),
+                                donate_argnums=(0,))
+full_step_sliced_slab = jax.jit(_full_step_sliced_slab_body,
+                                static_argnames=("axis_name",))
+full_step_sliced_slab_donate = jax.jit(_full_step_sliced_slab_body,
                                        static_argnames=("axis_name",),
                                        donate_argnums=(0,))
 

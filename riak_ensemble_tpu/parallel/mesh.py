@@ -88,7 +88,8 @@ class ShardedEngine:
     E must divide by mesh 'ens' size; M by mesh 'peer' size (pad views
     with absent peers if needed — all-zero view columns are inert).
 
-    The fused serving steps (``full_step``/``full_step_wide`` and
+    The fused serving steps (``full_step``/``full_step_wide``, the
+    op-slab form ``full_step_slab`` the served launch dispatches, and
     their ``_donate`` variants) are INSTANCE attributes: plain
     wrappers over the shard_map'd programs that default absent CAS
     planes and forward ``_cache_size`` so ``CompileWatch`` sees mesh
@@ -135,6 +136,23 @@ class ShardedEngine:
         self._full = smap(_full_body, _full_in, _full_out)
         self._full_donate = smap(_full_body, _full_in, _full_out,
                                  donate=True)
+
+        def _slab_body(st, slab, up):
+            # the local block of the op slab (engine.split_op_slab has
+            # the layout): each shard takes its own columns apart
+            el, ca, lz, k, sl, v, xe, xs = eng.split_op_slab(slab)
+            return _full_body(st, el, ca, k, sl, v, lz, up, xe, xs)
+
+        #: where the served launch puts its two host operands: the
+        #: step's own specs, so the dispatch places nothing
+        self.slab_sharding = NamedSharding(mesh, P(None, "ens"))
+        self.up_sharding = NamedSharding(mesh, P("ens", "peer"))
+        _slab_in = (_STATE_SPECS, P(None, "ens"), P("ens", "peer"))
+        # (state, slab, up): the jitted programs as they are (no CAS
+        # planes to default, and `_cache_size` is their own)
+        self.full_step_slab = smap(_slab_body, _slab_in, _full_out)
+        self.full_step_slab_donate = smap(_slab_body, _slab_in,
+                                          _full_out, donate=True)
         _wide_in = (_STATE_SPECS, P("ens"), P("ens"),
                     P(None, "ens", None), P(None, "ens", None),
                     P(None, "ens", None), P(None, "ens", None),

@@ -19,8 +19,7 @@ import pytest
 
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
-from jax.sharding import (  # noqa: E402
-    NamedSharding, PartitionSpec as P, SingleDeviceSharding)
+from jax.sharding import SingleDeviceSharding  # noqa: E402
 
 from riak_ensemble_tpu.ops import engine as eng  # noqa: E402
 from riak_ensemble_tpu.ops import pallas_quorum  # noqa: E402
@@ -75,20 +74,16 @@ def _placed(shapes, sharding):
         shapes)
 
 
-def _step_args(e, m, k, place, a=None):
-    """([active_idx,] elect, cand, kind, slot, val, lease, up,
-    exp_epoch, exp_seq) as placed shapes; ``a`` = the sliced step's
-    active-column width.  ``place(name, shape, dtype)`` returns the
+def _step_args(e, m, k, place):
+    """(elect, cand, kind, slot, val, lease, up, exp_epoch, exp_seq) as
+    placed shapes.  ``place(name, shape, dtype)`` returns the
     ShapeDtypeStruct for one operand."""
-    w = e if a is None else a
-    ops = [("elect", (w,), jnp.bool_), ("cand", (w,), jnp.int32),
-           ("kind", (k, w), jnp.int32), ("slot", (k, w), jnp.int32),
-           ("val", (k, w), jnp.int32), ("lease", (k, w), jnp.bool_),
+    ops = [("elect", (e,), jnp.bool_), ("cand", (e,), jnp.int32),
+           ("kind", (k, e), jnp.int32), ("slot", (k, e), jnp.int32),
+           ("val", (k, e), jnp.int32), ("lease", (k, e), jnp.bool_),
            ("up", (e, m), jnp.bool_),
-           ("exp_epoch", (k, w), jnp.int32),
-           ("exp_seq", (k, w), jnp.int32)]
-    if a is not None:
-        ops.insert(0, ("active_idx", (a,), jnp.int32))
+           ("exp_epoch", (k, e), jnp.int32),
+           ("exp_seq", (k, e), jnp.int32)]
     return [place(*op) for op in ops]
 
 
@@ -127,10 +122,10 @@ def test_sliced_donated_step_compiles_at_headline_shape(one_chip):
     state = _placed(jax.eval_shape(lambda: eng.init_state(E, M, S)),
                     one_chip)
 
-    aidx, *ops = _step_args(E, M, 16, _on(one_chip), a=256)
-    *pos, xe, xs = ops
-    compiled = eng.full_step_sliced_donate.lower(
-        state, aidx, *pos, exp_epoch=xe, exp_seq=xs).compile()
+    place = _on(one_chip)
+    compiled = eng.full_step_sliced_slab_donate.lower(
+        state, place("slab", (4 + 5 * 16, 256), jnp.int32),
+        place("up", (E, M), jnp.bool_)).compile()
     mem = compiled.memory_analysis()
     # the state alone is ~0.2 GB; the program must fit the 16 GB chip
     assert 0 < mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
@@ -170,15 +165,12 @@ def test_mesh_step_compiles_on_four_chips_without_ens_collectives(topo):
     mesh = engine.mesh
     state = _placed(jax.eval_shape(lambda: eng.init_state(e, M, S)),
                     eng.state_sharding(mesh))
-    spec = {"elect": P("ens"), "cand": P("ens"), "up": P("ens", "peer")}
-
-    def place(name, shape, dtype):
-        return jax.ShapeDtypeStruct(
-            shape, dtype,
-            sharding=NamedSharding(mesh, spec.get(name, P(None, "ens"))))
-
-    compiled = engine._full_donate.lower(
-        state, *_step_args(e, M, k, place)).compile()
+    compiled = engine.full_step_slab_donate.lower(
+        state,
+        jax.ShapeDtypeStruct((3 + 5 * k, e), jnp.int32,
+                             sharding=engine.slab_sharding),
+        jax.ShapeDtypeStruct((e, M), jnp.bool_,
+                             sharding=engine.up_sharding)).compile()
     text = compiled.as_text()
     assert not _COLLECTIVES.search(text), _COLLECTIVES.findall(text)
     # each device holds its quarter of the state, not all of it

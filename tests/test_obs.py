@@ -632,8 +632,8 @@ def test_served_flush_total_is_the_sum_of_the_same_marks(
         assert r["obs"] > 0.0 and r["pack"] >= 0.0
         # every span has a start stamp, the record a wall-clock anchor
         timed = {c for c in r
-                 if c not in ("k", "total", "enqueue", "starts",
-                              "clock")
+                 if c not in ("k", "uploads", "total", "enqueue",
+                              "starts", "clock")
                  and not c.startswith(("enqueue_", ))}
         assert timed <= set(r["starts"]), timed - set(r["starts"])
         assert len(r["clock"]) == 2
@@ -881,11 +881,8 @@ def _lowered(program):
     st = eng.init_state(e, m, s)
     up = jnp.ones((e, m), bool)
     if program == "step_sliced":
-        z = jnp.zeros((k, a), jnp.int32)
-        low = eng.full_step_sliced.lower(
-            st, jnp.arange(a, dtype=jnp.int32), jnp.zeros((a,), bool),
-            jnp.zeros((a,), jnp.int32), z, z, z,
-            jnp.zeros((k, a), bool), up, exp_epoch=z, exp_seq=z)
+        low = eng.full_step_sliced_slab.lower(
+            st, jnp.zeros((4 + 5 * k, a), jnp.int32), up)
     else:
         z = jnp.zeros((k, e), jnp.int32)
         args = (st, jnp.zeros((e,), bool), jnp.zeros((e,), jnp.int32),
