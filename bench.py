@@ -2452,55 +2452,6 @@ def _run_stepprobe(timeout: float, shapes: dict) -> "dict | None":
     return partial
 
 
-def run_widecmp(n_ens: int, n_peers: int, n_slots: int, k: int,
-                seconds: float) -> dict:
-    """Wide-scheduling A/B: the SAME distinct-slot op plane through a
-    scalar-scan service and a wide (RETPU_WIDE-style) service, one
-    process, same workload both arms.  Distinct slots per ensemble
-    guarantee the wide arm really takes the wide path (asserted via
-    wide_launches) — random slots would chain past the G<=2 gate and
-    silently compare scalar against scalar."""
-    from riak_ensemble_tpu.ops import engine as eng
-    from riak_ensemble_tpu.parallel.batched_host import (
-        BatchedEnsembleService, WallRuntime)
-
-    assert k <= n_slots, \
-        f"distinct-slot plane needs k <= n_slots ({k} > {n_slots})"
-    rng = np.random.default_rng(0)
-    kind = rng.choice([eng.OP_PUT, eng.OP_GET],
-                      (k, n_ens)).astype(np.int32)
-    slot = np.stack([rng.permutation(n_slots)[:k]
-                     for _ in range(n_ens)], axis=1).astype(np.int32)
-    val = rng.integers(1, 1 << 20, (k, n_ens), dtype=np.int32)
-
-    out: dict = {}
-    for wide in (False, True):
-        svc = BatchedEnsembleService(WallRuntime(), n_ens, n_peers,
-                                     n_slots, tick=None,
-                                     max_ops_per_tick=k)
-        svc._wide = wide
-        # Warm the exact programs this arm launches (first call also
-        # runs the elections fold-in).
-        svc.execute(kind, slot, val)
-        svc.execute(kind, slot, val)
-        t_end = time.perf_counter() + seconds
-        iters = 0
-        t0 = time.perf_counter()
-        while time.perf_counter() < t_end or not iters:
-            svc.execute(kind, slot, val)
-            iters += 1
-        elapsed = time.perf_counter() - t0
-        if wide:
-            assert svc.wide_launches > 0, \
-                "wide arm never took the wide path"
-        out["wide_ops_per_sec" if wide else "scalar_ops_per_sec"] = (
-            n_ens * k * iters / elapsed)
-        svc.stop()
-    out["wide_speedup"] = (out["wide_ops_per_sec"]
-                           / out["scalar_ops_per_sec"])
-    return out
-
-
 #: internal wall budget for the tpuprobe stage — under the driver's
 #: 600 s stage timeout so the probe trims its own tail (ladder rungs,
 #: A/B arms) instead of being SIGKILLed mid-measurement.
@@ -2515,12 +2466,11 @@ def run_tpuprobe(seconds: float) -> dict:
     individually timed; (b) the CompileWatch ledger from a full
     service warmup — a blown budget then reads "N named compiles cost
     X s", not "timeout"; (c) the ascending step ladder toward the
-    headline shape; (d) the Pallas-quorum and wide-scheduling A/Bs
-    with their mechanical keep/kill verdicts (Pallas: KEEP iff >= 10%
-    fused-step win at any ladder shape with bit-equal results; wide:
-    KEEP iff >= 1.2x on the distinct-slot widecmp rung — both
-    TPU-gated, so a CPU box reports "pending-tpu" alongside its
-    measured numbers; the wiring itself is rehearsed end to end).
+    headline shape; (d) the Pallas-quorum A/B with its mechanical
+    keep/kill verdict (KEEP iff >= 10% fused-step win at any ladder
+    shape with bit-equal results — TPU-gated, so a CPU box reports
+    "pending-tpu" alongside its measured numbers; the wiring itself is
+    rehearsed end to end).
 
     The Pallas arms run as SUBPROCESSES: ``RETPU_PALLAS_QUORUM`` binds
     at engine-module import, so an in-process A/B would silently
@@ -2538,7 +2488,7 @@ def run_tpuprobe(seconds: float) -> dict:
         return deadline - time.perf_counter()
 
     out: dict = {"staging": ["tiny_step", "compile_ledger", "ladder",
-                             "pallas_ab", "wide_ab"]}
+                             "pallas_ab"]}
 
     # (a) one tiny fused step, each launch timed individually — the
     # cheapest possible "is the chip actually executing" evidence.
@@ -2652,32 +2602,6 @@ def run_tpuprobe(seconds: float) -> dict:
             f"speedup={pallas_ab['speedup']} "
             f"bitequal={pallas_ab.get('bitequal')} vs the "
             ">=1.10-with-bit-equality bar")
-
-    # (d2) wide-scheduling A/B: in-process (the wide path is a
-    # service attribute, not an import-time knob).
-    try:
-        wide = run_widecmp(1024, 5, 64, 16, arm_secs)
-        out["wide_ab"] = {k: round(v, 1) if "per_sec" in k
-                          else round(v, 3)
-                          for k, v in wide.items()}
-        wide_speedup = wide["wide_speedup"]
-    except Exception as exc:
-        out["wide_ab"] = {"error": f"{type(exc).__name__}: {exc}"}
-        wide_speedup = None
-    if platform == "cpu":
-        out["wide_verdict"] = "pending-tpu"
-        out["wide_verdict_reason"] = (
-            "KEEP iff >=1.2x on the distinct-slot widecmp rung on "
-            "TPU; CPU numbers recorded above")
-    elif wide_speedup is None:
-        out["wide_verdict"] = "kill"
-        out["wide_verdict_reason"] = ("widecmp failed on the live "
-                                      "accelerator")
-    else:
-        out["wide_verdict"] = ("keep" if wide_speedup >= 1.2
-                               else "kill")
-        out["wide_verdict_reason"] = (
-            f"wide_speedup={round(wide_speedup, 3)} vs the 1.2x bar")
     return out
 
 
@@ -3689,8 +3613,6 @@ def _stage_entry(args) -> None:
         out = run_tpuprobe(args.seconds)
     elif args.stage == "stepprobe":
         out = run_stepprobe(**shapes)
-    elif args.stage == "widecmp":
-        out = run_widecmp(seconds=args.seconds, **shapes)
     elif args.stage == "repgroup":
         out = run_repgroup(args.seconds, smoke=False)
     elif args.stage == "faultsweep":
@@ -3738,7 +3660,7 @@ def main() -> None:
     ap.add_argument("--stage",
                     choices=("kernel", "service", "merkle", "reconfig",
                              "probe", "stepprobe", "repgroup",
-                             "widecmp", "escale", "faultsweep",
+                             "escale", "faultsweep",
                              "autotune", "fleetobs", "recovery",
                              "ingress", "commrepl", "tpuprobe"),
                     help="internal: run one stage in-process")
@@ -4164,7 +4086,7 @@ def main() -> None:
         "escale_mesh": svc.get("escale_mesh"),
         "escale_eff": svc.get("escale_eff"),
         # staged TPU probe (--stage tpuprobe): compile ledger, ladder
-        # and the Pallas-quorum/wide keep/kill verdicts (pending-tpu
+        # and the Pallas-quorum keep/kill verdict (pending-tpu
         # until a live window executes them on a real accelerator)
         "tpuprobe": svc.get("tpuprobe"),
         # bench-trend ratchet (smoke path): the trajectory check's

@@ -253,20 +253,6 @@ def scan_result_specs(ens: Optional[str] = "ens",
     )
 
 
-def wide_result_specs(ens: Optional[str] = "ens",
-                      peer: Optional[str] = "peer") -> "KvResult":
-    """:class:`KvResult` specs for :func:`kv_step_scan_wide`'s
-    ``[G, E, W]`` planes (``obj_vsn`` ``[G, E, W, 2]``,
-    ``tree_corrupt`` ``[G, E, M]``)."""
-    from jax.sharding import PartitionSpec as P
-    return KvResult(
-        committed=P(None, ens, None), get_ok=P(None, ens, None),
-        found=P(None, ens, None), value=P(None, ens, None),
-        obj_vsn=P(None, ens, None, None), quorum_ok=P(None, ens, None),
-        tree_corrupt=P(None, ens, peer),
-    )
-
-
 def state_sharding(mesh) -> "EngineState":
     """:func:`state_specs` bound to a concrete mesh: an
     :class:`EngineState` of ``NamedSharding`` ready for
@@ -645,26 +631,21 @@ def _kv_round(state: EngineState, ctx: _KvCtx, kind: jax.Array,
               exp_epoch: Optional[jax.Array] = None,
               exp_seq: Optional[jax.Array] = None
               ) -> Tuple[EngineState, KvResult]:
-    """One WIDE K/V protocol round given a precomputed context.
+    """One K/V protocol round given a precomputed context.
 
-    kind/slot/val/lease_ok/exp_epoch/exp_seq are ``[E, W]``: W
-    conflict-free op lanes per ensemble — the host schedules ops so
-    that the valid slots within a row are DISTINCT (duplicate-slot
-    ops go to later rounds), which is SURVEY §2.7's "conflict-free
-    slots advance in one batched kernel step".  Lanes see the
-    pre-round state (atomic for CAS because no other lane touches the
-    same slot) and commit seqs in lane order, so on a corruption-free
-    tree the result is bit-identical to applying the lanes as W
-    sequential 1-op rounds.  ``kv_step`` is exactly that with W = 1.
+    kind/slot/val/lease_ok/exp_epoch/exp_seq are ``[E, W]``: a lane
+    axis of W op lanes per ensemble whose valid slots must be DISTINCT
+    within a row.  Every caller in the tree passes W = 1
+    (``kind[:, None]``, squeezed after by :func:`_squeeze_lane`): the
+    host scheduler that filled wider rows is gone, and the axis stays
+    only because taking it out changes the served step's HLO (ROADMAP
+    D12).  Lanes see the pre-round state and commit seqs in lane
+    order; with W = 1 that is one op per ensemble per round.
 
-    Corruption caveat: lanes verify against the PRE-round tree, so
-    when two lanes' paths share an out-of-band-corrupted internal
-    node, a sequential application could let the first lane's read
-    repair heal the shared path before the second lane's gate runs;
-    the wide round instead excludes the replica on BOTH lanes and
-    flags it in ``tree_corrupt`` — strictly more conservative (an
-    unhealed path is never trusted), healed by the same repair/scrub
-    machinery one round later.
+    Corruption: a lane verifies against the PRE-round tree; a replica
+    whose path fails is excluded from the round and flagged in
+    ``tree_corrupt``, healed by the repair/scrub machinery one round
+    later.
     """
     e, ml = state.epoch.shape
     s = state.obj_epoch.shape[-1]
@@ -1004,87 +985,6 @@ def kv_step_scan(state: EngineState, kind: jax.Array, slot: jax.Array,
     return _adopt_epochs(state, ctx), res
 
 
-@functools.partial(jax.jit, static_argnames=("axis_name",))
-def kv_step_scan_wide(state: EngineState, kind: jax.Array,
-                      slot: jax.Array, val: jax.Array,
-                      lease_ok: jax.Array, up: jax.Array,
-                      axis_name: Optional[str] = None,
-                      exp_epoch: Optional[jax.Array] = None,
-                      exp_seq: Optional[jax.Array] = None
-                      ) -> Tuple[EngineState, KvResult]:
-    """G sequential WIDE rounds of W conflict-free lanes per launch.
-
-    kind/slot/val/lease_ok (and exp_epoch/exp_seq): ``[G, E, W]``.
-    The host schedules each flush's ops so a round's valid slots are
-    distinct within every ensemble (duplicate-slot ops land in later
-    rounds — occurrence-index grouping), which keeps per-key
-    serialization while amortizing the round's fixed cost (context
-    reuse, quorum reduces, gather/scatter launch overhead) over W ops
-    instead of 1.  Results are stacked ``[G, E, W]``.
-
-    Equivalent by construction to ``kv_step_scan`` over the same ops
-    flattened to ``[G*W, E]`` in (group, lane) order — differentially
-    tested in tests/test_engine_wide.py.
-
-    PRECONDITION (caller contract, not checked inside jit): within
-    every ``[g, e]`` row, the slots of valid ops (kind != OP_NOOP)
-    must be DISTINCT.  Duplicate scatter targets with differing values
-    in one round produce nondeterministic state (JAX leaves duplicate-
-    index scatter order unspecified).  The host scheduler
-    (ops/schedule.py) guarantees this by occurrence-index grouping;
-    direct kernel callers (mesh.ShardedEngine included) must do the
-    same, or run :func:`validate_wide_plane` on the concrete planes
-    (enabled in the service via ``RETPU_VALIDATE_WIDE=1``).
-    """
-    ctx = _kv_context(state, up, axis_name)
-    if exp_epoch is None:
-        exp_epoch = jnp.zeros_like(kind)
-    if exp_seq is None:
-        exp_seq = jnp.zeros_like(kind)
-
-    def body(st, op):
-        k, sl, v, lz, xe, xs = op
-        st2, r = _kv_round(st, ctx, k, sl, v, lz, axis_name, xe, xs)
-        return st2, r
-
-    state, res = jax.lax.scan(
-        body, state, (kind, slot, val, lease_ok, exp_epoch, exp_seq))
-    return _adopt_epochs(state, ctx), res
-
-
-def validate_wide_plane(kind, slot) -> None:
-    """Check the wide-round conflict-free precondition on CONCRETE
-    ``[G, E, W]`` planes: within one ``[g, e]`` row, ops with
-    kind != OP_NOOP and slot >= 0 must target distinct slots.  This is
-    deliberately STRICTER than the kernel's write gate (slot_valid
-    also requires slot < n_slots, engine.py ``_kv_round``): the
-    validator has no n_slots, and it mirrors the scheduler's chaining
-    rule exactly — schedule.py chains any slot >= 0 and gives slot < 0
-    ops forced-unique keys — so a plane the scheduler would emit never
-    trips it.  Raises ValueError with the first offending
-    (group, ensemble, slot).  Host-side only (not traceable); the
-    service runs it under ``RETPU_VALIDATE_WIDE=1``.
-    """
-    kind = np.asarray(kind)
-    slot = np.asarray(slot)
-    g, e, w = kind.shape
-    valid = (kind != OP_NOOP) & (slot >= 0)
-    # sentinel-out non-writing lanes (legal slots are >= 0, so distinct
-    # negative sentinels can never collide with a real slot), then look
-    # for duplicate slots per row
-    s = np.where(valid, slot, -1 - np.arange(w))
-    s_sorted = np.sort(s, axis=-1)
-    dup = (s_sorted[..., 1:] == s_sorted[..., :-1]).any(-1)
-    if dup.any():
-        gi, ei = np.argwhere(dup)[0]
-        row = slot[gi, ei][valid[gi, ei]]
-        vals, counts = np.unique(row, return_counts=True)
-        raise ValueError(
-            f"wide plane violates the conflict-free precondition: "
-            f"group {gi}, ensemble {ei} has duplicate valid slot "
-            f"{int(vals[counts > 1][0])} (kv_step_scan_wide docstring)")
-
-
 # ---------------------------------------------------------------------------
 # Result-plane compaction (active-column gather)
 
@@ -1093,8 +993,7 @@ def gather_result_columns(res: KvResult,
                           active_idx: jax.Array) -> KvResult:
     """Active-column compaction of a packed-layout result: gather the
     per-round ensemble axis of the CLIENT result planes down to the
-    active column set — ``[K, E] → [K, A]`` (``[G·W, E] → [G·W, A]``
-    for a wide launch already reshaped to round-major rows).
+    active column set — ``[K, E] → [K, A]``.
 
     ``active_idx [A]`` holds the global column indices the flush
     actually scheduled ops into, A pow2-bucketed by the host for
@@ -1446,39 +1345,6 @@ full_step_donate = jax.jit(_full_step_body,
                            donate_argnums=(0,))
 
 
-def _full_step_wide_body(state: EngineState, elect: jax.Array,
-                         cand: jax.Array, kind: jax.Array,
-                         slot: jax.Array, val: jax.Array,
-                         lease_ok: jax.Array, up: jax.Array,
-                         axis_name: Optional[str] = None,
-                         exp_epoch: Optional[jax.Array] = None,
-                         exp_seq: Optional[jax.Array] = None
-                         ) -> Tuple[EngineState, jax.Array, KvResult]:
-    """``full_step`` with ``[G, E, W]`` conflict-free op planes (see
-    :func:`kv_step_scan_wide`) — the wide-scheduled flagship step.
-
-    Carries :func:`kv_step_scan_wide`'s precondition: valid slots must
-    be distinct within every ``[g, e]`` row (see its docstring;
-    :func:`validate_wide_plane` checks concrete planes)."""
-    with jax.named_scope("elect"):
-        state, won = elect_step(state, elect, cand, up,
-                                axis_name=axis_name)
-    state, res = kv_step_scan_wide(
-        state, kind, slot, val, lease_ok, up, axis_name=axis_name,
-        exp_epoch=exp_epoch, exp_seq=exp_seq)
-    return state, won, res
-
-
-full_step_wide = jax.jit(_full_step_wide_body,
-                         static_argnames=("axis_name",))
-
-#: donated-state variant of :func:`full_step_wide` (see
-#: :data:`full_step_donate` for the aliasing contract).
-full_step_wide_donate = jax.jit(_full_step_wide_body,
-                                static_argnames=("axis_name",),
-                                donate_argnums=(0,))
-
-
 # ---------------------------------------------------------------------------
 # Active-column SLICED full step (the shrunk [K, A] launch grid)
 
@@ -1550,38 +1416,6 @@ def _full_step_sliced_body(state: EngineState, active_idx: jax.Array,
         sub, elect, cand, kind, slot, val, lease_ok, up_a,
         axis_name=axis_name, exp_epoch=exp_epoch, exp_seq=exp_seq)
     return _scatter_columns(state, sub, active_idx), won, res
-
-
-def _full_step_wide_sliced_body(state: EngineState,
-                                active_idx: jax.Array,
-                                elect: jax.Array, cand: jax.Array,
-                                kind: jax.Array, slot: jax.Array,
-                                val: jax.Array, lease_ok: jax.Array,
-                                up: jax.Array,
-                                axis_name: Optional[str] = None,
-                                exp_epoch: Optional[jax.Array] = None,
-                                exp_seq: Optional[jax.Array] = None
-                                ) -> Tuple[EngineState, jax.Array,
-                                           KvResult]:
-    """:func:`_full_step_sliced_body` with ``[G, A, W]`` conflict-free
-    wide op planes (see :func:`kv_step_scan_wide`; same active-set
-    contract as the scalar sliced step)."""
-    sub, up_a = _slice_columns(state, active_idx, up)
-    sub, won, res = _full_step_wide_body(
-        sub, elect, cand, kind, slot, val, lease_ok, up_a,
-        axis_name=axis_name, exp_epoch=exp_epoch, exp_seq=exp_seq)
-    return _scatter_columns(state, sub, active_idx), won, res
-
-
-# (the scalar sliced step is jitted in its op-slab form below:
-# full_step_sliced_slab, the one form the service launches)
-
-full_step_wide_sliced = jax.jit(_full_step_wide_sliced_body,
-                                static_argnames=("axis_name",))
-
-full_step_wide_sliced_donate = jax.jit(_full_step_wide_sliced_body,
-                                       static_argnames=("axis_name",),
-                                       donate_argnums=(0,))
 
 
 # ---------------------------------------------------------------------------

@@ -7,16 +7,20 @@ gossip).  This module is the scale path the north star describes: a
 host *service* that multiplexes thousands of engine-backed ensembles —
 
 - client ops (kget/kput/kdelete) queue per ensemble and flush as one
-  ``full_step`` launch per tick: ``[K, E]`` op matrices, one device
+  fused-step launch per tick: ``[K, E]`` op matrices, one device
   dispatch for every queued op of every ensemble (the batched analog
-  of E leader processes × worker pools);
+  of E leader processes × worker pools).  There is ONE way into the
+  step: every launch (a flush, an election-only round, ``execute()``,
+  a replica's apply) uploads one op slab and calls one program,
+  ``(state, op slab, up) -> (state, won, result)``, sliced where one
+  chip can slice and full width otherwise (``_launch_enqueue``);
 - the host side keeps what consensus doesn't need on-device: the
   key→slot assignment per ensemble, the payload store (device arrays
   carry int32 handles; real bytes live host-side keyed by handle —
   engine.py's object-store contract), per-ensemble leases (monotonic
   clock), and the failure detector (an ``up`` mask per ensemble);
 - leaderless or leader-down ensembles get an election folded into the
-  SAME launch (``full_step``'s elect inputs) — the thundering-herd
+  SAME launch (the slab's elect rows) — the thundering-herd
   re-election after failures is one kernel call, not E timers.
 
 Results come back to client futures after each flush (one d2h per
@@ -95,6 +99,7 @@ from riak_ensemble_tpu import faults, obs
 from riak_ensemble_tpu.config import Config
 from riak_ensemble_tpu.ops import engine as eng
 from riak_ensemble_tpu.parallel import enqueue_native, resolve_native
+from riak_ensemble_tpu.parallel.mesh import shard_active_columns
 from riak_ensemble_tpu.runtime import Future, Runtime, Timer
 from riak_ensemble_tpu.types import NOTFOUND
 
@@ -304,22 +309,6 @@ def _select_packer(engine):
     return functools.partial(_pack_results_gathered, sharding=rep)
 
 
-def _wide_to_packed_layout(res: eng.KvResult, g: int, w: int,
-                           e: int) -> eng.KvResult:
-    """Reshape a wide [G, E, W] result into the packed [G*W, E] layout
-    (lane-major per group) so :func:`_pack_results`/
-    :func:`unpack_results` serve both step flavors unchanged; the
-    launch path then routes rows back to op order via the plan's
-    (map_g, map_w)."""
-    def t(x):
-        return x.transpose(0, 2, 1).reshape(g * w, e)
-    return res._replace(
-        committed=t(res.committed), get_ok=t(res.get_ok),
-        found=t(res.found), value=t(res.value),
-        obj_vsn=res.obj_vsn.transpose(0, 2, 1, 3).reshape(g * w, e, 2),
-        quorum_ok=t(res.quorum_ok))
-
-
 #: smallest active-column bucket the pack compiles: below 8 columns
 #: the payload is mostly headers anyway, and every extra (K, A)
 #: bucket is one more XLA program — the floor keeps the warm grid
@@ -363,8 +352,8 @@ def unpack_results(flat: np.ndarray, e: int, m: int, k: int,
     compacted ``[K, A]`` and are scattered back through the index
     list into full-width ``[K, E]`` arrays — inactive columns get the
     all-false/zero NOOP results a full-width pack would have carried
-    for them, so every downstream consumer (resolve loops, wide
-    routing, WAL, replica CRC) is layout-blind.  ``sliced`` marks a
+    for them, so every downstream consumer (resolve loops, WAL,
+    replica CRC) is layout-blind.  ``sliced`` marks a
     launch whose step itself ran on the gathered grid: then the
     won/quorum_ok/corrupt planes are A-width too and scatter the
     same way (inactive columns won nothing, renewed nothing and
@@ -441,8 +430,8 @@ def unpack_results_sharded(flat: np.ndarray, e: int, m: int, k: int,
     (``shard_active[s]``, ≤ ``a_width`` entries; None = every shard
     at full width).  Each block unpacks through the single-shard
     oracle and the full-width planes concatenate back along E — so
-    every downstream consumer (mirror scatter, WAL, wide routing,
-    replica CRC) stays layout-blind, exactly as with the gathered
+    every downstream consumer (mirror scatter, WAL, replica CRC)
+    stays layout-blind, exactly as with the gathered
     pack."""
     e_loc = e // n_shards
     nb = packed_nbytes(e_loc, m, k, want_vsn,
@@ -501,14 +490,7 @@ class _LocalEngine:
     """
 
     init_state = staticmethod(eng.init_state)
-    full_step = staticmethod(eng.full_step)
-    full_step_donate = staticmethod(eng.full_step_donate)
-    full_step_wide = staticmethod(eng.full_step_wide)
-    full_step_wide_donate = staticmethod(eng.full_step_wide_donate)
-    full_step_wide_sliced = staticmethod(eng.full_step_wide_sliced)
-    full_step_wide_sliced_donate = staticmethod(
-        eng.full_step_wide_sliced_donate)
-    # the served launch's programs: (state, op slab, up)
+    # the launch's programs: (state, op slab, up)
     full_step_slab = staticmethod(eng.full_step_slab)
     full_step_slab_donate = staticmethod(eng.full_step_slab_donate)
     full_step_sliced_slab = staticmethod(eng.full_step_sliced_slab)
@@ -649,17 +631,12 @@ class _BatchAccum:
 
 
 class _StepFns(NamedTuple):
-    """The launch path's step programs (None = the engine has none, or
-    an override of the plain step rejected it).  ``slab`` and
-    ``sliced_slab`` take ``(state, op slab, up)``
-    (``engine.pack_op_slab`` has the layout) and are what a scalar
-    launch from host planes dispatches (a scalar SLICED launch has no
-    other form); the per-plane three serve device-resident planes, the
-    wide plan and overridden engines."""
+    """The two step programs a launch can dispatch, both ``(state, op
+    slab, up)`` (``engine.pack_op_slab`` has the layout), donated when
+    the service donates: ``slab`` at full width, ``sliced_slab`` on
+    the gathered active columns (None = the engine cannot slice: a
+    mesh engine)."""
 
-    step: Any
-    wide: Any
-    wide_sliced: Any
     slab: Any
     sliced_slab: Any
 
@@ -671,26 +648,21 @@ class _InFlightLaunch:
     result array whose d2h transfer is already running, the latency
     marks so far, the rollback snapshots) plus whatever the resolve
     half needs to finish the round (election vector for the leader
-    mirror, wide-plan routing, the flush's taken queue entries or an
-    ``execute_async`` future)."""
+    mirror, the flush's taken queue entries or an ``execute_async``
+    future)."""
 
     flat: Any               # device uint8 packed result (in flight)
     rec: Dict[str, float]   # latency marks (enqueue half)
-    k: int                  # caller's round count
-    k_eff: int              # rounds in the packed layout (wide: G*W)
+    k: int                  # round count
     want_vsn: bool
-    plan: Any               # WidePlan (wide launches) or None
-    w_b: int                # wide plane width (plan only)
-    kind_np: Any            # host kind plane (wide routing masks +
-    #                         the native mirror scatter); None for
-    #                         device-resident execute() planes
+    kind_np: Any            # host kind plane (the native mirror
+    #                         scatter reads it)
     elect: Any              # [E] bool — this launch's election vector
     cand: Any               # [E] int32 — its candidates
     now: float              # runtime.now at enqueue (lease renewal)
     state_snapshot: Any     # pre-launch EngineState (rollback)
     leader_snapshot: Any
     lease_snapshot: Any
-    donated: bool           # state buffers donated (no rollback)
     #: active-column compaction: the launch's active ensemble index
     #: list (None = full-width pack) and the pow2-bucketed packed
     #: column count — the resolve half scatters the compact [K, A]
@@ -704,12 +676,11 @@ class _InFlightLaunch:
     #: is n_shards per-shard blocks; ``shard_active`` holds each
     #: shard's LOCAL active index list when the flush compacted
     #: (None = per-shard full width).  Set on EVERY launch of a
-    #: shard-wise service — election-only and device-resident
-    #: (execute) launches included.
+    #: shard-wise service — election-only launches included.
     n_shards: int = 0
     shard_active: Any = None
     #: host slot plane in op order (the native mirror scatter's
-    #: companion to ``kind_np``); None for device-resident planes
+    #: companion to ``kind_np``)
     op_slot_np: Any = None
     #: flush path: the (ensemble, taken ops) pairs this launch serves
     taken: Any = None
@@ -977,26 +948,6 @@ class BatchedEnsembleService:
         self._timer: Optional[Timer] = None
         self._kick_pending = False  # burst flush queued (see _maybe_kick)
         self._jnp = jnp
-        #: opt-in wide rounds (RETPU_WIDE=1): a flush whose host op
-        #: planes schedule into <= 2 conflict-free wide rounds
-        #: launches through ``full_step_wide`` (ops/schedule.py);
-        #: deeper duplicate chains and device-resident planes keep the
-        #: scalar scan.  Replication note: the schedule is a pure
-        #: function of the shipped [K, E] planes, so replica hosts
-        #: recompute it bit-identically — but the flag itself must
-        #: match across a replication group (a mismatch diverges seq
-        #: assignment; the ack CRC detects it and forces re-sync).
-        self._wide = os.environ.get("RETPU_WIDE", "") == "1"
-        #: launches that actually took the wide path (tests assert the
-        #: A/B coverage is real; stats() reports it)
-        self.wide_launches = 0
-        #: launches whose operands went up as ONE op slab (scalar
-        #: launches from host planes through the engine's own step)
-        #: and launches that kept one upload per plane
-        #: (device-resident planes, the wide plan, an engine that
-        #: overrides the plain step)
-        self.slab_launches = 0
-        self.plane_launches = 0
         #: active-column compaction (RETPU_COMPACT=0 opts out): a
         #: flush's packed d2h payload gathers down to the columns that
         #: actually hold ops — O(K·A) instead of O(K·E) — with |A|
@@ -1092,9 +1043,6 @@ class BatchedEnsembleService:
         self.wal_compaction_ms_total = 0.0
         self._wal = None
         self._in_save = False
-        #: one-time flag: a WAL-enabled service served device-resident
-        #: execute() calls (which skip the WAL — see execute())
-        self._dev_exec_unlogged = False
         #: graceful storage degradation (docs/ARCHITECTURE.md §15):
         #: an EIO/ENOSPC surfacing from the WAL's durability barrier
         #: flips the service READ-ONLY (writes fail fast at enqueue,
@@ -1231,6 +1179,8 @@ class BatchedEnsembleService:
         self._step_costs: Dict[str, Dict[str, float]] = {}
         if self._obs:
             self._pack = self._watched("pack", self._pack)
+        #: the launch's step programs, bound once (see _bind_step_fns)
+        self._fns = self._bind_step_fns()
         #: per-tenant attribution planes [E] (a tenant is an ensemble
         #: row; named tenants via _row_name / set_tenant_label):
         #: keyed+fast-read ops, committed rounds, put payload bytes,
@@ -3388,74 +3338,28 @@ class BatchedEnsembleService:
                 self._obs_flush_settled(fl)
         return out
 
-    def _step_fns(self) -> _StepFns:
-        """The step programs the launch path dispatches: the
-        donated-state variants when donation is on and the engine
-        provides them (mesh engines may not).
-
-        An engine subclass that overrides the PLAIN step but inherits
-        a specialized variant (test fault injectors, wrappers) must
-        not have its override silently bypassed: a donated, SLICED or
-        OP-SLAB variant is only trusted when it is defined by the same
-        class (or instance) that defines the plain step — otherwise
-        the launch falls back to the plain full-grid per-plane program
-        (slicing and the slab are optimizations, never a semantic
-        requirement)."""
+    def _bind_step_fns(self) -> _StepFns:
+        """The engine's two launch programs, resolved ONCE (the
+        constructor calls this when the engine and ``_donate`` are
+        set): the donated twins when the service donates.  The service
+        trusts the engine it was given; a test that injects a fault
+        wraps EVERY program of its engine
+        (``testing.wrap_engine_steps``).  With obs on each reports its
+        executable-cache misses (ARCHITECTURE §11) as ``step`` /
+        ``step_sliced``: what a served flush launches, as the names
+        always meant (the benchmark's warm-up lines group by them)."""
         e = self.engine
-        inst = getattr(e, "__dict__", {})
-
-        def definer(attr):
-            for c in type(e).__mro__:
-                if attr in c.__dict__:
-                    return c
-            return None
-
-        def variant(name: str, plain_name: str, fallback):
-            """The named specialized program, trusted only when its
-            definer matches the plain step's (None = use fallback)."""
-            fn = getattr(e, name, None)
-            if fn is None:
-                return fallback
-            if name in inst:
-                return fn  # instance-level pair: trust it
-            if plain_name in inst:
-                return fallback  # the instance overrode the plain step
-            return (fn if definer(name) is definer(plain_name)
-                    else fallback)
-
-        def donated(name: str, plain):
-            """``plain``'s donated form; a rejected program stays
-            rejected (its donated form must not resurrect it)."""
-            if plain is None or not self._donate:
-                return plain
-            return variant(name + "_donate", name, plain)
-
+        twin = "_donate" if self._donate else ""
         fns = _StepFns(
-            donated("full_step", e.full_step),
-            donated("full_step_wide",
-                    getattr(e, "full_step_wide", None)),
-            donated("full_step_wide_sliced",
-                    variant("full_step_wide_sliced", "full_step_wide",
-                            None)),
-            donated("full_step_slab",
-                    variant("full_step_slab", "full_step", None)),
-            donated("full_step_sliced_slab",
-                    variant("full_step_sliced_slab", "full_step",
-                            None)))
+            getattr(e, "full_step_slab" + twin),
+            getattr(e, "full_step_sliced_slab" + twin, None))
         if not self._obs:
             return fns
-        # compile telemetry: every step variant the launch dispatches
-        # reports its executable-cache misses (ARCHITECTURE §11).
-        # "step" and "step_sliced" name what a served flush launches,
-        # as they always have (the benchmark's warm-up lines group
-        # by these names)
-        names = ("step_planes", "step_wide", "step_wide_sliced",
-                 "step", "step_sliced")
-        return _StepFns(*map(self._watched, names, fns))
+        return _StepFns(*map(self._watched, ("step", "step_sliced"),
+                             fns))
 
     def _watched(self, name: str, fn):
-        """Memoized CompileWatch wrapper around a launch program (a
-        changed underlying fn — engine swap, donate flip — re-wraps)."""
+        """Memoized CompileWatch wrapper around a launch program."""
         if fn is None:
             return None
         w = self._compile_watch.get(name)
@@ -3506,9 +3410,7 @@ class BatchedEnsembleService:
         if rec is None:
             rec = self.spans.begin()
         h2d = self.spans.span("h2d", rec).begin()
-        plan = self._wide_plan(kind, slot, val, k, exp_e, exp_s)
-        fns = self._step_fns()
-        host_planes = not isinstance(kind, jax.Array)
+        fns = self._fns
         # Active-column compaction, two strengths (the payload and
         # the grid both decouple from E):
         # - SLICED launch (single-shard engines, E >= SLICE_MIN_E,
@@ -3522,15 +3424,11 @@ class BatchedEnsembleService:
         #   E/4): the step keeps the full grid; only the packed
         #   result gathers down to [K, A] (the d2h cut alone).
         # Buckets ride the pow2 A ladder (mirroring the K ladder's
-        # compile-reuse discipline).  Device-resident planes skip
-        # compaction (reading the kind plane back would break the
-        # zero-transfer contract).  The wide path compacts too: the
-        # scheduler only rearranges ops WITHIN their ensemble column,
-        # so the [K, E] planes' active set is the plan's as well.
+        # compile-reuse discipline).
         active = aidx_np = shard_active = None
         a_width = 0
         sliced = False
-        if self._compact and k and host_planes:
+        if self._compact and k:
             cols = np.flatnonzero(
                 (np.asarray(kind) != eng.OP_NOOP).any(axis=0)
                 | np.asarray(elect, bool))
@@ -3544,8 +3442,7 @@ class BatchedEnsembleService:
                 # The step itself keeps the full grid (a sharded E
                 # axis cannot slice across shards) — this is the
                 # pack-gather strength only.
-                from riak_ensemble_tpu.ops import schedule as sch
-                per_shard, a_loc = sch.shard_active_columns(
+                per_shard, a_loc = shard_active_columns(
                     cols, self.n_ens, self._mesh_shards, A_BUCKET_MIN)
                 if a_loc < self.n_ens // self._mesh_shards:
                     active = cols.astype(np.int32)
@@ -3562,9 +3459,7 @@ class BatchedEnsembleService:
                 if a_b < self.n_ens:
                     active = cols.astype(np.int32)
                     a_width = a_b
-                    have = (fns.wide_sliced if plan is not None
-                            else fns.sliced_slab)
-                    sliced = (have is not None
+                    sliced = (fns.sliced_slab is not None
                               and self.n_ens >= SLICE_MIN_E
                               and a_b * 4 <= self.n_ens)
                     # sliced pads aim OUT OF RANGE (index E) so the
@@ -3580,81 +3475,24 @@ class BatchedEnsembleService:
         # read milliseconds of jitter it doesn't have (VERDICT r3 #4).
         # The up mask uploads only when the failure detector actually
         # changed it (sliced launches gather it on device).
-        e_w = a_width if sliced else self.n_ens
         uploads = int(self._up_dev is None)
         up_j = self._up_device()
-        slab_fn = None
-        if plan is None and host_planes:
-            slab_fn = fns.sliced_slab if sliced else fns.slab
-        aidx_j = slab_j = None
-        if slab_fn is not None:
-            # ONE upload: everything the step reads from the host as
-            # one op slab (engine.pack_op_slab has the row layout; the
-            # program takes it apart), put where the step wants it.  A
-            # sliced launch's index vector is a slab row; a
-            # pack-gather's (another width) is the packer's operand
-            # and a second upload.
-            slab_j = self._put(eng.pack_op_slab(
-                e_w, k, elect, cand, lease_ok,
-                (kind, slot, val, exp_e, exp_s),
-                active if sliced else None,
-                aidx_np if sliced else None), "slab")
-            uploads += 1
-            self.slab_launches += 1
-        else:
-            self.plane_launches += 1
-        if aidx_np is not None and (slab_j is None or not sliced):
+        # ONE upload: everything the step reads from the host as one
+        # op slab (engine.pack_op_slab has the row layout; the program
+        # takes it apart), put where the step wants it.  A sliced
+        # launch's index vector is a slab row; a pack-gather's
+        # (another width) is the packer's operand and a second upload.
+        slab_j = self._put(eng.pack_op_slab(
+            a_width if sliced else self.n_ens, k, elect, cand,
+            lease_ok, (kind, slot, val, exp_e, exp_s),
+            active if sliced else None,
+            aidx_np if sliced else None), "slab")
+        uploads += 1
+        aidx_j = None
+        if aidx_np is not None and not sliced:
             aidx_j = (self._shard_aidx(aidx_np) if self._mesh_shards
                       else jnp.asarray(aidx_np))
             uploads += 1
-        g_b = w_b = 0
-        if slab_j is None:
-            # PER-PLANE operands (device-resident planes, the wide
-            # plan, an engine that overrides the plain step): one
-            # upload per host plane; the lease plane uploads as [E]
-            # (sliced: [A]) and broadcasts to the op-plane shape
-            # device-side.
-            a_n = 0 if active is None else len(active)
-
-            def cslice(p):
-                """Host column slice [K, E](, W) → [K, a_width](, W);
-                padding columns stay NOOP/zero."""
-                out = np.zeros(p.shape[:1] + (a_width,) + p.shape[2:],
-                               p.dtype)
-                out[:, :a_n] = np.asarray(p)[:, active]
-                return out
-
-            def vslice(v, dtype):
-                out = np.zeros((a_width,), dtype)
-                out[:a_n] = np.asarray(v)[active]
-                return out
-
-            def upload(x):
-                nonlocal uploads
-                uploads += not isinstance(x, jax.Array)
-                return jnp.asarray(x)
-
-            lease_np = (vslice(lease_ok, bool) if sliced
-                        else np.asarray(lease_ok))
-            if plan is not None:
-                g_b, _, w_b = plan.kind.shape
-                lease_j = jnp.broadcast_to(
-                    upload(lease_np)[None, :, None], (g_b, e_w, w_b))
-                planes = (plan.kind, plan.slot, plan.val,
-                          plan.exp_epoch, plan.exp_seq)
-            else:
-                lease_j = (jnp.broadcast_to(upload(lease_np), (k, e_w))
-                           if k else jnp.zeros((0, self.n_ens), bool))
-                planes = (kind, slot, val, exp_e, exp_s)
-            kind_j, slot_j, val_j, exp_e_j, exp_s_j = (
-                None if p is None
-                else upload(cslice(p) if sliced else p)
-                for p in planes)
-            if sliced:
-                elect_j = upload(vslice(elect, bool))
-                cand_j = upload(vslice(cand, np.int32))
-            else:
-                elect_j, cand_j = upload(elect), upload(cand)
         rec["uploads"] = uploads
         h2d.end()
 
@@ -3669,39 +3507,10 @@ class BatchedEnsembleService:
         state_snapshot = self.state
         leader_snapshot = self.leader_np
         lease_snapshot = self.lease_until.copy()
-        attr = ("full_step_wide_sliced_donate"
-                if plan is not None and sliced
-                else "full_step_wide_donate" if plan is not None
-                else "full_step_sliced_slab_donate" if sliced
-                else "full_step_slab_donate" if slab_j is not None
-                else "full_step_donate")
-        donated = (self._donate
-                   and getattr(self.engine, attr, None) is not None)
         dispatch = self.spans.span("dispatch", rec).begin()
         try:
-            k_eff = k
-            if slab_j is not None:
-                state, won, res = slab_fn(self.state, slab_j, up_j)
-            elif plan is not None:
-                if sliced:
-                    state, won, res = fns.wide_sliced(
-                        self.state, aidx_j, elect_j, cand_j, kind_j,
-                        slot_j, val_j, lease_j, up_j,
-                        exp_epoch=exp_e_j, exp_seq=exp_s_j)
-                else:
-                    state, won, res = fns.wide(
-                        self.state, elect_j, cand_j, kind_j, slot_j,
-                        val_j, lease_j, up_j, exp_epoch=exp_e_j,
-                        exp_seq=exp_s_j)
-                res = _wide_to_packed_layout(res, g_b, w_b, e_w)
-                k_eff = g_b * w_b
-                self.wide_launches += 1
-            else:
-                state, won, res = fns.step(
-                    self.state, elect_j, cand_j, kind_j, slot_j,
-                    val_j, lease_j, up_j, exp_epoch=exp_e_j,
-                    exp_seq=exp_s_j)
-            self.state = state
+            step = fns.sliced_slab if sliced else fns.slab
+            self.state, won, res = step(self.state, slab_j, up_j)
             # a sliced launch's result planes are ALREADY A-width;
             # pack-gather mode hands the pack the index vector
             flat = self._pack(won, res, want_vsn,
@@ -3715,21 +3524,20 @@ class BatchedEnsembleService:
                 start()
         except BaseException:
             self._rollback_launch(state_snapshot, leader_snapshot,
-                                  lease_snapshot, donated)
+                                  lease_snapshot)
             raise
         finally:
             dispatch.end()
         return _InFlightLaunch(
-            flat=flat, rec=rec,
-            k=k, k_eff=k_eff, want_vsn=want_vsn, plan=plan, w_b=w_b,
-            kind_np=np.asarray(kind) if host_planes else None,
+            flat=flat, rec=rec, k=k, want_vsn=want_vsn,
+            kind_np=np.asarray(kind),
             elect=elect, cand=cand, now=now,
             state_snapshot=state_snapshot,
             leader_snapshot=leader_snapshot,
-            lease_snapshot=lease_snapshot, donated=donated,
+            lease_snapshot=lease_snapshot,
             active=active, a_width=a_width, sliced=sliced,
             n_shards=self._mesh_shards, shard_active=shard_active,
-            op_slot_np=np.asarray(slot) if host_planes else None,
+            op_slot_np=np.asarray(slot),
             flush_id=obs.next_flush_id() if self._obs else 0,
             t_join=rec["starts"]["h2d"])
 
@@ -3760,7 +3568,7 @@ class BatchedEnsembleService:
         return np.asarray(fl.flat)
 
     def _rollback_launch(self, state_snapshot, leader_snapshot,
-                         lease_snapshot, donated: bool) -> None:
+                         lease_snapshot) -> None:
         """Restore the pre-launch device state + host mirrors after a
         failed launch.  The host mirrors roll back with the state: a
         mirror claiming a leader the restored device state doesn't
@@ -3770,7 +3578,7 @@ class BatchedEnsembleService:
         restore() recovers); surfaced as a trace event."""
         arr = state_snapshot.epoch
         deleted = getattr(arr, "is_deleted", None)
-        if donated and deleted is not None and deleted():
+        if self._donate and deleted is not None and deleted():
             self._emit("svc_state_poisoned",
                        {"reason": "donated launch failed; no rollback "
                                   "snapshot survives buffer donation"})
@@ -3824,19 +3632,19 @@ class BatchedEnsembleService:
                     # the single-block layout; this path trades it
                     # for zero cross-device gathers on the pack side)
                     planes8 = unpack_results_sharded(
-                        flat, e, m, fl.k_eff, fl.want_vsn,
+                        flat, e, m, fl.k, fl.want_vsn,
                         fl.n_shards, shard_active=fl.shard_active,
                         a_width=fl.a_width)
                     native_arm = False
                 else:
-                    if self._native_resolve is not None and fl.k_eff:
+                    if self._native_resolve is not None and fl.k:
                         planes8 = self._native_resolve.unpack(
-                            flat, e, m, fl.k_eff, fl.want_vsn,
+                            flat, e, m, fl.k, fl.want_vsn,
                             fl.active, fl.a_width, fl.sliced)
                     native_arm = planes8 is not None
                 if planes8 is None:
                     planes8 = unpack_results(
-                        flat, e, m, fl.k_eff, fl.want_vsn,
+                        flat, e, m, fl.k, fl.want_vsn,
                         active=fl.active, a_width=fl.a_width,
                         sliced=fl.sliced)
                 if native_arm:
@@ -3852,7 +3660,7 @@ class BatchedEnsembleService:
             # occupancy (skewed/partial load drives this toward 0).
             self.payload_bytes += int(flat.nbytes)
             self.payload_bytes_full_width += packed_nbytes(
-                e, m, fl.k_eff, fl.want_vsn)
+                e, m, fl.k, fl.want_vsn)
             # shard-wise launches pack a_width columns PER SHARD, so
             # the effective packed width is a_width * n_shards
             self._occ_sum += (fl.a_width * max(fl.n_shards, 1) / e
@@ -3869,21 +3677,6 @@ class BatchedEnsembleService:
                 else:
                     self.tenant_rounds[self._live] += 1
             corrupt = corrupt_np if fl.k else None
-            if fl.plan is not None:
-                # Route the [G*W, E] results back to the caller's
-                # [K, E] op order; padding/NOOP rows read garbage
-                # lanes, so they are masked to the scalar path's NOOP
-                # results (all-false, zero value/vsn).
-                w_b = fl.w_b
-                ee_idx = np.arange(e, dtype=np.int32)[None, :]
-                fli = fl.plan.map_g * w_b + fl.plan.map_w
-                act = fl.kind_np != eng.OP_NOOP
-                committed = committed[fli, ee_idx] & act
-                get_ok = get_ok[fli, ee_idx] & act
-                found = found[fli, ee_idx] & act
-                value = np.where(act, value[fli, ee_idx], 0)
-                if vsn is not None:
-                    vsn = np.where(act[..., None], vsn[fli, ee_idx], 0)
 
             # Host mirror: a won election installed our candidate.
             self.leader_np = np.where(won_np, fl.cand, self.leader_np)
@@ -3933,7 +3726,7 @@ class BatchedEnsembleService:
             self.flushes += 1
         except BaseException:
             self._rollback_launch(fl.state_snapshot, fl.leader_snapshot,
-                                  fl.lease_snapshot, fl.donated)
+                                  fl.lease_snapshot)
             raise
         # A won election bumped the row's ballot epoch: the next
         # device access of each object re-versions it (update_key,
@@ -3970,40 +3763,6 @@ class BatchedEnsembleService:
                            if c not in DERIVED_MARKS)
         self.lat_records.append(rec)
         return committed, get_ok, found, value, vsn
-
-    def _wide_plan(self, kind, slot, val, k, exp_e, exp_s):
-        """Schedule host [K, E] planes into conflict-free wide rounds
-        when enabled and profitable (G <= 2 — the warmed shapes); None
-        keeps the scalar scan.  Pure function of the op planes, so a
-        replication-group replica recomputes the identical plan from
-        the shipped planes.
-
-        Serialization contract: a wide flush executes its ops in
-        (group, lane) order — per-SLOT order is preserved (the g-th
-        same-slot op runs in round g), but commit seqs across
-        DIFFERENT slots may interleave differently than the scalar
-        scan's k order.  That is a valid serialization with exactly
-        the reference's freedom (key-hashed workers complete distinct
-        keys in unspecified relative order, peer.erl:1220-1225); both
-        orders are deterministic per mode, which is what replication
-        needs."""
-        if (not self._wide or k <= 1 or isinstance(kind, jax.Array)
-                or getattr(self.engine, "full_step_wide", None) is None):
-            return None
-        from riak_ensemble_tpu.ops import schedule as sched_mod
-        zeros = np.zeros((k, self.n_ens), np.int32)
-        plan = sched_mod.schedule_wide(
-            kind, slot, val, None,  # lease rides [E]-broadcast instead
-            zeros if exp_e is None else exp_e,
-            zeros if exp_s is None else exp_s,
-            max_groups=2)
-        if plan is not None and os.environ.get(
-                "RETPU_VALIDATE_WIDE", "") == "1":
-            # opt-in guard for the kernel's conflict-free precondition
-            # (kv_step_scan_wide docstring): a scheduler bug here would
-            # otherwise surface as silent nondeterministic state
-            eng.validate_wide_plane(plan.kind, plan.slot)
-        return plan
 
     def _emit(self, kind: str, payload: Any) -> None:
         """Feed the runtime's tracing hook (utils.trace.Tracer) when
@@ -4120,10 +3879,6 @@ class BatchedEnsembleService:
                 (self._desired_mask | self._pending_mask
                  | self._queued_mask).sum()),
             "queued_ops": sum(self._queue_rounds),
-            "execute_unlogged": self._dev_exec_unlogged,
-            "wide_launches": self.wide_launches,
-            "slab_launches": self.slab_launches,
-            "plane_launches": self.plane_launches,
             "pipeline_depth": self.pipeline_depth,
             "launches_in_flight": len(self._inflight_launches),
             # whether launches donate the state buffers (platform-
@@ -4533,9 +4288,6 @@ class BatchedEnsembleService:
             "retpu_wal_compactions_total": fam(
                 "counter", "WAL folds into a fresh checkpoint",
                 self.wal_compactions),
-            "retpu_wide_launches_total": fam(
-                "counter", "launches through the wide scheduler",
-                self.wide_launches),
             "retpu_flight_anomalies_total": fam(
                 "counter", "flight-recorder trigger firings (flush "
                 "> 5x rolling p50)", self.flight.anomalies),
@@ -4979,11 +4731,11 @@ class BatchedEnsembleService:
             for kb, aw in buckets:
                 by_k.setdefault(int(kb), []).append(aw)
 
-        def a_widths(k_eff: int) -> List[Optional[int]]:
-            if k_eff == 0:
+        def a_widths(k: int) -> List[Optional[int]]:
+            if k == 0:
                 return [None]  # no per-round planes to compact
             if by_k is not None:
-                return by_k.get(k_eff, [])
+                return by_k.get(k, [])
             return self._a_ladder()
 
         # Warm the programs the launch path actually dispatches — with
@@ -4994,130 +4746,76 @@ class BatchedEnsembleService:
         # the launch dispatches EITHER the sliced step (A <= E/4 on a
         # sliced-capable engine: step + plain pack at A-width) OR the
         # full-grid step with the gathering pack — warm exactly that,
-        # in the operand form the launch uses: the op slab where the
-        # engine's own step takes it (operands placed as
-        # _launch_enqueue places them: placement is part of a
-        # program's cache key), per-plane operands otherwise.
-        fns = self._step_fns()
+        # with operands placed as _launch_enqueue places them
+        # (placement is part of a program's cache key).
+        fns = self._fns
         st = self.engine.init_state(e, m, s)
-        elect = jnp.zeros((e,), bool)
-        cand = jnp.zeros((e,), jnp.int32)
         up = self._put(np.ones((e, m), bool), "up")
         no_planes = (None,) * len(eng.SLAB_PLANES)
 
-        def zero_slab(k_eff: int, width: int, sliced: bool):
+        def zero_slab(k: int, width: int, sliced: bool):
             """An all-NOOP op slab; sliced: an all-pad index row
             (gathers clip harmlessly, the scatter drops everything —
             state untouched, program compiled)."""
             z = np.zeros((width,), np.int32)
             return self._put(eng.pack_op_slab(
-                width, k_eff, z, z, z, no_planes,
+                width, k, z, z, z, no_planes,
                 z[:0] if sliced else None,
                 z + e if sliced else None), "slab")
 
-        def cost(label: str, fn, *args, **kw) -> None:
-            ca = eng.lowered_cost_analysis(fn, *args, **kw)
+        def cost(label: str, fn, *args) -> None:
+            ca = eng.lowered_cost_analysis(fn, *args)
             if ca:
                 self._step_costs[label] = ca
 
-        def warm_bucket(k_eff: int, aw: int, wide_gw=None):
-            """One (K, A) bucket: the sliced program when the launch
-            path would slice there, else the pack-gather program on
-            the full-grid result already computed by the caller."""
+        def warm_sliced(k: int, aw: int) -> bool:
+            """One (K, A) bucket's sliced program, where the launch
+            path would slice there."""
             nonlocal st
-            use_sliced = ((fns.wide_sliced if wide_gw else
-                           fns.sliced_slab) is not None
-                          and e >= SLICE_MIN_E and aw * 4 <= e)
-            if not use_sliced:
+            if not (fns.sliced_slab is not None
+                    and e >= SLICE_MIN_E and aw * 4 <= e):
                 return False
-            if wide_gw:
-                g, w = wide_gw
-                kind_a = jnp.zeros((g, aw, w), jnp.int32)
-                lease_a = jnp.zeros((g, aw, w), bool)
-                st, won, res = fns.wide_sliced(
-                    st, jnp.full((aw,), e, jnp.int32),
-                    jnp.zeros((aw,), bool), jnp.zeros((aw,), jnp.int32),
-                    kind_a, kind_a, kind_a, lease_a, up,
-                    exp_epoch=kind_a, exp_seq=kind_a)
-                res = _wide_to_packed_layout(res, g, w, aw)
-            else:
-                slab = zero_slab(k_eff, aw, True)
-                st, won, res = fns.sliced_slab(st, slab, up)
-                if self._obs and capture_costs:
-                    cost(f"k{k_eff}_a{aw}", fns.sliced_slab, st, slab,
-                         up)
+            slab = zero_slab(k, aw, True)
+            st, won, res = fns.sliced_slab(st, slab, up)
+            if self._obs and capture_costs:
+                cost(f"k{k}_a{aw}", fns.sliced_slab, st, slab, up)
             np.asarray(pack(won, res, True, active_idx=None))
             return True
 
-        def warm_pack(won, res, k_eff: int, wide_gw=None) -> None:
+        k = 0
+        while True:
+            slab = zero_slab(k, e, False)
+            st, won, res = fns.slab(st, slab, up)
+            # per-bucket XLA cost gauges: always the deepest bucket
+            # (one extra lowering); every bucket when asked
+            if (self._obs and capture_costs is not False
+                    and (capture_costs or k >= self.max_k)):
+                cost(f"k{k}", fns.slab, st, slab, up)
             # The flush path (the read fast path's get-only/read-miss
             # fallback batches included) always packs WITH versions —
             # the (K, A) ladder covers those.  The version-less pack
-            # is what WAL-less execute/execute_async dispatch, and
-            # those skip compaction for device-resident planes — so
-            # warming it at FULL WIDTH per K bucket covers the real
-            # dispatch without doubling the whole warm grid (its
-            # first-use compile was still leaking into the dispatch
-            # p99 latency window).
-            for aw in a_widths(k_eff):
+            # is what WAL-less execute/execute_async dispatch: warmed
+            # at FULL WIDTH per K bucket, which covers a dense bulk
+            # batch without doubling the whole warm grid.
+            for aw in a_widths(k):
                 if aw is None:
                     # active_idx spelled out as the launch spells it:
                     # a keyword's presence is part of a jitted
                     # program's cache key
                     np.asarray(pack(won, res, True, active_idx=None))
                     np.asarray(pack(won, res, False, active_idx=None))
-                elif not warm_bucket(k_eff, aw, wide_gw):
-                    if self._mesh_shards:
-                        # shard-wise: [n_shards, A_loc] local pad-0
-                        # index matrix (the live flush's operand form)
-                        aidx = self._shard_aidx(np.zeros(
-                            (self._mesh_shards, aw), np.int32))
-                    else:
-                        aidx = jnp.zeros((aw,), jnp.int32)
+                elif not warm_sliced(k, aw):
+                    # pack-gather on the full-grid result; shard-wise:
+                    # the [n_shards, A_loc] local pad-0 index matrix
+                    # (the live flush's operand form)
+                    aidx = (self._shard_aidx(np.zeros(
+                        (self._mesh_shards, aw), np.int32))
+                        if self._mesh_shards
+                        else jnp.zeros((aw,), jnp.int32))
                     np.asarray(pack(won, res, True, active_idx=aidx))
-
-        k = 0
-        while True:
-            # the per-plane full-grid step: what device-resident
-            # execute()/execute_async planes dispatch (they never
-            # compact), and every launch of an engine without a slab
-            # program
-            kind = jnp.zeros((k, e), jnp.int32)
-            lease = jnp.zeros((k, e), bool)
-            fn, args, kw = fns.step, (
-                elect, cand, kind, kind, kind, lease, up), {
-                "exp_epoch": kind, "exp_seq": kind}
-            st, won, res = fn(st, *args, **kw)
-            if fns.slab is not None:
-                fn, args, kw = fns.slab, (zero_slab(k, e, False), up), {}
-                st, won, res = fn(st, *args, **kw)
-            # per-bucket XLA cost gauges: always the deepest bucket
-            # (one extra lowering); every bucket when asked
-            if (self._obs and capture_costs is not False
-                    and (capture_costs or k >= self.max_k)):
-                cost(f"k{k}", fn, st, *args, **kw)
-            warm_pack(won, res, k)
             if k >= self.max_k:
                 break
             k = 1 if k == 0 else k * 2
-        if self._wide and fns.wide is not None:
-            # The wide gate admits plans with G in {1, 2} and pow2 W
-            # up to _pow2_at_least(flush depth) — a non-pow2 max_k
-            # still schedules into the NEXT pow2 width, so warm
-            # through it.
-            w_max = 1 << (max(self.max_k, 1) - 1).bit_length()
-            for g in (1, 2):
-                w = 1
-                while w <= w_max:
-                    kind = jnp.zeros((g, e, w), jnp.int32)
-                    lease = jnp.zeros((g, e, w), bool)
-                    st, won, res = fns.wide(
-                        st, elect, cand, kind, kind, kind, lease, up,
-                        exp_epoch=kind, exp_seq=kind)
-                    warm_pack(won,
-                              _wide_to_packed_layout(res, g, w, e),
-                              g * w, wide_gw=(g, w))
-                    w *= 2
 
     def execute(self, kind: np.ndarray, slot: np.ndarray,
                 val: np.ndarray,
@@ -5143,31 +4841,17 @@ class BatchedEnsembleService:
         as queued ops: elections fold in, leases check/renew,
         corruption triggers exchange.
 
-        Callers may pass DEVICE-RESIDENT int32 arrays (jax.Array):
-        the op planes then never cross the host↔device link,
-        host-side payload validation is skipped (the encoding contract
-        above is the caller's to honor), and ``ops_served`` counts every lane
-        (k x E) since NOOP rows can't be counted without a transfer.
+        A ``jax.Array`` handed in is read back once at the door
+        (``np.asarray``) and is from there an ordinary host call:
+        validated, compacted, launched as one op slab and logged.
 
-        Durability: with a ``data_dir``, host-array calls log their
-        committed writes to the WAL before returning (the result IS
-        the ack).  Device-resident calls are NOT WAL'd — fetching the
-        op planes back would defeat the zero-transfer contract; their
-        RPO is bounded by the checkpoint cadence instead (documented
-        in ARCHITECTURE).
+        Durability: with a ``data_dir``, a call logs its committed
+        writes to the WAL before returning (the result IS the ack).
         """
         # A synchronous execute settles the launch pipeline first, so
         # results land in submission order behind any execute_async /
         # pipelined-flush work already in flight.
         self._drain_launches()
-        if isinstance(kind, jax.Array):
-            self._note_dev_exec_unlogged()
-            k = int(kind.shape[0])
-            committed, get_ok, found, value, _ = self._launch(
-                kind, slot, val, k, want_vsn=False,
-                exp_e=exp_epoch, exp_s=exp_seq)
-            self.ops_served += k * self.n_ens
-            return committed, get_ok, found, value
         kind = np.asarray(kind, np.int32)
         val = np.asarray(val, np.int32)
         if ((kind == eng.OP_PUT) & (val < 0)).any():
@@ -5195,20 +4879,8 @@ class BatchedEnsembleService:
         if self._wal is not None:
             self._log_execute_wal(kind, slot, val, committed, vsn,
                                   value)
-        self.ops_served += int((np.asarray(kind) != eng.OP_NOOP).sum())
+        self.ops_served += int((kind != eng.OP_NOOP).sum())
         return committed, get_ok, found, value
-
-    def _note_dev_exec_unlogged(self) -> None:
-        if self._wal is not None and not self._dev_exec_unlogged:
-            # The durability contract weakens on this path (no WAL
-            # record; RPO = checkpoint cadence) purely because of
-            # the argument TYPE — make that observable once per
-            # service instead of silent (ADVICE r3): a trace event
-            # plus a stats() counter.
-            self._dev_exec_unlogged = True
-            self._emit("svc_execute_unlogged", {
-                "reason": "device-resident op planes skip the WAL;"
-                          " RPO is the checkpoint cadence"})
 
     def _log_execute_wal(self, kind, slot, val, committed, vsn,
                          value=None) -> None:
@@ -5242,34 +4914,25 @@ class BatchedEnsembleService:
         so up to ``pipeline_depth`` batches overlap: batch N's d2h
         transfer + host resolve ride under batch N+1's device step.
         Results resolve strictly in submission order.  Same
-        device-resident vs host-array contract (payload encoding,
-        WAL/RPO) as :meth:`execute`.
+        argument contract (payload encoding, ``jax.Array`` read back
+        at the door, WAL) as :meth:`execute`.
         """
         fut = Future()
-        if isinstance(kind, jax.Array):
-            self._note_dev_exec_unlogged()
-            k = int(kind.shape[0])
-            exec_wal = None
-            want_vsn = False
-            n_ops = k * self.n_ens
-            exp_e = exp_epoch
-            exp_s = exp_seq
-        else:
-            kind = np.asarray(kind, np.int32)
-            val = np.asarray(val, np.int32)
-            if ((kind == eng.OP_PUT) & (val < 0)).any():
-                raise ValueError(
-                    "negative put payloads are not encodable "
-                    "(int32 handles; 0 = tombstone/delete)")
-            k = int(kind.shape[0])
-            slot = np.asarray(slot, np.int32)
-            want_vsn = self._wal is not None
-            exec_wal = (kind, slot, val) if want_vsn else None
-            n_ops = int((kind != eng.OP_NOOP).sum())
-            exp_e = (None if exp_epoch is None
-                     else np.asarray(exp_epoch, np.int32))
-            exp_s = (None if exp_seq is None
-                     else np.asarray(exp_seq, np.int32))
+        kind = np.asarray(kind, np.int32)
+        val = np.asarray(val, np.int32)
+        if ((kind == eng.OP_PUT) & (val < 0)).any():
+            raise ValueError(
+                "negative put payloads are not encodable "
+                "(int32 handles; 0 = tombstone/delete)")
+        k = int(kind.shape[0])
+        slot = np.asarray(slot, np.int32)
+        want_vsn = self._wal is not None
+        exec_wal = (kind, slot, val) if want_vsn else None
+        n_ops = int((kind != eng.OP_NOOP).sum())
+        exp_e = (None if exp_epoch is None
+                 else np.asarray(exp_epoch, np.int32))
+        exp_s = (None if exp_seq is None
+                 else np.asarray(exp_seq, np.int32))
         # Same election-mirror discipline as the pipelined flush: an
         # in-flight launch may be about to install a leader; electing
         # again would re-version its objects.

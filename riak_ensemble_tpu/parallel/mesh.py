@@ -26,7 +26,7 @@ a peer axis use spec ('ens', 'peer'), per-ensemble vectors use
 
 from __future__ import annotations
 
-from typing import Optional, Sequence
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -66,7 +66,36 @@ def make_mesh(n_ens: int, n_peer: int = 1,
 # historical names for existing callers.
 _STATE_SPECS = eng.state_specs()
 _SCAN_RESULT_SPECS = eng.scan_result_specs()
-_WIDE_RESULT_SPECS = eng.wide_result_specs()
+
+
+def shard_active_columns(active: np.ndarray, n_ens: int,
+                         n_shards: int, a_min: int
+                         ) -> Tuple[list, int]:
+    """Split a GLOBAL active-column index set into per-ens-shard LOCAL
+    index lists with one common pow2 bucket width.
+
+    The mesh keeps E in ``n_shards`` contiguous blocks of
+    ``E/n_shards`` rows (NamedSharding over the 'ens' axis), so a
+    global column index ``c`` lives on shard ``c // e_loc`` at local
+    index ``c % e_loc``.  Compaction-aware sharding computes the |A|
+    bucket PER SHARD — every shard packs the same ``a_width`` columns
+    (pow2 ≥ the busiest shard's count, floored at ``a_min``, capped at
+    ``e_loc``) so the shard_map'd packer sees one static shape while
+    each shard's d2h payload stays local.
+
+    Returns ``(per_shard, a_width)``: ``per_shard[s]`` is an int32
+    array of ≤ ``a_width`` LOCAL indices (the caller pads to
+    ``a_width``); ``a_width == e_loc`` means no compaction wins on
+    this flush (every shard at full width).
+    """
+    e_loc = n_ens // n_shards
+    active = np.asarray(active, np.int32)
+    shard_of = active // e_loc
+    per_shard = [active[shard_of == s] - s * e_loc
+                 for s in range(n_shards)]
+    busiest = max((p.size for p in per_shard), default=0)
+    a_width = 1 << (max(busiest, a_min, 1) - 1).bit_length()
+    return per_shard, min(a_width, e_loc)
 
 
 def _forward_cache_size(wrapper, jitted) -> None:
@@ -88,17 +117,16 @@ class ShardedEngine:
     E must divide by mesh 'ens' size; M by mesh 'peer' size (pad views
     with absent peers if needed — all-zero view columns are inert).
 
-    The fused serving steps (``full_step``/``full_step_wide``, the
-    op-slab form ``full_step_slab`` the served launch dispatches, and
-    their ``_donate`` variants) are INSTANCE attributes: plain
-    wrappers over the shard_map'd programs that default absent CAS
-    planes and forward ``_cache_size`` so ``CompileWatch`` sees mesh
-    compiles and ``BatchedEnsembleService._step_fns`` trusts the
-    donate pairing (instance-level pair).  There are NO sliced
-    variants — a mesh-sharded E axis cannot gather active columns
-    across shards without resharding; the mesh service keeps the full
-    grid and compacts the packed RESULT per ens-shard instead (see
-    ``batched_host``'s shard-wise packer).
+    The fused steps are INSTANCE attributes: ``full_step_slab`` (and
+    its ``_donate`` twin), the jitted ``(state, op slab, up)`` program
+    the service launches, and the per-plane ``full_step`` /
+    ``full_step_donate`` the slab form is compared against (plain
+    wrappers that default absent CAS planes and forward
+    ``_cache_size`` so ``CompileWatch`` sees mesh compiles).  There
+    are NO sliced variants — a mesh-sharded E axis cannot gather
+    active columns across shards without resharding; the mesh service
+    keeps the full grid and compacts the packed RESULT per ens-shard
+    instead (see ``batched_host``'s shard-wise packer).
     """
 
     def __init__(self, mesh: Mesh) -> None:
@@ -153,27 +181,9 @@ class ShardedEngine:
         self.full_step_slab = smap(_slab_body, _slab_in, _full_out)
         self.full_step_slab_donate = smap(_slab_body, _slab_in,
                                           _full_out, donate=True)
-        _wide_in = (_STATE_SPECS, P("ens"), P("ens"),
-                    P(None, "ens", None), P(None, "ens", None),
-                    P(None, "ens", None), P(None, "ens", None),
-                    P("ens", "peer"), P(None, "ens", None),
-                    P(None, "ens", None))
-        _wide_out = (_STATE_SPECS, P("ens"), _WIDE_RESULT_SPECS)
-
-        def _wide_body(st, el, ca, k, sl, v, lz, up, xe, xs):
-            return eng.full_step_wide(st, el, ca, k, sl, v, lz, up,
-                                      axis_name=ax, exp_epoch=xe,
-                                      exp_seq=xs)
-
-        self._full_wide = smap(_wide_body, _wide_in, _wide_out)
-        self._full_wide_donate = smap(_wide_body, _wide_in, _wide_out,
-                                      donate=True)
-        # instance-attribute serving steps (see class docstring)
+        # the per-plane reference steps (see class docstring)
         self.full_step = self._make_step(self._full)
         self.full_step_donate = self._make_step(self._full_donate)
-        self.full_step_wide = self._make_step(self._full_wide)
-        self.full_step_wide_donate = self._make_step(
-            self._full_wide_donate)
         self._reconfig = smap(
             lambda st, pr, nv, up: eng.reconfig_step(st, pr, nv, up,
                                                      axis_name=ax),
@@ -269,7 +279,7 @@ class ShardedEngine:
         return self._kv(state, kind, slot, val, lease_ok, up,
                         exp_epoch, exp_seq)
 
-    # full_step / full_step_wide (+ _donate) are instance attributes
+    # full_step / full_step_slab (+ _donate) are instance attributes
     # built in __init__ — see the class docstring.
 
     def reconfig_step(self, state, propose, new_view, up):

@@ -120,7 +120,7 @@ touched rows' trees — no device re-execution — and the result is
 bit-equal to a full-plane re-execution by construction (commit
 epochs/seqs are derivable: epoch is the replica's own ballot plane,
 seqs are consecutive per column from its own obj_seq_ctr).  Launches
-with elections, leader-side corruption/exchange, bulk device-resident
+with elections, leader-side corruption/exchange, bulk ``execute()``
 planes, or a delta-ineligible shape fall back to full-plane entries in
 the same stream; re-syncs and install barriers ride ahead exactly as
 before.  Entries coalesce: all launches settled by one flush (up to
@@ -3513,12 +3513,6 @@ class ReplicatedService(BatchedEnsembleService):
         if lease_ok is None:
             lease_ok = self.lease_until > self.runtime.now
 
-        # device-resident planes must be host arrays to ship
-        import jax
-        if isinstance(kind, jax.Array):
-            kind = np.asarray(kind)
-            slot = np.asarray(slot)
-            val = np.asarray(val)
         seq = self._grp_seq + 1
         meta = _entries_meta(entries, kind, slot, self.values)
         corr0 = self.corruptions

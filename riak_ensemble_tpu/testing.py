@@ -352,3 +352,51 @@ class ManagedCluster:
     check_quorum = Cluster.check_quorum
     suspend_peer = Cluster.suspend_peer
     resume_peer = Cluster.resume_peer
+
+
+# -- the batched service's step programs -------------------------------------
+
+#: every program a ``BatchedEnsembleService`` launch can dispatch
+#: (``(state, op slab, up) -> (state, won, KvResult)``)
+STEP_PROGRAMS = ("full_step_slab", "full_step_slab_donate",
+                 "full_step_sliced_slab", "full_step_sliced_slab_donate")
+
+
+class _SteppedEngine:
+    """``engine`` with each of its step programs run through
+    ``around``; everything else is the engine's own."""
+
+    def __init__(self, engine, around) -> None:
+        self._engine = engine
+        for name in STEP_PROGRAMS:
+            inner = getattr(engine, name, None)
+            if inner is not None:
+                setattr(self, name, self._through(
+                    around, inner, "sliced" in name))
+
+    @staticmethod
+    def _through(around, inner, sliced: bool):
+        def step(state, slab, up):
+            return around(inner, state, slab, up, sliced=sliced)
+        cache_size = getattr(inner, "_cache_size", None)
+        if cache_size is not None:
+            step._cache_size = cache_size  # obs.CompileWatch's probe
+        return step
+
+    def __getattr__(self, name):
+        return getattr(self._engine, name)
+
+
+def wrap_engine_steps(engine, around):
+    """An engine like ``engine`` whose EVERY step program (full width
+    and sliced, donated and not: whichever of :data:`STEP_PROGRAMS` it
+    has) runs through ``around(inner, state, slab, up, sliced=...)``,
+    which returns what the launch gets: ``(state, won, KvResult)``.
+
+    This is how a test injects a launch fault, counts launches or
+    tampers with operands or results, on the path that serves: the
+    service dispatches whichever program fits the flush, so an
+    injector on one of them would be bypassed by its twins.  The op
+    planes of ``slab`` are ``ops.engine.split_op_slab(slab, sliced)``.
+    """
+    return _SteppedEngine(engine, around)

@@ -7,7 +7,7 @@ bit-identically.  These tests pin:
   (pow2 padding included),
 - the skew-load equivalence sweep the issue demands: one hot ensemble
   at full depth + hundreds of idle/1-deep columns, seeded op mix
-  including OP_RMW and wide groups, compacted results element-equal
+  including OP_RMW, compacted results element-equal
   to a full-width-pack reference service,
 - corruption detected inside a heavily-compacted launch still reaches
   the exchange/scrub path (the corrupt mask stays full width),
@@ -116,12 +116,10 @@ E_SWEEP = 512
 K_SWEEP = 64
 
 
-def _skew_planes(rng, n_ens, n_slots, k, distinct=False):
+def _skew_planes(rng, n_ens, n_slots, k):
     """Seeded skewed op planes: column 0 hot at full depth k (mix of
     PUT / GET / CAS / RMW / tombstone-PUT), roughly a third of the
-    other columns 1-deep, a few 2-3 deep, the rest idle.  With
-    ``distinct``, slots within a column never repeat (the wide
-    scheduler then packs G <= 2 groups)."""
+    other columns 1-deep, a few 2-3 deep, the rest idle."""
     kind = np.zeros((k, n_ens), np.int32)
     slot = np.zeros((k, n_ens), np.int32)
     val = np.zeros((k, n_ens), np.int32)
@@ -133,10 +131,7 @@ def _skew_planes(rng, n_ens, n_slots, k, distinct=False):
             [eng.OP_PUT, eng.OP_GET, eng.OP_CAS, eng.OP_RMW,
              eng.OP_PUT], depth, p=[0.35, 0.25, 0.15, 0.15, 0.1])
         kind[:depth, col] = kinds
-        if distinct:
-            slot[:depth, col] = rng.permutation(n_slots)[:depth]
-        else:
-            slot[:depth, col] = rng.integers(0, n_slots, depth)
+        slot[:depth, col] = rng.integers(0, n_slots, depth)
         val[:depth, col] = rng.integers(1, 1 << 20, depth)
         tomb = (kinds == eng.OP_PUT) & (rng.random(depth) < 0.2)
         val[:depth, col][tomb] = 0
@@ -194,36 +189,6 @@ def test_skew_equivalence_sweep():
         svc.payload_bytes, ref.payload_bytes)
     assert svc.stats()["grid_occupancy"] <= 0.25
     assert ref.stats()["grid_occupancy"] == 1.0
-
-
-def test_skew_equivalence_wide_groups():
-    """The same sweep through the WIDE scheduler (distinct-slot
-    planes, both arms RETPU_WIDE semantics): compacted wide results
-    — the sliced [G, A, W] launch routed back through the plan — stay
-    element-identical to the full-width wide reference.  E = 256 so
-    the launch really slices (SLICE_MIN_E)."""
-    n_ens, n_slots, k = 256, 32, 16
-    svc, ref = make_pair(n_ens, 3, n_slots, k)
-    svc._wide = ref._wide = True
-    rng = np.random.default_rng(23)
-    for i, s in enumerate(rng.integers(0, 999, 2)):
-        kind, slot, val, exp_e, exp_s = _skew_planes(
-            np.random.default_rng(s), n_ens, n_slots, k,
-            distinct=True)
-        out_c = svc.execute(kind, slot, val, exp_epoch=exp_e,
-                            exp_seq=exp_s)
-        out_f = ref.execute(kind, slot, val, exp_epoch=exp_e,
-                            exp_seq=exp_s)
-        for name, a, b in zip(("committed", "get_ok", "found",
-                               "value"), out_c, out_f):
-            np.testing.assert_array_equal(a, b, err_msg=name)
-        if i == 0:  # first launch = all-columns election, full width
-            for sv in (svc, ref):
-                sv.payload_bytes = 0
-                sv.payload_bytes_full_width = 0
-    assert svc.wide_launches > 0 and ref.wide_launches > 0
-    assert_engine_equal(svc, ref)
-    assert svc.payload_bytes < ref.payload_bytes / 4
 
 
 def test_keyed_equivalence_with_rmw():

@@ -315,22 +315,20 @@ def test_service_flush_depth_buckets_to_pow2():
     """Distinct [K, E] shapes each cost an XLA compile; flush must
     bucket the batch depth to powers of two so skewed/varying queue
     lengths don't trigger compile churn (one program per depth)."""
+    from riak_ensemble_tpu.ops.engine import split_op_slab
     from riak_ensemble_tpu.parallel.batched_host import _LocalEngine
+    from riak_ensemble_tpu.testing import wrap_engine_steps
 
     seen = []
 
-    class RecordingEngine(_LocalEngine):
-        @staticmethod
-        def full_step(state, elect, cand, kind, slot, val, lease_ok,
-                      up, **kw):
-            seen.append(int(kind.shape[0]))
-            return _LocalEngine.full_step(
-                state, elect, cand, kind, slot, val, lease_ok, up, **kw)
+    def record(inner, state, slab, up, sliced):
+        seen.append(int(split_op_slab(slab, sliced)[3].shape[0]))
+        return inner(state, slab, up)
 
     runtime = Runtime(seed=50)
-    svc = BatchedEnsembleService(runtime, 8, 3, 16, tick=None,
-                                 config=fast_test_config(),
-                                 engine=RecordingEngine())
+    svc = BatchedEnsembleService(
+        runtime, 8, 3, 16, tick=None, config=fast_test_config(),
+        engine=wrap_engine_steps(_LocalEngine(), record))
     for depth in (1, 2, 3, 5, 7, 11, 13):
         futs = [svc.kput(0, f"k{i}", b"v") for i in range(depth)]
         while any(svc.queues):
@@ -622,35 +620,25 @@ def test_launch_failure_fails_ops_instead_of_orphaning():
     their futures forever — and the service must keep working once
     the device recovers (request_failed analog, peer.erl:1274-1275)."""
     from riak_ensemble_tpu.parallel.batched_host import _LocalEngine
+    from riak_ensemble_tpu.testing import wrap_engine_steps
 
-    class FlakyEngine(_LocalEngine):
-        fail_next = False
+    fail_next = []
 
-        @classmethod
-        def full_step(cls, *a, **kw):
-            if cls.fail_next:
-                cls.fail_next = False
-                raise RuntimeError("injected device failure")
-            return _LocalEngine.full_step(*a, **kw)
-
-        # a RETPU_WIDE=1 run launches through the wide twin — the
-        # injection must cover whichever flavor the flush takes
-        @classmethod
-        def full_step_wide(cls, *a, **kw):
-            if cls.fail_next:
-                cls.fail_next = False
-                raise RuntimeError("injected device failure")
-            return _LocalEngine.full_step_wide(*a, **kw)
+    def flaky(inner, state, slab, up, sliced):
+        if fail_next:
+            fail_next.clear()
+            raise RuntimeError("injected device failure")
+        return inner(state, slab, up)
 
     runtime = Runtime(seed=50)
-    svc = BatchedEnsembleService(runtime, 4, 3, 8, tick=None,
-                                 config=fast_test_config(),
-                                 engine=FlakyEngine())
+    svc = BatchedEnsembleService(
+        runtime, 4, 3, 8, tick=None, config=fast_test_config(),
+        engine=wrap_engine_steps(_LocalEngine(), flaky))
     ok = svc.kput(0, "a", b"1")
     svc.flush()
     assert ok.done and ok.value[0] == "ok"
 
-    FlakyEngine.fail_next = True
+    fail_next.append(True)
     f1 = svc.kput(0, "b", b"2")
     # a leased read of an untouched key serves from the committed
     # mirror BEFORE the failing launch — the failure can't reach it
@@ -682,31 +670,30 @@ def test_async_launch_failure_rolls_back_state():
     state or every subsequent flush consumes the poison and fails
     forever."""
     from riak_ensemble_tpu.parallel.batched_host import _LocalEngine
+    from riak_ensemble_tpu.testing import wrap_engine_steps
 
-    class AsyncPoisonEngine(_LocalEngine):
-        poison_next = False
+    poison_next = []
 
-        @classmethod
-        def full_step(cls, *a, **kw):
-            state, won, res = _LocalEngine.full_step(*a, **kw)
-            if cls.poison_next:
-                cls.poison_next = False
-                # The returned state LOOKS fine (it replaces
-                # svc.state), but the result fetch blows up — the
-                # async-dispatch failure shape.
-                res = res._replace(value="poisoned-not-an-array")
-            return state, won, res
+    def poison(inner, state, slab, up, sliced):
+        state, won, res = inner(state, slab, up)
+        if poison_next:
+            poison_next.clear()
+            # The returned state LOOKS fine (it replaces svc.state),
+            # but the result fetch blows up — the async-dispatch
+            # failure shape.
+            res = res._replace(value="poisoned-not-an-array")
+        return state, won, res
 
     runtime = Runtime(seed=50)
-    svc = BatchedEnsembleService(runtime, 4, 3, 8, tick=None,
-                                 config=fast_test_config(),
-                                 engine=AsyncPoisonEngine())
+    svc = BatchedEnsembleService(
+        runtime, 4, 3, 8, tick=None, config=fast_test_config(),
+        engine=wrap_engine_steps(_LocalEngine(), poison))
     assert_ok = svc.kput(0, "a", b"1")
     svc.flush()
     assert assert_ok.done and assert_ok.value[0] == "ok"
     good_state = svc.state
 
-    AsyncPoisonEngine.poison_next = True
+    poison_next.append(True)
     f1 = svc.kput(0, "b", b"2")
     with pytest.raises(Exception):
         svc.flush()

@@ -4,7 +4,7 @@ The mesh engine (8 virtual CPU devices, 'ens'-sharded, peer axis
 unsharded) must be BIT-IDENTICAL to the single-shard oracle over mixed
 put/CAS/RMW/tombstone streams — results, device state, host mirror
 slabs, and WAL bytes — including compacted (per-shard active-column
-bucketing) and wide-group flushes.  Plus the mesh serving-path
+bucketing) flushes.  Plus the mesh serving-path
 contracts: warmup covers the mesh step/pack variants (CompileWatch
 asserts zero serve-phase compiles), and checkpoints round-trip across
 shard counts (8→1 and 1→8) bit-equal.
@@ -152,31 +152,6 @@ def test_mesh_equals_oracle_compacted_flush():
         # the shard-wise path really took the per-shard branch
         assert meshed._occ_launches > 0
         assert meshed._occ_sum < meshed._occ_launches
-    finally:
-        oracle.stop()
-        meshed.stop()
-
-
-def test_mesh_equals_oracle_wide_flush():
-    """Wide-group flushes (distinct-slot ops coalesced into [G, E, W]
-    planes) through the mesh step must match the oracle."""
-    oracle = _mk(16, mesh=False, max_ops_per_tick=8)
-    meshed = _mk(16, mesh=True, max_ops_per_tick=8)
-    try:
-        for svc in (oracle, meshed):
-            svc._wide = True
-        results = []
-        for svc in (oracle, meshed):
-            futs = [svc.kput_many(e, ["w%d" % j for j in range(4)],
-                                  [b"v%d" % j for j in range(4)])
-                    for e in range(16)]
-            _drive(svc, futs)
-            futs = [svc.kget_many(e, ["w%d" % j for j in range(4)])
-                    for e in range(16)]
-            results.append(_drive(svc, futs))
-            assert svc.wide_launches > 0, "wide path never engaged"
-        assert results[0] == results[1]
-        _assert_state_equal(oracle, meshed)
     finally:
         oracle.stop()
         meshed.stop()

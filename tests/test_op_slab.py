@@ -1,6 +1,6 @@
-"""The op slab: one upload per scalar launch (ISSUE 31).
+"""The op slab: one upload per launch (ISSUE 31, 32).
 
-Everything a scalar launch reads from the host travels as ONE int32
+Everything a launch reads from the host travels as ONE int32
 array (``engine.pack_op_slab`` has the row layout) in one transfer,
 placed where the step wants it, and is taken apart inside the compiled
 program.  Pinned here:
@@ -11,9 +11,12 @@ program.  Pinned here:
 - a served flush records its transfers (``uploads``): 1 on a sliced
   launch, at most 2 on a pack-gather or mesh launch, and runs no eager
   device op inside the ``h2d`` span;
+- every way into the step (keyed ops of each kind, an election-only
+  launch, ``execute()`` from host or ``jax.Array`` planes, a replica's
+  apply) is one such launch;
 - the mesh slab is committed to the step's own ``P(None, 'ens')``;
-- device-resident planes and engines that override the plain step keep
-  the per-plane call, and the override is what runs;
+- a wrapped engine's step (``testing.wrap_engine_steps``) is what
+  runs, sliced and at full width;
 - ``warmup`` covers the slab programs: a flush of a warmed bucket
   compiles nothing.
 """
@@ -52,6 +55,12 @@ def _operands(k, elections, seed):
     return elect, cand, lease, (kind, slot, val, exp_e, exp_s)
 
 
+def _per_plane(engine):
+    """The per-plane REFERENCE program the slab forms are compared
+    with: the mesh engine's own, the module's jit otherwise."""
+    return getattr(engine, "full_step", eng.full_step)
+
+
 def _led_state(engine):
     """A state with history: every ensemble has elected a leader and
     holds a few committed writes."""
@@ -60,7 +69,7 @@ def _led_state(engine):
     kind = jnp.full((2, E), eng.OP_PUT, jnp.int32)
     slot = jnp.stack([jnp.arange(E, dtype=jnp.int32) % S,
                       (jnp.arange(E, dtype=jnp.int32) + 3) % S])
-    st, won, _ = engine.full_step(
+    st, won, _ = _per_plane(engine)(
         st, jnp.ones((E,), bool), jnp.zeros((E,), jnp.int32), kind,
         slot, slot + 7, jnp.zeros((2, E), bool), up)
     assert np.asarray(won).all()
@@ -111,7 +120,7 @@ def test_slab_program_matches_per_plane_program(form, elections, k):
     else:
         kind, slot, val, xe, xs = (jnp.asarray(p) for p in planes)
         lease_j = jnp.broadcast_to(jnp.asarray(lease), (k, E))
-        want = engine.full_step(
+        want = _per_plane(engine)(
             st, jnp.asarray(elect), jnp.asarray(cand), kind, slot, val,
             lease_j, up, exp_epoch=xe, exp_seq=xs)
         slab = eng.pack_op_slab(E, k, elect, cand, lease, planes)
@@ -177,52 +186,173 @@ def _settle(svc, futs):
     return [f.value for f in futs]
 
 
-def _served_records(kind):
-    """Drive one service of the given launch kind through an election
-    flush and three steady flushes; returns (svc, steady records,
-    spy)."""
-    if kind == "sliced":       # E >= SLICE_MIN_E, 3 columns: A = 8
-        svc = BatchedEnsembleService(WallRuntime(), 512, M, S,
-                                     tick=None)
-    elif kind == "pack_gather":  # under SLICE_MIN_E
-        svc = BatchedEnsembleService(WallRuntime(), E, M, S, tick=None)
-    else:
-        svc = BatchedEnsembleService(WallRuntime(), E, M, S, tick=None,
-                                     engine=mesh_engine(4))
-    _settle(svc, [svc.kput(0, "warm", b"w")])  # elects every column
-    spy = _SpyJnp()
-    svc._jnp = spy
-    n0 = len(svc.lat_records)
+def _service(shape, **kw):
+    if shape == "sliced":       # E >= SLICE_MIN_E, 3 columns: A = 8
+        return BatchedEnsembleService(WallRuntime(), 512, M, S,
+                                      tick=None, **kw)
+    if shape == "pack_gather":  # under SLICE_MIN_E
+        return BatchedEnsembleService(WallRuntime(), E, M, S,
+                                      tick=None, **kw)
+    return BatchedEnsembleService(WallRuntime(), E, M, S, tick=None,
+                                  engine=mesh_engine(4), **kw)
+
+
+COLS = (1, 7, 40)
+
+
+def _ok(vals):
+    assert all(v[0] == "ok" for v in vals), vals
+    return vals
+
+
+def _drive_kput(svc):
     for i in range(3):
-        vals = _settle(svc, [svc.kput(c, f"k{i}", b"v%d" % i)
-                             for c in (1, 7, 40)])
-        assert all(v[0] == "ok" for v in vals), vals
-    recs = [r for r in list(svc.lat_records)[n0:] if r.get("k")]
-    assert len(recs) >= 3
-    return svc, recs, spy
+        _ok(_settle(svc, [svc.kput(c, f"k{i}", b"v%d" % i)
+                          for c in COLS]))
 
 
-@pytest.mark.parametrize("kind,most", [("sliced", 1), ("pack_gather", 2),
-                                       ("mesh", 2)])
-def test_served_flush_counts_its_uploads(kind, most):
-    svc, recs, spy = _served_records(kind)
+def _drive_kupdate(svc):     # CAS planes present
+    vals = _ok(_settle(svc, [svc.kput(c, "k", b"v") for c in COLS]))
+    _ok(_settle(svc, [svc.kupdate(c, "k", v[1], b"v2")
+                      for c, v in zip(COLS, vals)]))
+
+
+def _drive_kmodify(svc):     # the RMW arm
+    from riak_ensemble_tpu import funref
+    for _ in range(2):
+        _ok(_settle(svc, [svc.kmodify(c, "ctr", funref.ref("rmw:add", 5),
+                                      0) for c in COLS]))
+
+
+def _drive_kdelete(svc):
+    _ok(_settle(svc, [svc.kput(c, "k", b"v") for c in COLS]))
+    _ok(_settle(svc, [svc.kdelete(c, "k") for c in COLS]))
+
+
+def _drive_kget_no_lease(svc):
+    _ok(_settle(svc, [svc.kput(c, "k", b"v") for c in COLS]))
+    svc.lease_until[:] = 0.0
+    futs = [svc.kget(c, "k") for c in COLS]
+    assert not any(f.done for f in futs), "the lease served the read"
+    assert _settle(svc, futs) == [("ok", b"v")] * len(COLS)
+
+
+def _drive_election_only(svc):
+    """K = 0: a leader goes down with nothing queued.  The launch
+    uploads the changed ``up`` mask beside its slab."""
+    svc.set_peer_up(7, int(svc.leader_np[7]), False)
+    flushes = svc.flushes
+    svc.flush()
+    assert svc.flushes == flushes + 1 and svc.leader_np[7] >= 0
+    rec = svc.lat_records[-1]
+    assert rec["k"] == 0 and rec["uploads"] == 2, rec
+
+
+def _dense_planes(xp):
+    kind = xp.full((2, E), eng.OP_PUT, xp.int32)
+    slot = xp.zeros((2, E), xp.int32)
+    return kind, slot, slot + 5
+
+
+def _drive_execute_host(svc):
+    committed, *_ = svc.execute(*_dense_planes(np))
+    assert committed.all()
+
+
+def _drive_execute_jax(svc):
+    """``jax.Array`` planes are read back at the door: the same one
+    launch, the same answer."""
+    kind, slot, val = _dense_planes(jnp)
+    committed, *_ = svc.execute(kind, slot, val)
+    assert isinstance(committed, np.ndarray) and committed.all()
+    _, get_ok, found, value = svc.execute(
+        jnp.full_like(kind, eng.OP_GET), slot, val)
+    assert get_ok.all() and found.all() and (value == 5).all()
+
+
+#: (service shape, drive, most uploads on a launch that carries ops)
+CASES = {
+    "sliced": ("sliced", _drive_kput, 1),
+    "pack_gather": ("pack_gather", _drive_kput, 2),
+    "mesh": ("mesh", _drive_kput, 2),
+    "kupdate": ("sliced", _drive_kupdate, 1),
+    "kmodify": ("sliced", _drive_kmodify, 1),
+    "kdelete": ("pack_gather", _drive_kdelete, 2),
+    "kget_no_lease": ("sliced", _drive_kget_no_lease, 1),
+    "election_only": ("sliced", _drive_election_only, 2),
+    "execute_host": ("pack_gather", _drive_execute_host, 1),
+    "execute_jax": ("mesh", _drive_execute_jax, 1),
+}
+
+#: the only step programs a launch may compile
+STEP_PROGRAMS = {"step", "step_sliced"}
+
+
+def _assert_slab_launches(svc, recs, shape, most):
+    assert recs
+    for r in recs:
+        assert 1 <= r["uploads"] <= most, r
+        if shape == "sliced" and r["k"]:
+            assert r["uploads"] == 1, r
+    assert {e["fn"] for e in svc._compile_log
+            if e["fn"].startswith("step")} <= STEP_PROGRAMS, \
+        list(svc._compile_log)
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_served_flush_counts_its_uploads(case):
+    shape, drive, most = CASES[case]
+    svc = _service(shape)
     try:
+        _settle(svc, [svc.kput(0, "warm", b"w")])  # elects every column
+        spy = _SpyJnp()
+        svc._jnp = spy
+        n0 = len(svc.lat_records)
+        drive(svc)
+        recs = [r for r in list(svc.lat_records)[n0:] if "uploads" in r]
+        _assert_slab_launches(svc, recs, shape, most)
         for r in recs:
-            assert 1 <= r["uploads"] <= most, r
-            if kind == "sliced":
-                assert r["uploads"] == 1, r
             # no eager device op inside the h2d span: all the launch
             # takes from jnp there is the index vector's upload
             t0 = r["starts"]["h2d"]
             inside = {n for n, t in spy.taken if t0 <= t <= t0 + r["h2d"]}
             assert inside <= {"asarray"}, inside
-            if kind != "pack_gather":
+            if shape != "pack_gather":
                 assert not inside, inside
-        st = svc.stats()
-        assert st["slab_launches"] >= len(recs) + 1
-        assert st["plane_launches"] == 0
     finally:
         svc.stop()
+
+
+def test_a_replicas_apply_is_a_slab_launch(tmp_path):
+    """Three hosts: the first settled round elects every column, so it
+    ships full-plane and each replica RE-EXECUTES it through the same
+    door (slab, the packer's index vector, and ``up``'s first
+    upload)."""
+    from riak_ensemble_tpu.config import fast_test_config
+    from riak_ensemble_tpu.parallel import repgroup
+
+    srvs = [repgroup.ReplicaServer(
+        E, 3, S, data_dir=str(tmp_path / f"r{i}"),
+        config=fast_test_config()) for i in (1, 2)]
+    svc = repgroup.ReplicatedService(
+        WallRuntime(), E, 1, S, group_size=3,
+        peers=[("127.0.0.1", s.repl_port) for s in srvs],
+        ack_timeout=15.0, config=fast_test_config(),
+        data_dir=str(tmp_path / "leader"))
+    try:
+        assert svc.takeover()
+        _ok(_settle(svc, [svc.kput(c, "k", b"v") for c in COLS]))
+        svc.heartbeat()
+        svc._drain_pending(block_all=True)
+        for s in srvs + [svc]:
+            lane = getattr(s, "svc", s)
+            recs = [r for r in lane.lat_records if "uploads" in r]
+            _assert_slab_launches(lane, recs, "pack_gather", 3)
+            assert any(r["k"] for r in recs), "no op-carrying apply"
+    finally:
+        svc.stop()
+        for s in srvs:
+            s.stop()
 
 
 def test_mesh_slab_is_committed_to_the_steps_sharding():
@@ -257,65 +387,35 @@ def test_mesh_slab_is_committed_to_the_steps_sharding():
         svc.stop()
 
 
-def test_device_resident_planes_keep_the_per_plane_call():
-    svc = BatchedEnsembleService(WallRuntime(), E, M, S, tick=None)
+@pytest.mark.parametrize("n_ens,program", [
+    (512, "full_step_sliced_slab"), (E, "full_step_slab")],
+    ids=["sliced", "full-width"])
+def test_a_wrapped_engines_step_is_what_runs(n_ens, program):
+    """``testing.wrap_engine_steps`` is how a test stands in the way
+    of the step: whichever program the flush dispatches, the wrapper
+    is what runs, once per launch."""
+    from riak_ensemble_tpu.testing import wrap_engine_steps
+
+    calls = []
+
+    def count(inner, state, slab, up, sliced):
+        calls.append((inner, sliced))
+        return inner(state, slab, up)
+
+    base = _LocalEngine()
+    svc = BatchedEnsembleService(WallRuntime(), n_ens, M, S, tick=None,
+                                 engine=wrap_engine_steps(base, count))
     try:
         _settle(svc, [svc.kput(0, "warm", b"w")])
-        slabs = svc.slab_launches
-        kind = jnp.full((2, E), eng.OP_PUT, jnp.int32)
-        slot = jnp.zeros((2, E), jnp.int32)
-        committed, *_ = svc.execute(kind, slot, slot + 5)
-        assert np.asarray(committed).all()
-        assert svc.slab_launches == slabs and svc.plane_launches == 1
-        # elect, cand and the lease row: the planes never moved
-        assert svc.lat_records[-1]["uploads"] == 3
-        # the same planes from the host ride the slab
-        svc.execute(np.asarray(kind), np.asarray(slot),
-                    np.asarray(slot) + 5)
-        assert svc.slab_launches == slabs + 1
-        assert svc.lat_records[-1]["uploads"] == 1
-    finally:
-        svc.stop()
-
-
-class _CountingEngine(_LocalEngine):
-    """A fault injector's shape: overrides the PLAIN step only."""
-    calls = 0
-
-    @classmethod
-    def full_step(cls, *a, **kw):
-        cls.calls += 1
-        return _LocalEngine.full_step(*a, **kw)
-
-
-def _instance_override():
-    engine = _LocalEngine()
-    engine.calls = 0
-
-    def full_step(*a, **kw):
-        engine.calls += 1
-        return _LocalEngine.full_step(*a, **kw)
-    engine.full_step = full_step
-    return engine
-
-
-@pytest.mark.parametrize("make", [_CountingEngine, _instance_override],
-                         ids=["subclass", "instance"])
-def test_an_overridden_plain_step_is_what_runs(make):
-    engine = make()
-    svc = BatchedEnsembleService(WallRuntime(), 512, M, S, tick=None,
-                                 engine=engine)
-    try:
-        fns = svc._step_fns()
-        assert fns.slab is None and fns.sliced_slab is None
-        vals = _settle(svc, [svc.kput(c, "k", b"v") for c in (3, 9)])
-        assert all(v[0] == "ok" for v in vals)
-        launches = sum(1 for r in svc.lat_records if "uploads" in r)
-        assert engine.calls == launches > 0
-        assert svc.slab_launches == 0
-        assert svc.plane_launches == launches
-        assert all(r["uploads"] >= 7 for r in svc.lat_records
-                   if r.get("k"))
+        n0, l0 = len(calls), len(svc.lat_records)
+        _ok(_settle(svc, [svc.kput(c, "k", b"v") for c in (3, 9)]))
+        launches = sum(1 for r in list(svc.lat_records)[l0:]
+                       if "uploads" in r)
+        assert len(calls) - n0 == launches > 0
+        twin = program + ("_donate" if svc._donate else "")
+        assert all(inner is getattr(base, twin)
+                   and sliced == ("sliced" in program)
+                   for inner, sliced in calls[n0:]), calls[n0:]
     finally:
         svc.stop()
 
@@ -340,6 +440,5 @@ def test_warmup_covers_the_slab_programs(n_ens, cols):
             _settle(svc, [svc.kput(c, f"k{i}", b"v") for c in cols])
         leaked = [e for e in svc._compile_log if e["phase"] == "serve"]
         assert svc._c_compile.labels("serve").value == serve0, leaked
-        assert svc.slab_launches >= 3 and svc.plane_launches == 0
     finally:
         svc.stop()

@@ -175,24 +175,3 @@ def test_quorum_met_wide_pallas_3dim_view_mask(interpreted_engine_gate):
         _jax.clear_caches()
     np.testing.assert_array_equal(got3, ref)
     np.testing.assert_array_equal(got4, ref)
-
-
-def test_validate_wide_plane():
-    """The host-side guard for the wide kernel's conflict-free
-    precondition: distinct valid slots pass; a duplicate valid slot in
-    one [g, e] row raises; duplicates masked by OP_NOOP are fine."""
-    from riak_ensemble_tpu.ops import engine as eng
-
-    g, e, w = 2, 3, 4
-    kind = np.full((g, e, w), eng.OP_PUT, np.int32)
-    slot = np.tile(np.arange(w, dtype=np.int32), (g, e, 1))
-    eng.validate_wide_plane(kind, slot)  # distinct: ok
-
-    bad = slot.copy()
-    bad[1, 2, 3] = bad[1, 2, 0]  # duplicate valid slot
-    with pytest.raises(ValueError, match="ensemble 2"):
-        eng.validate_wide_plane(kind, bad)
-
-    kind2 = kind.copy()
-    kind2[1, 2, 3] = eng.OP_NOOP  # same dup but invalid lane: ok
-    eng.validate_wide_plane(kind2, bad)

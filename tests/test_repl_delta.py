@@ -340,7 +340,7 @@ def test_delta_equivalence_keyed_rmw_inline(tmp_path):
         _stop(svc, srvs)
 
 
-def test_delta_equivalence_batched_wide_groups(tmp_path):
+def test_delta_equivalence_batched_groups(tmp_path):
     svc, srvs = _group(tmp_path)
     try:
         keys = [f"key{j}" for j in range(6)]

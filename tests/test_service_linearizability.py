@@ -218,6 +218,7 @@ def test_service_linearizable_across_launch_failures(seed):
     linearizable — a rollback that resurrected or dropped a committed
     write would surface as a Violation on read-back."""
     from riak_ensemble_tpu.parallel.batched_host import _LocalEngine
+    from riak_ensemble_tpu.testing import wrap_engine_steps
 
     inject_rng = np.random.default_rng(seed + 50_000)
     # The nemesis SCHEDULE guarantees >=1 firing per seed (one launch
@@ -229,20 +230,21 @@ def test_service_linearizable_across_launch_failures(seed):
     forced_launch = 1 + int(inject_rng.integers(6))
     launch_no = 0
 
-    class FailingEngine(_LocalEngine):
-        def full_step(self, *a, **kw):
-            nonlocal launch_no
-            launch_no += 1
-            if launch_no == forced_launch or inject_rng.random() < 0.15:
-                raise RuntimeError("injected-launch-failure")
-            return _LocalEngine.full_step(*a, **kw)
+    def failing(inner, state, slab, up, sliced):
+        nonlocal launch_no
+        launch_no += 1
+        if launch_no == forced_launch or inject_rng.random() < 0.15:
+            raise RuntimeError("injected-launch-failure")
+        return inner(state, slab, up)
 
     rng = np.random.default_rng(seed)
     runtime = Runtime(seed=seed)
     config = fast_test_config()
     svc = BatchedEnsembleService(runtime, N_ENS, N_PEERS, n_slots=8,
                                  tick=None, max_ops_per_tick=8,
-                                 config=config, engine=FailingEngine())
+                                 config=config,
+                                 engine=wrap_engine_steps(
+                                     _LocalEngine(), failing))
     models = {(e, k): KeyModel(f"{e}/key{k}")
               for e in range(N_ENS) for k in range(N_KEYS)}
     vals = itertools.count(1)

@@ -534,33 +534,34 @@ def test_buffer_mode_delete_reaches_kernel_before_ack(tmp_path,
     w.close()
 
 
-def test_device_resident_execute_unlogged_is_observable(tmp_path):
-    """ADVICE r3: a WAL-enabled service serving device-resident
-    execute() calls silently weakens the durability contract (no WAL
-    record; RPO = checkpoint cadence).  That must be observable: a
-    one-time trace event plus a stats() flag."""
+def test_device_resident_execute_is_logged_and_replays(tmp_path):
+    """``jax.Array`` planes handed to execute() are read back at the
+    door and are from there an ordinary host call: the committed
+    writes are WAL'd before the call returns (the result is the ack)
+    and replay after a crash.  (They used to skip the WAL because of
+    their argument's type.)"""
     import jax.numpy as jnp
 
     from riak_ensemble_tpu.ops import engine as eng
 
-    events = []
     runtime, svc = make_durable(tmp_path)
-    runtime.trace = lambda kind, payload: events.append((kind, payload))
-    k = 2
-    kind = jnp.full((k, svc.n_ens), eng.OP_PUT, jnp.int32)
-    slot = jnp.zeros((k, svc.n_ens), jnp.int32)
-    val = jnp.ones((k, svc.n_ens), jnp.int32)
-    assert svc.stats()["execute_unlogged"] is False
-    svc.execute(kind, slot, val)
-    svc.execute(kind, slot, val)
-    unlogged = [e for e in events if e[0] == "svc_execute_unlogged"]
-    assert len(unlogged) == 1, "exactly one one-time trace event"
-    assert svc.stats()["execute_unlogged"] is True
-    # host-array calls still WAL-log: the flag marks the weaker path's
-    # use, it does not disable durability for the strong one
+    n = svc.n_ens
+    kind = jnp.full((2, n), eng.OP_PUT, jnp.int32)
+    slot = jnp.asarray(np.tile(np.array([[0], [1]], np.int32), (1, n)))
+    val = jnp.arange(1, 2 * n + 1, dtype=jnp.int32).reshape(2, n)
     before = svc._wal.count
-    svc.execute(np.full((1, svc.n_ens), eng.OP_PUT, np.int32),
-                np.zeros((1, svc.n_ens), np.int32),
-                np.full((1, svc.n_ens), 7, np.int32))
-    assert svc._wal.count > before
-    svc.stop()
+    committed, *_ = svc.execute(kind, slot, val)
+    assert committed.all()
+    assert svc._wal.count >= before + 2 * n, "writes were not logged"
+    crash(svc)
+
+    rt2 = Runtime(seed=17)
+    svc2 = BatchedEnsembleService.restore(
+        rt2, str(tmp_path / "data"), tick=None,
+        config=fast_test_config(), data_dir=str(tmp_path / "data"))
+    _, get_ok, found, value = svc2.execute(
+        jnp.full((2, n), eng.OP_GET, jnp.int32), slot,
+        jnp.zeros((2, n), jnp.int32))
+    assert get_ok.all() and found.all()
+    np.testing.assert_array_equal(value, np.asarray(val))
+    svc2.stop()
