@@ -1406,10 +1406,11 @@ def _full_step_sliced_body(state: EngineState, active_idx: jax.Array,
     (``_adopt_epochs``) and lease-renewing quorum confirmations run
     only for active columns — an idle ensemble's lease lapses and
     its stragglers heal on its NEXT active launch, which is exactly
-    when the heal is first observable.  Single-shard launches only
-    (a mesh-sharded E axis cannot gather across shards without
-    resharding; the mesh service keeps the full grid and compacts
-    the packed result instead).
+    when the heal is first observable.  Under ``shard_map`` (the
+    mesh engine's sliced programs) every shard runs this body on its
+    own LOCAL rows with its own LOCAL indices (pad = the local row
+    count): the gather and the scatter stay on the chip that holds
+    the rows, and nothing crosses the 'ens' axis.
     """
     sub, up_a = _slice_columns(state, active_idx, up)
     sub, won, res = _full_step_body(
@@ -1436,7 +1437,11 @@ def _full_step_sliced_body(state: EngineState, active_idx: jax.Array,
 # R = head + 5 K with head 3 (4 sliced), so the shape alone carries K
 # and W and a (K, A) bucket stays one program.  Under ``shard_map`` the
 # slab is sharded ``P(None, 'ens')`` like the op planes it replaces: a
-# row of the local block is that shard's ``P('ens')`` vector.
+# row of the local block is that shard's ``P('ens')`` vector.  A SLICED
+# mesh slab is ``n_shards`` blocks of one width ``a_loc`` side by side
+# (W = n_shards * a_loc), and each shard's local block is an ordinary
+# sliced slab of its own: its columns gathered to the block's front,
+# its index row LOCAL (pad = the rows a shard holds, E / n_shards).
 
 SLAB_ELECT, SLAB_CAND, SLAB_LEASE, SLAB_ACTIVE_IDX = 0, 1, 2, 3
 #: the per-round planes, K rows each, in slab order
@@ -1448,7 +1453,7 @@ def _slab_head(sliced: bool) -> int:
 
 
 def pack_op_slab(width: int, k: int, elect, cand, lease_ok, planes,
-                 active=None, active_idx=None) -> np.ndarray:
+                 active=None, active_idx=None, at=None) -> np.ndarray:
     """Host half of the op slab: a FRESH ``[R, width]`` int32 array
     (an upload may still be reading the previous launch's).
 
@@ -1457,26 +1462,29 @@ def pack_op_slab(width: int, k: int, elect, cand, lease_ok, planes,
     versions).  Full width: ``width`` is E and everything is copied
     whole.  Sliced (``active_idx`` given, ``[width]``, pad = E):
     ``active`` names the real columns, gathered to the slab's first
-    ``len(active)`` columns; padding columns stay NOOP/zero."""
+    ``len(active)`` columns; padding columns stay NOOP/zero.  A mesh's
+    sliced slab (the per-shard blocks above) names with ``at`` the slab
+    column each of ``active`` goes to: the front of its shard's block."""
     sliced = active_idx is not None
     head = _slab_head(sliced)
     slab = np.zeros((head + len(SLAB_PLANES) * k, width), np.int32)
-    n = len(active) if sliced else width
+    if at is None:
+        at = slice(len(active) if sliced else width)
 
     def cols(x):
         x = np.asarray(x)
         return x[..., active] if sliced else x
 
-    slab[SLAB_ELECT, :n] = cols(elect)
-    slab[SLAB_CAND, :n] = cols(cand)
-    slab[SLAB_LEASE, :n] = cols(lease_ok)
+    slab[SLAB_ELECT, at] = cols(elect)
+    slab[SLAB_CAND, at] = cols(cand)
+    slab[SLAB_LEASE, at] = cols(lease_ok)
     if sliced:
         slab[SLAB_ACTIVE_IDX] = active_idx
     if k:
         body = slab[head:].reshape(len(SLAB_PLANES), k, width)
         for i, p in enumerate(planes):
             if p is not None:
-                body[i, :, :n] = cols(p)
+                body[i][:, at] = cols(p)
     return slab
 
 

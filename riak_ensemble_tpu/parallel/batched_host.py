@@ -12,8 +12,8 @@ host *service* that multiplexes thousands of engine-backed ensembles —
   of E leader processes × worker pools).  There is ONE way into the
   step: every launch (a flush, an election-only round, ``execute()``,
   a replica's apply) uploads one op slab and calls one program,
-  ``(state, op slab, up) -> (state, won, result)``, sliced where one
-  chip can slice and full width otherwise (``_launch_enqueue``);
+  ``(state, op slab, up) -> (state, won, result)``, sliced where a
+  device can slice and full width otherwise (``_launch_enqueue``);
 - the host side keeps what consensus doesn't need on-device: the
   key→slot assignment per ensemble, the payload store (device arrays
   carry int32 handles; real bytes live host-side keyed by handle —
@@ -38,10 +38,11 @@ docs/ARCHITECTURE.md §7 "Two-phase launch pipeline".
 Launches are ACTIVE-COLUMN COMPACTED: one hot ensemble forces the
 `[K, E]` grid to its queue depth, but the flush gathers down to the
 columns that actually hold ops (`[K, A]`, A pow2-bucketed like the K
-ladder).  On single-shard engines at low occupancy the fused step
-itself runs on the gathered grid (``engine.full_step_sliced_slab`` —
+ladder).  At low occupancy of what one device holds the fused step
+itself runs on the gathered grid (``full_step_sliced_slab``, one
+chip's or, per shard inside ``shard_map``, the mesh engine's —
 compute, h2d and the packed d2h all scale with the live working
-set); mesh engines and mid-occupancy launches keep the full-grid
+set); small rings and mid-occupancy launches keep the full-grid
 step and gather only the packed result.  The host unpack scatters
 everything back to full width — pure re-indexing, results
 bit-identical to the full-width pack (``RETPU_COMPACT=0`` opts
@@ -112,7 +113,8 @@ from riak_ensemble_tpu.types import NOTFOUND
 #: dominant-mark argmax and these sums can never drift apart.
 #: ``starts`` and ``clock`` are the span primitive's stamps
 #: (obs.spans), not seconds.
-DERIVED_MARKS = frozenset(("k", "uploads", "total", "starts", "clock")
+DERIVED_MARKS = frozenset(("k", "uploads", "sliced", "total", "starts",
+                           "clock")
                           + obs.flightrec.DERIVED_MARKS)
 
 #: per-entry field extractor for the per-op SLO fold (C-level
@@ -422,7 +424,8 @@ def unpack_results(flat: np.ndarray, e: int, m: int, k: int,
 def unpack_results_sharded(flat: np.ndarray, e: int, m: int, k: int,
                            want_vsn: bool, n_shards: int,
                            shard_active: Optional[List[np.ndarray]]
-                           = None, a_width: int = 0):
+                           = None, a_width: int = 0,
+                           sliced: bool = False):
     """Invert the shard-wise packer (:func:`_make_shardwise_packer`):
     the payload is ``n_shards`` :func:`_pack_results` blocks in shard
     order, each covering a contiguous ``e_loc = E/n_shards`` column
@@ -432,9 +435,11 @@ def unpack_results_sharded(flat: np.ndarray, e: int, m: int, k: int,
     oracle and the full-width planes concatenate back along E — so
     every downstream consumer (mirror scatter, WAL, replica CRC)
     stays layout-blind, exactly as with the gathered
-    pack."""
+    pack.  ``sliced``: every shard's STEP ran on its gathered
+    ``[K, a_width]`` grid, so each block is a sliced launch's
+    (its won/quorum/corrupt planes ``a_width`` wide too)."""
     e_loc = e // n_shards
-    nb = packed_nbytes(e_loc, m, k, want_vsn,
+    nb = packed_nbytes(a_width if sliced else e_loc, m, k, want_vsn,
                        a_width if shard_active is not None else None)
     parts = []
     for s in range(n_shards):
@@ -442,7 +447,8 @@ def unpack_results_sharded(flat: np.ndarray, e: int, m: int, k: int,
         parts.append(unpack_results(
             flat[s * nb:(s + 1) * nb], e_loc, m, k, want_vsn,
             active=act,
-            a_width=0 if shard_active is None else a_width))
+            a_width=0 if shard_active is None else a_width,
+            sliced=sliced))
 
     def cat(i, axis):
         if parts[0][i] is None:
@@ -634,8 +640,8 @@ class _StepFns(NamedTuple):
     """The two step programs a launch can dispatch, both ``(state, op
     slab, up)`` (``engine.pack_op_slab`` has the layout), donated when
     the service donates: ``slab`` at full width, ``sliced_slab`` on
-    the gathered active columns (None = the engine cannot slice: a
-    mesh engine)."""
+    the gathered active columns (None = the launch cannot slice on
+    this engine)."""
 
     slab: Any
     sliced_slab: Any
@@ -964,6 +970,11 @@ class BatchedEnsembleService:
         self.payload_bytes_full_width = 0
         self._occ_sum = 0.0
         self._occ_launches = 0
+        #: launches whose STEP ran on the gathered [K, a_loc] grid,
+        #: and those that stepped the full grid (each flush record
+        #: carries its own ``sliced`` 0/1 beside ``uploads``)
+        self.launches_sliced = 0
+        self.launches_unsliced = 0
         #: RMW observability: host-path kmodify CAS attempts that
         #: failed and were retried (write races, plus transient
         #: quorum failures — indistinguishable client-side), and ops
@@ -3350,9 +3361,12 @@ class BatchedEnsembleService:
         always meant (the benchmark's warm-up lines group by them)."""
         e = self.engine
         twin = "_donate" if self._donate else ""
-        fns = _StepFns(
-            getattr(e, "full_step_slab" + twin),
-            getattr(e, "full_step_sliced_slab" + twin, None))
+        sliced = getattr(e, "full_step_sliced_slab" + twin, None)
+        if getattr(e, "n_ens_shards", 1) != (self._mesh_shards or 1):
+            # 'ens' shards under a sharded 'peer' axis: the gathered
+            # pack knows no per-shard blocks, so the full grid stays
+            sliced = None
+        fns = _StepFns(getattr(e, "full_step_slab" + twin), sliced)
         if not self._obs:
             return fns
         return _StepFns(*map(self._watched, ("step", "step_sliced"),
@@ -3412,63 +3426,52 @@ class BatchedEnsembleService:
         h2d = self.spans.span("h2d", rec).begin()
         fns = self._fns
         # Active-column compaction, two strengths (the payload and
-        # the grid both decouple from E):
-        # - SLICED launch (single-shard engines, E >= SLICE_MIN_E,
-        #   |A| bucketed at or under E/4): the fused step itself
-        #   runs on the gathered [K, A] grid — compute, HBM traffic,
+        # the grid both decouple from E), ONE rule read per ens-shard
+        # (one chip is the case n_sh = 1; a shard-wise mesh buckets at
+        # the busiest shard's pow2 width a_loc, so the bucketing, the
+        # gathers and the packed d2h payload all stay shard-local: no
+        # replicated index constraint, no all-gather):
+        # - SLICED launch (``_slices``: the engine has a sliced
+        #   program, a shard holds e_loc >= SLICE_MIN_E rows, a_loc at
+        #   or under e_loc/4): the fused step itself runs on the gathered
+        #   [K, a_loc] grid of each shard: compute, HBM traffic,
         #   op-plane h2d and the packed result all scale with the
         #   live working set.  The active set must include every
         #   electing column (their rounds run inside the same
-        #   launch).
-        # - PACK-GATHER (mesh engines, small/mid grids, or |A| above
-        #   E/4): the step keeps the full grid; only the packed
-        #   result gathers down to [K, A] (the d2h cut alone).
+        #   launch).  On a mesh each shard's columns fill its own
+        #   block of the slab (engine "The op slab") and its index
+        #   row is LOCAL: the gather runs inside shard_map.
+        # - PACK-GATHER (small/mid grids, or a_loc above e_loc/4):
+        #   the step keeps the full grid; only the packed result
+        #   gathers down to [K, a_loc] (the d2h cut alone).
         # Buckets ride the pow2 A ladder (mirroring the K ladder's
         # compile-reuse discipline).
-        active = aidx_np = shard_active = None
+        active = aidx_np = shard_active = at = None
         a_width = 0
         sliced = False
-        if self._compact and k:
-            cols = np.flatnonzero(
-                (np.asarray(kind) != eng.OP_NOOP).any(axis=0)
-                | np.asarray(elect, bool))
-            if cols.size and self._mesh_shards:
-                # Compaction-aware SHARDING: the |A| bucket is
-                # computed PER ENS-SHARD — every shard packs the same
-                # pow2 width (the busiest shard's bucket) of its own
-                # LOCAL columns, so the bucketing, the column gather
-                # and the packed d2h payload all stay shard-local
-                # (no replicated index constraint, no all-gather).
-                # The step itself keeps the full grid (a sharded E
-                # axis cannot slice across shards) — this is the
-                # pack-gather strength only.
-                per_shard, a_loc = shard_active_columns(
-                    cols, self.n_ens, self._mesh_shards, A_BUCKET_MIN)
-                if a_loc < self.n_ens // self._mesh_shards:
-                    active = cols.astype(np.int32)
-                    a_width = a_loc
+        n_sh = self._mesh_shards or 1
+        e_loc = self.n_ens // n_sh
+        cols = np.flatnonzero(
+            (np.asarray(kind) != eng.OP_NOOP).any(axis=0)
+            | np.asarray(elect, bool)) if self._compact and k else ()
+        if len(cols):
+            per_shard, a_loc = shard_active_columns(
+                cols, self.n_ens, n_sh, A_BUCKET_MIN)
+            if a_loc < e_loc:
+                active = cols.astype(np.int32)
+                a_width = a_loc
+                sliced = self._slices(a_loc)
+                # sliced pads aim OUT OF RANGE (the local row count)
+                # so the state scatter drops them; the pack gather
+                # pads with column 0 (ignored by the host unpack)
+                aidx_np = np.full((n_sh, a_loc), e_loc if sliced else 0,
+                                  np.int32)
+                for si, p in enumerate(per_shard):
+                    aidx_np[si, :p.size] = p
+                if self._mesh_shards:
                     shard_active = per_shard
-                    aidx_np = np.zeros((self._mesh_shards, a_loc),
-                                       np.int32)
-                    for si, p in enumerate(per_shard):
-                        aidx_np[si, :p.size] = p
-            elif cols.size:
-                a_b = A_BUCKET_MIN
-                while a_b < cols.size:
-                    a_b <<= 1
-                if a_b < self.n_ens:
-                    active = cols.astype(np.int32)
-                    a_width = a_b
-                    sliced = (fns.sliced_slab is not None
-                              and self.n_ens >= SLICE_MIN_E
-                              and a_b * 4 <= self.n_ens)
-                    # sliced pads aim OUT OF RANGE (index E) so the
-                    # state scatter drops them; the pack gather pads
-                    # with column 0 (ignored by the host unpack)
-                    aidx_np = np.full((a_b,),
-                                      self.n_ens if sliced else 0,
-                                      np.int32)
-                    aidx_np[:cols.size] = active
+                    if sliced:  # each column's place in its block
+                        at = np.flatnonzero(aidx_np.ravel() < e_loc)
         # EVERY input upload belongs to the h2d mark — an upload
         # inlined into the step call would bill its (synchronous)
         # transfer to 'dispatch' and make the async-enqueue number
@@ -3480,20 +3483,23 @@ class BatchedEnsembleService:
         # ONE upload: everything the step reads from the host as one
         # op slab (engine.pack_op_slab has the row layout; the program
         # takes it apart), put where the step wants it.  A sliced
-        # launch's index vector is a slab row; a pack-gather's
-        # (another width) is the packer's operand and a second upload.
+        # launch's index rows are a slab row; a pack-gather's
+        # (another width) are the packer's operand and a second upload.
         slab_j = self._put(eng.pack_op_slab(
-            a_width if sliced else self.n_ens, k, elect, cand,
+            n_sh * a_width if sliced else self.n_ens, k, elect, cand,
             lease_ok, (kind, slot, val, exp_e, exp_s),
             active if sliced else None,
-            aidx_np if sliced else None), "slab")
+            aidx_np.ravel() if sliced else None, at), "slab")
         uploads += 1
         aidx_j = None
         if aidx_np is not None and not sliced:
             aidx_j = (self._shard_aidx(aidx_np) if self._mesh_shards
-                      else jnp.asarray(aidx_np))
+                      else jnp.asarray(aidx_np[0]))
             uploads += 1
         rec["uploads"] = uploads
+        rec["sliced"] = int(sliced)
+        self.launches_sliced += sliced
+        self.launches_unsliced += not sliced
         h2d.end()
 
         # Rollback snapshots: under async dispatch a device failure
@@ -3540,6 +3546,17 @@ class BatchedEnsembleService:
             op_slot_np=np.asarray(slot),
             flush_id=obs.next_flush_id() if self._obs else 0,
             t_join=rec["starts"]["h2d"])
+
+    def _slices(self, a_loc: int) -> bool:
+        """THE rule for a sliced launch, read per ens-shard (one chip
+        is one shard): the engine has a sliced program, a shard holds
+        at least ``SLICE_MIN_E`` rows, and the launch's columns,
+        bucketed at the busiest shard's pow2 width ``a_loc``, are at
+        most a quarter of them.  The launch and ``warmup`` both ask
+        here."""
+        e_loc = self.n_ens // (self._mesh_shards or 1)
+        return (self._fns.sliced_slab is not None
+                and e_loc >= SLICE_MIN_E and a_loc * 4 <= e_loc)
 
     def _shard_aidx(self, pad: np.ndarray):
         """Place a ``[n_shards, A_loc]`` per-shard local active-index
@@ -3634,7 +3651,7 @@ class BatchedEnsembleService:
                     planes8 = unpack_results_sharded(
                         flat, e, m, fl.k, fl.want_vsn,
                         fl.n_shards, shard_active=fl.shard_active,
-                        a_width=fl.a_width)
+                        a_width=fl.a_width, sliced=fl.sliced)
                     native_arm = False
                 else:
                     if self._native_resolve is not None and fl.k:
@@ -3903,6 +3920,8 @@ class BatchedEnsembleService:
             "payload_bytes_full_width": self.payload_bytes_full_width,
             "grid_occupancy": (self._occ_sum / self._occ_launches
                                if self._occ_launches else 1.0),
+            "launches_sliced": self.launches_sliced,
+            "launches_unsliced": self.launches_unsliced,
             # WAL-compaction pauses (deferred off the hot path; the
             # svc_compaction latency mark carries the same numbers
             # into latency_breakdown())
@@ -4743,8 +4762,8 @@ class BatchedEnsembleService:
         # compiled program's aliasing, so the plain warm wouldn't cover
         # it).  The throwaway state is THREADED through the calls: a
         # donated call consumes its input state.  Per (K, A) bucket
-        # the launch dispatches EITHER the sliced step (A <= E/4 on a
-        # sliced-capable engine: step + plain pack at A-width) OR the
+        # the launch dispatches EITHER the sliced step (A <= E/4 of
+        # what a shard holds: step + plain pack at A-width) OR the
         # full-grid step with the gathering pack — warm exactly that,
         # with operands placed as _launch_enqueue places them
         # (placement is part of a program's cache key).
@@ -4753,15 +4772,18 @@ class BatchedEnsembleService:
         up = self._put(np.ones((e, m), bool), "up")
         no_planes = (None,) * len(eng.SLAB_PLANES)
 
+        n_sh = self._mesh_shards or 1
+        e_loc = e // n_sh
+
         def zero_slab(k: int, width: int, sliced: bool):
-            """An all-NOOP op slab; sliced: an all-pad index row
+            """An all-NOOP op slab; sliced: all-pad index rows
             (gathers clip harmlessly, the scatter drops everything —
             state untouched, program compiled)."""
             z = np.zeros((width,), np.int32)
             return self._put(eng.pack_op_slab(
                 width, k, z, z, z, no_planes,
                 z[:0] if sliced else None,
-                z + e if sliced else None), "slab")
+                z + e_loc if sliced else None), "slab")
 
         def cost(label: str, fn, *args) -> None:
             ca = eng.lowered_cost_analysis(fn, *args)
@@ -4770,12 +4792,11 @@ class BatchedEnsembleService:
 
         def warm_sliced(k: int, aw: int) -> bool:
             """One (K, A) bucket's sliced program, where the launch
-            path would slice there."""
+            path would slice there (its rule, read per shard)."""
             nonlocal st
-            if not (fns.sliced_slab is not None
-                    and e >= SLICE_MIN_E and aw * 4 <= e):
+            if not self._slices(aw):
                 return False
-            slab = zero_slab(k, aw, True)
+            slab = zero_slab(k, n_sh * aw, True)
             st, won, res = fns.sliced_slab(st, slab, up)
             if self._obs and capture_costs:
                 cost(f"k{k}_a{aw}", fns.sliced_slab, st, slab, up)

@@ -117,16 +117,19 @@ class ShardedEngine:
     E must divide by mesh 'ens' size; M by mesh 'peer' size (pad views
     with absent peers if needed — all-zero view columns are inert).
 
-    The fused steps are INSTANCE attributes: ``full_step_slab`` (and
-    its ``_donate`` twin), the jitted ``(state, op slab, up)`` program
-    the service launches, and the per-plane ``full_step`` /
-    ``full_step_donate`` the slab form is compared against (plain
-    wrappers that default absent CAS planes and forward
-    ``_cache_size`` so ``CompileWatch`` sees mesh compiles).  There
-    are NO sliced variants — a mesh-sharded E axis cannot gather
-    active columns across shards without resharding; the mesh service
-    keeps the full grid and compacts the packed RESULT per ens-shard
-    instead (see ``batched_host``'s shard-wise packer).
+    The fused steps are INSTANCE attributes: ``full_step_slab`` and
+    ``full_step_sliced_slab`` (and their ``_donate`` twins), the
+    jitted ``(state, op slab, up)`` programs the service launches, and
+    the per-plane ``full_step`` / ``full_step_donate`` the slab form
+    is compared against (plain wrappers that default absent CAS planes
+    and forward ``_cache_size`` so ``CompileWatch`` sees mesh
+    compiles).  The SLICED step gathers INSIDE ``shard_map``: every
+    shard takes its own local rows by the local indices of its own
+    block of the slab (``ops/engine.py`` "The op slab"), steps
+    ``[K, a_loc]`` and scatters back, so no row crosses a chip and
+    nothing is resharded; ``won`` and the result planes come back
+    ``n_shards * a_loc`` wide, one block per shard, which
+    ``batched_host``'s shard-wise packer packs as they are.
     """
 
     def __init__(self, mesh: Mesh) -> None:
@@ -181,6 +184,16 @@ class ShardedEngine:
         self.full_step_slab = smap(_slab_body, _slab_in, _full_out)
         self.full_step_slab_donate = smap(_slab_body, _slab_in,
                                           _full_out, donate=True)
+
+        def _sliced_slab_body(st, slab, up):
+            # one ordinary sliced slab a shard (see class docstring)
+            return eng._full_step_sliced_slab_body(st, slab, up,
+                                                   axis_name=ax)
+
+        self.full_step_sliced_slab = smap(_sliced_slab_body, _slab_in,
+                                          _full_out)
+        self.full_step_sliced_slab_donate = smap(
+            _sliced_slab_body, _slab_in, _full_out, donate=True)
         # the per-plane reference steps (see class docstring)
         self.full_step = self._make_step(self._full)
         self.full_step_donate = self._make_step(self._full_donate)
@@ -279,8 +292,9 @@ class ShardedEngine:
         return self._kv(state, kind, slot, val, lease_ok, up,
                         exp_epoch, exp_seq)
 
-    # full_step / full_step_slab (+ _donate) are instance attributes
-    # built in __init__ — see the class docstring.
+    # full_step / full_step_slab / full_step_sliced_slab (+ _donate)
+    # are instance attributes built in __init__ — see the class
+    # docstring.
 
     def reconfig_step(self, state, propose, new_view, up):
         """Joint-consensus membership change over the mesh

@@ -14,6 +14,7 @@ file may do it (never at import, never in a skipif/parametrize).
 
 import os
 import re
+import time
 
 import pytest
 
@@ -177,3 +178,48 @@ def test_mesh_step_compiles_on_four_chips_without_ens_collectives(topo):
     full = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(state))
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes < full / 4 * 1.1
+
+
+def test_sliced_mesh_step_compiles_on_four_chips_without_ens_collectives(
+        topo, record_property):
+    """`ycsb-a.ring40k-n5-mesh4`'s window flush: 40,960 x 5 x 128 over
+    the 2x2 mesh, K 1, `a_loc` 8 columns a shard.  Every shard gathers
+    its own rows by its own local indices, so the sliced program too
+    holds no collective; its compile seconds and temporaries are
+    recorded beside the full-grid program's at the same K."""
+    from riak_ensemble_tpu.parallel.mesh import mesh_engine
+
+    e, k, a_loc = 40_960, 1, 8
+    engine = mesh_engine(4, devices=topo.devices)
+    state = _placed(jax.eval_shape(lambda: eng.init_state(e, M, S)),
+                    eng.state_sharding(engine.mesh))
+    up = jax.ShapeDtypeStruct((e, M), jnp.bool_,
+                              sharding=engine.up_sharding)
+
+    def compile_(program, head, width):
+        t0 = time.perf_counter()
+        compiled = program.lower(
+            state, jax.ShapeDtypeStruct((head + 5 * k, width), jnp.int32,
+                                        sharding=engine.slab_sharding),
+            up).compile()
+        return compiled, time.perf_counter() - t0
+
+    sliced, sliced_s = compile_(engine.full_step_sliced_slab_donate, 4,
+                                4 * a_loc)
+    grid, grid_s = compile_(engine.full_step_slab_donate, 3, e)
+    text = sliced.as_text()
+    assert not _COLLECTIVES.search(text), _COLLECTIVES.findall(text)
+    mem, grid_mem = sliced.memory_analysis(), grid.memory_analysis()
+    for name, value in (
+            ("sliced_compile_s", sliced_s), ("grid_compile_s", grid_s),
+            ("sliced_temp_bytes", mem.temp_size_in_bytes),
+            ("grid_temp_bytes", grid_mem.temp_size_in_bytes),
+            ("argument_bytes", mem.argument_size_in_bytes)):
+        record_property(name, value)
+        print(f"{name} {value}")
+    # a quarter of the state a device; the program fits the chip (its
+    # temporaries are one chip's sliced program's at 10,240 rows: the
+    # compiler's whole-plane copies, PERF.md section 5, not the mesh's)
+    full = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(state))
+    assert mem.argument_size_in_bytes < full / 4 * 1.1
+    assert 0 < mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9

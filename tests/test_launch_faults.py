@@ -1,8 +1,9 @@
 """A launch that fails, on every arm of the one door into the step.
 
 {one chip sliced, one chip full width, 'ens'-sharded mesh on four
-virtual devices} x {the device fails inside the step, the packed
-result's fetch fails} x {state not donated, donated}.  The fault stands
+virtual devices at the full grid, the same mesh sliced per shard} x
+{the device fails inside the step, the packed result's fetch fails} x
+{state not donated, donated}.  The fault stands
 in the way of whichever program the flush dispatches
 (``testing.wrap_engine_steps``), so these fail, roll back and poison
 on the path that serves.
@@ -41,8 +42,10 @@ from riak_ensemble_tpu.parallel.mesh import mesh_engine  # noqa: E402
 from riak_ensemble_tpu.testing import wrap_engine_steps  # noqa: E402
 
 COLS = (1, 7, 40)
-#: n_ens per arm: the sliced arm's three columns bucket to A = 8 <= E/4
-N_ENS = {"sliced": SLICE_MIN_E, "full": 64, "mesh4": 64}
+#: n_ens per arm: the sliced arms' three columns bucket to A = 8, at
+#: most a quarter of what one chip (or one of the mesh's four) holds
+N_ENS = {"sliced": SLICE_MIN_E, "full": 64, "mesh4": 64,
+         "mesh4-sliced": 4 * SLICE_MIN_E}
 
 
 class _Fault:
@@ -89,14 +92,15 @@ def _settle(svc, futs):
 @pytest.mark.parametrize("donate", [False, True],
                          ids=["undonated", "donated"])
 @pytest.mark.parametrize("where", ["step", "fetch"])
-@pytest.mark.parametrize("shape", ["sliced", "full", "mesh4"])
+@pytest.mark.parametrize("shape", list(N_ENS))
 def test_failed_launch(shape, where, donate, monkeypatch):
     monkeypatch.setenv("RETPU_DONATE", "1" if donate else "0")
     fault = _Fault(where)
     runtime = WallRuntime()
     events = []
     runtime.trace = lambda kind, payload: events.append(kind)
-    base = mesh_engine(4) if shape == "mesh4" else _LocalEngine()
+    mesh = shape.startswith("mesh4")
+    base = mesh_engine(4) if mesh else _LocalEngine()
     svc = BatchedEnsembleService(
         runtime, N_ENS[shape], 3, 8, tick=None,
         engine=wrap_engine_steps(base, fault.around))
@@ -114,7 +118,7 @@ def test_failed_launch(shape, where, donate, monkeypatch):
         with pytest.raises(RuntimeError, match=f"injected {where}"):
             svc.flush()
         assert [f.done and f.value for f in futs] == ["failed"] * 3
-        assert fault.sliced == [shape == "sliced"]
+        assert fault.sliced == [shape.endswith("sliced")]
         np.testing.assert_array_equal(svc.leader_np, leader0)
         np.testing.assert_array_equal(svc.lease_until, lease0)
         poisoned = events.count("svc_state_poisoned")
@@ -128,7 +132,7 @@ def test_failed_launch(shape, where, donate, monkeypatch):
             assert state0.epoch.is_deleted(), "donation consumed nothing"
             if where == "step":
                 assert svc.state is state0   # the consumed buffers
-                if shape == "mesh4":
+                if mesh:
                     return   # see the module docstring
                 f = svc.kput(COLS[0], "b", b"3")
                 with pytest.raises(Exception, match="buffer|deleted"):

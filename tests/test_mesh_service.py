@@ -9,6 +9,10 @@ contracts: warmup covers the mesh step/pack variants (CompileWatch
 asserts zero serve-phase compiles), and checkpoints round-trip across
 shard counts (8→1 and 1→8) bit-equal.
 
+The SLICED mesh launch (ISSUE 33: four virtual devices, a shard of
+``SLICE_MIN_E`` rows) is held against the full-grid mesh launch and
+against one chip's sliced launch on the same ops.
+
 Marked ``mesh`` so the suite can run as its own session
 (``pytest -m mesh``); the forced 8-device CPU mesh comes from
 conftest.py's XLA_FLAGS bootstrap (process-wide by design — the flag
@@ -26,7 +30,7 @@ jax = pytest.importorskip("jax")
 
 from riak_ensemble_tpu import funref  # noqa: E402
 from riak_ensemble_tpu.parallel.batched_host import (  # noqa: E402
-    BatchedEnsembleService, WallRuntime, mesh_ens_shards,
+    SLICE_MIN_E, BatchedEnsembleService, WallRuntime, mesh_ens_shards,
 )
 from riak_ensemble_tpu.parallel.mesh import mesh_engine  # noqa: E402
 
@@ -208,3 +212,148 @@ def test_checkpoint_across_shard_counts(direction):
         if dst is not None:
             dst.stop()
         shutil.rmtree(d, ignore_errors=True)
+
+
+# -- the sliced mesh launch (ISSUE 33) ---------------------------------------
+
+N_SH = 4
+E_LOC = SLICE_MIN_E          # rows a shard holds: the least that slices
+E_SL = N_SH * E_LOC
+
+#: active columns of one flush, by what they do to the per-shard blocks
+SLICED_CASES = {
+    # every shard busy, unevenly: a_loc 8 is the busiest shard's bucket
+    "spread": [1, 7, 40, E_LOC + 3, 2 * E_LOC, 2 * E_LOC + 9,
+               3 * E_LOC + 200, E_SL - 1],
+    # every column on ONE shard: three blocks all pad
+    "one_shard": [2 * E_LOC + c for c in (0, 5, 6, 77, 255)],
+    # shard 2 EMPTY (its block all pad), its neighbours not
+    "empty_shard": [0, 3, E_LOC + 1, E_LOC + 2, 3 * E_LOC + 8],
+    # 65 columns on shard 1: a_loc 128, and 128 * 4 > E_LOC rows, so
+    # the mesh steps the full grid and only the pack gathers
+    "too_wide": [5] + [E_LOC + 2 * c for c in range(65)],
+}
+
+
+def _sliced_arms():
+    """(the mesh as it serves, the same mesh held to the full grid,
+    one chip) at E_SL ensembles: three services fed the same ops."""
+    def mk(engine):
+        return BatchedEnsembleService(WallRuntime(), E_SL, 3, 8,
+                                      tick=None, engine=engine)
+    mesh, grid, one = (mk(mesh_engine(N_SH)), mk(mesh_engine(N_SH)),
+                       mk(None))
+    assert mesh._fns.sliced_slab is not None
+    grid._fns = grid._fns._replace(sliced_slab=None)
+    return mesh, grid, one
+
+
+def _state_rows(svc, rows=slice(None)):
+    return [np.asarray(x)[rows] for x in svc.state]
+
+
+def _launches(svc, n0):
+    return [(r["k"], r["uploads"], r["sliced"])
+            for r in list(svc.lat_records)[n0:] if "uploads" in r]
+
+
+@pytest.mark.parametrize("k", [1, 4])
+@pytest.mark.parametrize("case", list(SLICED_CASES))
+def test_sliced_mesh_launch_matches_full_grid_and_one_chip(case, k):
+    cols = SLICED_CASES[case]
+    slices = case != "too_wide"
+    arms = _sliced_arms()
+    try:
+        replies, before = [], None
+        for svc in arms:
+            _drive(svc, [svc.kput(0, "warm", b"w")])  # elects every row
+            if svc is arms[0]:
+                before = _state_rows(svc)
+            n0 = len(svc.lat_records)
+            # K ops deep on every column, ONE flush: writes, then a
+            # read of the first through the same launch at K 4
+            futs = [svc.kput(c, f"k{j}", b"v%d.%d" % (c, j))
+                    for c in cols for j in range(max(k - 1, 1))]
+            if k > 1:
+                futs += [svc.kget(c, "k0") for c in cols]
+            replies.append(_drive(svc, futs))
+            got = _launches(svc, n0)
+            if svc is arms[0]:    # the mesh as it serves
+                assert got == [(k, 1, 1) if slices else (k, 2, 0)], got
+            elif svc is arms[1]:  # held to the full grid: pack-gather
+                assert got == [(k, 2, 0)], got
+            else:                 # one chip slices all four cases
+                assert got == [(k, 1, 1)], got
+        assert replies[0] == replies[1] == replies[2]
+        assert all(r[0] == "ok" for r in replies[0]), replies[0]
+        mesh, grid, one = arms
+        assert mesh.launches_sliced == int(slices)
+        # every touched ensemble's rows: the three arms agree
+        for name, a, b, c in zip(mesh.state._fields, _state_rows(mesh, cols),
+                                 _state_rows(grid, cols),
+                                 _state_rows(one, cols)):
+            np.testing.assert_array_equal(a, b, err_msg=f"state.{name}")
+            np.testing.assert_array_equal(a, c, err_msg=f"state.{name}")
+        # ...and no other row of the sliced arm moved
+        idle = np.setdiff1d(np.arange(E_SL), cols)
+        for name, was, now in zip(mesh.state._fields, before,
+                                  _state_rows(mesh)):
+            np.testing.assert_array_equal(was[idle], now[idle],
+                                          err_msg=f"state.{name}")
+        for svc in (grid, one):
+            np.testing.assert_array_equal(mesh.leader_np, svc.leader_np)
+            np.testing.assert_array_equal(mesh._slot_vsn_np,
+                                          svc._slot_vsn_np)
+    finally:
+        for svc in arms:
+            svc.stop()
+
+
+def test_election_only_launch_on_a_mesh_that_slices():
+    """K = 0 carries no op planes to compact: the mesh steps the full
+    grid, as one chip does, and the re-elected row then serves through
+    a sliced launch."""
+    col = 2 * E_LOC + 5
+    arms = _sliced_arms()
+    try:
+        out = []
+        for svc in arms:
+            _drive(svc, [svc.kput(col, "k", b"v")])
+            svc.set_peer_up(col, int(svc.leader_np[col]), False)
+            n0 = len(svc.lat_records)
+            svc.flush()
+            assert _launches(svc, n0) == [(0, 2, 0)]  # slab + ``up``
+            assert svc.leader_np[col] >= 0
+            n0 = len(svc.lat_records)
+            out.append(_drive(svc, [svc.kput(col, "k", b"v2"),
+                                    svc.kget_vsn(col, "k")]))
+            assert [x[2] for x in _launches(svc, n0)] == [
+                int(svc is not arms[1])] * len(_launches(svc, n0))
+        assert out[0] == out[1] == out[2]
+        for a, b, c in zip(*(_state_rows(svc, [col]) for svc in arms)):
+            np.testing.assert_array_equal(a, b)
+            np.testing.assert_array_equal(a, c)
+    finally:
+        for svc in arms:
+            svc.stop()
+
+
+def test_warmup_covers_the_sliced_mesh_programs():
+    """``warmup`` tests the launch's own rule per shard: a sliced flush
+    of a warmed (K, a_loc) bucket compiles nothing."""
+    svc = BatchedEnsembleService(WallRuntime(), E_SL + N_SH * 8, 3, 8,
+                                 tick=None, max_ops_per_tick=2,
+                                 engine=mesh_engine(N_SH))
+    try:
+        svc.warmup(buckets=[(1, 8), (1, None), (2, 8), (2, None)])
+        assert "step_sliced" in [e["fn"] for e in svc._compile_log]
+        serve0 = svc._c_compile.labels("serve").value
+        _drive(svc, [svc.kput(0, "warm", b"w")])
+        for i in range(2):
+            _drive(svc, [svc.kput(c, f"k{i}", b"v")
+                         for c in (2, 300, 301, E_SL)])
+        assert svc.launches_sliced >= 2
+        leaked = [e for e in svc._compile_log if e["phase"] == "serve"]
+        assert svc._c_compile.labels("serve").value == serve0, leaked
+    finally:
+        svc.stop()

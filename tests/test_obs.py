@@ -632,8 +632,8 @@ def test_served_flush_total_is_the_sum_of_the_same_marks(
         assert r["obs"] > 0.0 and r["pack"] >= 0.0
         # every span has a start stamp, the record a wall-clock anchor
         timed = {c for c in r
-                 if c not in ("k", "uploads", "total", "enqueue",
-                              "starts", "clock")
+                 if c not in ("k", "uploads", "sliced", "total",
+                              "enqueue", "starts", "clock")
                  and not c.startswith(("enqueue_", ))}
         assert timed <= set(r["starts"]), timed - set(r["starts"])
         assert len(r["clock"]) == 2

@@ -8,9 +8,11 @@ program.  Pinned here:
 - the slab programs are BIT-identical to the per-plane programs on the
   same operands (full width, sliced, the 'ens'-sharded mesh step; with
   and without elections; K 1 and 4);
-- a served flush records its transfers (``uploads``): 1 on a sliced
-  launch, at most 2 on a pack-gather or mesh launch, and runs no eager
-  device op inside the ``h2d`` span;
+- a served flush records its transfers (``uploads``) and whether its
+  step sliced (``sliced``): 1 upload on a sliced launch, one chip's or
+  a mesh's, at most 2 on a pack-gather launch (a mesh whose shards
+  hold too few rows to slice among them), and no eager device op
+  inside the ``h2d`` span;
 - every way into the step (keyed ops of each kind, an election-only
   launch, ``execute()`` from host or ``jax.Array`` planes, a replica's
   apply) is one such launch;
@@ -32,7 +34,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from riak_ensemble_tpu.ops import engine as eng  # noqa: E402
 from riak_ensemble_tpu.parallel.batched_host import (  # noqa: E402
-    BatchedEnsembleService, WallRuntime, _LocalEngine)
+    SLICE_MIN_E, BatchedEnsembleService, WallRuntime, _LocalEngine)
 from riak_ensemble_tpu.parallel.mesh import mesh_engine  # noqa: E402
 
 E, M, S, A = 64, 3, 8, 8
@@ -134,6 +136,66 @@ def test_slab_program_matches_per_plane_program(form, elections, k):
     _assert_same(got, want)
 
 
+@pytest.mark.parametrize("k", [1, 4])
+@pytest.mark.parametrize("elections", [False, True],
+                         ids=["no-elect", "elect"])
+def test_mesh_sliced_slab_program_matches_one_chips(elections, k):
+    """The mesh's sliced program on per-shard blocks (each shard its
+    own LOCAL index row, one shard's block all pad) against one chip's
+    sliced program on the same columns: the same state, and the same
+    ``won`` and result columns, found at each column's place in its
+    shard's block."""
+    from riak_ensemble_tpu.parallel.mesh import shard_active_columns
+    n_sh, e_loc = 4, E // 4
+    engine = mesh_engine(n_sh)
+    elect, cand, lease, planes = _operands(k, elections, seed=33 + k)
+    active = np.array([2, 5, 11, 40, 63], np.int32)   # shard 1 empty
+    elect[[e for e in range(E) if e not in active]] = False
+    if elections:       # two that are won, whatever the seed drew
+        elect[[2, 63]], cand[[2, 63]] = True, 0
+    up_np = np.ones((E, M), bool)
+    up_np[::5, 1] = False
+    up = jnp.asarray(up_np)
+
+    aidx = np.full((A,), E, np.int32)
+    aidx[:active.size] = active
+    want = eng.full_step_sliced_slab(
+        _led_state(_LocalEngine()),
+        jnp.asarray(eng.pack_op_slab(A, k, elect, cand, lease, planes,
+                                     active, aidx)), up)
+
+    per_shard, a_loc = shard_active_columns(active, E, n_sh, 2)
+    assert a_loc == 4 and [p.tolist() for p in per_shard] == [
+        [2, 5, 11], [], [8], [15]]
+    rows = np.full((n_sh, a_loc), e_loc, np.int32)
+    for s, p in enumerate(per_shard):
+        rows[s, :p.size] = p
+    at = np.flatnonzero(rows.ravel() < e_loc)
+    slab = eng.pack_op_slab(n_sh * a_loc, k, elect, cand, lease, planes,
+                            active, rows.ravel(), at)
+    assert slab.shape == (4 + 5 * k, n_sh * a_loc)
+    np.testing.assert_array_equal(
+        slab[eng.SLAB_ACTIVE_IDX].reshape(n_sh, a_loc), rows)
+    got = engine.full_step_sliced_slab(
+        _led_state(engine), jax.device_put(slab, engine.slab_sharding),
+        jax.device_put(up_np, engine.up_sharding))
+
+    assert np.asarray(want[2].committed).any(), "nothing committed"
+    if elections:
+        assert np.asarray(want[1]).any(), "no election won"
+    _assert_same(got[0], want[0])
+    n = active.size
+    pad = np.setdiff1d(np.arange(n_sh * a_loc), at)
+    np.testing.assert_array_equal(np.asarray(got[1])[at],
+                                  np.asarray(want[1])[:n])
+    for name, g, w in zip(want[2]._fields, got[2], want[2]):
+        g, w = np.asarray(g), np.asarray(w)
+        assert g.shape[1] == n_sh * a_loc, (name, g.shape)
+        np.testing.assert_array_equal(g[:, at], w[:, :n], err_msg=name)
+        if name in ("committed", "get_ok", "found"):
+            assert not g[:, pad].any(), name
+
+
 @pytest.mark.parametrize("sliced", [False, True], ids=["full", "sliced"])
 def test_split_returns_what_pack_was_given(sliced):
     """Layout round trip, absent CAS planes included (None = zeros)."""
@@ -193,7 +255,10 @@ def _service(shape, **kw):
     if shape == "pack_gather":  # under SLICE_MIN_E
         return BatchedEnsembleService(WallRuntime(), E, M, S,
                                       tick=None, **kw)
-    return BatchedEnsembleService(WallRuntime(), E, M, S, tick=None,
+    # a mesh slices where a SHARD holds SLICE_MIN_E rows; at E = 64 a
+    # shard holds 16, and the full grid and the pack-gather stay
+    n_ens = 4 * SLICE_MIN_E if shape == "mesh_sliced" else E
+    return BatchedEnsembleService(WallRuntime(), n_ens, M, S, tick=None,
                                   engine=mesh_engine(4), **kw)
 
 
@@ -275,6 +340,8 @@ CASES = {
     "sliced": ("sliced", _drive_kput, 1),
     "pack_gather": ("pack_gather", _drive_kput, 2),
     "mesh": ("mesh", _drive_kput, 2),
+    "mesh_sliced": ("mesh_sliced", _drive_kput, 1),
+    "mesh_sliced_kupdate": ("mesh_sliced", _drive_kupdate, 1),
     "kupdate": ("sliced", _drive_kupdate, 1),
     "kmodify": ("sliced", _drive_kmodify, 1),
     "kdelete": ("pack_gather", _drive_kdelete, 2),
@@ -290,10 +357,14 @@ STEP_PROGRAMS = {"step", "step_sliced"}
 
 def _assert_slab_launches(svc, recs, shape, most):
     assert recs
+    slices = shape in ("sliced", "mesh_sliced")
     for r in recs:
         assert 1 <= r["uploads"] <= most, r
-        if shape == "sliced" and r["k"]:
+        assert r["sliced"] == int(slices and r["k"] > 0), r
+        if r["sliced"]:
             assert r["uploads"] == 1, r
+    assert svc.stats()["launches_sliced"] >= sum(
+        r["sliced"] for r in recs)
     assert {e["fn"] for e in svc._compile_log
             if e["fn"].startswith("step")} <= STEP_PROGRAMS, \
         list(svc._compile_log)
