@@ -91,7 +91,8 @@ DERIVED_MARKS = ("enqueue", "resolve_native", "resolve_fallback",
 #: derived marks, not additive latency components — shared with
 #: bench's tail attribution so the two dominant-mark argmaxes can
 #: never drift apart
-META_FIELDS = ("k", "uploads", "sliced", "total") + DERIVED_MARKS + (
+META_FIELDS = ("k", "uploads", "sliced", "arrival",
+               "total") + DERIVED_MARKS + (
     "flush_id", "t", "a_width", "payload_bytes", "queued_rounds",
     "in_flight",
     # obs.spans: {mark: first start, perf_counter} and the record's

@@ -113,8 +113,8 @@ from riak_ensemble_tpu.types import NOTFOUND
 #: dominant-mark argmax and these sums can never drift apart.
 #: ``starts`` and ``clock`` are the span primitive's stamps
 #: (obs.spans), not seconds.
-DERIVED_MARKS = frozenset(("k", "uploads", "sliced", "total", "starts",
-                           "clock")
+DERIVED_MARKS = frozenset(("k", "uploads", "sliced", "arrival", "total",
+                           "starts", "clock")
                           + obs.flightrec.DERIVED_MARKS)
 
 #: per-entry field extractor for the per-op SLO fold (C-level
@@ -731,10 +731,23 @@ class BatchedEnsembleService:
     """N engine-backed ensembles behind a put/get API.
 
     ``n_slots`` bounds live keys per ensemble (slots are recycled when
-    keys are deleted).  ``tick`` is the flush cadence: lower = lower
-    latency, higher = bigger batches; ``tick=None`` disables the timer
-    entirely — the caller drives ``flush()`` (bench / WallRuntime mode).
+    keys are deleted).  Work that has boarded starts a flush: an
+    enqueue arms a look at the queue for the runtime's next turn, and
+    the first turns that bring nothing new flush what arrived
+    (:meth:`_note_arrival`).  ``tick`` is the CEILING on that wait (a
+    stream that never goes quiet flushes this long after the last
+    flush ended) and the idle heartbeat (due retries, elections and
+    the pipeline's tail with nothing queued); ``tick=None`` arms
+    neither — the caller drives ``flush()`` (bench / WallRuntime mode).
     """
+
+    #: consecutive runtime turns without an enqueue that mean the
+    #: front end has gone quiet.  Two, not one: on an asyncio loop a
+    #: frame takes two turns from its socket to its enqueue (the
+    #: transport's read wakes the connection's task, which parses on
+    #: the turn after), so after ONE empty turn the rest of a burst
+    #: may be received and not yet parsed.
+    QUIET_TURNS = 2
 
     def __init__(self, runtime: Runtime, n_ens: int, n_peers: int,
                  n_slots: int = 128, tick: Optional[float] = 0.005,
@@ -952,7 +965,24 @@ class BatchedEnsembleService:
         self.scrub_every_flushes = scrub_every_flushes
         self._scrubbed_at_flush = 0
         self._timer: Optional[Timer] = None
-        self._kick_pending = False  # burst flush queued (see _maybe_kick)
+        #: what starts a flush (see _note_arrival): whether a look at
+        #: the queue is deferred, whether anything was enqueued since
+        #: the last look, quiet turns seen in a row, whether a queue
+        #: has reached a full launch's depth, whether the flush under
+        #: way was started by arrivals (its launches' records say so),
+        #: and whether a loop flush is under way at all
+        self._look_armed = False
+        self._arrived = False
+        self._quiet_turns = 0
+        self._queue_full = False
+        self._by_arrival = False
+        self._in_loop_flush = False
+        #: settled launches by what started them: the arrival trigger;
+        #: ``tick`` for every other launch that carried rounds (the
+        #: timer, a full queue, a caller's own flush() or execute());
+        #: ``idle`` for a launch of no rounds (an election alone).
+        #: Adds up to ``flushes``.
+        self.flush_triggers = {"arrival": 0, "tick": 0, "idle": 0}
         self._jnp = jnp
         #: active-column compaction (RETPU_COMPACT=0 opts out): a
         #: flush's packed d2h payload gathers down to the columns that
@@ -3206,7 +3236,7 @@ class BatchedEnsembleService:
 
     def _push(self, ens: int, op) -> None:
         """Enqueue one pending entry (timestamped for the queue-wait
-        latency component) and arm the burst trigger.  Write entries
+        latency component) and arm the flush trigger.  Write entries
         register in the per-slot pending-write index here — the ONE
         choke point every keyed write passes — and deregister when
         their entry resolves or fails; a slot with a nonzero count
@@ -3235,38 +3265,58 @@ class BatchedEnsembleService:
         self.queues[ens].append(op)
         self._queue_rounds[ens] += op.n
         self._active.add(ens)
-        self._maybe_kick(ens)
+        self._note_arrival(ens)
 
     def _queue_recycle(self, ens: int, item: Tuple[Any, int, int]
                        ) -> None:
         self._recycle_pending[ens].append(item)
         self._recycle_dirty.add(ens)
 
-    def _maybe_kick(self, ens: int) -> None:
-        """Burst trigger: a queue that just reached a full launch's
-        depth flushes NOW (deferred to the next runtime turn, never
-        reentrant inside an enqueue) instead of waiting out the tick —
-        batching is for amortization, not added latency.  Only in
-        timer-driven mode; caller-driven services control their own
-        flush points."""
-        if self.tick is None or self._kick_pending:
+    def _note_arrival(self, ens: int) -> None:
+        """What starts a flush on a timer-driven service: work that
+        has boarded, not the clock.  An enqueue with no look pending
+        defers ONE look at the queue to the runtime's next turn (never
+        reentrant inside an enqueue).  A look that finds new entries
+        since the last one looks again next turn; after
+        :attr:`QUIET_TURNS` turns in a row that brought nothing the
+        front end has gone quiet and everything parsed meanwhile
+        boards one flush (a lone op flushes at once, a burst whole,
+        and what arrived while the previous flush held the loop rides
+        the next one).  A queue at a full launch's depth (``max_k``)
+        flushes at the next look whatever else arrives: batching is
+        for amortization, not added latency.  The timer stays as the
+        ceiling (:meth:`_on_tick`).  Caller-driven services
+        (``tick=None``) control their own flush points."""
+        if self.tick is None:
             return
-        if self._queue_rounds[ens] < self.max_k:
-            return
-        self._kick_pending = True
+        if self._queue_rounds[ens] >= self.max_k:
+            self._queue_full = True
+        if self._look_armed:
+            self._arrived = True
+        else:
+            self._arm_look()
 
-        def kick() -> None:
-            self._kick_pending = False
-            self._loop_flush()
-            # One flush serves max_k per ensemble; a burst deeper
-            # than that (its later enqueues hit the _kick_pending
-            # guard) keeps draining — including its sub-threshold
-            # tail, which is part of the same burst, not a fresh
-            # trickle that should wait for the tick.
-            if self._active:
-                self._kick_pending = True
-                self.runtime.defer(kick)
-        self.runtime.defer(kick)
+    def _arm_look(self) -> None:
+        self._look_armed = True
+        self._arrived = False
+        self._quiet_turns = 0
+        self.runtime.defer(self._look)
+
+    def _look(self) -> None:
+        if not self._active:    # a flush took everything meanwhile
+            self._look_armed = self._queue_full = False
+            return
+        if not self._queue_full:
+            if self._arrived:
+                self._arrived = False
+                self._quiet_turns = 0
+            else:
+                self._quiet_turns += 1
+            if self._quiet_turns < self.QUIET_TURNS:
+                self.runtime.defer(self._look)
+                return
+        self._look_armed = False
+        self._loop_flush(arrival=not self._queue_full)
 
     def _schedule(self) -> None:
         if self.tick is None:
@@ -3278,25 +3328,47 @@ class BatchedEnsembleService:
         self._timer = self.runtime.schedule(self.tick, self._on_tick)
 
     def _on_tick(self) -> None:
-        try:
-            self._loop_flush()
-        finally:
-            self._schedule()
+        """The timer: ``tick`` after the last loop flush ended.  The
+        ceiling on what :meth:`_look` waits for (a stream that never
+        goes quiet flushes here) and, with nothing queued, the idle
+        flush: due retries, the pipeline's tail, elections, chained
+        CAS halves."""
+        self._loop_flush()
 
-    def _loop_flush(self) -> None:
-        """A flush driven by the service's own loop (the tick, a
-        burst's kick): what the loop thread does from its end to the
-        next one's start is the span ``between_flushes``, so every
-        instant of that thread lies inside a named span."""
-        if not self._obs:
-            self.flush()
+    def _loop_flush(self, arrival: bool = False) -> None:
+        """A flush driven by the service's own loop (a look, the
+        timer), after which the timer runs from this flush's end:
+        what the loop thread does from there to the next one's start
+        is the span ``between_flushes``, so every instant of that
+        thread lies inside a named span."""
+        if self._timer is None or self._in_loop_flush:
+            # stopped; or re-entered from inside a loop flush: the
+            # checkpoint writer of a WAL compaction (orbax) runs an
+            # event loop of its own that turns this loop's callbacks,
+            # and a flush started there would step, and donate, the
+            # state being written.  What is queued boards when the
+            # outer flush ends: it looks again.
             return
-        self.spans.between_end()
+        self._timer.cancel()
+        self._by_arrival = arrival
+        self._in_loop_flush = True
+        if self._obs:
+            self.spans.between_end()
         try:
             self.flush()
         finally:
+            self._by_arrival = self._in_loop_flush = False
             if self._timer is not None:  # not stopped meanwhile
-                self.spans.between_begin()
+                self._schedule()
+                if self._obs:
+                    self.spans.between_begin()
+                # deeper than one launch takes: the rest is the same
+                # work, not a trickle to wait out the tick
+                self._queue_full = any(
+                    self._queue_rounds[e] >= self.max_k
+                    for e in self._active)
+                if self._active and not self._look_armed:
+                    self._arm_look()
 
     def _election_inputs(self) -> Tuple[np.ndarray, np.ndarray]:
         """Elect wherever there is no leader or the leader is down;
@@ -3498,6 +3570,7 @@ class BatchedEnsembleService:
             uploads += 1
         rec["uploads"] = uploads
         rec["sliced"] = int(sliced)
+        rec["arrival"] = int(self._by_arrival)
         self.launches_sliced += sliced
         self.launches_unsliced += not sliced
         h2d.end()
@@ -3741,6 +3814,9 @@ class BatchedEnsembleService:
                 self._emit("svc_exchange", {"ensembles": int(run.sum())})
                 exchange.end()
             self.flushes += 1
+            self.flush_triggers[
+                "arrival" if rec.get("arrival")
+                else "tick" if fl.k else "idle"] += 1
         except BaseException:
             self._rollback_launch(fl.state_snapshot, fl.leader_snapshot,
                                   fl.lease_snapshot)
@@ -3922,6 +3998,7 @@ class BatchedEnsembleService:
                                if self._occ_launches else 1.0),
             "launches_sliced": self.launches_sliced,
             "launches_unsliced": self.launches_unsliced,
+            "flush_triggers": dict(self.flush_triggers),
             # WAL-compaction pauses (deferred off the hot path; the
             # svc_compaction latency mark carries the same numbers
             # into latency_breakdown())

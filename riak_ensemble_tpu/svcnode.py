@@ -874,7 +874,12 @@ def main(argv=None) -> int:
     ap.add_argument("--n-ens", type=int, default=1024)
     ap.add_argument("--n-peers", type=int, default=5)
     ap.add_argument("--n-slots", type=int, default=128)
-    ap.add_argument("--tick", type=float, default=0.005)
+    ap.add_argument("--tick", type=float, default=0.005,
+                    help="the longest a queued op waits for a flush "
+                         "when requests never stop arriving, and the "
+                         "idle heartbeat (elections, due retries); "
+                         "arrivals start a flush themselves as soon "
+                         "as the front end goes quiet")
     ap.add_argument("--fast", action="store_true",
                     help="fast_test_config timeouts")
     ap.add_argument("--dynamic", action="store_true", default=None,

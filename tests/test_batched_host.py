@@ -756,15 +756,19 @@ def test_burst_flush_does_not_wait_for_tick():
     runtime.run_for(0.01)  # far less than the 10s tick
     assert all(f.done and f.value[0] == "ok" for f in futs), \
         "burst did not trigger an early flush"
-    # a burst DEEPER than max_k drains fully too (chained kicks)
+    # a burst DEEPER than max_k drains fully too (a look re-armed
+    # after each flush that left work queued)
     deep = [svc.kput(0, f"d{i}", b"v") for i in range(11)]
     runtime.run_for(0.01)
     assert all(f.done and f.value[0] == "ok" for f in deep), \
         "multi-launch burst left a residue waiting for the tick"
-    # below the threshold: ops wait for the (huge) tick — still queued
+    # below the threshold an op does not wait out the (huge) tick
+    # either: its arrival starts the flush (tests/test_flush_trigger.py)
     f = svc.kput(1, "x", b"v")
+    assert not f.done, "flushed inside the enqueue"
     runtime.run_for(0.01)
-    assert not f.done
+    assert f.done and f.value[0] == "ok"
+    assert svc.lat_records[-1]["arrival"] == 1
 
 
 def test_service_leader_watchers():
