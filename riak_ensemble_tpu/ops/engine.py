@@ -1349,17 +1349,76 @@ full_step_donate = jax.jit(_full_step_body,
 # Active-column SLICED full step (the shrunk [K, A] launch grid)
 
 
+# The sliced step's two edges address the A rows of a plane IN THE
+# LAYOUT THE PLANE IS STORED IN, so a launch reads and writes [., A]
+# and never [., E].  The chip stores an ``[E, M, S]`` int32 plane M
+# OUTERMOST (layout ``{2,0,1}``, tiles over (E, S): the M of 5 is not
+# padded to the tile's 8), while a gather or scatter along axis 0
+# wants E outermost: written as ``jnp.take(x, idx, axis=0)`` /
+# ``x.at[idx].set(...)`` the compiler relayouts the WHOLE plane both
+# ways on every launch (25.6 MB each at 10,000 x 5 x 128: three
+# quarters of the device's busy time, PERF.md section 6, PR 40).
+# Viewed as ``[M * E, S]`` rows (:func:`_peer_rows`) the stored plane
+# is a bitcast, the gather a row gather of A * M rows, and the scatter
+# one in-place fusion on the donated buffer.  Where the stored layout
+# is another (small S, a CPU) the view costs what the compiler makes
+# of it and the values are the same.
+
+#: the state planes a sliced launch addresses as rows
+_ROW_VIEWED = ("obj_epoch", "obj_seq", "obj_val")
+
+
+def _peer_rows(x: jax.Array) -> jax.Array:
+    """``[E, M, S]`` viewed as ``[M * E, S]``: row ``m * E + e``."""
+    e, m, s = x.shape
+    return jnp.transpose(x, (1, 0, 2)).reshape(m * e, s)
+
+
+def _take_peer_rows(x: jax.Array, idx_c: jax.Array) -> jax.Array:
+    """``jnp.take(x, idx_c, axis=0)`` of an ``[E, M, S]`` plane, read
+    as A * M rows of :func:`_peer_rows`."""
+    e, m, _ = x.shape
+    rows = jnp.arange(m, dtype=idx_c.dtype)[:, None] * e + idx_c[None, :]
+    return jnp.transpose(jnp.take(_peer_rows(x), rows, axis=0), (1, 0, 2))
+
+
+def _set_peer_rows(x: jax.Array, sub: jax.Array,
+                   active_idx: jax.Array) -> jax.Array:
+    """``x.at[active_idx].set(sub, mode="drop")`` of an ``[E, M, S]``
+    plane, written as A * M rows of :func:`_peer_rows`.  Real indices
+    are distinct and every pad gets an out-of-range row of its own, so
+    the rows are unique as the scatter is promised."""
+    e, m, s = x.shape
+    a = active_idx.shape[0]
+    at = jnp.arange(m, dtype=active_idx.dtype)[:, None]
+    rows = jnp.where((active_idx < e)[None, :],
+                     at * e + active_idx[None, :],
+                     m * e + at * a + jnp.arange(a, dtype=active_idx.dtype))
+    out = _peer_rows(x).at[rows.reshape(m * a)].set(
+        jnp.transpose(sub, (1, 0, 2)).reshape(m * a, s),
+        mode="drop", unique_indices=True)
+    return jnp.transpose(out.reshape(m, e, s), (1, 0, 2))
+
+
 def _slice_columns(state: EngineState, active_idx: jax.Array,
                    up: jax.Array) -> Tuple[EngineState, jax.Array]:
     """Gather the A active ensembles' rows out of every state plane
     (and the up mask): ``[E, ...] → [A, ...]``.  Padding entries
     (index E, out of range) clip to row E-1 — harmless, their op
     lanes are NOOP/elect-False so they never write, and the scatter
-    drops them."""
+    drops them.  The three object planes are read as rows of their
+    stored layout (above): no whole plane moves.  ``tree_leaf`` is
+    stored E outermost and gathers where it lies; ``tree_node`` is
+    stored E MINOR-most, its gather is a lane gather, and the chip
+    still relayouts it (7.2 MB) on the way in and out, as it does the
+    small ``[E]`` / ``[E, M]`` planes."""
     e = state.epoch.shape[0]
     with jax.named_scope("slice_columns"):
         idx_c = jnp.clip(active_idx, 0, e - 1)
-        sub = jax.tree.map(lambda x: jnp.take(x, idx_c, axis=0), state)
+        sub = EngineState(*(
+            _take_peer_rows(x, idx_c) if f in _ROW_VIEWED
+            else jnp.take(x, idx_c, axis=0)
+            for f, x in zip(state._fields, state)))
         return sub, jnp.take(up, idx_c, axis=0)
 
 
@@ -1368,12 +1427,16 @@ def _scatter_columns(state: EngineState, sub: EngineState,
     """Scatter the stepped sub-state back into the full planes.
     Padding entries aim out of bounds (index E) and are DROPPED;
     real indices are distinct, so the scatter is conflict-free.
-    With the full state donated, this lowers to an in-place update
-    of the A touched rows instead of a full-plane copy."""
+    With the full state donated, the object planes' and
+    ``tree_leaf``'s scatters update the A touched rows of the
+    parameter's own buffer (``tests/test_chip_compile.py`` reads that
+    off the program compiled for the chip); ``tree_node`` and the
+    small planes are scattered on a relayouted copy."""
     with jax.named_scope("scatter_columns"):
-        return jax.tree.map(
-            lambda full, s: full.at[active_idx].set(s, mode="drop"),
-            state, sub)
+        return EngineState(*(
+            _set_peer_rows(full, s, active_idx) if f in _ROW_VIEWED
+            else full.at[active_idx].set(s, mode="drop")
+            for f, full, s in zip(state._fields, state, sub)))
 
 
 def _full_step_sliced_body(state: EngineState, active_idx: jax.Array,
@@ -1530,8 +1593,10 @@ def _full_step_sliced_slab_body(state: EngineState, slab: jax.Array,
 
 #: the served step programs: ``(state, slab, up)``, plain and donated
 #: (see :data:`full_step_donate` for the aliasing contract; the sliced
-#: step's scatter back into the donated full planes is an in-place
-#: A-row update)
+#: step's scatter back into the donated object planes and ``tree_leaf``
+#: is an in-place A-row update on the chip, ``tree_node`` and the small
+#: planes pass through a relayouted copy: "The sliced step's two
+#: edges" above)
 full_step_slab = jax.jit(_full_step_slab_body,
                          static_argnames=("axis_name",))
 full_step_slab_donate = jax.jit(_full_step_slab_body,

@@ -12,6 +12,7 @@ library, and under pytest-xdist only the worker that is handed this
 file may do it (never at import, never in a skipif/parametrize).
 """
 
+import functools
 import os
 import re
 import time
@@ -117,19 +118,97 @@ def test_pallas_kernel_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@functools.cache
+def _sliced_step(one_chip, k, a):
+    """The donated sliced step of the 10,000 x 5 x 128 state at one
+    (K, A) bucket, compiled once for the tests that read it."""
+    state = _placed(jax.eval_shape(lambda: eng.init_state(E, M, S)),
+                    one_chip)
+    place = _on(one_chip)
+    return eng.full_step_sliced_slab_donate.lower(
+        state, place("slab", (4 + 5 * k, a), jnp.int32),
+        place("up", (E, M), jnp.bool_)).compile()
+
+
 def test_sliced_donated_step_compiles_at_headline_shape(one_chip):
     """The program most flushes of `svcnode --n-ens 10000` launch:
     A=256 active columns of the 10,000 x 5 x 128 state, K=16."""
-    state = _placed(jax.eval_shape(lambda: eng.init_state(E, M, S)),
-                    one_chip)
-
-    place = _on(one_chip)
-    compiled = eng.full_step_sliced_slab_donate.lower(
-        state, place("slab", (4 + 5 * 16, 256), jnp.int32),
-        place("up", (E, M), jnp.bool_)).compile()
-    mem = compiled.memory_analysis()
+    mem = _sliced_step(one_chip, 16, 256).memory_analysis()
     # the state alone is ~0.2 GB; the program must fit the 16 GB chip
     assert 0 < mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+#: HLO ops that move a whole array: a relayout (`copy`), a move
+#: between memory spaces (`copy-start` / `copy-done`), a piecewise one
+_MOVES = re.compile(r" (copy|copy-start|copy-done|slice-start)\(")
+
+
+def _whole_plane_moves(text, planes):
+    """``{op: count}`` of the compiled program's moves whose result
+    holds one of ``planes`` (shape prefixes such as ``s32[10000,5,128]``,
+    whatever the layout)."""
+    found = {}
+    for line in text.splitlines():
+        op = _MOVES.search(line)
+        # what stands before the op is the instruction's name and type
+        if op and any(p in line[:op.start()] for p in planes):
+            found[op.group(1)] = found.get(op.group(1), 0) + 1
+    return found
+
+
+def _scatter_sources(text, rows):
+    """What each scatter fusion of the entry computation that writes a
+    ``rows``-shaped plane (``s32[50000,128]``) takes as the plane it
+    updates, followed through bitcasts: the defining line."""
+    entry = text[text.index("\nENTRY "):]
+    defs = {}
+    for line in entry.splitlines():
+        name, eq, rest = line.strip().removeprefix("ROOT ").partition(" = ")
+        if eq:
+            defs[name] = rest
+    sources = []
+    for rest in defs.values():
+        if not (rest.startswith(rows) and " fusion(" in rest
+                and "scatter_columns" in rest):
+            continue
+        src = rest.split(" fusion(", 1)[1].split(",")[0].strip(" )")
+        while " bitcast(" in defs[src]:
+            src = defs[src].split(" bitcast(", 1)[1].split(")")[0]
+        sources.append(defs[src])
+    return sources
+
+
+def _assert_object_planes_stay_put(text, e, record_property, tag):
+    """The sliced launch reads and writes A rows of each object plane
+    where the plane lies (ISSUE 40): no whole-plane move, and each of
+    the three scatters updates the donated parameter itself."""
+    # a plane as the state holds it, and as `engine._peer_rows` views it
+    plane, rows = f"s32[{e},{M},{S}]", f"s32[{e * M},{S}]"
+    moves = _whole_plane_moves(text, (plane, rows))
+    tree_node = _whole_plane_moves(text, (f"u32[{e},{M},",))
+    record_property(f"{tag}_object_plane_moves", moves)
+    record_property(f"{tag}_tree_plane_moves", tree_node)
+    print(f"{tag} object-plane moves {moves} tree-plane moves {tree_node}")
+    assert not moves, moves
+    sources = _scatter_sources(text, rows)
+    assert len(sources) == 3, sources
+    assert all(" parameter(" in s for s in sources), sources
+
+
+@pytest.mark.parametrize("k,a", [(1, 8), (16, 256)], ids=["k1a8", "k16a256"])
+def test_sliced_step_moves_no_object_plane(one_chip, record_property, k, a):
+    """`ycsb-a.ring10k-n5`'s window flush (K 1, A 8) and the headline
+    bucket: the compiler stores an object plane M outermost
+    (`{2,0,1}`), and the edges address it as `[M * E, S]` rows, a
+    bitcast of that layout."""
+    compiled = _sliced_step(one_chip, k, a)
+    _assert_object_planes_stay_put(compiled.as_text(), E, record_property,
+                                   f"k{k}a{a}")
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    record_property(f"k{k}a{a}_temp_bytes", temp)
+    print(f"k{k}a{a} temp_bytes {temp}")
+    if (k, a) == (1, 8):        # 125.6 MB before ISSUE 40
+        assert temp < 16e6
 
 
 def test_step_with_pallas_quorum_lowers_the_kernel(one_chip, monkeypatch):
@@ -223,3 +302,27 @@ def test_sliced_mesh_step_compiles_on_four_chips_without_ens_collectives(
     full = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(state))
     assert mem.argument_size_in_bytes < full / 4 * 1.1
     assert 0 < mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_sliced_mesh_step_moves_no_object_plane(topo, record_property):
+    """The mesh cell's window flush (K 1, `a_loc` 8): each chip's
+    program is one chip's, so each chip's 10,240 x 5 x 128 object
+    planes stay where they lie too."""
+    from riak_ensemble_tpu.parallel.mesh import mesh_engine
+
+    e, k, a_loc = 40_960, 1, 8
+    engine = mesh_engine(4, devices=topo.devices)
+    state = _placed(jax.eval_shape(lambda: eng.init_state(e, M, S)),
+                    eng.state_sharding(engine.mesh))
+    compiled = engine.full_step_sliced_slab_donate.lower(
+        state,
+        jax.ShapeDtypeStruct((4 + 5 * k, 4 * a_loc), jnp.int32,
+                             sharding=engine.slab_sharding),
+        jax.ShapeDtypeStruct((e, M), jnp.bool_,
+                             sharding=engine.up_sharding)).compile()
+    _assert_object_planes_stay_put(compiled.as_text(), e // 4,
+                                   record_property, "mesh_k1a8")
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    record_property("mesh_k1a8_temp_bytes", temp)
+    print(f"mesh_k1a8 temp_bytes {temp}")
+    assert temp < 16e6

@@ -136,6 +136,20 @@ def test_slab_program_matches_per_plane_program(form, elections, k):
     _assert_same(got, want)
 
 
+def _shard_blocks(active, n_sh):
+    """A sliced slab's index rows for ``active`` over ``n_sh`` shards
+    of E columns: ``(rows [n_sh, a_loc]`` of LOCAL indices, pad =
+    the rows a shard holds; ``at``, the slab column of each active
+    column; ``a_loc)``."""
+    from riak_ensemble_tpu.parallel.mesh import shard_active_columns
+    e_loc = E // n_sh
+    per_shard, a_loc = shard_active_columns(active, E, n_sh, 2)
+    rows = np.full((n_sh, a_loc), e_loc, np.int32)
+    for sh, p in enumerate(per_shard):
+        rows[sh, :p.size] = p
+    return rows, np.flatnonzero(rows.ravel() < e_loc), a_loc
+
+
 @pytest.mark.parametrize("k", [1, 4])
 @pytest.mark.parametrize("elections", [False, True],
                          ids=["no-elect", "elect"])
@@ -145,7 +159,6 @@ def test_mesh_sliced_slab_program_matches_one_chips(elections, k):
     sliced program on the same columns: the same state, and the same
     ``won`` and result columns, found at each column's place in its
     shard's block."""
-    from riak_ensemble_tpu.parallel.mesh import shard_active_columns
     n_sh, e_loc = 4, E // 4
     engine = mesh_engine(n_sh)
     elect, cand, lease, planes = _operands(k, elections, seed=33 + k)
@@ -164,13 +177,9 @@ def test_mesh_sliced_slab_program_matches_one_chips(elections, k):
         jnp.asarray(eng.pack_op_slab(A, k, elect, cand, lease, planes,
                                      active, aidx)), up)
 
-    per_shard, a_loc = shard_active_columns(active, E, n_sh, 2)
-    assert a_loc == 4 and [p.tolist() for p in per_shard] == [
+    rows, at, a_loc = _shard_blocks(active, n_sh)
+    assert a_loc == 4 and [r[r < e_loc].tolist() for r in rows] == [
         [2, 5, 11], [], [8], [15]]
-    rows = np.full((n_sh, a_loc), e_loc, np.int32)
-    for s, p in enumerate(per_shard):
-        rows[s, :p.size] = p
-    at = np.flatnonzero(rows.ravel() < e_loc)
     slab = eng.pack_op_slab(n_sh * a_loc, k, elect, cand, lease, planes,
                             active, rows.ravel(), at)
     assert slab.shape == (4 + 5 * k, n_sh * a_loc)
@@ -194,6 +203,102 @@ def test_mesh_sliced_slab_program_matches_one_chips(elections, k):
         np.testing.assert_array_equal(g[:, at], w[:, :n], err_msg=name)
         if name in ("committed", "get_ok", "found"):
             assert not g[:, pad].any(), name
+
+
+def _random_state(e, m, s, seed):
+    """Every plane of an ``[e, m, s]`` state filled with seeded noise
+    (no protocol meaning: the edges only move rows)."""
+    rng = np.random.default_rng(seed)
+
+    def noise(x):
+        if x.dtype == jnp.bool_:
+            return jnp.asarray(rng.random(x.shape) < 0.5)
+        return jnp.asarray(rng.integers(0, 1 << 30, x.shape)
+                           .astype(x.dtype))
+
+    return jax.tree.map(noise, jax.eval_shape(
+        lambda: eng.init_state(e, m, s)))
+
+
+@pytest.mark.parametrize("a", [8, 64])
+@pytest.mark.parametrize("shape", [(300, 5, 128), (64, 3, 16),
+                                   (512, 5, 256)], ids=str)
+def test_sliced_edges_match_take_and_set(shape, a):
+    """The sliced step's gather and scatter address the object planes
+    as rows of their ``[M * E, S]`` view (ISSUE 40): bit-equal to
+    ``jnp.take(x, idx, 0)`` / ``x.at[idx].set(s, mode="drop")`` on
+    every plane, padding indices (= E) clipped by the one and dropped
+    by the other."""
+    e, m, s = shape
+    rng = np.random.default_rng(40 + a)
+    real = rng.choice(e, a - 5, replace=False).astype(np.int32)
+    aidx = np.full((a,), e, np.int32)       # 5 pads, not all at the end
+    aidx[np.sort(rng.choice(a, a - 5, replace=False))] = real
+    aidx_j = jnp.asarray(aidx)
+    state = _random_state(e, m, s, seed=1)
+    up = jnp.asarray(rng.random((e, m)) < 0.8)
+
+    sub, up_a = jax.jit(eng._slice_columns)(state, aidx_j, up)
+    idx_c = jnp.clip(aidx_j, 0, e - 1)
+    _assert_same(sub, jax.tree.map(lambda x: jnp.take(x, idx_c, axis=0),
+                                   state))
+    np.testing.assert_array_equal(np.asarray(up_a),
+                                  np.asarray(up)[np.asarray(idx_c)])
+
+    stepped = _random_state(a, m, s, seed=2)
+    got = jax.jit(eng._scatter_columns)(state, stepped, aidx_j)
+    _assert_same(got, jax.tree.map(
+        lambda full, x: full.at[aidx_j].set(x, mode="drop"),
+        state, stepped))
+    # the pads wrote nothing, the real rows hold the stepped values
+    idle = np.setdiff1d(np.arange(e), real)
+    for g, x, new in zip(got, state, stepped):
+        np.testing.assert_array_equal(np.asarray(g)[idle],
+                                      np.asarray(x)[idle])
+        np.testing.assert_array_equal(np.asarray(g)[aidx[aidx < e]],
+                                      np.asarray(new)[aidx < e])
+
+
+@pytest.mark.parametrize("k", [1, 4])
+@pytest.mark.parametrize("form", ["one-chip", "mesh4"])
+def test_sliced_step_matches_full_width_step(form, k):
+    """The same operations through the sliced program and through the
+    full-width program: the same state, and the same ``won`` and
+    result columns at each active column's place (healthy ensembles:
+    an idle column's full-width NOOP round changes nothing)."""
+    n_sh = 4 if form == "mesh4" else 1
+    engine = mesh_engine(n_sh) if form == "mesh4" else _LocalEngine()
+    elect, cand, lease, planes = _operands(k, True, seed=40 + k)
+    active = np.array([2, 5, 11, 40, 63], np.int32)
+    idle = np.setdiff1d(np.arange(E), active)
+    elect[idle], lease[idle] = False, False
+    elect[[2, 63]], cand[[2, 63]] = True, 0
+    planes[0][:, idle] = eng.OP_NOOP
+    up = jnp.ones((E, M), bool)
+
+    def placed(slab):
+        return (jax.device_put(slab, engine.slab_sharding)
+                if form == "mesh4" else jnp.asarray(slab))
+
+    want = engine.full_step_slab(
+        _led_state(engine),
+        placed(eng.pack_op_slab(E, k, elect, cand, lease, planes)), up)
+
+    rows, at, a_loc = _shard_blocks(active, n_sh)
+    got = engine.full_step_sliced_slab(
+        _led_state(engine),
+        placed(eng.pack_op_slab(n_sh * a_loc, k, elect, cand, lease,
+                                planes, active, rows.ravel(), at)), up)
+
+    assert np.asarray(want[2].committed).any(), "nothing committed"
+    assert np.asarray(want[1]).any(), "no election won"
+    _assert_same(got[0], want[0])
+    np.testing.assert_array_equal(np.asarray(got[1])[at],
+                                  np.asarray(want[1])[active])
+    for name, g, w in zip(want[2]._fields, got[2], want[2]):
+        np.testing.assert_array_equal(np.asarray(g)[:, at],
+                                      np.asarray(w)[:, active],
+                                      err_msg=name)
 
 
 @pytest.mark.parametrize("sliced", [False, True], ids=["full", "sliced"])
