@@ -46,11 +46,11 @@ stack reports into:
   jax/jaxlib versions, ``RETPU_*`` knobs) every flight dump and every
   bench JSON embeds, so cross-round comparisons stop being faith.
 - :mod:`.opslo` — per-op SLO tracing (round 9): every keyed op's
-  submit→enqueue→flush-join→settle→ack stamps in bounded numpy slab
-  rings keyed by ``flush_id``, feeding client-perceived latency
-  histograms per op kind and per tenant; each flush's slowest rows
-  attach to the span store so ``timeline(fid)`` resolves a tail op
-  down to its stage split.
+  rx→submit→enqueue→flush-join→settle→ack stamps in bounded numpy
+  slab rings keyed by ``flush_id``, feeding the latency histograms
+  per op kind and per tenant (arrival at the server's loop to ack);
+  ``rows_of(fid)`` resolves a flush's tail op down to its stage split
+  beside ``timeline(fid)``.
 - :mod:`.compilewatch` — compile-event hooks around every jitted
   step/pack/scatter variant (executable-cache-size deltas, exact, not
   a latency heuristic): warmup coverage gaps surface as

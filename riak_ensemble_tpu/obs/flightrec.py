@@ -29,7 +29,7 @@ from typing import Any, Dict, List, Optional
 from riak_ensemble_tpu.obs.fingerprint import box_fingerprint
 
 __all__ = ["FlightRecorder", "DUMP_SCHEMA", "META_FIELDS",
-           "DERIVED_MARKS", "dump_keep"]
+           "DERIVED_MARKS", "SHAPE_FIELDS", "dump_keep"]
 
 
 def dump_keep(default: int = 64) -> int:
@@ -85,19 +85,31 @@ DERIVED_MARKS = ("enqueue", "resolve_native", "resolve_fallback",
                  "pack", "wal_encode", "wal_append", "wal_fsync",
                  "fe_decode", "fe_dispatch", "fe_reply",
                  "fe_reply_direct",
-                 "between_flushes", "gc", "obs")
+                 "between_flushes", "gc", "obs",
+                 # the enqueue half's inside: the slab's device_put
+                 # alone (inside h2d; on a mesh the per-shard
+                 # placement) and the two jit calls apart (inside
+                 # dispatch; the rest of it is the d2h copy's start)
+                 "h2d_put", "dispatch_step", "dispatch_pack")
+
+#: per-flush SHAPE fields, counts and not seconds: rounds, uploads,
+#: whether the step ran sliced, whether an arrival started the flush,
+#: and the launch's shape: the pow2 width it packed at (``a``; 0 on
+#: the full grid), its real columns, the busiest shard's, the shards
+SHAPE_FIELDS = ("k", "uploads", "sliced", "arrival",
+                "a", "cols", "cols_max", "shards")
 
 #: per-flush record fields that are shape/identity metadata or
 #: derived marks, not additive latency components — shared with
 #: bench's tail attribution so the two dominant-mark argmaxes can
 #: never drift apart
-META_FIELDS = ("k", "uploads", "sliced", "arrival",
-               "total") + DERIVED_MARKS + (
+META_FIELDS = SHAPE_FIELDS + ("total",) + DERIVED_MARKS + (
     "flush_id", "t", "a_width", "payload_bytes", "queued_rounds",
     "in_flight",
-    # obs.spans: {mark: first start, perf_counter} and the record's
-    # (perf_counter, time.time()) anchor
-    "starts", "clock")
+    # obs.spans: {mark: first start, perf_counter}, the record's
+    # (perf_counter, time.time()) anchor and the rows of the requests
+    # the flush answered
+    "starts", "clock", "reqs")
 
 
 class FlightRecorder:

@@ -48,6 +48,20 @@ collects what the loop thread did between flushes (front-end decode
 and dispatch, ``between_flushes``, pauses of the collector) until
 :meth:`SpanRecorder.close` folds it into the record of the flush that
 settles next.
+
+A request's life
+----------------
+
+The front end (``svcnode.ServiceServer``) stamps every frame where it
+has it whole (``t_rx``) and asks :class:`PollWatch` how long the loop
+had not looked at its sockets by then (``rx_hold``): the wait a request
+spends in its socket while a flush holds the loop, which no span of
+the service can see.  In the loop cycles the recorder samples
+(:attr:`SpanRecorder.detail`) each request leaves one row ``[op,
+direct, t_rx, rx_hold_s, residence_s]`` under ``reqs`` in the record
+that is open when its reply is written: the settling flush's, or the
+loop's, which :meth:`SpanRecorder.close` folds into the next flush's
+(lists extend where floats add).
 """
 
 from __future__ import annotations
@@ -61,7 +75,7 @@ from collections import OrderedDict, deque
 from typing import Any, Dict, List, Optional, Tuple
 
 __all__ = ["next_flush_id", "SpanStore", "SPANS", "timeline",
-           "SpanRecorder", "Span", "NULL_SPAN", "GcWatch"]
+           "SpanRecorder", "Span", "NULL_SPAN", "GcWatch", "PollWatch"]
 
 _now = time.perf_counter
 
@@ -187,8 +201,12 @@ class SpanRecorder:
         starts = rec["starts"]
         for name, t0 in loop.pop("starts").items():
             starts.setdefault(name, t0)
-        for name, dt in loop.items():
-            rec[name] = rec.get(name, 0.0) + dt
+        for name, v in loop.items():
+            # seconds add, rows (``reqs``) extend
+            if name in rec:
+                rec[name] += v
+            else:
+                rec[name] = v
         loop.clear()
         loop["starts"] = {}
 
@@ -271,6 +289,72 @@ class GcWatch:
             "longest_ms": self.longest_ms,
             "recent": [list(p) for p in self.recent],
         }
+
+
+class PollWatch:
+    """When a selector event loop last looked at its sockets.
+
+    One per loop, shared by the servers on it (:meth:`of` /
+    :meth:`release`): the selector's ``select`` is wrapped to stamp
+    each poll's entry and return.  A ``StreamReader`` wakes its reader
+    one turn after ``data_received``, so when a frame is read in turn
+    N+1 its bytes were delivered by poll N, and the request reached
+    the socket somewhere after poll N-1 returned.  :attr:`base` is
+    that instant, moved forward by the seconds the loop has since
+    spent INSIDE ``select`` (looking), so ``t_rx - base`` is how long
+    the loop had not looked when the frame was read: a hard upper
+    bound on the frame's wait in its socket and its reader's buffer,
+    near zero on an idle loop and a flush and a turn long under
+    load."""
+
+    __slots__ = ("base", "_sel", "_inner", "_users", "_looked", "_ring")
+
+    def __init__(self, selector) -> None:
+        self._sel = selector
+        self._inner = selector.select
+        self._users = 0
+        self._looked = 0.0
+        now = _now()
+        #: (return stamp, seconds looked by then) of the last two polls
+        self._ring = ((now, 0.0),) * 2
+        self.base = now
+        selector.select = self._select
+
+    def _select(self, timeout=None):
+        t0 = _now()
+        events = self._inner(timeout)
+        t1 = _now()
+        looked = self._looked = self._looked + (t1 - t0)
+        # this is poll N+1 of the docstring: the older of the two
+        # polls before it is poll N-1
+        before, last = self._ring
+        self._ring = (last, (t1, looked))
+        self.base = before[0] + (looked - before[1])
+        return events
+
+    @classmethod
+    def of(cls, loop) -> Optional["PollWatch"]:
+        """The loop's watch, made at its first use; None where the
+        loop has no selector to stamp (then ``rx_hold`` has no
+        source and is not reported)."""
+        sel = getattr(loop, "_selector", None)
+        if sel is None or not hasattr(sel, "select"):
+            return None
+        watch = getattr(sel.select, "__self__", None)
+        if not isinstance(watch, cls):
+            try:
+                watch = cls(sel)
+            except AttributeError:  # a selector that takes no attribute
+                return None
+        watch._users += 1
+        return watch
+
+    def release(self) -> None:
+        self._users -= 1
+        if self._users <= 0 and getattr(
+                self._sel.select, "__self__", None) is self:
+            del self._sel.select    # the class's own again
+
 
 #: process-wide monotonic flush ids — shared by every service in the
 #: process so leader and in-process replica launches never collide

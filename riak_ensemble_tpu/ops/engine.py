@@ -1607,34 +1607,3 @@ full_step_sliced_slab = jax.jit(_full_step_sliced_slab_body,
 full_step_sliced_slab_donate = jax.jit(_full_step_sliced_slab_body,
                                        static_argnames=("axis_name",),
                                        donate_argnums=(0,))
-
-
-# ---------------------------------------------------------------------------
-# Compile/cost introspection (observability plane, ARCHITECTURE §11)
-
-
-def lowered_cost_analysis(fn, *args, **kwargs):
-    """XLA cost analysis of ``fn`` lowered at these argument shapes
-    — WITHOUT a backend compile (``Lowered.cost_analysis`` runs the
-    HLO cost model on the lowering, a few ms even for the full step).
-    Returns ``{"flops": f, "bytes_accessed": b}`` with whatever keys
-    the backend reports, or None for a program that cannot be lowered
-    from here (mesh placements: the sharded step is a plain method,
-    not a jitted callable).
-
-    Used by ``BatchedEnsembleService.warmup`` to record per-(K, A)-
-    bucket cost gauges next to the compile-event log, so a bucket's
-    device cost and its compile cost live on the same surface.
-    """
-    lower = getattr(fn, "lower", None)
-    if lower is None:
-        return None
-    ca = lower(*args, **kwargs).cost_analysis()
-    if not ca:  # a backend without an HLO cost model answers None
-        return None
-    out = {}
-    if "flops" in ca:
-        out["flops"] = float(ca["flops"])
-    if "bytes accessed" in ca:
-        out["bytes_accessed"] = float(ca["bytes accessed"])
-    return out or None

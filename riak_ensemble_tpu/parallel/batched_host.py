@@ -112,14 +112,17 @@ from riak_ensemble_tpu.types import NOTFOUND
 #: Single-sourced from flightrec so the flight recorder's
 #: dominant-mark argmax and these sums can never drift apart.
 #: ``starts`` and ``clock`` are the span primitive's stamps
-#: (obs.spans), not seconds.
-DERIVED_MARKS = frozenset(("k", "uploads", "sliced", "arrival", "total",
-                           "starts", "clock")
+#: (obs.spans), not seconds; ``a``, ``cols``, ``cols_max`` and
+#: ``shards`` are the launch's shape and ``reqs`` the rows of the
+#: requests the flush answered.
+DERIVED_MARKS = frozenset(obs.flightrec.SHAPE_FIELDS
+                          + ("total", "starts", "clock", "reqs")
                           + obs.flightrec.DERIVED_MARKS)
 
 #: per-entry field extractor for the per-op SLO fold (C-level
 #: attrgetter: one call per taken entry beats a Python loop body)
-_OP_SLO_FIELDS = operator.attrgetter("kind", "n", "t_sub", "t_enq")
+_OP_SLO_FIELDS = operator.attrgetter("kind", "n", "t_rx", "t_sub",
+                                     "t_enq")
 
 
 
@@ -548,6 +551,9 @@ class _PendingOp:
     #: per-op SLO ring (obs.opslo): API-entry submit timestamp
     #: (0 = use t_enq; the settle-time record_flush reads both)
     t_sub: float = 0.0
+    #: where the front end had the op's frame whole (``svc.t_rx`` at
+    #: the push; 0 = an in-process caller, which has no such stamp)
+    t_rx: float = 0.0
 
 
 @dataclass(slots=True)
@@ -586,6 +592,9 @@ class _PendingBatch:
     #: per-op SLO ring (obs.opslo): API-entry submit timestamp
     #: (0 = use t_enq; the settle-time record_flush reads both)
     t_sub: float = 0.0
+    #: where the front end had the op's frame whole (``svc.t_rx`` at
+    #: the push; 0 = an in-process caller, which has no such stamp)
+    t_rx: float = 0.0
 
     def split(self, head_n: int) -> Tuple["_PendingBatch", "_PendingBatch"]:
         """Split into (head, tail) when a flush's K cap lands inside
@@ -603,7 +612,7 @@ class _PendingBatch:
                           cut(self.exp_e, 0, head_n),
                           cut(self.exp_s, 0, head_n), self.accum,
                           self.want_vsn, self.t_enq, head_n,
-                          self.t_sub)
+                          self.t_sub, self.t_rx)
         t = _PendingBatch(self.kind, self.slot[head_n:],
                           self.handle[head_n:], self.fut,
                           self.pos[head_n:], cut(self.keys, head_n, None),
@@ -611,7 +620,7 @@ class _PendingBatch:
                           cut(self.exp_e, head_n, None),
                           cut(self.exp_s, head_n, None), self.accum,
                           self.want_vsn, self.t_enq, self.n - head_n,
-                          self.t_sub)
+                          self.t_sub, self.t_rx)
         return h, t
 
 
@@ -1005,6 +1014,14 @@ class BatchedEnsembleService:
         #: carries its own ``sliced`` 0/1 beside ``uploads``)
         self.launches_sliced = 0
         self.launches_unsliced = 0
+        #: the unsliced launches that pack-gathered (the rest stepped
+        #: and packed the full grid), and over every launch that
+        #: packed at a width (``a`` > 0) the sums of the busiest
+        #: shard's share of its columns and of the share of its
+        #: blocks that was padding (``stats()["mesh"]``)
+        self.launches_gathered = 0
+        self._busiest_sum = 0.0
+        self._pad_sum = 0.0
         #: RMW observability: host-path kmodify CAS attempts that
         #: failed and were retried (write races, plus transient
         #: quorum failures — indistinguishable client-side), and ops
@@ -1134,6 +1151,16 @@ class BatchedEnsembleService:
         #: wire, counted where it encodes and decodes
         self.frontend = {"frames_in": 0, "bytes_in": 0,
                          "frames_out": 0, "bytes_out": 0}
+        #: a request's life (obs.spans, "A request's life"): the stamp
+        #: of the frame the front end is dispatching right now (0.0
+        #: outside that call: an in-process caller has none), which
+        #: :meth:`_push` copies onto the entry, and, once a server on
+        #: a selector loop fronts this service, how long the loop had
+        #: not looked at its sockets when each of the last
+        #: frames was read (seconds; the server's array, a ring
+        #: indexed by ``frontend["frames_in"]``)
+        self.t_rx = 0.0
+        self.rx_holds: Optional[np.ndarray] = None
         #: native single-pass resolve kernel (RETPU_NATIVE_RESOLVE=0
         #: or a missing toolchain pins the pure-Python fallback — the
         #: oracle arm; docs/ARCHITECTURE.md §12).  Resolved at
@@ -1184,10 +1211,13 @@ class BatchedEnsembleService:
             "retpu_flush_total_ms",
             "settled launch wall time (all marks summed)")
         #: per-op SLO plane (obs.opslo, docs/ARCHITECTURE.md §11):
-        #: bounded stamp ring + the client-perceived latency
-        #: histogram it feeds (labeled by op kind) — the surface that
-        #: answers "what p99 does a kput caller actually see, and was
-        #: that tail queue wait, a compile, or the device".
+        #: bounded stamp ring + the latency histogram it feeds
+        #: (labeled by op kind; a wire op from its arrival at the
+        #: server's loop, ``t_rx``, an in-process one from its API
+        #: call) — the surface that answers "what p99 does a kput
+        #: spend in this server, and was that tail queue wait, a
+        #: compile, or the device".  The wait before ``t_rx`` is not
+        #: in it: ``stats()["frontend"]["rx_hold_ms"]`` bounds that.
         #: RETPU_SLO_RING=0 disables the ring ALONE (per-op + tenant
         #: latency histograms freeze; counters and the rest of the
         #: obs plane stay live) — the op-trace A/B's off arm.
@@ -1196,8 +1226,11 @@ class BatchedEnsembleService:
                      else None)
         self._h_op = self.obs_registry.histogram(
             "retpu_op_latency_ms",
-            "client-perceived op latency (submit to ack; "
-            "mirror-served leased reads included)",
+            "op latency, arrival at the server's loop to ack (a wire "
+            "op from the front end's stamp of its frame, an "
+            "in-process op from its API call; mirror-served leased "
+            "reads included; the wait in the socket before the loop "
+            "reads the frame is not in it)",
             label_name="kind")
         #: compile/device telemetry: every jitted step/pack variant
         #: the launch path dispatches is wrapped in a CompileWatch
@@ -1215,9 +1248,6 @@ class BatchedEnsembleService:
             "retpu_compile_ms_total",
             "wall ms spent inside watched calls that compiled",
             label_name="phase")
-        #: per-(K)-bucket step cost analysis captured at warmup
-        #: (engine.lowered_cost_analysis) — flops/bytes gauges
-        self._step_costs: Dict[str, Dict[str, float]] = {}
         if self._obs:
             self._pack = self._watched("pack", self._pack)
         #: the launch's step programs, bound once (see _bind_step_fns)
@@ -3261,6 +3291,7 @@ class BatchedEnsembleService:
                 self._obs_note_put_bytes(
                     ens, op.handle if isinstance(op, _PendingBatch)
                     else (op.handle,))
+        op.t_rx = self.t_rx
         op.t_enq = time.perf_counter()
         self.queues[ens].append(op)
         self._queue_rounds[ens] += op.n
@@ -3519,7 +3550,7 @@ class BatchedEnsembleService:
         # Buckets ride the pow2 A ladder (mirroring the K ladder's
         # compile-reuse discipline).
         active = aidx_np = shard_active = at = None
-        a_width = 0
+        a_width = cols_max = 0
         sliced = False
         n_sh = self._mesh_shards or 1
         e_loc = self.n_ens // n_sh
@@ -3529,6 +3560,7 @@ class BatchedEnsembleService:
         if len(cols):
             per_shard, a_loc = shard_active_columns(
                 cols, self.n_ens, n_sh, A_BUCKET_MIN)
+            cols_max = max(p.size for p in per_shard)
             if a_loc < e_loc:
                 active = cols.astype(np.int32)
                 a_width = a_loc
@@ -3557,11 +3589,13 @@ class BatchedEnsembleService:
         # takes it apart), put where the step wants it.  A sliced
         # launch's index rows are a slab row; a pack-gather's
         # (another width) are the packer's operand and a second upload.
-        slab_j = self._put(eng.pack_op_slab(
+        slab_np = eng.pack_op_slab(
             n_sh * a_width if sliced else self.n_ens, k, elect, cand,
             lease_ok, (kind, slot, val, exp_e, exp_s),
             active if sliced else None,
-            aidx_np.ravel() if sliced else None, at), "slab")
+            aidx_np.ravel() if sliced else None, at)
+        with self.spans.span("h2d_put", rec):   # inside h2d
+            slab_j = self._put(slab_np, "slab")
         uploads += 1
         aidx_j = None
         if aidx_np is not None and not sliced:
@@ -3571,8 +3605,20 @@ class BatchedEnsembleService:
         rec["uploads"] = uploads
         rec["sliced"] = int(sliced)
         rec["arrival"] = int(self._by_arrival)
+        # the launch's shape: the pow2 width it packed at (0: the
+        # full grid ran and nothing was gathered), its real columns,
+        # the busiest shard's, and the shards (n_sh blocks of ``a``)
+        n_cols = len(cols)
+        rec["a"] = a_width
+        rec["cols"] = n_cols
+        rec["cols_max"] = cols_max
+        rec["shards"] = n_sh
         self.launches_sliced += sliced
         self.launches_unsliced += not sliced
+        if a_width:
+            self.launches_gathered += not sliced
+            self._busiest_sum += cols_max / n_cols
+            self._pad_sum += 1.0 - n_cols / (n_sh * a_width)
         h2d.end()
 
         # Rollback snapshots: under async dispatch a device failure
@@ -3588,12 +3634,16 @@ class BatchedEnsembleService:
         lease_snapshot = self.lease_until.copy()
         dispatch = self.spans.span("dispatch", rec).begin()
         try:
+            # the two jit calls apart (inside dispatch; what is
+            # left of it is the d2h copy's start)
             step = fns.sliced_slab if sliced else fns.slab
-            self.state, won, res = step(self.state, slab_j, up_j)
+            with self.spans.span("dispatch_step", rec):
+                self.state, won, res = step(self.state, slab_j, up_j)
             # a sliced launch's result planes are ALREADY A-width;
             # pack-gather mode hands the pack the index vector
-            flat = self._pack(won, res, want_vsn,
-                              active_idx=None if sliced else aidx_j)
+            with self.spans.span("dispatch_pack", rec):
+                flat = self._pack(won, res, want_vsn,
+                                  active_idx=None if sliced else aidx_j)
             # Kick the packed vector's d2h transfer off NOW — the
             # resolve half (possibly a full flush later) only blocks
             # on its completion, so the transfer rides under the next
@@ -3947,7 +3997,7 @@ class BatchedEnsembleService:
         if not recs:
             return out
         comps = sorted({c for r in recs for c in r
-                        if c not in ("k", "starts", "clock")})
+                        if c not in ("k", "starts", "clock", "reqs")})
         for c in comps:
             vals = np.asarray([r.get(c, 0.0) for r in recs]) * 1e3
             out[c] = {"p50_ms": float(np.percentile(vals, 50)),
@@ -4022,7 +4072,7 @@ class BatchedEnsembleService:
             # what the front end moved over the wire, and the
             # collector's pauses since the flush timer started
             # (obs.spans; ARCHITECTURE §11)
-            "frontend": dict(self.frontend),
+            "frontend": self._frontend_stats(),
             "gc": self._gc_watch.stats(),
             "flight_anomalies": self.flight.anomalies,
             "tenants": self.tenant_stats(top=8),
@@ -4056,7 +4106,45 @@ class BatchedEnsembleService:
                 "shards": self._resolve_shards,
                 "sharded_flushes": self.sharded_flushes,
             },
+            **self._mesh_stats(),
         }
+
+    def _frontend_stats(self) -> Dict[str, Any]:
+        """The front end's counters and, where a server stamps its
+        loop's polls, ``rx_hold_ms``: over the last frames read, how
+        long the loop had not looked at its sockets by then.  The
+        operator's loop-lag figure: it bounds the wait in the socket
+        that ``retpu_op_latency_ms`` cannot see (under open-loop
+        arrivals a request waits half of it on average)."""
+        fe = dict(self.frontend)
+        holds = self.rx_holds
+        n = 0 if holds is None else min(fe["frames_in"], len(holds))
+        if n:
+            ms = holds[:n] * 1e3
+            p50, p95 = np.percentile(ms, (50, 95))
+            fe["rx_hold_ms"] = {"p50": float(p50), "p95": float(p95),
+                                "max": float(ms.max())}
+        return fe
+
+    def _mesh_stats(self) -> Dict[str, Any]:
+        """``stats()["mesh"]``, on an 'ens'-sharded engine only: how
+        its launches ran and, over those that packed at a width, how
+        skewed the shards were (the busiest shard's share of the
+        columns: 1 / shards when even) and how much of the blocks
+        was padding."""
+        if not self._mesh_shards:
+            return {}
+        shaped = self.launches_sliced + self.launches_gathered
+        return {"mesh": {
+            "shards": self._mesh_shards,
+            "launches_sliced": self.launches_sliced,
+            "launches_pack_gathered": self.launches_gathered,
+            "launches_full_grid": (self.launches_unsliced
+                                   - self.launches_gathered),
+            "busiest_shard_share": (self._busiest_sum / shaped
+                                    if shaped else None),
+            "pad_share": self._pad_sum / shaped if shaped else None,
+        }}
 
     def _lease_valid_fraction(self) -> float:
         """Fraction of live ensembles whose lease is margin-valid on
@@ -4204,7 +4292,6 @@ class BatchedEnsembleService:
         directly."""
         self.obs_registry.collect(self._obs_service_collect)
         self.obs_registry.collect(self._obs_tenant_collect)
-        self.obs_registry.collect(self._obs_cost_collect)
         self.obs_registry.collect(self._obs_fault_collect)
         self.obs_registry.collect(self.controller.collect)
         # live backend memory (device plane telemetry): reads the
@@ -4227,23 +4314,6 @@ class BatchedEnsembleService:
                 "bytes in use per local jax device (NaN when the "
                 "backend reports no memory stats)",
                 _backend_mem_bytes_per_device(), label="device"),
-        }
-
-    def _obs_cost_collect(self) -> Dict[str, Any]:
-        """Per-bucket XLA cost-analysis gauges captured at warmup
-        (labels are step buckets: ``k8``, ``k8_a16``, ...)."""
-        return {
-            "retpu_step_cost_flops": obs.registry.family(
-                "gauge", "warmup-lowered step cost model flops",
-                {b: c.get("flops") for b, c in
-                 self._step_costs.items()
-                 if c.get("flops") is not None}, label="bucket"),
-            "retpu_step_cost_bytes": obs.registry.family(
-                "gauge", "warmup-lowered step bytes accessed",
-                {b: c.get("bytes_accessed") for b, c in
-                 self._step_costs.items()
-                 if c.get("bytes_accessed") is not None},
-                label="bucket"),
         }
 
     def _obs_fault_collect(self) -> Dict[str, Any]:
@@ -4568,17 +4638,18 @@ class BatchedEnsembleService:
                     4)),
             "retpu_tenant_op_p50_ms": fam(
                 "gauge", "tenant op latency p50 (each entry charged "
-                "its measured submit-to-ack time, per-op SLO ring)",
+                "its measured time from its first stamp to its ack, "
+                "per-op SLO ring)",
                 lambda rr: round(self._tenant_pctl(rr, 0.5), 3)),
             "retpu_tenant_op_p99_ms": fam(
                 "gauge", "tenant op latency p99 (each entry charged "
-                "its measured submit-to-ack time, per-op SLO ring)",
+                "its measured time from its first stamp to its ack, "
+                "per-op SLO ring)",
                 lambda rr: round(self._tenant_pctl(rr, 0.99), 3)),
         }
 
     def _obs_account_taken(self, taken, committed,
                            t_settle: Optional[float] = None,
-                           rec: Optional[Dict[str, float]] = None,
                            fid: int = 0,
                            t_join: float = 0.0,
                            ent_meta=None) -> None:
@@ -4587,21 +4658,21 @@ class BatchedEnsembleService:
         entry) feeding vectorized folds — O(|entries|) appends, not
         per-op Python dicts.
 
-        The per-op SLO ring records each entry's REAL client-
-        perceived submit→ack latency (an entry's ops share its
-        stamps — batch granularity within an entry, entry granularity
-        within the flush; the join/settle/ack times are the flush's,
-        shared); the fold targets are the per-kind
-        ``retpu_op_latency_ms`` histogram, the per-tenant ``[E, B]``
-        plane, and the span store (the flush's slowest entry attaches
-        under ``slow_ops`` with its stage split, so
-        ``obs.timeline(fid)`` resolves a tail op to queue wait vs
-        flush vs ack).  Leased fast reads contribute their own
-        samples from the hit hook.  ``t_settle`` is when the flush's
-        outcome was known (on a replicated leader: AFTER the host
-        quorum — ack stamps land after quorum settle by
-        construction); ``rec`` is the launch's latency record,
-        consulted for the slow entry's dominating flush mark."""
+        The per-op SLO ring records each entry's measured latency
+        from its first stamp to its ack (``t_rx`` where the op came
+        through the front end, else the API call's submit; an
+        entry's ops share its stamps — batch granularity within an
+        entry, entry granularity within the flush; the
+        join/settle/ack times are the flush's, shared); the fold
+        targets are the per-kind ``retpu_op_latency_ms`` histogram
+        and the per-tenant ``[E, B]`` plane, and the ring's rows
+        carry the flush id (``OpSloRing.rows_of(fid)`` resolves a
+        tail op to queue wait vs flush vs ack beside
+        ``obs.timeline(fid)``).  Leased fast reads contribute their
+        own samples from the hit hook.  ``t_settle`` is when the
+        flush's outcome was known (on a replicated leader: AFTER the
+        host quorum — ack stamps land after quorum settle by
+        construction)."""
         now = time.perf_counter()
         rows: List[int] = [e for e, _ops in taken]
         if not rows:
@@ -4611,18 +4682,18 @@ class BatchedEnsembleService:
             # slab path collects the per-entry columns while the
             # flush walk builds its op lanes) — the settle fold never
             # re-walks entries whose futures are completion-slab rows
-            kk_l, enss, nn_l, ts_l, te_l = ent_meta
+            kk_l, enss, nn_l, tr_l, ts_l, te_l = ent_meta
         else:
-            cols: List[Tuple] = []  # (kind, n, t_sub, t_enq)/entry
+            cols: List[Tuple] = []  # _OP_SLO_FIELDS of each entry
             enss = []
             fields = _OP_SLO_FIELDS
             for e, ops in taken:
                 cols.extend(map(fields, ops))
                 enss.extend([e] * len(ops))
             if cols:
-                kk_l, nn_l, ts_l, te_l = zip(*cols)
+                kk_l, nn_l, tr_l, ts_l, te_l = zip(*cols)
             else:
-                kk_l = nn_l = ts_l = te_l = ()
+                kk_l = nn_l = tr_l = ts_l = te_l = ()
         rr = np.asarray(rows, np.int64)
         if committed is not None:
             np.add.at(self.tenant_commits, rr,
@@ -4635,7 +4706,7 @@ class BatchedEnsembleService:
         if self._slo is None:
             return
         folded = self._slo.record_flush(
-            kk_l, enss, nn_l, ts_l, te_l, fid,
+            kk_l, enss, nn_l, tr_l, ts_l, te_l, fid,
             t_join if t_join else (t_settle or now),
             t_settle if t_settle else now, now)
         if folded is None:
@@ -4643,8 +4714,8 @@ class BatchedEnsembleService:
         _phys, lat_ms = folded
         bidx = np.searchsorted(self._lat_edges, lat_ms)
         # per-tenant: each entry's ops charged the entry's own
-        # client-perceived latency (replacing PR 6's flush-oldest
-        # upper bound with the measured per-entry value)
+        # measured latency (replacing PR 6's flush-oldest upper
+        # bound with the per-entry value)
         np.add.at(self._tenant_lat, (ee, bidx), w)
         # per-kind registry histogram: fold bucket counts per kind
         # present in this flush (<= 5 kinds, B buckets — bounded)
@@ -4661,38 +4732,6 @@ class BatchedEnsembleService:
                 ccounts[bi] += int(counts[bi])
             child.count += int(w[sel].sum())
             child.sum += float((lat_ms[sel] * w[sel]).sum())
-        # tail attachment: the flush's slowest entry joins the span
-        # record under its flush_id, with the launch's dominating
-        # mark riding along when the record is at hand.  Built from
-        # THIS call's locals, never from the ring row — a flush wider
-        # than the ring capacity recycles physical rows within one
-        # record_flush, and reading the row back would attach a
-        # different entry's identity to the tail sample.
-        i = int(np.argmax(lat_ms))
-        t_sub_i = ts_l[i] if ts_l[i] > 0.0 else te_l[i]
-        tj = t_join if t_join else (t_settle or now)
-        tst = t_settle if t_settle else now
-        slow = {
-            "kind": obs.opslo.KIND_NAMES[int(kk_l[i])],
-            "ens": int(enss[i]),
-            "n": int(nn_l[i]),
-            "flush_id": int(fid),
-            "ms": round(max(0.0, now - t_sub_i) * 1e3, 3),
-            "stages_ms": {
-                "assign": round(max(0.0, te_l[i] - t_sub_i) * 1e3, 3),
-                "queue_wait": round(max(0.0, tj - te_l[i]) * 1e3, 3),
-                "flush": round(max(0.0, tst - tj) * 1e3, 3),
-                "ack": round(max(0.0, now - tst) * 1e3, 3),
-            },
-        }
-        if rec is not None:
-            marks = {c: v for c, v in rec.items()
-                     if isinstance(v, (int, float))
-                     and c not in obs.flightrec.META_FIELDS}
-            if marks:
-                slow["flush_mark"] = max(marks, key=marks.get)
-        if fid:
-            obs.SPANS.record(fid, "leader", [], slow_ops=[slow])
 
     def _obs_note_put_bytes(self, ens: int, handles) -> None:
         """Attribute queued put payload bytes to the row's tenant
@@ -4739,14 +4778,16 @@ class BatchedEnsembleService:
             # process's monotonic clock — the clock the per-link
             # offset estimates map between)
             t_mono=time.monotonic())
-        self.flight.record({
+        ring = {
             "flush_id": fl.flush_id, "t": time.time(),
-            "k": fl.k, "a_width": fl.a_width,
-            "payload_bytes": fl.payload_nbytes,
+            "k": fl.k, "payload_bytes": fl.payload_nbytes,
             "queued_rounds": sum(self._queue_rounds[e]
                                  for e in self._active),
             "in_flight": len(self._inflight_launches),
-            **rec})
+            **rec}
+        # (the dump's name for the record's ``a``)
+        ring["a_width"] = ring.pop("a", fl.a_width)
+        self.flight.record(ring)
         if self._autotune:
             # the runtime controller's cadence: one counted flush,
             # one integer compare; evaluations run every
@@ -4774,7 +4815,7 @@ class BatchedEnsembleService:
                 b <<= 1
         return ladder
 
-    def warmup(self, buckets=None, capture_costs=None) -> None:
+    def warmup(self, buckets=None) -> None:
         """Pre-compile the launch path's XLA programs on a THROWAWAY
         state (never the live one: a warmup launch that mutated
         ``self.state`` outside the real op stream would corrupt it —
@@ -4800,24 +4841,16 @@ class BatchedEnsembleService:
         ``buckets``: optional iterable of ``(k, a_width)`` pairs
         (a_width None = full width) restricting the PACK grid — the
         step ladder always warms in full.  bench.py and svcnode share
-        the default full grid.
-
-        ``capture_costs``: XLA cost-analysis gauges per warmed step
-        bucket (``retpu_step_cost_flops``/``_bytes``, labeled by
-        bucket; engine.lowered_cost_analysis — an extra lowering per
-        bucket, ~0.5 s each).  None (default) captures only the
-        deepest full-width bucket so routine warmups stay cheap;
-        True captures every bucket (svcnode ``--warm`` boots do);
-        False skips capture.  Compile events recorded during warmup
-        land under ``phase="warmup"`` either way.
+        the default full grid.  Compile events recorded during
+        warmup land under ``phase="warmup"``.
         """
         self._in_warmup = True
         try:
-            self._warmup(buckets, capture_costs)
+            self._warmup(buckets)
         finally:
             self._in_warmup = False
 
-    def _warmup(self, buckets, capture_costs) -> None:
+    def _warmup(self, buckets) -> None:
         jnp = self._jnp
         e, m, s = self.n_ens, self.n_peers, self.n_slots
         pack = self._pack
@@ -4862,11 +4895,6 @@ class BatchedEnsembleService:
                 z[:0] if sliced else None,
                 z + e_loc if sliced else None), "slab")
 
-        def cost(label: str, fn, *args) -> None:
-            ca = eng.lowered_cost_analysis(fn, *args)
-            if ca:
-                self._step_costs[label] = ca
-
         def warm_sliced(k: int, aw: int) -> bool:
             """One (K, A) bucket's sliced program, where the launch
             path would slice there (its rule, read per shard)."""
@@ -4875,8 +4903,6 @@ class BatchedEnsembleService:
                 return False
             slab = zero_slab(k, n_sh * aw, True)
             st, won, res = fns.sliced_slab(st, slab, up)
-            if self._obs and capture_costs:
-                cost(f"k{k}_a{aw}", fns.sliced_slab, st, slab, up)
             np.asarray(pack(won, res, True, active_idx=None))
             return True
 
@@ -4884,11 +4910,6 @@ class BatchedEnsembleService:
         while True:
             slab = zero_slab(k, e, False)
             st, won, res = fns.slab(st, slab, up)
-            # per-bucket XLA cost gauges: always the deepest bucket
-            # (one extra lowering); every bucket when asked
-            if (self._obs and capture_costs is not False
-                    and (capture_costs or k >= self.max_k)):
-                cost(f"k{k}", fns.slab, st, slab, up)
             # The flush path (the read fast path's get-only/read-miss
             # fallback batches included) always packs WITH versions —
             # the (K, A) ladder covers those.  The version-less pack
@@ -5161,12 +5182,13 @@ class BatchedEnsembleService:
         exps_l: List[int] = []
         offs: List[int] = []
         lane_n = 0
-        #: per-entry SLO stamp columns (obs.opslo satellite): t_sub/
-        #: t_enq collected HERE at enqueue time off the pending
+        #: per-entry SLO stamp columns (obs.opslo satellite): t_rx/
+        #: t_sub/t_enq collected HERE at enqueue time off the pending
         #: entries (kind/ens/weight are the run descriptors above) —
         #: the settle-side fold then sources stamps from the pending
         #: slab instead of re-walking the taken entries after their
         #: futures were replaced by completion-slab rows
+        trx_l: List[float] = []
         tsub_l: List[float] = []
         tenq_l: List[float] = []
         for e in sorted(active):
@@ -5214,6 +5236,7 @@ class BatchedEnsembleService:
                     ent_row0.append(j)
                     ent_len.append(n)
                     ent_kind.append(op.kind)
+                    trx_l.append(op.t_rx)
                     tsub_l.append(op.t_sub)
                     tenq_l.append(op.t_enq)
                     if isinstance(op, _PendingBatch):
@@ -5297,7 +5320,7 @@ class BatchedEnsembleService:
                 exp_e[rows, cols] = l_expe
                 exp_s[rows, cols] = l_exps
             lanes = (ec, er, el, lane_n, offs,
-                     (ent_kind, ent_col, ent_len, tsub_l, tenq_l)
+                     (ent_kind, ent_col, ent_len, trx_l, tsub_l, tenq_l)
                      if self._obs else None)
             if native_pack:
                 self.native_enqueue_flushes += 1
@@ -6536,7 +6559,7 @@ class BatchedEnsembleService:
             if self._obs:
                 with self.spans.span("obs", rec):
                     self._obs_account_taken(
-                        taken, committed, t_settle, rec, fid, t_join,
+                        taken, committed, t_settle, fid, t_join,
                         ent_meta=lanes[5] if len(lanes) > 5 else None)
             self._drain_recycles()
             return served
@@ -6675,6 +6698,6 @@ class BatchedEnsembleService:
         if self._obs and taken:
             with self.spans.span("obs", rec):
                 self._obs_account_taken(taken, committed, t_settle,
-                                        rec, fid, t_join)
+                                        fid, t_join)
         self._drain_recycles()
         return served

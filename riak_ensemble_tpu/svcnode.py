@@ -128,17 +128,19 @@ import itertools
 import os
 import struct
 import sys
+import time
 from typing import Any, Dict, Optional, Tuple
 
 from riak_ensemble_tpu import faults, wire
 from riak_ensemble_tpu.config import Config, fast_test_config
 from riak_ensemble_tpu.netruntime import NetRuntime
-from riak_ensemble_tpu.obs.spans import NULL_SPAN
+from riak_ensemble_tpu.obs.spans import NULL_SPAN, PollWatch
 from riak_ensemble_tpu.parallel.batched_host import BatchedEnsembleService
 from riak_ensemble_tpu.utils.jaxcache import setup_compile_cache
 
 _HDR = struct.Struct(">I")
 _MAX_FRAME = 16 << 20
+_now = time.perf_counter
 
 
 def _slab_lens(lens_buf, arena_buf) -> "np.ndarray":
@@ -185,6 +187,9 @@ def _slab_vals(lens_buf, arena_buf) -> list:
 #: taken back).
 _MAX_INFLIGHT = 1024
 _MAX_WRITE_BUF = 8 << 20
+#: how many frames' ``rx_hold`` the service keeps (a power of two:
+#: ``stats()["frontend"]["rx_hold_ms"]`` is over the last this many)
+_RX_HOLD_FRAMES = 4096
 
 
 class ServiceServer:
@@ -206,8 +211,23 @@ class ServiceServer:
         # again for every request.
         self._spans = [svc.spans.span(name) for name in (
             "fe_decode", "fe_dispatch", "fe_reply", "fe_reply_direct")]
+        # A request's life (obs.spans, "A request's life"; none of it
+        # with RETPU_OBS=0): every frame is stamped where it is whole
+        # (``t_rx``: the per-op plane's first stamp, ``svc.t_rx``
+        # while the frame is dispatched) and, where the loop has a
+        # selector to stamp, leaves how long the loop had not looked
+        # at its sockets by then in ``svc.rx_holds``
+        # (``stats()["frontend"]["rx_hold_ms"]``); in the cycles the
+        # recorder samples it also leaves a row in the record that is
+        # open when its reply is written.
+        self._poll: Optional[PollWatch] = None
 
     async def start(self) -> Tuple[str, int]:
+        if self.svc._obs and self._poll is None:
+            self._poll = PollWatch.of(asyncio.get_running_loop())
+            if self._poll is not None and self.svc.rx_holds is None:
+                import numpy as np
+                self.svc.rx_holds = np.zeros((_RX_HOLD_FRAMES,))
         self._server = await asyncio.start_server(
             self._on_client, self.host, self.port)
         addr = self._server.sockets[0].getsockname()
@@ -219,6 +239,9 @@ class ServiceServer:
             self._server.close()
             await self._server.wait_closed()
             self._server = None
+        if self._poll is not None:
+            self._poll.release()
+            self._poll = None
         self.svc.stop()
 
     def _dispatch(self, op: str, args: tuple):
@@ -311,12 +334,17 @@ class ServiceServer:
         # can't grow the queues/pending maps without bound (the
         # VERDICT/advisor backpressure finding).
         inflight = asyncio.Semaphore(_MAX_INFLIGHT)
-        bp = self.svc.svc_backpressure
-        fe = self.svc.frontend
-        spans = self.svc.spans
+        svc = self.svc
+        bp = svc.svc_backpressure
+        fe = svc.frontend
+        spans = svc.spans
         fe_decode, fe_dispatch, fe_reply, fe_reply_direct = self._spans
+        stamped = svc._obs
+        poll, holds = self._poll, svc.rx_holds
+        hold_mask = 0 if holds is None else len(holds) - 1
 
-        def send(req_id: Any, result: Any) -> None:
+        def send(req_id: Any, result: Any,
+                 row: Optional[list] = None) -> None:
             # Responses are written from flush-context future waiters
             # too — never after close, and never into an unbounded
             # buffer for a client that stopped reading (advisor: drain
@@ -330,6 +358,13 @@ class ServiceServer:
                 except wire.WireError:
                     payload = wire.encode((req_id, "failed"))
                 writer.write(_HDR.pack(len(payload)) + payload)
+            if row is not None:
+                # the request's row, into the record of the flush
+                # that answers it (a reply written at once: the
+                # loop's, which the next flush takes)
+                row[1] = 0 if spans.is_settling else 1
+                row[4] = _now() - row[2]
+                spans.open.setdefault("reqs", []).append(row)
             fe["frames_out"] += 1
             fe["bytes_out"] += _HDR.size + len(payload)
             transport = writer.transport
@@ -350,6 +385,15 @@ class ServiceServer:
                 if length > _MAX_FRAME:
                     break  # hostile length: drop the connection
                 frame = await reader.readexactly(length)
+                t_rx, row = 0.0, None
+                if stamped:
+                    t_rx = _now()
+                    hold = None
+                    if poll is not None:
+                        hold = t_rx - poll.base
+                        holds[fe["frames_in"] & hold_mask] = hold
+                    if spans.detail:
+                        row = [None, 1, t_rx, hold, 0.0]
                 fe["frames_in"] += 1
                 fe["bytes_in"] += _HDR.size + length
                 try:
@@ -359,8 +403,10 @@ class ServiceServer:
                         args = tuple(msg[2:])
                 except (wire.WireError, IndexError, TypeError):
                     break  # malformed: drop the connection
+                if row is not None:
+                    row[0] = op
                 if op == "stats":
-                    send(req_id, self.svc.stats())
+                    send(req_id, self.svc.stats(), row)
                     continue
                 if op == "metrics":
                     # the obs-plane export verb: the whole registry
@@ -368,9 +414,11 @@ class ServiceServer:
                     # text when asked (both wire-encodable)
                     if args and args[0] == "prometheus":
                         send(req_id,
-                             self.svc.obs_registry.render_prometheus())
+                             self.svc.obs_registry.render_prometheus(),
+                             row)
                     else:
-                        send(req_id, self.svc.obs_registry.snapshot())
+                        send(req_id, self.svc.obs_registry.snapshot(),
+                             row)
                     continue
                 if op == "controller":
                     # runtime-controller verb (ARCHITECTURE §14):
@@ -382,7 +430,7 @@ class ServiceServer:
                             self.svc.controller.health_section(),
                         "decisions":
                             self.svc.controller.journal.snapshot(),
-                    })
+                    }, row)
                     continue
                 if op == "health":
                     # ensemble-health verb (the cluster-status
@@ -395,9 +443,9 @@ class ServiceServer:
                             if type(ens_arg) is not int or \
                                     not 0 <= ens_arg < self.svc.n_ens:
                                 raise ValueError(ens_arg)
-                        send(req_id, self.svc.health(ens_arg))
+                        send(req_id, self.svc.health(ens_arg), row)
                     except Exception:
-                        send(req_id, ("error", "bad-request"))
+                        send(req_id, ("error", "bad-request"), row)
                     continue
                 if op == "fleet":
                     # fleet-scope obs verbs (docs/ARCHITECTURE.md
@@ -429,17 +477,17 @@ class ServiceServer:
                             fn = (lambda f=fid:
                                   self.svc.fleet_timeline(f))
                         else:
-                            send(req_id, ("error", "bad-request"))
+                            send(req_id, ("error", "bad-request"), row)
                             continue
                         result = await asyncio.get_running_loop() \
                             .run_in_executor(None, fn)
-                        send(req_id, result)
+                        send(req_id, result, row)
                     except Exception:
-                        send(req_id, ("error", "bad-request"))
+                        send(req_id, ("error", "bad-request"), row)
                     continue
                 if op in ("create_ensemble", "destroy_ensemble",
                           "resolve_ensemble"):
-                    send(req_id, self._lifecycle(op, args))
+                    send(req_id, self._lifecycle(op, args), row)
                     continue
                 if inflight.locked():
                     # the read loop is about to block on the
@@ -447,6 +495,8 @@ class ServiceServer:
                     # has _MAX_INFLIGHT unresolved ops
                     bp["inflight_stalls"] += 1
                 await inflight.acquire()
+                # the op is made in this call: its first stamp
+                svc.t_rx = t_rx
                 try:
                     with fe_dispatch if spans.detail else NULL_SPAN:
                         fut = self._dispatch(op, args)
@@ -455,18 +505,21 @@ class ServiceServer:
                     # client: answer, don't let the task die with an
                     # unhandled traceback
                     inflight.release()
-                    send(req_id, ("error", "bad-request"))
+                    send(req_id, ("error", "bad-request"), row)
                     continue
+                finally:
+                    svc.t_rx = 0.0  # an in-process caller has none
                 if fut is None:
                     inflight.release()
-                    send(req_id, ("error", "unknown-op"))
+                    send(req_id, ("error", "unknown-op"), row)
                     continue
 
                 # Resolution happens inside a flush on this same
                 # loop; the waiter writes the response directly.
-                def on_done(result: Any, rid: Any = req_id) -> None:
+                def on_done(result: Any, rid: Any = req_id,
+                            row: Optional[list] = row) -> None:
                     inflight.release()
-                    send(rid, result)
+                    send(rid, result, row)
                 fut.add_waiter(on_done)
                 await writer.drain()
         except (asyncio.IncompleteReadError, ConnectionError):
@@ -858,10 +911,8 @@ async def serve(n_ens: int, n_peers: int, n_slots: int,
         # pow2 active-column widths, both want_vsn pack variants
         # (covers the read fast path's get-only fallback shapes) — so
         # no client ever pays a mid-serving first-compile inside its
-        # op latency (the dispatch p99 blip).  A --warm boot also
-        # captures the per-bucket XLA cost gauges
-        # (retpu_step_cost_flops/_bytes) for the metrics verb.
-        svc.warmup(capture_costs=True)
+        # op latency (the dispatch p99 blip).
+        svc.warmup()
     server = ServiceServer(svc, host, port)
     await server.start()
     return server

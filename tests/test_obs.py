@@ -641,8 +641,8 @@ def test_served_flush_total_is_the_sum_of_the_same_marks(
         assert r["obs"] > 0.0 and r["pack"] >= 0.0
         # every span has a start stamp, the record a wall-clock anchor
         timed = {c for c in r
-                 if c not in ("k", "uploads", "sliced", "arrival",
-                              "total", "enqueue", "starts", "clock")
+                 if c not in obs.flightrec.SHAPE_FIELDS + (
+                     "total", "enqueue", "starts", "clock", "reqs")
                  and not c.startswith(("enqueue_", ))}
         assert timed <= set(r["starts"]), timed - set(r["starts"])
         assert len(r["clock"]) == 2
@@ -734,7 +734,10 @@ def test_frontend_counts_frames_and_times_the_loop(monkeypatch,
             fe = dict(svc.frontend)
             assert fe["frames_in"] == fe["frames_out"] == n
             assert fe["bytes_in"] > 4 * n and fe["bytes_out"] > 4 * n
-            assert svc.stats()["frontend"] == fe
+            stats_fe = svc.stats()["frontend"]
+            assert {c: stats_fe[c] for c in fe} == fe
+            # the loop-lag figure rides the same switch as the marks
+            assert ("rx_hold_ms" in stats_fe) == obs_on
             snap = svc.obs_registry.snapshot()
             assert snap["retpu_frontend_frames_total"] == {
                 "in": n, "out": n}
@@ -807,7 +810,8 @@ SESSION_SPANS = ("svc.wal", "svc.wal_encode", "svc.wal_append",
                  "svc.wal_fsync", "svc.resolve", "svc.between_flushes",
                  "svc.h2d", "svc.dispatch", "svc.device_d2h",
                  "svc.unpack", "svc.pack", "svc.obs", "svc.fe_decode",
-                 "svc.fe_dispatch", "svc.fe_reply", "py.gc")
+                 "svc.fe_dispatch", "svc.fe_reply", "py.gc",
+                 "svc.h2d_put", "svc.dispatch_step", "svc.dispatch_pack")
 
 
 @pytest.fixture(scope="module")

@@ -454,6 +454,24 @@ CASES = {
     "election_only": ("sliced", _drive_election_only, 2),
     "execute_host": ("pack_gather", _drive_execute_host, 1),
     "execute_jax": ("mesh", _drive_execute_jax, 1),
+    # the launch's shape in the record (SHAPES below)
+    "shape_sliced": ("sliced", _drive_kput, 1),
+    "shape_pack_gather": ("pack_gather", _drive_kput, 2),
+    "shape_election_full_grid": ("sliced", _drive_election_only, 2),
+    "shape_mesh_sliced": ("mesh_sliced", _drive_kput, 1),
+    "shape_mesh_pack_gather": ("mesh", _drive_kput, 2),
+}
+
+#: (a, cols, cols_max, shards) of every launch the drive makes: the
+#: pow2 width it packed at (0: the full grid, nothing gathered), its
+#: real columns, the busiest shard's, the shards.  COLS are three
+#: columns of shard 0; at E = 64 over four shards column 40 is shard 2's
+SHAPES = {
+    "shape_sliced": (8, 3, 3, 1),
+    "shape_pack_gather": (8, 3, 3, 1),
+    "shape_election_full_grid": (0, 0, 0, 1),
+    "shape_mesh_sliced": (8, 3, 3, 4),
+    "shape_mesh_pack_gather": (8, 3, 2, 4),
 }
 
 #: the only step programs a launch may compile
@@ -467,7 +485,10 @@ def _assert_slab_launches(svc, recs, shape, most):
         assert 1 <= r["uploads"] <= most, r
         assert r["sliced"] == int(slices and r["k"] > 0), r
         if r["sliced"]:
-            assert r["uploads"] == 1, r
+            assert r["uploads"] == 1 and r["a"] > 0, r
+        assert r["shards"] == (svc._mesh_shards or 1), r
+        assert r["cols_max"] <= r["cols"] <= svc.n_ens, r
+        assert r["a"] == 0 or r["cols_max"] <= r["a"], r
     assert svc.stats()["launches_sliced"] >= sum(
         r["sliced"] for r in recs)
     assert {e["fn"] for e in svc._compile_log
@@ -487,6 +508,9 @@ def test_served_flush_counts_its_uploads(case):
         drive(svc)
         recs = [r for r in list(svc.lat_records)[n0:] if "uploads" in r]
         _assert_slab_launches(svc, recs, shape, most)
+        if case in SHAPES:
+            assert {(r["a"], r["cols"], r["cols_max"], r["shards"])
+                    for r in recs} == {SHAPES[case]}
         for r in recs:
             # no eager device op inside the h2d span: all the launch
             # takes from jnp there is the index vector's upload

@@ -3,7 +3,7 @@
 Covers the tentpole contracts end to end: stamp monotonicity and the
 op→flush_id join on the pipelined (depth 2) keyed path, the join
 surviving a batch split across flushes, ack-after-quorum on a LIVE
-replication group, the injected-slow-op demo (client-perceived tail
+replication group, the injected-slow-op demo (the op's tail
 attributed to its dominating stage via ``obs.timeline``), and the
 compile-event hook catching a deliberately un-warmed (K, A) bucket.
 """
@@ -53,7 +53,7 @@ def test_op_spans_depth2_pipelined():
         tl = obs.timeline(int(ring.fid[r]))
         assert tl is not None and "leader" in tl
     # per-kind histogram: every put counted once (3 rounds x 4 ens x
-    # 2 keys), client-perceived latency nonzero
+    # 2 keys), the entry's latency nonzero
     put = svc._h_op.labels("put")
     assert put.count == 24
     assert put.percentile(0.99) >= put.percentile(0.5) >= 0
@@ -158,11 +158,12 @@ def test_op_ack_lands_after_quorum_settle(tmp_path):
 
 
 def test_injected_slow_op_tail_attribution(monkeypatch):
-    """Acceptance demo: one injected-slow op's client-perceived tail
-    is correctly attributed via ``obs.timeline`` — a queue-stalled op
-    shows ``queue_wait`` dominating its stage split, a d2h-stalled op
-    shows the flush stage dominating WITH the flush's own dominant
-    mark naming ``device_d2h``."""
+    """Acceptance demo: one injected-slow op's tail is correctly
+    attributed by the ring's rows of its flush id
+    (``OpSloRing.rows_of``) beside ``obs.timeline`` — a queue-stalled
+    op shows ``queue_wait`` dominating its stage split, a d2h-stalled
+    op shows the flush stage dominating AND the flush's own span
+    record naming ``device_d2h`` as its longest mark."""
     svc = BatchedEnsembleService(WallRuntime(), 4, 3, 8, tick=None,
                                  max_ops_per_tick=2)
     # steady state first (compiles out of the way)
@@ -182,9 +183,8 @@ def test_injected_slow_op_tail_attribution(monkeypatch):
     # itself correctly attributed to its 'flush' stage)
     row = max(_acked_rows(ring), key=lambda r: ring.t_ack[r])
     fid = int(ring.fid[row])
-    tl = obs.timeline(fid)
-    slow = tl["leader"]["slow_ops"][0]
-    assert slow["ms"] >= 55.0, slow
+    slow = ring.rows_of(fid)[0]
+    assert slow["flush_id"] == fid and slow["ms"] >= 55.0, slow
     st = slow["stages_ms"]
     assert st["queue_wait"] > max(st["flush"], st["ack"],
                                   st["assign"]), slow
@@ -206,13 +206,14 @@ def test_injected_slow_op_tail_attribution(monkeypatch):
              and ring.t_ack[r] - ring.t_submit[r] > 0.07]
     assert rows2, "stalled op not found in the ring"
     fid2 = int(ring.fid[rows2[-1]])
-    slow2 = obs.timeline(fid2)["leader"]["slow_ops"][0]
+    slow2 = ring.rows_of(fid2)[0]
     st2 = slow2["stages_ms"]
     assert st2["flush"] > max(st2["queue_wait"], st2["ack"],
                               st2["assign"]), slow2
-    # the dominating PR 6 flush mark rides the tail sample: the
-    # stall sat in the d2h wait
-    assert slow2["flush_mark"] == "device_d2h", slow2
+    # the same flush id names the flush's own span record, whose
+    # longest mark says where the stall sat: the d2h wait
+    marks = dict(obs.timeline(fid2)["leader"]["spans"])
+    assert max(marks, key=marks.get) == "device_d2h", marks
     svc.stop()
 
 
@@ -256,7 +257,7 @@ def test_ring_bounded_and_obs_off_short_circuit(monkeypatch):
     ring = opslo.OpSloRing(capacity=64)
     for i in range(200):
         t = float(i + 1)
-        ring.record_flush([2], [0], [1], [0.0], [t], i + 1, t,
+        ring.record_flush([2], [0], [1], [0.0], [0.0], [t], i + 1, t,
                           t + 1.0, t + 2.0)
     assert ring.cap == 64 and ring._next == 200
     monkeypatch.setenv("RETPU_OBS", "0")
