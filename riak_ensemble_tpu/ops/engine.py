@@ -316,12 +316,31 @@ def build_uppers(leaves: jax.Array) -> jax.Array:
     return jnp.concatenate(outs, axis=-2) if len(outs) > 1 else outs[0]
 
 
+# Merkle paths.  The chip stores ``tree_node [E, M, U, LANES]`` U
+# minor-most and lane-dense (13.4 MB at 64 x 3 x 65,536).  A gather or
+# a scatter along U wants the 4-wide LANES minor-most instead, padded
+# to the tile's 128 lanes: written as ``take_along_axis`` /
+# ``.at[].set`` the compiler moved the whole plane to that layout and
+# back in EVERY round of the scan, 573 MB each way (PERF.md section 6,
+# PR 42).  So the round reads and writes the node plane where it lies,
+# by masks over U (:func:`_hit`): a parent recomputed from a node level
+# is :func:`hash.fold_block` (the fold's mixes masked to the block, one
+# pass), the path's stored hashes are compared with the recomputed ones
+# in one pass over the plane (extracting them, a one-hot sum per level
+# slice, brought a relayout back), and the path write is one select
+# over the plane.  A masked pass costs the plane's own size once, and
+# ``tree_node`` is a fifteenth of ``tree_leaf`` at every shape.
+# ``tree_leaf`` (201 MB there) is what must NOT be passed over: the
+# slot's leaf and the 16 leaves under its first parent are gathered,
+# and its write is a scatter, as before.
+
+
 def _gather_children(arr: jax.Array, parent_idx: jax.Array,
                      n: int) -> jax.Array:
     """Gather the 16 children of ``parent_idx [E, W]`` from a
     per-replica level array ``arr [E, Ml, n, LANES]`` →
     ``[E, Ml, W, 16, LANES]`` (zero-padded beyond ``n``, matching
-    :func:`_fold_blocks`)."""
+    :func:`_fold_blocks`).  The round calls it on ``tree_leaf`` only."""
     e, w = parent_idx.shape
     ml = arr.shape[1]
     idx = (parent_idx[..., None] * TREE_WIDTH
@@ -333,28 +352,45 @@ def _gather_children(arr: jax.Array, parent_idx: jax.Array,
     return jnp.where(valid[:, None, :, :, None], g, jnp.uint32(0))
 
 
+def _hit(n: int, idx: jax.Array) -> jax.Array:
+    """One-hot of ``idx [E, W]`` over a level's n nodes, shaped to
+    mask ``[E, Ml, W, n, LANES]``: ``[E, 1, W, n, 1]`` bool."""
+    at = jnp.arange(n, dtype=jnp.int32)
+    return (at == idx[..., None])[:, None, :, :, None]
+
+
+def _node_levels(tree_node: jax.Array, s: int):
+    """``(offset, level [E, Ml, n, LANES])`` of the flat node plane,
+    leafward → root."""
+    offs, _ = _tree_offsets(s)
+    return [(off, jax.lax.slice_in_dim(tree_node, off, off + n, axis=2))
+            for off, n in zip(offs, tree_sizes(s))]
+
+
 def _verify_path(tree_leaf: jax.Array, tree_node: jax.Array,
                  slot: jax.Array) -> jax.Array:
     """Root-ward path verification for W slots per ensemble: recompute
     each stored parent on the paths from its stored children and
     compare (``get_path``/``verify_hash``, synctree.erl:302-340).
     ``slot [E, W]`` → ``[E, Ml, W]`` bool — replica's tree corrupted
-    on lane w's path."""
+    on lane w's path.  The first parent's children are 16 gathered
+    leaves; every level above is read by masks ("Merkle paths")."""
     s = tree_leaf.shape[-2]
-    offs, _ = _tree_offsets(s)
-    sizes = tree_sizes(s)
-    e, ml = tree_leaf.shape[:2]
-    bad = jnp.zeros((e, ml, slot.shape[1]), bool)
-    child_arr, child_n, idx = tree_leaf, s, slot
-    for off, n in zip(offs, sizes):
+    u = tree_node.shape[2]
+    node = tree_node[:, :, None]                             # [E,Ml,1,U,L]
+    wrong = jnp.zeros((), bool)
+    below, idx = None, slot
+    for off, level in _node_levels(tree_node, s):
         pidx = idx // TREE_WIDTH                             # [E, W]
-        expect = hashk.fold(_gather_children(child_arr, pidx, child_n))
-        level = jax.lax.slice_in_dim(tree_node, off, off + n, axis=2)
-        stored = jnp.take_along_axis(
-            level, pidx[:, None, :, None], axis=2)           # [E,Ml,W,L]
-        bad = bad | (expect != stored).any(-1)
-        child_arr, child_n, idx = level, n, pidx
-    return bad
+        if below is None:
+            expect = hashk.fold(_gather_children(tree_leaf, pidx, s))
+        else:
+            expect = hashk.fold_block(below[:, :, None], pidx[:, None, :],
+                                      TREE_WIDTH)
+        wrong = wrong | (_hit(u, off + pidx)
+                         & (node != expect[:, :, :, None]))
+        below, idx = level, pidx
+    return wrong.any((3, 4))
 
 
 def _write_path(tree_leaf: jax.Array, tree_node: jax.Array,
@@ -367,36 +403,49 @@ def _write_path(tree_leaf: jax.Array, tree_node: jax.Array,
     Non-writing replicas' nodes are untouched (a recompute would
     silently alter a corrupted-but-unwritten tree).
 
-    HBM discipline: updates are SCATTERS at the touched (slot, path)
-    positions, not full-plane ``where`` rewrites — per round only
-    O(E·M·W·height·LANES) elements move, not the whole
-    ``[E, M, S(+U), LANES]`` tree (inside the kv scan the carried
-    buffers alias, so the scatter lowers to an in-place update).
-    Masked-off lanes aim out of bounds and are DROPPED, which keeps
-    duplicate in-bounds targets conflict-free: lanes sharing a parent
-    all scatter the identical post-update fold of its 16 children.
+    HBM discipline, plane by plane ("Merkle paths" above).
+    ``tree_leaf`` is SCATTERED at the touched slot: only
+    O(E·M·LANES) elements of the largest plane move (inside the kv
+    scan the carried buffer aliases, so the scatter is an in-place
+    update); a masked-off replica aims out of bounds and is DROPPED.
+    ``tree_node`` is written by ONE masked select over the plane in
+    the layout it is stored in: each level's new parent is folded from
+    the level below AS THIS ROUND WRITES IT (its own new child
+    substituted in the mask, no intermediate plane), and the select
+    places the height's parents together.  That is a pass over U per
+    round where the scatter moved O(height) nodes, and it is the cheap
+    side: U is a fifteenth of S, and the scatter's layout cost 43
+    times the plane.  One lane (W = 1, every caller's width): lanes
+    that share a parent would each need the others' new children.
     """
     e, ml, w = mask.shape
+    if w != 1:
+        raise NotImplementedError(
+            "the masked path write takes one lane (ROADMAP D12)")
     s = tree_leaf.shape[-2]
-    offs, total = _tree_offsets(s)
-    sizes = tree_sizes(s)
     eidx = jnp.arange(e, dtype=jnp.int32)[:, None, None]     # [E, 1, 1]
     midx = jnp.arange(ml, dtype=jnp.int32)[None, :, None]    # [1, Ml, 1]
     sl = jnp.where(mask, slot[:, None, :], s)                # [E, Ml, W]
     tree_leaf = tree_leaf.at[eidx, midx, sl].set(
         jnp.broadcast_to(new_leaf[:, None], (e, ml, w, hashk.LANES)),
         mode="drop")
-    child_arr, child_n, idx = tree_leaf, s, slot
-    node = tree_node
-    for off, n in zip(offs, sizes):
-        pidx = idx // TREE_WIDTH                             # [E, W]
-        parent = hashk.fold(_gather_children(child_arr, pidx, child_n))
-        tgt = jnp.where(mask, off + pidx[:, None, :], total)
-        node = node.at[eidx, midx, tgt].set(parent, mode="drop")
-        child_arr, child_n = (
-            jax.lax.slice_in_dim(node, off, off + n, axis=2), n)
-        idx = pidx
-    return tree_leaf, node
+    wr = mask[:, :, :, None, None]                           # [E,Ml,1,1,1]
+    pidx = slot // TREE_WIDTH                                # [E, 1]
+    parent = hashk.fold(_gather_children(tree_leaf, pidx, s))  # [E,Ml,1,L]
+    levels = _node_levels(tree_node, s)
+    writes = [(levels[0][0] + pidx, parent)]
+    for (_, below), (off, _) in zip(levels, levels[1:]):
+        # the level below with this round's write in place
+        below = jnp.where(wr & _hit(below.shape[2], pidx),
+                          parent[:, :, :, None], below[:, :, None])
+        pidx = pidx // TREE_WIDTH
+        parent = hashk.fold_block(below, pidx[:, None, :], TREE_WIDTH)
+        writes.append((off + pidx, parent))
+    node = tree_node[:, :, None]                             # [E,Ml,1,U,L]
+    for tgt, parent in writes:
+        node = jnp.where(wr & _hit(tree_node.shape[2], tgt),
+                         parent[:, :, :, None], node)
+    return tree_leaf, node[:, :, 0]
 
 
 def init_state(n_ensembles: int, n_peers: int, n_slots: int,
@@ -580,6 +629,58 @@ def elect_step(state: EngineState, elect: jax.Array, cand: jax.Array,
 # K/V kernel
 
 
+def _as_stored(x: jax.Array) -> jax.Array:
+    """An ``[E, M, S]`` object plane named as the chip stores it,
+    ``[M, E, S]`` (and back: the swap is its own inverse).  The chip
+    keeps such a plane M OUTERMOST (layout ``{2,0,1}``, tiles over
+    (E, S): the M of 3 or 5 is not padded to the tile's 8), so the
+    view is a bitcast there, and a gather or scatter written against
+    its rows (:func:`_peer_rows`) reads and writes the plane where it
+    lies.  Written against ``[E, M, S]`` the compiler relayouts the
+    whole plane E outermost and back: 50 MB each way per plane and
+    ROUND at 64 x 3 x 65,536, 25.6 MB per launch at the sliced step's
+    edges (PERF.md section 6, PRs 42 and 40).  Where the stored layout
+    is another (small S, a CPU) the view costs what the compiler makes
+    of it and the values are the same.  THE one name for "the plane
+    as stored": the round's slot accesses and the sliced step's two
+    edges both go through it."""
+    return jnp.transpose(x, (1, 0, 2))
+
+
+def _peer_rows(x: jax.Array) -> jax.Array:
+    """``[E, M, S]`` viewed as ``[M * E, S]``: row ``m * E + e`` (a
+    reshape of :func:`_as_stored`, so a bitcast where that is one and
+    a shard's rows E are whole tiles of 8)."""
+    e, m, s = x.shape
+    return _as_stored(x).reshape(m * e, s)
+
+
+def _slot_read(plane: jax.Array, slot: jax.Array) -> jax.Array:
+    """``plane[e, m, slot[e, w]]`` of an ``[E, Ml, S]`` object plane →
+    ``[E, Ml, W]`` (``slot [E, W]`` in range): each row of
+    :func:`_peer_rows` gathers its W columns where it lies."""
+    e, ml, _ = plane.shape
+    with jax.named_scope("slot_gather"):
+        got = jnp.take_along_axis(_peer_rows(plane),
+                                  jnp.tile(slot, (ml, 1)), axis=1)
+        return _as_stored(got.reshape(ml, e, slot.shape[1]))
+
+
+def _slot_write(plane: jax.Array, slot: jax.Array,
+                new: jax.Array) -> jax.Array:
+    """``plane.at[e, m, slot[e, m, w]].set(new[e, w], mode="drop")``:
+    ``slot [E, Ml, W]`` (S = out of range = no write), as one scatter
+    into the rows of :func:`_peer_rows` (in place on the scan's carry)."""
+    e, ml, s = plane.shape
+    w = slot.shape[2]
+    with jax.named_scope("slot_scatter"):
+        row = jnp.arange(ml * e, dtype=jnp.int32)[:, None]
+        out = _peer_rows(plane).at[
+            row, _as_stored(slot).reshape(ml * e, w)].set(
+                jnp.tile(new, (ml, 1)), mode="drop")
+        return _as_stored(out.reshape(ml, e, s))
+
+
 class _KvCtx(NamedTuple):
     """Loop-invariant K/V round context.
 
@@ -647,7 +748,7 @@ def _kv_round(state: EngineState, ctx: _KvCtx, kind: jax.Array,
     ``tree_corrupt``, healed by the repair/scrub machinery one round
     later.
     """
-    e, ml = state.epoch.shape
+    e = state.epoch.shape[0]
     s = state.obj_epoch.shape[-1]
     w = kind.shape[1]
     heard = ctx.heard                                        # [E, Ml]
@@ -670,14 +771,10 @@ def _kv_round(state: EngineState, ctx: _KvCtx, kind: jax.Array,
 
     # Per-replica object at each lane's slot: ONE gather per plane
     # (invalid slots read the absent object).
-    def at_slot(plane):
-        with jax.named_scope("slot_gather"):
-            return jnp.take_along_axis(
-                plane, slot_c[:, None, :], axis=2)           # [E, Ml, W]
     sv = slot_valid[:, None, :]
-    pe = jnp.where(sv, at_slot(state.obj_epoch), 0)
-    ps = jnp.where(sv, at_slot(state.obj_seq), 0)
-    pv = jnp.where(sv, at_slot(state.obj_val), 0)
+    pe = jnp.where(sv, _slot_read(state.obj_epoch, slot_c), 0)
+    ps = jnp.where(sv, _slot_read(state.obj_seq, slot_c), 0)
+    pv = jnp.where(sv, _slot_read(state.obj_val, slot_c), 0)
 
     # Integrity gate (tree-is-truth, synctree.erl:44-73): the object
     # must match its leaf, and the slot's root-ward path must verify.
@@ -819,19 +916,10 @@ def _kv_round(state: EngineState, ctx: _KvCtx, kind: jax.Array,
     # columns move through HBM (in place inside the kv scan's carry).
     # Non-writing lanes aim out of bounds and are dropped, so clipped
     # invalid slots can never collide with a real lane's write.
-    eidx = jnp.arange(e, dtype=jnp.int32)[:, None, None]
-    midx = jnp.arange(ml, dtype=jnp.int32)[None, :, None]
     sl2 = jnp.where(do_write, slot_c[:, None, :], s)         # [E, Ml, W]
-
-    def set_slot(plane, new):
-        with jax.named_scope("slot_scatter"):
-            return plane.at[eidx, midx, sl2].set(
-                jnp.broadcast_to(new[:, None, :], (e, ml, w)),
-                mode="drop")
-
-    obj_epoch = set_slot(state.obj_epoch, w_epoch)
-    obj_seq = set_slot(state.obj_seq, w_seq)
-    obj_val = set_slot(state.obj_val, w_val)
+    obj_epoch = _slot_write(state.obj_epoch, sl2, w_epoch)
+    obj_seq = _slot_write(state.obj_seq, sl2, w_seq)
+    obj_val = _slot_write(state.obj_val, sl2, w_val)
     obj_seq_ctr = state.obj_seq_ctr + ranks[:, -1]
 
     # Synchronous tree maintenance: leaves + root-ward paths, same
@@ -1351,27 +1439,15 @@ full_step_donate = jax.jit(_full_step_body,
 
 # The sliced step's two edges address the A rows of a plane IN THE
 # LAYOUT THE PLANE IS STORED IN, so a launch reads and writes [., A]
-# and never [., E].  The chip stores an ``[E, M, S]`` int32 plane M
-# OUTERMOST (layout ``{2,0,1}``, tiles over (E, S): the M of 5 is not
-# padded to the tile's 8), while a gather or scatter along axis 0
-# wants E outermost: written as ``jnp.take(x, idx, axis=0)`` /
-# ``x.at[idx].set(...)`` the compiler relayouts the WHOLE plane both
-# ways on every launch (25.6 MB each at 10,000 x 5 x 128: three
-# quarters of the device's busy time, PERF.md section 6, PR 40).
-# Viewed as ``[M * E, S]`` rows (:func:`_peer_rows`) the stored plane
-# is a bitcast, the gather a row gather of A * M rows, and the scatter
-# one in-place fusion on the donated buffer.  Where the stored layout
-# is another (small S, a CPU) the view costs what the compiler makes
-# of it and the values are the same.
+# and never [., E]: an object plane as A * M rows of
+# :func:`_peer_rows` (see :func:`_as_stored`; ``jnp.take(x, idx,
+# axis=0)`` / ``x.at[idx].set(...)`` relayouted the WHOLE plane both
+# ways on every launch, three quarters of the device's busy time at
+# 10,000 x 5 x 128, PERF.md section 6, PR 40).  The gather is a row
+# gather and the scatter one in-place fusion on the donated buffer.
 
 #: the state planes a sliced launch addresses as rows
 _ROW_VIEWED = ("obj_epoch", "obj_seq", "obj_val")
-
-
-def _peer_rows(x: jax.Array) -> jax.Array:
-    """``[E, M, S]`` viewed as ``[M * E, S]``: row ``m * E + e``."""
-    e, m, s = x.shape
-    return jnp.transpose(x, (1, 0, 2)).reshape(m * e, s)
 
 
 def _take_peer_rows(x: jax.Array, idx_c: jax.Array) -> jax.Array:
@@ -1379,7 +1455,7 @@ def _take_peer_rows(x: jax.Array, idx_c: jax.Array) -> jax.Array:
     as A * M rows of :func:`_peer_rows`."""
     e, m, _ = x.shape
     rows = jnp.arange(m, dtype=idx_c.dtype)[:, None] * e + idx_c[None, :]
-    return jnp.transpose(jnp.take(_peer_rows(x), rows, axis=0), (1, 0, 2))
+    return _as_stored(jnp.take(_peer_rows(x), rows, axis=0))
 
 
 def _set_peer_rows(x: jax.Array, sub: jax.Array,
@@ -1395,9 +1471,9 @@ def _set_peer_rows(x: jax.Array, sub: jax.Array,
                      at * e + active_idx[None, :],
                      m * e + at * a + jnp.arange(a, dtype=active_idx.dtype))
     out = _peer_rows(x).at[rows.reshape(m * a)].set(
-        jnp.transpose(sub, (1, 0, 2)).reshape(m * a, s),
+        _as_stored(sub).reshape(m * a, s),
         mode="drop", unique_indices=True)
-    return jnp.transpose(out.reshape(m, e, s), (1, 0, 2))
+    return _as_stored(out.reshape(m, e, s))
 
 
 def _slice_columns(state: EngineState, active_idx: jax.Array,

@@ -102,19 +102,68 @@ def fold(children: jnp.ndarray) -> jnp.ndarray:
     scope on the device path; the host tree keeps cryptographic md5.
     """
     width = children.shape[-2]
-    # trace-time numpy constants: [width, 1] salts + odd multipliers
+    salt, mul = _fold_consts(width)
+    acc = _premix(children, salt, mul).sum(axis=-2, dtype=jnp.uint32)
+    return _seal(acc, width)
+
+
+def _fold_consts(width: int) -> Tuple[np.ndarray, np.ndarray]:
+    """Trace-time numpy constants of :func:`fold`: ``[width, 1]``
+    position salts and odd per-position multipliers."""
     pos = np.arange(width, dtype=np.uint32)
     salt = _fmix(pos * _C2 + np.uint32(0x9E3779B9))[:, None]
     mul = (_fmix(pos * _F1 + _C1) | np.uint32(1))[:, None]
+    return salt, mul
+
+
+def _premix(children, salt, mul):
+    """:func:`fold`'s per-child avalanche: ``[..., n, LANES]`` children
+    with their ``[n, 1]`` salts and multipliers, mixed independently."""
     lane = jnp.arange(LANES, dtype=jnp.uint32)
-    h = _fmix((children ^ salt) * mul + lane)
-    acc = h.sum(axis=-2, dtype=jnp.uint32)
+    return _fmix((children ^ salt) * mul + lane)
+
+
+def _seal(acc, width: int):
+    """:func:`fold`'s tail over the summed mixes ``[..., LANES]``."""
     # two cross-lane stirs: after roll(1)+fmix then roll(2), lane j
     # reads lanes {j, j-1, j-2, j-3} — a change in ANY input lane
     # avalanches every output lane (test_fold_avalanche pins ~50%)
     acc = _fmix(acc ^ jnp.roll(acc, 1, axis=-1))
     acc = acc ^ jnp.roll(acc, 2, axis=-1)
     return _fmix(acc ^ np.uint32(width))
+
+
+def fold_block(level: jnp.ndarray, block: jnp.ndarray,
+               width: int = 16) -> jnp.ndarray:
+    """:func:`fold` of ONE ``width``-block of a level, read where the
+    level lies: ``level [..., n, LANES]``, ``block [...]`` (broadcast
+    against the leading axes) → ``[..., LANES]``, bit-equal to
+    ``fold(pad(level)[..., block * width:(block + 1) * width, :])``
+    with the short last block zero-padded.
+
+    The fold is a position-salted mix SUMMED over the width, so the sum
+    over the whole level of the mixes masked to the block IS the
+    block's fold: one elementwise pass and a reduce along n, no gather.
+    That is what lets a caller leave a lane-dense ``[..., n, LANES]``
+    plane in the layout the chip stores it in (``ops/engine.py``,
+    "Merkle paths").
+    """
+    n = level.shape[-2]
+    pos = np.arange(n)
+    salt, mul = _fold_consts(width)
+    h = _premix(level, salt[pos % width], mul[pos % width])
+    in_block = (jnp.asarray(pos // width, jnp.int32)[:, None]
+                == block[..., None, None])
+    acc = jnp.where(in_block, h, np.uint32(0)).sum(axis=-2,
+                                                   dtype=jnp.uint32)
+    short = n % width
+    if short:
+        # what the zero children that pad the last block mix to
+        pad = _premix(jnp.zeros((width - short, LANES), jnp.uint32),
+                      salt[short:], mul[short:]).sum(0, dtype=jnp.uint32)
+        acc = acc + jnp.where((block == n // width)[..., None], pad,
+                              np.uint32(0))
+    return _seal(acc, width)
 
 
 def leaf_hash(epoch: jnp.ndarray, seq: jnp.ndarray) -> jnp.ndarray:

@@ -24,6 +24,7 @@ import jax.numpy as jnp  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
 from riak_ensemble_tpu.ops import engine as eng  # noqa: E402
+from riak_ensemble_tpu.ops import hash as hashk  # noqa: E402
 from riak_ensemble_tpu.ops import pallas_quorum  # noqa: E402
 
 E, M, S, V = 10_000, 5, 128, 2
@@ -143,16 +144,22 @@ def test_sliced_donated_step_compiles_at_headline_shape(one_chip):
 _MOVES = re.compile(r" (copy|copy-start|copy-done|slice-start)\(")
 
 
-def _whole_plane_moves(text, planes):
-    """``{op: count}`` of the compiled program's moves whose result
-    holds one of ``planes`` (shape prefixes such as ``s32[10000,5,128]``,
-    whatever the layout)."""
-    found = {}
+def _plane_moves(text, planes):
+    """``(op, name and result type)`` of each of the compiled
+    program's moves whose result holds one of ``planes`` (shape
+    prefixes such as ``s32[10000,5,128]``, whatever the layout)."""
     for line in text.splitlines():
         op = _MOVES.search(line)
         # what stands before the op is the instruction's name and type
         if op and any(p in line[:op.start()] for p in planes):
-            found[op.group(1)] = found.get(op.group(1), 0) + 1
+            yield op.group(1), line[:op.start()].strip()
+
+
+def _whole_plane_moves(text, planes):
+    """``{op: count}`` of :func:`_plane_moves`."""
+    found = {}
+    for op, _ in _plane_moves(text, planes):
+        found[op] = found.get(op, 0) + 1
     return found
 
 
@@ -209,6 +216,53 @@ def test_sliced_step_moves_no_object_plane(one_chip, record_property, k, a):
     print(f"k{k}a{a} temp_bytes {temp}")
     if (k, a) == (1, 8):        # 125.6 MB before ISSUE 40
         assert temp < 16e6
+
+
+#: `ycsb-a.ring64-n3-deep`: Riak's default ring with a deep keyspace
+DEEP_E, DEEP_M, DEEP_S = 64, 3, 65_536
+
+
+@pytest.mark.parametrize("k", [1, 2], ids=["k1", "k2"])
+def test_deep_ring_round_leaves_the_planes_where_they_lie(
+        one_chip, record_property, k):
+    """The donated full-width slab step every flush of the deep ring
+    launches (64 < `SLICE_MIN_E`), with and without the scan's `while`
+    (ISSUE 42).  The chip stores `tree_node` U minor-most
+    (`{2,3,1,0}`): a gather or scatter along U moved the whole plane
+    to a layout with the 4-wide LANES minor-most, padded to 128 lanes
+    (573 MB, every round); the object planes are stored M outermost
+    and were relayouted E outermost and back, every round.  The round
+    now reads and writes all four where they lie, and `tree_leaf`
+    (201 MB) keeps its gather and its scatter."""
+    e, m, s = DEEP_E, DEEP_M, DEEP_S
+    state = _placed(jax.eval_shape(lambda: eng.init_state(e, m, s)),
+                    one_chip)
+    u = state.tree_node.shape[2]
+    place = _on(one_chip)
+    compiled = eng.full_step_slab_donate.lower(
+        state, place("slab", (3 + 5 * k, e), jnp.int32),
+        place("up", (e, m), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert (" while(" in text) == (k > 1)
+    node = list(_plane_moves(text, (f"u32[{e},{m},{u},{hashk.LANES}]",)))
+    # `{3,...}`: dimension 3, LANES, minor-most
+    lanes_minor = [head for _, head in node
+                   if re.search(r"\]\{3,", head)]
+    objects = _whole_plane_moves(
+        text, (f"s32[{e},{m},{s}]", f"s32[{m},{e},{s}]",
+               f"s32[{e * m},{s}]"))
+    leaf = _whole_plane_moves(text, (f"u32[{e},{m},{s},{hashk.LANES}]",))
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    for name, value in (("tree_node_moves", [h for _, h in node]),
+                        ("object_plane_moves", objects),
+                        ("tree_leaf_moves", leaf), ("temp_bytes", temp)):
+        record_property(f"deep_k{k}_{name}", value)
+        print(f"deep_k{k} {name} {value}")
+    assert not lanes_minor, lanes_minor
+    assert not objects, objects
+    assert not leaf, leaf
+    # 578.5 MB at K 2 before ISSUE 42: `tree_node` padded to 128 lanes
+    assert temp < 100e6
 
 
 def test_step_with_pallas_quorum_lowers_the_kernel(one_chip, monkeypatch):
