@@ -85,6 +85,7 @@ import functools
 import operator
 import os
 import random
+import sys
 import time
 import weakref
 from collections import deque
@@ -528,6 +529,76 @@ class WallRuntime:
             "flush() from the caller")
 
 
+class _FreeSlots:
+    """One ensemble's allocatable slots, sized by what has been handed
+    out and not by the keyspace: slots ``[0, fresh)`` were never
+    allocated (the mark walks down from ``n_slots``) and ``recycled``
+    holds the ones handed back, which go out again first, last in
+    first out.  That is the order a list of every slot, popped from
+    its end, gave; ``len``, truth, ``pop`` and ``append`` are that
+    list's."""
+
+    __slots__ = ("fresh", "recycled")
+
+    def __init__(self, fresh: int, recycled: Optional[List[int]] = None
+                 ) -> None:
+        self.fresh = fresh
+        self.recycled: List[int] = [] if recycled is None else recycled
+
+    @classmethod
+    def unused(cls, n_slots: int, used) -> "_FreeSlots":
+        """Every slot of ``range(n_slots)`` that ``used`` (a set) does
+        not hold: the mark under its lowest slot, the gaps above it
+        ascending (so the highest goes out first)."""
+        fresh = min(used, default=n_slots)
+        return cls(fresh, [s for s in range(fresh + 1, n_slots)
+                           if s not in used])
+
+    @classmethod
+    def from_list(cls, slots: List[int]) -> "_FreeSlots":
+        """A checkpoint from before PR 43 lists every free slot, in
+        pop order from its end: the leading ``0, 1, 2, ...`` run is
+        the mark, the rest goes out first as it would have."""
+        fresh = 0
+        for s in slots:
+            if s != fresh:
+                break
+            fresh += 1
+        return cls(fresh, list(slots[fresh:]))
+
+    def __len__(self) -> int:
+        return self.fresh + len(self.recycled)
+
+    def pop(self) -> int:
+        if self.recycled:
+            return self.recycled.pop()
+        if not self.fresh:
+            raise IndexError("pop from an ensemble with no free slot")
+        self.fresh -= 1
+        return self.fresh
+
+    def append(self, slot: int) -> None:
+        self.recycled.append(slot)
+
+
+def _note(counts: Dict[int, int], slot: int) -> None:
+    """One more queued write on ``slot`` (a per-ensemble ``{slot:
+    count}`` that holds only the slots with something queued)."""
+    counts[slot] = counts.get(slot, 0) + 1
+
+
+def _unnote(counts: Dict[int, int], slot: int) -> None:
+    """One fewer; a slot at zero leaves the dict.  An unpaired
+    un-note is a bug, but it must park reads on the safe device
+    round, never underflow into "every later write is invisible":
+    a slot that is not there stays not there."""
+    c = counts.get(slot, 0)
+    if c > 1:
+        counts[slot] = c - 1
+    elif c:
+        del counts[slot]
+
+
 @dataclass(slots=True)
 class _PendingOp:
     kind: int
@@ -785,7 +856,13 @@ class BatchedEnsembleService:
         #: per-ens-shard blocks and active-column compaction computes
         #: its |A| bucket PER SHARD (compaction-aware sharding)
         self._mesh_shards = mesh_ens_shards(self.engine)
-        self.state = self.engine.init_state(n_ens, n_peers, n_slots)
+        # the device's planes and their first tree, built where they
+        # will live; waited for, so that the stamp is the build's
+        # seconds (the first launch would have waited for them)
+        t_start = time.perf_counter()
+        self.state = jax.block_until_ready(
+            self.engine.init_state(n_ens, n_peers, n_slots))
+        t_state = time.perf_counter()
         #: host failure detector input (set_peer_up)
         self.up = np.ones((n_ens, n_peers), dtype=bool)
         self._up_dev = None  # cached device copy (see _up_device)
@@ -807,8 +884,8 @@ class BatchedEnsembleService:
         self._pending_mask = np.zeros((n_ens,), dtype=bool)
         #: per-ensemble key→slot and free slots
         self.key_slot: List[Dict[Any, int]] = [dict() for _ in range(n_ens)]
-        self.free_slots: List[List[int]] = [
-            list(range(n_slots)) for _ in range(n_ens)]
+        self.free_slots: List[_FreeSlots] = [
+            _FreeSlots(n_slots) for _ in range(n_ens)]
         #: per-ensemble slot write generation: bumped on every queued
         #: put, so a delete's deferred recycle can tell whether a later
         #: write re-used the slot (then recycling would orphan it)
@@ -832,20 +909,23 @@ class BatchedEnsembleService:
         #: back to handle storage.
         self._inline_slots: List[set] = [set() for _ in range(n_ens)]
         #: slots with QUEUED (not yet resolved) host-payload writes:
-        #: flat per-slot count rows ([E][S], plain Python ints).  The
-        #: RMW fast-path eligibility must see these — slot_handle
-        #: only reflects COMMITTED writes, and a device RMW racing a
+        #: per ensemble a ``{slot: count}`` that holds only the slots
+        #: with something queued (``_note`` / ``_unnote``; a slot at
+        #: zero leaves it, so membership is the gate).  The RMW
+        #: fast-path eligibility must see these — slot_handle only
+        #: reflects COMMITTED writes, and a device RMW racing a
         #: same-flush kput would do int32 arithmetic on the put's
-        #: payload HANDLE (silent corruption).  SLAB-ROW layout (not
-        #: per-row dicts) so the enqueue/resolve halves note and
-        #: un-note whole batches by position; Python lists, not a
-        #: numpy plane, on purpose — the accesses are per-slot scalar
-        #: bumps, where a list indexes ~3x faster than a numpy cell
-        #: (measured; docs/ARCHITECTURE.md §12).  Advisory queue
-        #: state (reset with the queues, never persisted); drift only
-        #: parks a slot on the safe host path.
-        self._queued_handle_writes: List[List[int]] = [
-            [0] * n_slots for _ in range(n_ens)]
+        #: payload HANDLE (silent corruption).  Sized by the queue,
+        #: not by the keyspace: a count row per slot was 8 B of
+        #: pointer for every slot of every ensemble, all of them
+        #: walked by each full collector pass (PR 43; the accesses are
+        #: per-slot scalar bumps, 70 ns more an op on a dict than on
+        #: a list row and 380 ns less than on a numpy cell,
+        #: docs/ARCHITECTURE.md §12).  Advisory queue state (reset
+        #: with the queues, never persisted); drift only parks a slot
+        #: on the safe host path.
+        self._queued_handle_writes: List[Dict[int, int]] = [
+            dict() for _ in range(n_ens)]
         #: payload store: handle -> value (device carries handles).
         #: Handles are int32 on device and 0 is the tombstone sentinel,
         #: so released handles are recycled — a monotonically growing
@@ -909,15 +989,14 @@ class BatchedEnsembleService:
         #: tombstone): a fast read of a slot with any pending write
         #: falls back to the device round — the round orders it after
         #: the writes, and the mirror-before-ack discipline alone only
-        #: covers writes whose resolve already ran.  Flat [E][S]
-        #: count rows like ``_queued_handle_writes`` (same measured
-        #: list-vs-numpy-cell reasoning): the enqueue half notes a
-        #: whole batch's slots by position at ``_push`` time and the
-        #: completion-slab resolve un-notes every write lane at
-        #: settle — the PR 4 fast-read gate sees slab-enqueued
+        #: covers writes whose resolve already ran.  Per ensemble a
+        #: ``{slot: count}`` like ``_queued_handle_writes``: the
+        #: enqueue half notes a whole batch's slots at ``_push`` time
+        #: and the completion-slab resolve un-notes every write lane
+        #: at settle — the PR 4 fast-read gate sees slab-enqueued
         #: writes the moment they queue.
-        self._pending_writes: List[List[int]] = [
-            [0] * n_slots for _ in range(n_ens)]
+        self._pending_writes: List[Dict[int, int]] = [
+            dict() for _ in range(n_ens)]
         #: rows whose last resolve flagged synctree corruption: fast
         #: reads bypass to the device round (its integrity gate vets
         #: the read) until the exchange/scrub reports the row synced
@@ -1284,6 +1363,12 @@ class BatchedEnsembleService:
         self.controller = obs.RuntimeController(self)
         self._autotune = self.controller.enabled
         self._register_obs_metrics()
+        #: the start, stamped once (``stats()["startup"]``): seconds
+        #: building the device's planes and their first tree, and
+        #: seconds building everything this constructor holds on the
+        #: host
+        self.startup = {"state_init_s": t_state - t_start,
+                        "host_init_s": time.perf_counter() - t_state}
         self._schedule()
 
     # -- dynamic ensemble lifecycle ----------------------------------------
@@ -1389,16 +1474,16 @@ class BatchedEnsembleService:
         for h in self.slot_handle[row].values():
             self._release_handle(h)
         self.key_slot[row] = {}
-        self.free_slots[row] = list(range(self.n_slots))
+        self.free_slots[row] = _FreeSlots(self.n_slots)
         self.slot_gen[row] = {}
         self.slot_handle[row] = {}
         self._inline_slots[row] = set()
         self._inline_np[row] = False
-        self._queued_handle_writes[row] = [0] * self.n_slots
+        self._queued_handle_writes[row] = {}
         self._recycle_pending[row] = []
         self._slot_vsn_ok[row] = False
         self._inline_value_ok[row] = False
-        self._pending_writes[row] = [0] * self.n_slots
+        self._pending_writes[row] = {}
         self._corrupt_rows[row] = False
         self.elections_np[row] = 0
         # a recycled row starts with no watchers (the reference cleans
@@ -1516,7 +1601,7 @@ class BatchedEnsembleService:
             g_append(g)
         qh = self._queued_handle_writes[ens]
         for s in slot_l:
-            qh[s] += 1
+            qh[s] = qh.get(s, 0) + 1
         if miss_pos:
             accum.fill(fut, miss_pos, ["failed"] * len(miss_pos),
                        self._safe_resolve)
@@ -1584,7 +1669,7 @@ class BatchedEnsembleService:
             g_append(g)
         qh = self._queued_handle_writes[ens]
         for s in slot:
-            qh[s] += 1
+            qh[s] = qh.get(s, 0) + 1
         if miss_pos:
             accum.fill(fut, miss_pos, ["failed"] * len(miss_pos),
                        self._safe_resolve)
@@ -2405,7 +2490,7 @@ class BatchedEnsembleService:
         """(miss_reason, result) for one slot read off the committed
         host mirror; ``result`` is only valid when the reason is None.
         The caller has already passed :meth:`_fast_read_ok`."""
-        if self._pending_writes[ens][slot]:
+        if slot in self._pending_writes[ens]:
             return "pending_write", None
         vsn: Any = None
         if want_vsn:
@@ -2466,15 +2551,10 @@ class BatchedEnsembleService:
         return False
 
     def _note_write(self, ens: int, slot: int) -> None:
-        self._pending_writes[ens][slot] += 1
+        _note(self._pending_writes[ens], slot)
 
     def _unnote_write(self, ens: int, slot: int) -> None:
-        # clamped at 0 like the old dict pop — an unpaired un-note is
-        # a bug, but it must park reads on the safe device round, not
-        # underflow into "every later write is invisible"
-        row = self._pending_writes[ens]
-        if row[slot] > 0:
-            row[slot] -= 1
+        _unnote(self._pending_writes[ens], slot)
 
     def _rmw_eligible(self, ens: int, slot: int) -> bool:
         """A slot the device fast path may RMW: no QUEUED host-payload
@@ -2483,18 +2563,16 @@ class BatchedEnsembleService:
         arithmetic over a payload HANDLE (committed or about to
         commit earlier in the same flush) would corrupt the data
         while acking 'ok'."""
-        if self._queued_handle_writes[ens][slot]:
+        if slot in self._queued_handle_writes[ens]:
             return False
         return (slot in self._inline_slots[ens]
                 or self.slot_handle[ens].get(slot, 0) == 0)
 
     def _note_handle_write(self, ens: int, slot: int) -> None:
-        self._queued_handle_writes[ens][slot] += 1
+        _note(self._queued_handle_writes[ens], slot)
 
     def _unnote_handle_write(self, ens: int, slot: int) -> None:
-        row = self._queued_handle_writes[ens]
-        if row[slot] > 0:
-            row[slot] -= 1
+        _unnote(self._queued_handle_writes[ens], slot)
 
     def _push_rmw(self, ens: int, key: Any, slot: int,
                   dev: Tuple[int, int], fut: Future) -> None:
@@ -2855,7 +2933,11 @@ class BatchedEnsembleService:
             # must rebuild every tree (docs/MIGRATION.md).
             "hash_format": hashk.HASH_FORMAT,
             "key_slot": self.key_slot,
-            "free_slots": self.free_slots,
+            # (mark, recycled) per ensemble: PR 43's form; before it
+            # "free_slots" listed every free slot, and restore() still
+            # reads that
+            "free_marks": [(f.fresh, f.recycled)
+                           for f in self.free_slots],
             "slot_gen": self.slot_gen,
             "slot_handle": self.slot_handle,
             "inline_slots": [sorted(s) for s in self._inline_slots],
@@ -2963,7 +3045,12 @@ class BatchedEnsembleService:
                 svc.state,
                 jnp.ones((svc.n_ens, svc.n_peers), bool))
         svc.key_slot = host["key_slot"]
-        svc.free_slots = host["free_slots"]
+        if "free_marks" in host:
+            svc.free_slots = [_FreeSlots(fresh, recycled)
+                              for fresh, recycled in host["free_marks"]]
+        else:
+            svc.free_slots = [_FreeSlots.from_list(slots)
+                              for slots in host["free_slots"]]
         svc.slot_gen = host["slot_gen"]
         svc.slot_handle = host["slot_handle"]
         svc._inline_slots = [set(s) for s in host.get(
@@ -3156,9 +3243,8 @@ class BatchedEnsembleService:
         # slots are live; everything else — including tombstoned
         # slots — is allocatable).
         for ens in range(e_):
-            used = set(self.key_slot[ens].values())
-            self.free_slots[ens] = [s for s in range(s_)
-                                    if s not in used]
+            self.free_slots[ens] = _FreeSlots.unused(
+                s_, set(self.key_slot[ens].values()))
         # Ballot epochs >= newest installed object epoch per ensemble.
         epoch = np.maximum(epoch, obj_epoch.max(-1))
         state = self.state._replace(
@@ -3273,10 +3359,10 @@ class BatchedEnsembleService:
         never serves a lease-protected fast read."""
         if op.kind != eng.OP_GET:
             if isinstance(op, _PendingBatch):
-                # whole-batch note on the [E][S] slab row
+                # whole-batch note on the ensemble's counts
                 pw = self._pending_writes[ens]
                 for s in op.slot:
-                    pw[s] += 1
+                    pw[s] = pw.get(s, 0) + 1
             else:
                 self._note_write(ens, op.slot)
             if self._storage_degraded is not None:
@@ -4074,6 +4160,8 @@ class BatchedEnsembleService:
             # (obs.spans; ARCHITECTURE §11)
             "frontend": self._frontend_stats(),
             "gc": self._gc_watch.stats(),
+            "startup": dict(self.startup),
+            "slots": self._slot_stats(),
             "flight_anomalies": self.flight.anomalies,
             "tenants": self.tenant_stats(top=8),
             # native single-pass resolve kernel (ARCHITECTURE §12):
@@ -4108,6 +4196,29 @@ class BatchedEnsembleService:
             },
             **self._mesh_stats(),
         }
+
+    def _slot_stats(self) -> Dict[str, int]:
+        """``stats()["slots"]``: the keyspace (every slot of every
+        ensemble), the slots keys hold, the ones handed back and
+        waiting to go out again, and the bytes of the host's per-slot
+        structures: the five mirror slabs (zero pages until a slot is
+        written) and what the free-slot marks and the queued-write
+        counts hold, which follows what is in use."""
+        sized = sys.getsizeof
+        fresh = recycled = 0
+        held = sum(a.nbytes for a in (
+            self._slot_vsn_np, self._slot_vsn_ok, self._inline_value_np,
+            self._inline_value_ok, self._inline_np))
+        for f in self.free_slots:
+            fresh += f.fresh
+            recycled += len(f.recycled)
+            held += sized(f.recycled)
+        held += sum(map(sized, self._pending_writes))
+        held += sum(map(sized, self._queued_handle_writes))
+        keyspace = self.n_ens * self.n_slots
+        return {"keyspace": keyspace,
+                "in_use": keyspace - fresh - recycled,
+                "recycled": recycled, "host_bytes": held}
 
     def _frontend_stats(self) -> Dict[str, Any]:
         """The front end's counters and, where a server stamps its
@@ -4206,9 +4317,7 @@ class BatchedEnsembleService:
                 "committed_epoch": committed[0],
                 "committed_seq": committed[1],
                 "queued_ops": int(self._queue_rounds[ens]),
-                "pending_writes": (self.n_slots
-                                   - self._pending_writes[ens]
-                                   .count(0)),
+                "pending_writes": len(self._pending_writes[ens]),
                 "live_keys": len(self.key_slot[ens]),
                 "tenant": self.tenant_label(ens),
             }
@@ -4230,9 +4339,7 @@ class BatchedEnsembleService:
                             if self._wal is not None else None),
             "queued_ops": int(sum(self._queue_rounds)),
             "launches_in_flight": len(self._inflight_launches),
-            "pending_writes": int(sum(
-                self.n_slots - row.count(0)
-                for row in self._pending_writes)),
+            "pending_writes": sum(map(len, self._pending_writes)),
             "live_payloads": len(self.values),
             "flushes": int(self.flushes),
             "ops_served": int(self.ops_served),
@@ -6234,13 +6341,12 @@ class BatchedEnsembleService:
                 h = handle_l[i]
                 s = slot_l[i]
                 # every op un-notes (committed or not), exactly like
-                # the oracle loop — clamped at 0 like _unnote_write
-                # (an unpaired un-note must park reads on the device
-                # round, never underflow)
-                if pw[s] > 0:
-                    pw[s] -= 1
-                if h and qh[s] > 0:
-                    qh[s] -= 1
+                # the oracle loop (``_unnote``: an unpaired un-note
+                # must park reads on the device round, never
+                # underflow)
+                _unnote(pw, s)
+                if h:
+                    _unnote(qh, s)
                 if not comm:
                     release(h)
                     if keys[i] is not None:
@@ -6282,8 +6388,7 @@ class BatchedEnsembleService:
             pw = self._pending_writes[e]
             for i, comm in enumerate(comm_l):
                 s = slot_l[i]
-                if pw[s] > 0:  # clamped, like _unnote_write
-                    pw[s] -= 1
+                _unnote(pw, s)
                 if not comm:
                     if keys[i] is not None:
                         recycle((keys[i], s, gen_l[i]))

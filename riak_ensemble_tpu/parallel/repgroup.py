@@ -148,7 +148,7 @@ from riak_ensemble_tpu import faults, funref, obs, wire
 from riak_ensemble_tpu.config import Config
 from riak_ensemble_tpu.ops import engine as eng
 from riak_ensemble_tpu.parallel.batched_host import (
-    BatchedEnsembleService, WallRuntime, _PendingBatch,
+    BatchedEnsembleService, WallRuntime, _FreeSlots, _PendingBatch,
     warmup_kernels)
 from riak_ensemble_tpu.types import NOTFOUND
 from riak_ensemble_tpu.utils.jaxcache import setup_compile_cache
@@ -309,9 +309,8 @@ def rebuild_derived(svc: BatchedEnsembleService) -> None:
     The pending-write index is NOT cleared: it tracks live queue
     entries, which survive an install and still resolve afterward."""
     for e in range(svc.n_ens):
-        used = set(svc.key_slot[e].values())
-        svc.free_slots[e] = [s for s in range(svc.n_slots)
-                             if s not in used]
+        svc.free_slots[e] = _FreeSlots.unused(
+            svc.n_slots, set(svc.key_slot[e].values()))
         svc.slot_gen[e] = {}
         svc._recycle_pending[e] = []
         svc._slot_vsn_ok[e] = False

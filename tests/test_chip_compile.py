@@ -218,13 +218,18 @@ def test_sliced_step_moves_no_object_plane(one_chip, record_property, k, a):
         assert temp < 16e6
 
 
-#: `ycsb-a.ring64-n3-deep`: Riak's default ring with a deep keyspace
-DEEP_E, DEEP_M, DEEP_S = 64, 3, 65_536
+#: Riak's default ring with a deep keyspace: `ycsb-a.ring64-n3-deep`
+#: (height 4) and `ycsb-a.ring64-n3-h5`, the synctree's own 1M
+#: segments (height 5, 5.85 GB of state); with the most the step may
+#: need beside its arguments at each
+DEEP_E, DEEP_M = 64, 3
+DEEP_SLOTS = {"s64k": (65_536, 100e6), "s1m": (1_048_576, 1e9)}
 
 
+@pytest.mark.parametrize("shape", list(DEEP_SLOTS))
 @pytest.mark.parametrize("k", [1, 2], ids=["k1", "k2"])
 def test_deep_ring_round_leaves_the_planes_where_they_lie(
-        one_chip, record_property, k):
+        one_chip, record_property, k, shape):
     """The donated full-width slab step every flush of the deep ring
     launches (64 < `SLICE_MIN_E`), with and without the scan's `while`
     (ISSUE 42).  The chip stores `tree_node` U minor-most
@@ -233,8 +238,10 @@ def test_deep_ring_round_leaves_the_planes_where_they_lie(
     (573 MB, every round); the object planes are stored M outermost
     and were relayouted E outermost and back, every round.  The round
     now reads and writes all four where they lie, and `tree_leaf`
-    (201 MB) keeps its gather and its scatter."""
-    e, m, s = DEEP_E, DEEP_M, DEEP_S
+    (201 MB) keeps its gather and its scatter.  At 1M slots (ISSUE 43)
+    the same holds and the whole program, arguments and temporaries,
+    stays under half a chip."""
+    e, m, (s, temp_limit) = DEEP_E, DEEP_M, DEEP_SLOTS[shape]
     state = _placed(jax.eval_shape(lambda: eng.init_state(e, m, s)),
                     one_chip)
     u = state.tree_node.shape[2]
@@ -252,17 +259,21 @@ def test_deep_ring_round_leaves_the_planes_where_they_lie(
         text, (f"s32[{e},{m},{s}]", f"s32[{m},{e},{s}]",
                f"s32[{e * m},{s}]"))
     leaf = _whole_plane_moves(text, (f"u32[{e},{m},{s},{hashk.LANES}]",))
-    temp = compiled.memory_analysis().temp_size_in_bytes
+    mem = compiled.memory_analysis()
+    temp, args = mem.temp_size_in_bytes, mem.argument_size_in_bytes
     for name, value in (("tree_node_moves", [h for _, h in node]),
                         ("object_plane_moves", objects),
-                        ("tree_leaf_moves", leaf), ("temp_bytes", temp)):
-        record_property(f"deep_k{k}_{name}", value)
-        print(f"deep_k{k} {name} {value}")
+                        ("tree_leaf_moves", leaf), ("temp_bytes", temp),
+                        ("argument_bytes", args)):
+        record_property(f"deep_{shape}_k{k}_{name}", value)
+        print(f"deep_{shape}_k{k} {name} {value}")
     assert not lanes_minor, lanes_minor
     assert not objects, objects
     assert not leaf, leaf
-    # 578.5 MB at K 2 before ISSUE 42: `tree_node` padded to 128 lanes
-    assert temp < 100e6
+    # at 65,536 slots 578.5 MB at K 2 before ISSUE 42 (`tree_node`
+    # padded to 128 lanes); at 1M slots 9,169 MB
+    assert temp < temp_limit
+    assert args + temp < 8e9
 
 
 def test_step_with_pallas_quorum_lowers_the_kernel(one_chip, monkeypatch):

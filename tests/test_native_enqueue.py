@@ -110,8 +110,8 @@ def _run_arm(arm, seed, monkeypatch, pipeline_depth=1):
         "inl_np": svc._inline_value_np.copy(),
         "inline_np": svc._inline_np.copy(),
         "inline_sets": [sorted(s) for s in svc._inline_slots],
-        "pending_writes": [list(r) for r in svc._pending_writes],
-        "queued_handle": [list(r)
+        "pending_writes": [dict(r) for r in svc._pending_writes],
+        "queued_handle": [dict(r)
                           for r in svc._queued_handle_writes],
         "slot_handle": [dict(d) for d in svc.slot_handle],
         "stats": svc.stats(),
@@ -157,8 +157,8 @@ def test_two_arm_equivalence(seed, depth, monkeypatch):
     assert a["inline_sets"] == b["inline_sets"]
     assert a["slot_handle"] == b["slot_handle"]
     # every queued write was un-noted by exactly one resolve/fail arm
-    assert not any(map(any, a["pending_writes"]))
-    assert not any(map(any, a["queued_handle"]))
+    assert not any(a["pending_writes"])
+    assert not any(a["queued_handle"])
 
 
 @needs_kernel

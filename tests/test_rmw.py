@@ -569,7 +569,7 @@ def test_kmodify_after_unflushed_kput_keeps_host_path():
     _drive(svc, [g])
     assert g.value == ("ok", b"payload")
     # ...and the queue-state bookkeeping drains with the ops
-    assert not any(svc._queued_handle_writes[0])
+    assert not svc._queued_handle_writes[0]
 
 
 def test_tenant_export_settles_pipeline_first():
