@@ -483,6 +483,8 @@ def mesh_placement(svc) -> dict:
         _backend_mem_bytes_per_device)
 
     for name, leaf in zip(svc.state._fields, svc.state):
+        if leaf is None:  # no row plane at this shape
+            continue
         shards = leaf.addressable_shards
         check(len({s.device for s in shards}) == 4
               and all(s.data.shape[0] * 4 == leaf.shape[0]

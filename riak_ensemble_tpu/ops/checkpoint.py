@@ -27,24 +27,40 @@ def save(path: str, state: EngineState) -> None:
 
     path = os.path.abspath(path)
     ckptr = ocp.PyTreeCheckpointer()
-    ckptr.save(path, state._asdict(), force=True)
+    ckptr.save(path, _planes(state), force=True)
 
 
-def load(path: str, template: Optional[EngineState] = None) -> EngineState:
+def _planes(state: EngineState) -> dict:
+    """The state's arrays by field: a state without a row plane
+    (``tree_rows`` None) writes and reads no such entry."""
+    return {k: v for k, v in state._asdict().items() if v is not None}
+
+
+def load(path: str, template: Optional[EngineState] = None,
+         flat_trees: bool = False) -> EngineState:
     """Restore a checkpoint.  ``template`` (an ``init_state`` of the
     same shapes) restores each array DIRECTLY onto the template
     leaf's sharding — so a checkpoint taken under one device
     placement restores onto another (mesh-sharded save → single-shard
     serve and back) without inheriting the save-time placement from
     the file.  Without a template, arrays come back with saved
-    metadata."""
+    metadata.
+
+    ``flat_trees``: the image was written before the tree's storage
+    stamp (``engine.TREE_FORM``) and holds every upper level flat in
+    ``tree_node``, in a shape the template may not have.  Its upper
+    levels are left on disk and the TEMPLATE's are returned in their
+    place: the caller rebuilds every tree from the restored object
+    planes (docs/MIGRATION.md)."""
     import jax
     import orbax.checkpoint as ocp
 
     path = os.path.abspath(path)
     ckptr = ocp.PyTreeCheckpointer()
     if template is not None:
-        tpl = template._asdict()
+        tpl = _planes(template)
+        if flat_trees:
+            return template._replace(**_load_but_the_trees(ckptr, path, tpl))
         restore_args = jax.tree.map(
             lambda x: ocp.ArrayRestoreArgs(sharding=x.sharding)
             if isinstance(x, jax.Array) else ocp.RestoreArgs(), tpl)
@@ -53,3 +69,23 @@ def load(path: str, template: Optional[EngineState] = None) -> EngineState:
     else:
         restored = ckptr.restore(path)
     return EngineState(**restored)
+
+
+def _load_but_the_trees(ckptr, path: str, tpl: dict) -> dict:
+    """Every plane of the image but its upper levels, onto the
+    template's shardings."""
+    import jax
+    import numpy as np
+    import orbax.checkpoint as ocp
+
+    kept = {k: v for k, v in tpl.items()
+            if k not in ("tree_node", "tree_rows")}
+    # orbax reads an image whole: the flat node plane goes to the host
+    # as numpy and is dropped
+    item = dict(kept, tree_node=0)
+    restore_args = dict(
+        jax.tree.map(lambda x: ocp.ArrayRestoreArgs(sharding=x.sharding),
+                     kept),
+        tree_node=ocp.RestoreArgs(restore_type=np.ndarray))
+    restored = ckptr.restore(path, item=item, restore_args=restore_args)
+    return {k: restored[k] for k in kept}

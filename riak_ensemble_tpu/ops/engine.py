@@ -29,8 +29,10 @@ transitions are jitted array kernels:
 
 **Integrity is on the data path** (the synctree tree-is-truth design,
 ``src/synctree.erl:44-73``): every replica carries a Merkle trie over
-its slot store — ``tree_leaf`` (per-slot object hashes) plus
-``tree_node`` (the upper levels, root last).  Every committed write
+its slot store — ``tree_leaf`` (per-slot object hashes) plus the upper
+levels, stored by size ("Merkle paths" below): ``tree_rows`` (the
+levels of 128 nodes and more, as rows of 128 nodes) and ``tree_node``
+(the levels under 128, root last).  Every committed write
 updates the leaf AND recomputes its root-ward path in the same kernel
 (the always-up-to-date write-path property — ``put_obj`` →
 ``update_hash``/``send_update_hash``, peer.erl:1669-1715); every read
@@ -169,10 +171,14 @@ class EngineState(NamedTuple):
     its local peer slice; ``leader``/``obj_seq_ctr`` are replicated
     along 'peer'.
 
-    ``tree_leaf``/``tree_node`` are each replica's synctree: leaf k is
-    the hash of the replica's object at slot k; ``tree_node`` holds the
-    upper levels flattened leafward→root (sizes from
-    :func:`tree_sizes`).  Maintained synchronously by the K/V kernels.
+    ``tree_leaf``/``tree_rows``/``tree_node`` are each replica's
+    synctree: leaf k is the hash of the replica's object at slot k;
+    the upper levels (sizes from :func:`tree_sizes`) are stored by
+    size (:func:`tree_layout`): those of 128 nodes and more as rows of
+    128 nodes in ``tree_rows``, those under 128, root last, flat in
+    ``tree_node``.  A keyspace of 2,032 slots or fewer has no level of
+    128 nodes and NO row plane: ``tree_rows`` is None, no leaf of the
+    pytree.  Maintained synchronously by the K/V kernels.
     """
 
     epoch: jax.Array        # [E, M] int32  per-peer current epoch
@@ -189,7 +195,9 @@ class EngineState(NamedTuple):
     obj_seq: jax.Array      # [E, M, S] int32  replica store: obj seqs
     obj_val: jax.Array      # [E, M, S] int32  replica store: payloads
     tree_leaf: jax.Array    # [E, M, S, LANES] uint32  Merkle leaf hashes
-    tree_node: jax.Array    # [E, M, U, LANES] uint32  upper levels, flat
+    tree_node: jax.Array    # [E, M, T, LANES] uint32  levels < 128 nodes
+    tree_rows: Optional[jax.Array] = None  # [E, M, R, 512] uint32  levels
+    #                         of >= 128 nodes, a row = 128 nodes x LANES
 
 
 class KvResult(NamedTuple):
@@ -219,7 +227,10 @@ def state_specs(ens: Optional[str] = "ens",
 
     ``ens``/``peer`` name the mesh axes the E and M dims shard over
     (None = replicated along that axis).  Field ↔ spec table lives in
-    docs/ARCHITECTURE.md §17.
+    docs/ARCHITECTURE.md §17.  A state without a row plane
+    (``tree_rows`` None) takes these specs as they are: a spec tree is
+    a PREFIX of what it places, and a leaf of it covers an empty
+    subtree.
     """
     from jax.sharding import PartitionSpec as P
     return EngineState(
@@ -236,6 +247,7 @@ def state_specs(ens: Optional[str] = "ens",
         obj_val=P(ens, peer, None),
         tree_leaf=P(ens, peer, None, None),
         tree_node=P(ens, peer, None, None),
+        tree_rows=P(ens, peer, None, None),
     )
 
 
@@ -304,35 +316,179 @@ def _fold_blocks(x: jax.Array) -> jax.Array:
                                                 hashk.LANES)))
 
 
-def build_uppers(leaves: jax.Array) -> jax.Array:
+def _build_levels(leaves: jax.Array) -> list:
     """Bottom-up rebuild of the upper levels from ``[..., S, LANES]``
-    leaves → flat ``[..., U, LANES]`` (the ``rehash`` role,
+    leaves, leafward → root (the ``rehash`` role,
     synctree.erl:489-535, one fused pass)."""
     outs = []
     cur = leaves
     for _ in tree_sizes(leaves.shape[-2]):
         cur = _fold_blocks(cur)
         outs.append(cur)
-    return jnp.concatenate(outs, axis=-2) if len(outs) > 1 else outs[0]
+    return outs
 
 
-# Merkle paths.  The chip stores ``tree_node [E, M, U, LANES]`` U
-# minor-most and lane-dense (13.4 MB at 64 x 3 x 65,536).  A gather or
-# a scatter along U wants the 4-wide LANES minor-most instead, padded
-# to the tile's 128 lanes: written as ``take_along_axis`` /
-# ``.at[].set`` the compiler moved the whole plane to that layout and
-# back in EVERY round of the scan, 573 MB each way (PERF.md section 6,
-# PR 42).  So the round reads and writes the node plane where it lies,
-# by masks over U (:func:`_hit`): a parent recomputed from a node level
-# is :func:`hash.fold_block` (the fold's mixes masked to the block, one
-# pass), the path's stored hashes are compared with the recomputed ones
-# in one pass over the plane (extracting them, a one-hot sum per level
-# slice, brought a relayout back), and the path write is one select
-# over the plane.  A masked pass costs the plane's own size once, and
-# ``tree_node`` is a fifteenth of ``tree_leaf`` at every shape.
-# ``tree_leaf`` (201 MB there) is what must NOT be passed over: the
-# slot's leaf and the 16 leaves under its first parent are gathered,
-# and its write is a scatter, as before.
+def build_uppers(leaves: jax.Array) -> jax.Array:
+    """:func:`_build_levels` as one flat ``[..., U, LANES]`` array: the
+    plain form of a tree's upper levels, which the state stores as
+    :func:`levels_to_rows` lays them out."""
+    return jnp.concatenate(_build_levels(leaves), axis=-2)
+
+
+#: nodes in a row of ``tree_rows``: the chip's lane width (the 128 of
+#: every ``T(., 128)`` tile), not a knob
+ROW_NODES = 128
+ROW_WORDS = ROW_NODES * hashk.LANES
+
+#: how a checkpoint's upper levels are stored, stamped beside
+#: ``hash_format``: 2 = ``tree_rows`` + ``tree_node`` (:func:`tree_layout`);
+#: an image without the stamp holds them flat in ``tree_node`` and is
+#: restored by rebuilding every tree (docs/MIGRATION.md)
+TREE_FORM = 2
+
+
+class TreeLayout(NamedTuple):
+    """Where an ``n_slots``-leaf trie's upper levels are stored."""
+
+    sizes: Tuple[int, ...]      # every upper level, leafward → root
+    row_levels: int             # the first L of them are stored as rows
+    row_offs: Tuple[int, ...]   # [L] each row level's first row
+    rows: int                   # R: rows a replica holds (0: no plane)
+    tail_offs: Tuple[int, ...]  # the other levels' offsets in tree_node
+    tail_nodes: int             # T
+
+
+@functools.lru_cache(maxsize=None)
+def tree_layout(n_slots: int) -> TreeLayout:
+    """A level of n >= 128 nodes takes ``ceil(n / 128)`` rows of
+    ``tree_rows``, starting on a row of its own, a short last row
+    padded with zero hashes (what :func:`_fold_blocks` pads with).
+    Width 16 divides 128, so the 16 children of any parent lie inside
+    ONE row, and that row also holds the path's own node of the level.
+    R is padded to a multiple of 8, so that ``[E, M, R, 512]`` viewed
+    ``[E * M * R, 512]`` is a bitcast of the chip's (8, 128) tiles.
+    The levels under 128 nodes (at most 127 + 8 + 1 nodes) stay flat
+    in ``tree_node``, root last."""
+    sizes = tree_sizes(n_slots)
+    n_row = sum(n >= ROW_NODES for n in sizes)
+    row_offs, rows = [], 0
+    for n in sizes[:n_row]:
+        row_offs.append(rows)
+        rows += -(-n // ROW_NODES)
+    tail_offs, tail = [], 0
+    for n in sizes[n_row:]:
+        tail_offs.append(tail)
+        tail += n
+    return TreeLayout(sizes, n_row, tuple(row_offs), -(-rows // 8) * 8,
+                      tuple(tail_offs), tail)
+
+
+def levels_to_rows(levels: Sequence[jax.Array]
+                   ) -> Tuple[Optional[jax.Array], jax.Array]:
+    """The upper levels (``[..., n, LANES]`` each, leafward → root) as
+    the state stores them: ``(tree_rows [..., R, 512] or None,
+    tree_node [..., T, LANES])``.  Inside a row the 128 nodes are the
+    MINOR axis (word ``lane * 128 + node``): a gathered row is viewed
+    ``[LANES, 128]`` with its nodes on the chip's lanes."""
+    sizes = tuple(lv.shape[-2] for lv in levels)
+    n_row = sum(n >= ROW_NODES for n in sizes)
+    lead = levels[0].shape[:-2]
+    rows = []
+    for lv in levels[:n_row]:
+        nr = -(-lv.shape[-2] // ROW_NODES)
+        pad = nr * ROW_NODES - lv.shape[-2]
+        if pad:
+            lv = jnp.concatenate(
+                [lv, jnp.zeros(lead + (pad, hashk.LANES), jnp.uint32)],
+                axis=-2)
+        lv = lv.reshape(lead + (nr, ROW_NODES, hashk.LANES))
+        rows.append(jnp.swapaxes(lv, -1, -2).reshape(
+            lead + (nr, ROW_WORDS)))
+    tail = jnp.concatenate(list(levels[n_row:]), axis=-2)
+    if not rows:
+        return None, tail
+    used = sum(r.shape[-2] for r in rows)
+    pad = -(-used // 8) * 8 - used
+    if pad:
+        rows.append(jnp.zeros(lead + (pad, ROW_WORDS), jnp.uint32))
+    return jnp.concatenate(rows, axis=-2), tail
+
+
+def rows_to_levels(tree_rows: Optional[jax.Array], tree_node: jax.Array,
+                   n_slots: int) -> list:
+    """:func:`levels_to_rows` back: the stored form as the list of
+    ``[..., n, LANES]`` levels, leafward → root."""
+    lay = tree_layout(n_slots)
+    lead = tree_node.shape[:-2]
+    out = []
+    for off, n in zip(lay.row_offs, lay.sizes):
+        nr = -(-n // ROW_NODES)
+        lv = jax.lax.slice_in_dim(tree_rows, off, off + nr, axis=-2)
+        lv = jnp.swapaxes(
+            lv.reshape(lead + (nr, hashk.LANES, ROW_NODES)), -1, -2)
+        out.append(lv.reshape(lead + (nr * ROW_NODES, hashk.LANES))
+                   [..., :n, :])
+    for off, n in zip(lay.tail_offs, lay.sizes[lay.row_levels:]):
+        out.append(jax.lax.slice_in_dim(tree_node, off, off + n, axis=-2))
+    return out
+
+
+def _build_tree(leaves: jax.Array
+                ) -> Tuple[Optional[jax.Array], jax.Array]:
+    """``(tree_rows, tree_node)`` rebuilt bottom-up from the leaves."""
+    return levels_to_rows(_build_levels(leaves))
+
+
+def _trees_differ(state: "EngineState", rows: Optional[jax.Array],
+                  tail: jax.Array) -> jax.Array:
+    """``[E, Ml]``: a replica's stored upper levels are not ``(rows,
+    tail)``."""
+    bad = (tail != state.tree_node).any((-1, -2))
+    if rows is not None:
+        bad = bad | (rows != state.tree_rows).any((-1, -2))
+    return bad
+
+
+def _select_trees(mask: jax.Array, rows: Optional[jax.Array],
+                  tail: jax.Array, state: "EngineState"
+                  ) -> Tuple[Optional[jax.Array], jax.Array]:
+    """``(rows, tail)`` on the replicas in ``mask [E, Ml]``, the
+    state's own upper levels elsewhere."""
+    m4 = mask[:, :, None, None]
+    return (None if rows is None
+            else jnp.where(m4, rows, state.tree_rows),
+            jnp.where(m4, tail, state.tree_node))
+
+
+# Merkle paths.  What is stored where, and why 128.  The chip tiles
+# every plane (8, 128) over its two minor-most axes and stores a
+# lane-dense ``[E, M, U, LANES]`` U minor-most.  A gather or a scatter
+# along U wants the 4-wide LANES minor-most instead, padded to the
+# tile's 128 lanes: written as ``take_along_axis`` / ``.at[].set`` the
+# compiler moved the whole plane to that layout and back in EVERY round
+# of the scan (PERF.md section 6, PR 42).  Masks over U
+# (:func:`_hit`) leave the plane where it lies, and a masked pass costs
+# the plane's own bytes once: nothing at 136 nodes, 13-14 passes over
+# 215 MB a round at 69,905 (3.55 ms, PR 43).  No formulation over the
+# logical shape ``[E, M, U, LANES]`` does both (PERF.md section 6,
+# PR 44's table), so the big levels are STORED otherwise: a level of
+# 128 nodes or more as rows of 128 nodes (:func:`tree_layout`), one
+# plane ``tree_rows [E, M, R, 512]`` whose 2-D view ``[E * M * R, 512]``
+# is a bitcast, exactly the object planes' :func:`_peer_rows`.  A round
+# GATHERS the one row per row level per replica that its path crosses
+# (:func:`_gather_path_rows`: 576 rows of 2 KB at 64 x 3 x 1,048,576),
+# folds, compares and substitutes inside the gathered block with its
+# 128 nodes on the lanes (:func:`hash.fold_block`, ``nodes_last``), and
+# scatters the rows back in place on the scan's carry.  The levels
+# under 128 nodes keep the masks over ``tree_node``: a parent
+# recomputed from a node level is :func:`hash.fold_block`, the path's
+# stored hashes are compared with the recomputed ones in one pass
+# (extracting them, a one-hot sum per level slice, brought a relayout
+# back), and the path write is one select.  128 is the lane width and
+# the only threshold: a shorter row would pad to it, a longer one has
+# no tile to be a row of.  ``tree_leaf`` (3.2 GB there) is what must
+# NEVER be passed over: the slot's leaf and the 16 leaves under its
+# first parent are gathered, and its write is a scatter.
 
 
 def _gather_children(arr: jax.Array, parent_idx: jax.Array,
@@ -360,63 +516,139 @@ def _hit(n: int, idx: jax.Array) -> jax.Array:
 
 
 def _node_levels(tree_node: jax.Array, s: int):
-    """``(offset, level [E, Ml, n, LANES])`` of the flat node plane,
-    leafward → root."""
-    offs, _ = _tree_offsets(s)
+    """``(offset, level [E, Ml, n, LANES])`` of the levels that
+    ``tree_node`` holds flat (those under 128 nodes), leafward → root."""
+    lay = tree_layout(s)
     return [(off, jax.lax.slice_in_dim(tree_node, off, off + n, axis=2))
-            for off, n in zip(offs, tree_sizes(s))]
+            for off, n in zip(lay.tail_offs, lay.sizes[lay.row_levels:])]
+
+
+def _path_row_index(s: int, e: int, ml: int, slot: jax.Array
+                    ) -> jax.Array:
+    """``[E, Ml, L]``: the row of the 2-D view ``[E * Ml * R, 512]``
+    that holds the path's node (and that node's 15 siblings) at each
+    row level, ascending in that order."""
+    lay = tree_layout(s)
+    idx = slot[:, 0] // TREE_WIDTH                           # [E]
+    at = []
+    for off in lay.row_offs:
+        at.append(off + idx // ROW_NODES)
+        idx = idx // TREE_WIDTH
+    replica = (jnp.arange(e, dtype=jnp.int32)[:, None] * ml
+               + jnp.arange(ml, dtype=jnp.int32))            # [E, Ml]
+    return (replica * lay.rows)[:, :, None] + jnp.stack(at, -1)[:, None]
+
+
+def _gather_path_rows(tree_rows: Optional[jax.Array], s: int,
+                      slot: jax.Array) -> Optional[jax.Array]:
+    """The rows a round's paths cross, ``[E, Ml, L, LANES, 128]``: ONE
+    gather on the 2-D view for all row levels, which the verify and the
+    write of the round both read.  ``slot [E, 1]`` in range; None where
+    the state has no row plane."""
+    if tree_rows is None:
+        return None
+    e, ml, r, _ = tree_rows.shape
+    if slot.shape[1] != 1:
+        raise NotImplementedError(
+            "the gathered path takes one lane (ROADMAP D12)")
+    ridx = _path_row_index(s, e, ml, slot)
+    got = tree_rows.reshape(e * ml * r, ROW_WORDS).at[
+        ridx.reshape(-1)].get(unique_indices=True, indices_are_sorted=True,
+                              mode="promise_in_bounds")
+    return got.reshape(e, ml, ridx.shape[2], hashk.LANES, ROW_NODES)
+
+
+def _row_hit(idx: jax.Array) -> jax.Array:
+    """One-hot of node ``idx [E]`` inside its row, shaped to mask a
+    gathered row ``[E, Ml, LANES, 128]``: ``[E, 1, 1, 128]`` bool."""
+    at = jnp.arange(ROW_NODES, dtype=jnp.int32)
+    return (at == (idx % ROW_NODES)[:, None])[:, None, None, :]
+
+
+def _row_parent(row: jax.Array, pidx: jax.Array) -> jax.Array:
+    """Parent ``pidx [E]`` folded from its 16 children inside the
+    gathered row ``[E, Ml, LANES, 128]`` that holds them →
+    ``[E, Ml, LANES]``."""
+    per_row = ROW_NODES // TREE_WIDTH
+    return hashk.fold_block(row, (pidx % per_row)[:, None], TREE_WIDTH,
+                            nodes_last=True)
 
 
 def _verify_path(tree_leaf: jax.Array, tree_node: jax.Array,
-                 slot: jax.Array) -> jax.Array:
+                 slot: jax.Array,
+                 path_rows: Optional[jax.Array] = None) -> jax.Array:
     """Root-ward path verification for W slots per ensemble: recompute
     each stored parent on the paths from its stored children and
     compare (``get_path``/``verify_hash``, synctree.erl:302-340).
     ``slot [E, W]`` → ``[E, Ml, W]`` bool — replica's tree corrupted
     on lane w's path.  The first parent's children are 16 gathered
-    leaves; every level above is read by masks ("Merkle paths")."""
+    leaves; a row level is checked inside its gathered row
+    (``path_rows``, :func:`_gather_path_rows`), every level above by
+    masks over ``tree_node`` ("Merkle paths")."""
     s = tree_leaf.shape[-2]
-    u = tree_node.shape[2]
-    node = tree_node[:, :, None]                             # [E,Ml,1,U,L]
+    t = tree_node.shape[2]
+    node = tree_node[:, :, None]                             # [E,Ml,1,T,L]
     wrong = jnp.zeros((), bool)
     below, idx = None, slot
+    row_wrong, carried = None, None
+    if path_rows is not None:
+        pidx = slot[:, 0] // TREE_WIDTH                      # [E]
+        expect = hashk.fold(
+            _gather_children(tree_leaf, pidx[:, None], s))[:, :, 0]
+        row_wrong = jnp.zeros((), bool)
+        for lv in range(path_rows.shape[2]):
+            row = path_rows[:, :, lv]                        # [E,Ml,L,128]
+            row_wrong = row_wrong | (_row_hit(pidx)
+                                     & (row != expect[..., None]))
+            idx, pidx = pidx[:, None], pidx // TREE_WIDTH
+            expect = _row_parent(row, pidx)
+        carried = expect[:, :, None]                         # [E,Ml,1,L]
     for off, level in _node_levels(tree_node, s):
         pidx = idx // TREE_WIDTH                             # [E, W]
-        if below is None:
-            expect = hashk.fold(_gather_children(tree_leaf, pidx, s))
-        else:
+        if below is not None:
             expect = hashk.fold_block(below[:, :, None], pidx[:, None, :],
                                       TREE_WIDTH)
-        wrong = wrong | (_hit(u, off + pidx)
+        elif carried is not None:   # folded from the last row level's row
+            expect = carried
+        else:
+            expect = hashk.fold(_gather_children(tree_leaf, pidx, s))
+        wrong = wrong | (_hit(t, off + pidx)
                          & (node != expect[:, :, :, None]))
         below, idx = level, pidx
-    return wrong.any((3, 4))
+    wrong = wrong.any((3, 4))
+    if row_wrong is not None:
+        wrong = wrong | row_wrong.any((2, 3))[:, :, None]
+    return wrong
 
 
 def _write_path(tree_leaf: jax.Array, tree_node: jax.Array,
-                slot: jax.Array, new_leaf: jax.Array,
-                mask: jax.Array) -> Tuple[jax.Array, jax.Array]:
+                slot: jax.Array, new_leaf: jax.Array, mask: jax.Array,
+                tree_rows: Optional[jax.Array] = None,
+                path_rows: Optional[jax.Array] = None
+                ) -> Tuple[jax.Array, jax.Array, Optional[jax.Array]]:
     """Set lane w's leaf to ``new_leaf [E, W, LANES]`` on replicas in
     ``mask [E, Ml, W]`` and recompute their root-ward paths — the
     synchronous write-path hash update (``update_hash`` +
     ``update_path``, peer.erl:1731-1738, synctree.erl:201-209).
     Non-writing replicas' nodes are untouched (a recompute would
-    silently alter a corrupted-but-unwritten tree).
+    silently alter a corrupted-but-unwritten tree).  Returns
+    ``(tree_leaf, tree_node, tree_rows)``.
 
     HBM discipline, plane by plane ("Merkle paths" above).
     ``tree_leaf`` is SCATTERED at the touched slot: only
     O(E·M·LANES) elements of the largest plane move (inside the kv
     scan the carried buffer aliases, so the scatter is an in-place
     update); a masked-off replica aims out of bounds and is DROPPED.
-    ``tree_node`` is written by ONE masked select over the plane in
-    the layout it is stored in: each level's new parent is folded from
-    the level below AS THIS ROUND WRITES IT (its own new child
-    substituted in the mask, no intermediate plane), and the select
-    places the height's parents together.  That is a pass over U per
-    round where the scatter moved O(height) nodes, and it is the cheap
-    side: U is a fifteenth of S, and the scatter's layout cost 43
-    times the plane.  One lane (W = 1, every caller's width): lanes
-    that share a parent would each need the others' new children.
+    ``tree_rows`` likewise: in each gathered row (``path_rows``, the
+    round's one gather) this round's new child is substituted, the
+    parent above folded from the row AS THIS ROUND WRITES IT, and the
+    rows scattered back in one scatter, a masked-off replica's aimed at
+    an out-of-range row of its own.  ``tree_node`` is written by ONE
+    masked select over its T <= 136 nodes, each level's new parent
+    folded from the level below with its own new child substituted in
+    the mask, no intermediate plane.  One lane (W = 1, every caller's
+    width): lanes that share a parent would each need the others' new
+    children.
     """
     e, ml, w = mask.shape
     if w != 1:
@@ -432,6 +664,26 @@ def _write_path(tree_leaf: jax.Array, tree_node: jax.Array,
     wr = mask[:, :, :, None, None]                           # [E,Ml,1,1,1]
     pidx = slot // TREE_WIDTH                                # [E, 1]
     parent = hashk.fold(_gather_children(tree_leaf, pidx, s))  # [E,Ml,1,L]
+    if path_rows is not None:
+        n_lv, r = path_rows.shape[2], tree_rows.shape[2]
+        at, up = pidx[:, 0], parent[:, :, 0]                 # [E], [E,Ml,L]
+        rows = []
+        for lv in range(n_lv):
+            rows.append(jnp.where(_row_hit(at), up[..., None],
+                                  path_rows[:, :, lv]))
+            at = at // TREE_WIDTH
+            up = _row_parent(rows[-1], at)
+        pidx, parent = at[:, None], up[:, :, None]
+        # a masked-off replica's rows go past the end, each to a row
+        # of its own (unique as the scatter is promised), and drop
+        past = e * ml * r + jnp.arange(e * ml * n_lv, dtype=jnp.int32)
+        ridx = jnp.where(mask, _path_row_index(s, e, ml, slot),
+                         past.reshape(e, ml, n_lv))
+        tree_rows = tree_rows.reshape(e * ml * r, ROW_WORDS).at[
+            ridx.reshape(-1)].set(
+                jnp.stack(rows, 2).reshape(e * ml * n_lv, ROW_WORDS),
+                mode="drop", unique_indices=True
+            ).reshape(e, ml, r, ROW_WORDS)
     levels = _node_levels(tree_node, s)
     writes = [(levels[0][0] + pidx, parent)]
     for (_, below), (off, _) in zip(levels, levels[1:]):
@@ -441,11 +693,11 @@ def _write_path(tree_leaf: jax.Array, tree_node: jax.Array,
         pidx = pidx // TREE_WIDTH
         parent = hashk.fold_block(below, pidx[:, None, :], TREE_WIDTH)
         writes.append((off + pidx, parent))
-    node = tree_node[:, :, None]                             # [E,Ml,1,U,L]
+    node = tree_node[:, :, None]                             # [E,Ml,1,T,L]
     for tgt, parent in writes:
         node = jnp.where(wr & _hit(tree_node.shape[2], tgt),
                          parent[:, :, :, None], node)
-    return tree_leaf, node[:, :, 0]
+    return tree_leaf, node[:, :, 0], tree_rows
 
 
 def init_state(n_ensembles: int, n_peers: int, n_slots: int,
@@ -467,7 +719,7 @@ def init_state(n_ensembles: int, n_peers: int, n_slots: int,
     zero = jnp.zeros((), jnp.int32)
     empty_leaf = hashk.obj_leaf_hash(zero, zero, zero)           # [LANES]
     leaves = jnp.broadcast_to(empty_leaf, (s, hashk.LANES))
-    uppers = build_uppers(leaves)                                # [U, LANES]
+    rows, tail = _build_tree(leaves)                # [R, 512], [T, LANES]
     return EngineState(
         epoch=jnp.zeros((e, m), jnp.int32),
         fact_seq=jnp.zeros((e, m), jnp.int32),
@@ -481,8 +733,9 @@ def init_state(n_ensembles: int, n_peers: int, n_slots: int,
         obj_seq=jnp.zeros((e, m, s), jnp.int32),
         obj_val=jnp.zeros((e, m, s), jnp.int32),
         tree_leaf=jnp.broadcast_to(leaves, (e, m, s, hashk.LANES)),
-        tree_node=jnp.broadcast_to(uppers,
-                                   (e, m) + uppers.shape),
+        tree_node=jnp.broadcast_to(tail, (e, m) + tail.shape),
+        tree_rows=(None if rows is None
+                   else jnp.broadcast_to(rows, (e, m) + rows.shape)),
     )
 
 
@@ -782,8 +1035,9 @@ def _kv_round(state: EngineState, ctx: _KvCtx, kind: jax.Array,
         leaf = jnp.take_along_axis(
             state.tree_leaf, slot_c[:, None, :, None], axis=2)  # [E,Ml,W,L]
         leaf_ok = (leaf == hashk.obj_leaf_hash(pe, ps, pv)).all(-1)
+        path_rows = _gather_path_rows(state.tree_rows, s, slot_c)
         path_bad = _verify_path(state.tree_leaf, state.tree_node,
-                                slot_c)
+                                slot_c, path_rows)
     replica_ok = heard3 & leaf_ok & ~path_bad                # [E, Ml, W]
     tree_corrupt = ((path_bad | ~leaf_ok) & heard3
                     & (active & slot_valid)[:, None, :]).any(-1)
@@ -927,9 +1181,9 @@ def _kv_round(state: EngineState, ctx: _KvCtx, kind: jax.Array,
     # from the post-scatter children, so duplicate targets agree.
     with jax.named_scope("merkle_write"):
         new_leaf = hashk.obj_leaf_hash(w_epoch, w_seq, w_val)  # [E, W, L]
-        tree_leaf, tree_node = _write_path(
+        tree_leaf, tree_node, tree_rows = _write_path(
             state.tree_leaf, state.tree_node, slot_c, new_leaf,
-            do_write)
+            do_write, state.tree_rows, path_rows)
 
     # Version reported for any served object INCLUDING tombstones —
     # the reference's kget hands back the notfound obj with its vsn,
@@ -954,7 +1208,8 @@ def _kv_round(state: EngineState, ctx: _KvCtx, kind: jax.Array,
     )
     new_state = state._replace(obj_epoch=obj_epoch, obj_seq=obj_seq,
                                obj_val=obj_val, obj_seq_ctr=obj_seq_ctr,
-                               tree_leaf=tree_leaf, tree_node=tree_node)
+                               tree_leaf=tree_leaf, tree_node=tree_node,
+                               tree_rows=tree_rows)
     return new_state, res
 
 
@@ -1119,8 +1374,7 @@ def verify_trees(state: EngineState, axis_name: Optional[str] = None
     corruption vs object/leaf divergence.
     """
     del axis_name  # per-replica local; no collectives needed
-    expect_upper = build_uppers(state.tree_leaf)
-    node_bad = (expect_upper != state.tree_node).any(-1).any(-1)
+    node_bad = _trees_differ(state, *_build_tree(state.tree_leaf))
     expect_leaf = hashk.obj_leaf_hash(state.obj_epoch, state.obj_seq,
                                       state.obj_val)
     leaf_bad = (expect_leaf != state.tree_leaf).any(-1).any(-1)
@@ -1135,9 +1389,10 @@ def rebuild_trees(state: EngineState, mask: jax.Array) -> EngineState:
     leaves = hashk.obj_leaf_hash(state.obj_epoch, state.obj_seq,
                                  state.obj_val)
     tree_leaf = jnp.where(mask[:, :, None, None], leaves, state.tree_leaf)
-    tree_node = jnp.where(mask[:, :, None, None], build_uppers(tree_leaf),
-                          state.tree_node)
-    return state._replace(tree_leaf=tree_leaf, tree_node=tree_node)
+    tree_rows, tree_node = _select_trees(mask, *_build_tree(tree_leaf),
+                                         state)
+    return state._replace(tree_leaf=tree_leaf, tree_node=tree_node,
+                          tree_rows=tree_rows)
 
 
 def _pmax2(x: jax.Array, axis_name: Optional[str]) -> jax.Array:
@@ -1181,8 +1436,7 @@ def exchange_step(state: EngineState, run: jax.Array, up: jax.Array,
     expect_leaf = hashk.obj_leaf_hash(state.obj_epoch, state.obj_seq,
                                       state.obj_val)
     leaf_ok = (expect_leaf == state.tree_leaf).all(-1)       # [E, Ml, S]
-    node_ok = (build_uppers(state.tree_leaf)
-               == state.tree_node).all(-1).all(-1)           # [E, Ml]
+    node_ok = ~_trees_differ(state, *_build_tree(state.tree_leaf))
     h = heard[:, :, None] & leaf_ok & (state.obj_seq > 0)
 
     neg = jnp.int32(-1)
@@ -1222,11 +1476,11 @@ def exchange_step(state: EngineState, run: jax.Array, up: jax.Array,
     rebuild = adopt[:, None] & heard                         # [E, Ml]
     fix_leaf = tgt | (leaf_ok & rebuild[:, :, None])
     tree_leaf = jnp.where(fix_leaf[..., None], leaves, state.tree_leaf)
-    tree_node = jnp.where(rebuild[:, :, None, None],
-                          build_uppers(tree_leaf), state.tree_node)
+    tree_rows, tree_node = _select_trees(rebuild, *_build_tree(tree_leaf),
+                                         state)
     new_state = state._replace(obj_epoch=obj_epoch, obj_seq=obj_seq,
                                obj_val=obj_val, tree_leaf=tree_leaf,
-                               tree_node=tree_node)
+                               tree_node=tree_node, tree_rows=tree_rows)
     return new_state, diverged, adopt
 
 
@@ -1484,15 +1738,18 @@ def _slice_columns(state: EngineState, active_idx: jax.Array,
     lanes are NOOP/elect-False so they never write, and the scatter
     drops them.  The three object planes are read as rows of their
     stored layout (above): no whole plane moves.  ``tree_leaf`` is
-    stored E outermost and gathers where it lies; ``tree_node`` is
-    stored E MINOR-most, its gather is a lane gather, and the chip
-    still relayouts it (7.2 MB) on the way in and out, as it does the
-    small ``[E]`` / ``[E, M]`` planes."""
+    stored E outermost and gathers where it lies, and so is
+    ``tree_rows`` where the state has one (an ensemble's rows are one
+    range of the plane); ``tree_node`` is stored E MINOR-most, its
+    gather is a lane gather, and the chip still relayouts it (7.2 MB)
+    on the way in and out, as it does the small ``[E]`` / ``[E, M]``
+    planes."""
     e = state.epoch.shape[0]
     with jax.named_scope("slice_columns"):
         idx_c = jnp.clip(active_idx, 0, e - 1)
         sub = EngineState(*(
-            _take_peer_rows(x, idx_c) if f in _ROW_VIEWED
+            None if x is None
+            else _take_peer_rows(x, idx_c) if f in _ROW_VIEWED
             else jnp.take(x, idx_c, axis=0)
             for f, x in zip(state._fields, state)))
         return sub, jnp.take(up, idx_c, axis=0)
@@ -1510,7 +1767,8 @@ def _scatter_columns(state: EngineState, sub: EngineState,
     small planes are scattered on a relayouted copy."""
     with jax.named_scope("scatter_columns"):
         return EngineState(*(
-            _set_peer_rows(full, s, active_idx) if f in _ROW_VIEWED
+            None if full is None
+            else _set_peer_rows(full, s, active_idx) if f in _ROW_VIEWED
             else full.at[active_idx].set(s, mode="drop")
             for f, full, s in zip(state._fields, state, sub)))
 

@@ -116,10 +116,13 @@ def _fold_consts(width: int) -> Tuple[np.ndarray, np.ndarray]:
     return salt, mul
 
 
-def _premix(children, salt, mul):
+def _premix(children, salt, mul, nodes_last: bool = False):
     """:func:`fold`'s per-child avalanche: ``[..., n, LANES]`` children
-    with their ``[n, 1]`` salts and multipliers, mixed independently."""
+    with their ``[n, 1]`` salts and multipliers, mixed independently
+    (``nodes_last``: ``[..., LANES, n]`` children, ``[1, n]`` salts)."""
     lane = jnp.arange(LANES, dtype=jnp.uint32)
+    if nodes_last:
+        lane = lane[:, None]
     return _fmix((children ^ salt) * mul + lane)
 
 
@@ -134,12 +137,15 @@ def _seal(acc, width: int):
 
 
 def fold_block(level: jnp.ndarray, block: jnp.ndarray,
-               width: int = 16) -> jnp.ndarray:
+               width: int = 16, nodes_last: bool = False) -> jnp.ndarray:
     """:func:`fold` of ONE ``width``-block of a level, read where the
     level lies: ``level [..., n, LANES]``, ``block [...]`` (broadcast
     against the leading axes) → ``[..., LANES]``, bit-equal to
     ``fold(pad(level)[..., block * width:(block + 1) * width, :])``
-    with the short last block zero-padded.
+    with the short last block zero-padded.  ``nodes_last``: the level
+    comes ``[..., LANES, n]``, its nodes on the minor axis (a gathered
+    row of ``ops/engine.py``'s row plane: 128 nodes on the chip's 128
+    lanes).
 
     The fold is a position-salted mix SUMMED over the width, so the sum
     over the whole level of the mixes masked to the block IS the
@@ -148,14 +154,21 @@ def fold_block(level: jnp.ndarray, block: jnp.ndarray,
     plane in the layout the chip stores it in (``ops/engine.py``,
     "Merkle paths").
     """
-    n = level.shape[-2]
+    n = level.shape[-1 if nodes_last else -2]
     pos = np.arange(n)
     salt, mul = _fold_consts(width)
-    h = _premix(level, salt[pos % width], mul[pos % width])
+    at_salt, at_mul = salt[pos % width], mul[pos % width]
+    # (mixes first, mask second: the order the step programs of the
+    # shapes without a row plane were traced in, kept to the letter)
+    if nodes_last:
+        at_salt, at_mul = at_salt.T, at_mul.T
+    h = _premix(level, at_salt, at_mul, nodes_last)
     in_block = (jnp.asarray(pos // width, jnp.int32)[:, None]
                 == block[..., None, None])
-    acc = jnp.where(in_block, h, np.uint32(0)).sum(axis=-2,
-                                                   dtype=jnp.uint32)
+    if nodes_last:
+        in_block = jnp.swapaxes(in_block, -1, -2)
+    acc = jnp.where(in_block, h, np.uint32(0)).sum(
+        axis=-1 if nodes_last else -2, dtype=jnp.uint32)
     short = n % width
     if short:
         # what the zero children that pad the last block mix to

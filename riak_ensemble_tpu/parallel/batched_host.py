@@ -863,6 +863,15 @@ class BatchedEnsembleService:
         self.state = jax.block_until_ready(
             self.engine.init_state(n_ens, n_peers, n_slots))
         t_state = time.perf_counter()
+        #: ``stats()["tree"]``: where the shape puts the Merkle upper
+        #: levels (``engine.tree_layout``) and the rows of the row
+        #: plane a K/V round gathers and scatters; static, the round's
+        #: form follows the shape alone
+        lay = eng.tree_layout(n_slots)
+        self._tree_stats = {
+            "row_levels": lay.row_levels, "rows": lay.rows,
+            "tail_nodes": lay.tail_nodes,
+            "rows_per_round": n_ens * n_peers * lay.row_levels}
         #: host failure detector input (set_peer_up)
         self.up = np.ones((n_ens, n_peers), dtype=bool)
         self._up_dev = None  # cached device copy (see _up_device)
@@ -2932,6 +2941,10 @@ class BatchedEnsembleService:
             # persisted verbatim, so a restore under a different fold
             # must rebuild every tree (docs/MIGRATION.md).
             "hash_format": hashk.HASH_FORMAT,
+            # ... and in which form: the levels of 128 nodes and more
+            # as rows of `tree_rows` (engine.TREE_FORM).  An image
+            # without the stamp holds every level flat in `tree_node`.
+            "tree_form": eng.TREE_FORM,
             "key_slot": self.key_slot,
             # (mark, recycled) per ensemble: PR 43's form; before it
             # "free_slots" listed every free slot, and restore() still
@@ -3030,17 +3043,23 @@ class BatchedEnsembleService:
         n_ens, n_peers, n_slots = host["shape"]
         kw = cls._merge_dynamic(kw, bool(host.get("dynamic", False)))
         svc = cls(runtime, n_ens, n_peers, n_slots, **kw)
+        # An image from before the tree's storage stamp holds the upper
+        # levels flat in `tree_node`: its object planes and leaves are
+        # restored, its upper levels are not read into this state.
+        flat_trees = host.get("tree_form") != eng.TREE_FORM
         svc.state = ckpt.load(os.path.join(d, "engine"),
-                              template=svc.state)
+                              template=svc.state, flat_trees=flat_trees)
         # Hash-format migration: checkpoints persist tree_leaf/
-        # tree_node verbatim, so an image written under a different
-        # fold would fail _verify_path on EVERY slot (reads of
-        # committed data returning failures cluster-wide).  Rebuild
-        # every replica tree from the restored object store before any
-        # WAL replay touches a subset of slots.  Format history:
-        # riak_ensemble_tpu/ops/hash.py HASH_FORMAT; docs/MIGRATION.md.
+        # tree_node/tree_rows verbatim, so an image written under a
+        # different fold would fail _verify_path on EVERY slot (reads
+        # of committed data returning failures cluster-wide), and one
+        # written in another storage form has no upper levels this
+        # state can take.  Rebuild every replica tree from the restored
+        # object store before any WAL replay touches a subset of
+        # slots.  Format history: riak_ensemble_tpu/ops/hash.py
+        # HASH_FORMAT, ops/engine.py TREE_FORM; docs/MIGRATION.md.
         from riak_ensemble_tpu.ops import hash as hashk
-        if host.get("hash_format", 2) != hashk.HASH_FORMAT:
+        if flat_trees or host.get("hash_format", 2) != hashk.HASH_FORMAT:
             svc.state = svc.engine.rebuild_trees(
                 svc.state,
                 jnp.ones((svc.n_ens, svc.n_peers), bool))
@@ -4162,6 +4181,7 @@ class BatchedEnsembleService:
             "gc": self._gc_watch.stats(),
             "startup": dict(self.startup),
             "slots": self._slot_stats(),
+            "tree": dict(self._tree_stats),
             "flight_anomalies": self.flight.anomalies,
             "tenants": self.tenant_stats(top=8),
             # native single-pass resolve kernel (ARCHITECTURE §12):

@@ -230,6 +230,8 @@ def dump_state(svc: BatchedEnsembleService) -> Tuple:
     cluster transport)."""
     fields = []
     for name, arr in zip(eng.EngineState._fields, svc.state):
+        if arr is None:  # no row plane at this shape (engine.tree_layout)
+            continue
         a = np.asarray(arr)
         fields.append((name, a.dtype.str, list(a.shape), a.tobytes()))
     host = (
@@ -264,13 +266,11 @@ def install_state(svc: BatchedEnsembleService, dump: Tuple) -> None:
             f"lifecycle-mode mismatch: snapshot dynamic={bool(host[5])}"
             f" vs this lane dynamic={svc.dynamic} — every group host "
             "must run the same --dynamic setting")
-    by_name = {name: (dt, shape, raw) for name, dt, shape, raw in fields}
-    new = {}
-    for name in eng.EngineState._fields:
-        dt, shape, raw = by_name[name]
-        new[name] = jnp.asarray(
-            np.frombuffer(raw, np.dtype(dt)).reshape(shape))
-    svc.state = eng.EngineState(**new)
+    # (a state without a row plane dumps no `tree_rows`: the field's
+    # default, None)
+    svc.state = eng.EngineState(**{
+        name: jnp.asarray(np.frombuffer(raw, np.dtype(dt)).reshape(shape))
+        for name, dt, shape, raw in fields})
     (key_slot, slot_handle, values, next_handle, leader_b, dynamic,
      live_b, free_rows, ens_names, member_b, *rest) = host
     inline = (rest[0] if rest

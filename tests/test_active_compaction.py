@@ -49,10 +49,12 @@ def make_pair(n_ens, n_peers, n_slots, k):
 
 
 def assert_engine_equal(a, b):
-    for f in eng.EngineState._fields:
-        np.testing.assert_array_equal(
-            np.asarray(getattr(a.state, f)),
-            np.asarray(getattr(b.state, f)), err_msg=f)
+    for f, x, y in zip(eng.EngineState._fields, a.state, b.state):
+        if x is None or y is None:      # no row plane at this shape
+            assert x is None and y is None, f
+            continue
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
+                                      err_msg=f)
 
 
 # -- layout round trip -------------------------------------------------------

@@ -890,6 +890,78 @@ def test_periodic_scrub_cadence():
     svc.stop()
 
 
+@pytest.mark.parametrize("n_slots", [4, 4096])
+def test_restore_of_an_image_without_the_tree_stamp_rebuilds(tmp_path,
+                                                             n_slots):
+    """The tree's storage stamp (ISSUE 44): a checkpoint from before it
+    holds every upper level flat in ``tree_node`` (``[E, M, 273, 4]`` at
+    4,096 slots, where this state holds 2 rows of ``tree_rows`` and 17
+    nodes) and no ``tree_form``.  Restore reads its object planes and
+    leaves, rebuilds every tree from them, and serves verified reads
+    (docs/MIGRATION.md)."""
+    import pickle
+
+    import jax.numpy as jnp
+
+    from riak_ensemble_tpu import save as savelib
+    from riak_ensemble_tpu.ops import checkpoint as ckpt
+    from riak_ensemble_tpu.ops import engine as eng
+
+    runtime, svc = make_service(n_ens=2, n_peers=3, n_slots=n_slots)
+    for e in range(2):
+        assert settle(runtime, svc.kput(e, "k", b"v%d" % e))[0] == "ok"
+    svc.save(str(tmp_path / "c"))
+    state = svc.state
+    assert (state.tree_rows is None) == (n_slots == 4)
+    svc.stop()
+
+    d = tmp_path / "c"
+    n = int(savelib.read(str(d / "CURRENT")).decode())
+    host_path = str(d / f"ckpt.{n}" / "host")
+    host = pickle.loads(savelib.read(host_path))
+    assert host.pop("tree_form") == eng.TREE_FORM
+    savelib.write(host_path, pickle.dumps(host, protocol=4))
+    # the image as the parent wrote it: the flat node plane and no row
+    # plane; scrambled, so that reading it would poison every path
+    flat = jnp.concatenate(eng.rows_to_levels(
+        state.tree_rows, state.tree_node, n_slots), axis=-2)
+    assert flat.shape[2] == eng._tree_offsets(n_slots)[1]
+    old = {k: v for k, v in state._asdict().items() if k != "tree_rows"}
+    old["tree_node"] = flat ^ jnp.uint32(0x0BADF00D)
+    import orbax.checkpoint as ocp
+    ocp.PyTreeCheckpointer().save(str(d / f"ckpt.{n}" / "engine"), old,
+                                  force=True)
+
+    rt2 = Runtime(seed=12)
+    svc2 = BatchedEnsembleService.restore(
+        rt2, str(d), tick=0.005, config=fast_test_config())
+    assert svc2.state.tree_node.shape == state.tree_node.shape
+    for f in ("obj_epoch", "obj_seq", "obj_val", "tree_leaf", "epoch"):
+        np.testing.assert_array_equal(getattr(svc2.state, f),
+                                      getattr(state, f), err_msg=f)
+    node_bad, leaf_bad = eng.verify_trees(svc2.state)
+    assert not np.asarray(node_bad).any() and not np.asarray(leaf_bad).any()
+    np.testing.assert_array_equal(svc2.state.tree_node, state.tree_node)
+    if n_slots == 4096:
+        np.testing.assert_array_equal(svc2.state.tree_rows, state.tree_rows)
+    svc2.set_fast_reads(False)          # every read a verified round
+    for e in range(2):
+        assert settle(rt2, svc2.kget(e, "k")) == ("ok", b"v%d" % e)
+    assert settle(rt2, svc2.kput(0, "k", b"post"))[0] == "ok"
+    assert settle(rt2, svc2.kget(0, "k")) == ("ok", b"post")
+    assert svc2.stats()["corruptions_detected"] == 0
+    # ...and writes the stamp with its next image
+    svc2.save(str(tmp_path / "c2"))
+    n2 = int(savelib.read(str(tmp_path / "c2" / "CURRENT")).decode())
+    host2 = pickle.loads(savelib.read(
+        str(tmp_path / "c2" / f"ckpt.{n2}" / "host")))
+    assert host2["tree_form"] == eng.TREE_FORM
+    again = ckpt.load(str(tmp_path / "c2" / f"ckpt.{n2}" / "engine"),
+                      template=svc2.state)
+    assert (again.tree_rows is None) == (n_slots == 4)
+    svc2.stop()
+
+
 def test_restore_rebuilds_trees_on_hash_format_change(tmp_path):
     """Hash-format migration (round-5 ADVICE): a checkpoint written
     under a different device fold persists tree_leaf/tree_node that

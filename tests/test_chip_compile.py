@@ -69,9 +69,12 @@ def _placed(shapes, sharding):
     """A pytree of ShapeDtypeStructs with ``sharding`` (one sharding,
     or a matching pytree of them) attached to every leaf."""
     if not isinstance(sharding, jax.sharding.Sharding):
+        # the shardings lead: they name a row plane (`tree_rows`) that
+        # a state of 2,032 slots or fewer has not, None in `shapes`
         return jax.tree.map(
-            lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
-            shapes, sharding)
+            lambda s, x: x if x is None else jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=s),
+            sharding, shapes)
     return jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
         shapes)
@@ -223,7 +226,26 @@ def test_sliced_step_moves_no_object_plane(one_chip, record_property, k, a):
 #: segments (height 5, 5.85 GB of state); with the most the step may
 #: need beside its arguments at each
 DEEP_E, DEEP_M = 64, 3
-DEEP_SLOTS = {"s64k": (65_536, 100e6), "s1m": (1_048_576, 1e9)}
+DEEP_SLOTS = {"s64k": (65_536, 100e6), "s1m": (1_048_576, 100e6)}
+
+
+def _row_plane_results(text, shapes):
+    """``(op, name and result type)`` of every instruction of the
+    compiled program, inside fusions and the `while` body too, whose
+    result is a row plane (``shapes``: the 4-D plane and its 2-D view)
+    and which is more than a name for one that exists: not a
+    parameter, a tuple element, a bitcast, nor a fusion (its root is
+    listed)."""
+    found = []
+    for line in text.splitlines():
+        name, eq, rest = line.strip().removeprefix("ROOT ").partition(" = ")
+        if not eq or not rest.startswith(shapes):
+            continue
+        kind, _, after = rest.partition("} ")
+        op = after.split("(", 1)[0]
+        if op not in ("parameter", "get-tuple-element", "bitcast", "fusion"):
+            found.append((op, f"{name} = {kind}}}"))
+    return found
 
 
 @pytest.mark.parametrize("shape", list(DEEP_SLOTS))
@@ -240,7 +262,12 @@ def test_deep_ring_round_leaves_the_planes_where_they_lie(
     now reads and writes all four where they lie, and `tree_leaf`
     (201 MB) keeps its gather and its scatter.  At 1M slots (ISSUE 43)
     the same holds and the whole program, arguments and temporaries,
-    stays under half a chip."""
+    stays under half a chip.  The node levels of 128 nodes and more
+    are rows of `tree_rows` (ISSUE 44; 217 MB at 1M slots, where the
+    flat node plane took 13-14 passes a round): the program gathers
+    the paths' rows and scatters them back into the donated buffer,
+    and nothing else in it, in the `while` body or outside, makes an
+    array of the plane's size."""
     e, m, (s, temp_limit) = DEEP_E, DEEP_M, DEEP_SLOTS[shape]
     state = _placed(jax.eval_shape(lambda: eng.init_state(e, m, s)),
                     one_chip)
@@ -259,9 +286,17 @@ def test_deep_ring_round_leaves_the_planes_where_they_lie(
         text, (f"s32[{e},{m},{s}]", f"s32[{m},{e},{s}]",
                f"s32[{e * m},{s}]"))
     leaf = _whole_plane_moves(text, (f"u32[{e},{m},{s},{hashk.LANES}]",))
+    r = state.tree_rows.shape[2]
+    assert r == eng.tree_layout(s).rows and r % 8 == 0
+    rows = _row_plane_results(
+        text, (f"u32[{e},{m},{r},{eng.ROW_WORDS}]",
+               f"u32[{e * m * r},{eng.ROW_WORDS}]"))
+    node_row_moves = [(op, head) for op, head in rows if op != "scatter"]
     mem = compiled.memory_analysis()
     temp, args = mem.temp_size_in_bytes, mem.argument_size_in_bytes
     for name, value in (("tree_node_moves", [h for _, h in node]),
+                        ("node_row_moves", node_row_moves),
+                        ("node_row_results", [h for _, h in rows]),
                         ("object_plane_moves", objects),
                         ("tree_leaf_moves", leaf), ("temp_bytes", temp),
                         ("argument_bytes", args)):
@@ -270,8 +305,22 @@ def test_deep_ring_round_leaves_the_planes_where_they_lie(
     assert not lanes_minor, lanes_minor
     assert not objects, objects
     assert not leaf, leaf
+    # one scatter, in place, and no relayout: whatever else holds the
+    # plane holds it in the stored tiles.  Where the plane fits the
+    # chip's on-core memory (15.7 MB at 65,536 slots: `S(1)`) the
+    # compiler may carry it there for the launch and back, pieces of
+    # it (`slice-start`) joined by a `ConcatBitcast`; at 1M slots
+    # (217 MB) there is nothing but the scatter.
+    assert [op for op, _ in rows].count("scatter") == 1, rows
+    assert all(re.search(r"\]\{(3,2,)?1,0:T\(8,128\)(S\(1\))?\}$", head)
+               for _, head in rows), rows
+    assert all(op in ("copy-done", "custom-call")
+               for op, _ in node_row_moves), node_row_moves
+    if shape == "s1m":
+        assert not node_row_moves, node_row_moves
     # at 65,536 slots 578.5 MB at K 2 before ISSUE 42 (`tree_node`
-    # padded to 128 lanes); at 1M slots 9,169 MB
+    # padded to 128 lanes); at 1M slots 9,169 MB then, 579 MB before
+    # ISSUE 44 (the scan's moves of the flat node plane)
     assert temp < temp_limit
     assert args + temp < 8e9
 

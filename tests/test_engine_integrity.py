@@ -409,3 +409,164 @@ def test_sharded_integrity_matches_single_device():
     dv = np.asarray(diverged)
     assert dv[:, 1].all() and not dv[:, 0].any()
     assert not bool(np.asarray(nb).any()) and not bool(np.asarray(lb).any())
+
+
+# -- the levels stored as rows of 128 nodes (ISSUE 44) -----------------------
+
+#: 2 x 3 x 4,096: 256 + 16 + 1 upper nodes, the 256 two rows of
+#: ``tree_rows``, the 17 flat in ``tree_node``
+RE, RM, RS = 2, 3, 4096
+
+
+def _row_state():
+    """An elected 2 x 3 x 4,096 state with a put on slots in both rows
+    of the row level and on the short end."""
+    up = jnp.ones((RE, RM), bool)
+    st, won = eng.elect_step(eng.init_state(RE, RM, RS),
+                             jnp.ones((RE,), bool),
+                             jnp.zeros((RE,), jnp.int32), up)
+    assert bool(won.all())
+    for slot in (5, 2047, 2048, RS - 1):
+        st, res = _row_op(st, eng.OP_PUT, slot, slot + 7)
+        assert bool(res.committed.all())
+    return st
+
+
+def _row_op(st, kind, slot, val=0, up=None):
+    up = jnp.ones((RE, RM), bool) if up is None else up
+    return eng.kv_step(st, jnp.full((RE,), kind, jnp.int32),
+                       jnp.full((RE,), slot, jnp.int32),
+                       jnp.full((RE,), val, jnp.int32),
+                       jnp.ones((RE,), bool), up)
+
+
+def _flip_row_node(st, peer, idx, lane=1):
+    """Flip one bit of node ``idx`` of the row level on ``peer``."""
+    at = (slice(None), peer, idx // eng.ROW_NODES,
+          lane * eng.ROW_NODES + idx % eng.ROW_NODES)
+    return st._replace(tree_rows=st.tree_rows.at[at].set(
+        st.tree_rows[at] ^ jnp.uint32(1 << 9)))
+
+
+def _assert_plain_trees(st):
+    """Every replica's stored upper levels are the plain bottom-up
+    build over its own leaves, and its leaves its objects' hashes."""
+    flat = eng.build_uppers(st.tree_leaf)
+    levels, off = [], 0
+    for n in eng.tree_sizes(RS):
+        levels.append(flat[:, :, off:off + n])
+        off += n
+    rows, tail = eng.levels_to_rows(levels)
+    assert np.array_equal(st.tree_rows, rows)
+    assert np.array_equal(st.tree_node, tail)
+    assert np.array_equal(st.tree_leaf, hashk.obj_leaf_hash(
+        st.obj_epoch, st.obj_seq, st.obj_val))
+
+
+def test_row_levels_are_the_plain_build():
+    st = _row_state()
+    assert st.tree_rows.shape == (RE, RM, 8, eng.ROW_WORDS)
+    assert st.tree_node.shape == (RE, RM, 17, hashk.LANES)
+    _assert_plain_trees(st)
+    node_bad, leaf_bad = eng.verify_trees(st)
+    assert not np.asarray(node_bad).any() and not np.asarray(leaf_bad).any()
+
+
+@pytest.mark.parametrize("slot", [5, 2047, 2048, RS - 1])
+def test_flipped_row_node_is_caught_on_its_path_and_healed(slot):
+    """A flipped word in a node of the row level: the read through it
+    flags that replica alone and its repair heals it; a read under
+    another parent does not see it; ``verify_trees`` does."""
+    good = _row_state()
+    st = _flip_row_node(good, peer=2, idx=slot // 16)
+    node_bad, leaf_bad = eng.verify_trees(st)
+    assert np.asarray(node_bad)[:, 2].all()
+    assert np.asarray(node_bad).sum() == RE and not np.asarray(leaf_bad).any()
+    beside = (slot + 1024) % RS          # another block of the level above
+    st1, res = _row_op(st, eng.OP_GET, beside)
+    assert bool(res.get_ok.all()) and not np.asarray(res.tree_corrupt).any()
+    assert np.asarray(eng.verify_trees(st1)[0])[:, 2].all()
+    st2, res = _row_op(st1, eng.OP_GET, slot)
+    tc = np.asarray(res.tree_corrupt)
+    assert tc[:, 2].all() and not tc[:, :2].any()
+    assert bool(res.get_ok.all())
+    np.testing.assert_array_equal(res.value, slot + 7)
+    assert not np.asarray(eng.verify_trees(st2)[0]).any()
+    for f, a, b in zip(st2._fields, st2, good):
+        assert np.array_equal(a, b), f
+    _assert_plain_trees(st2)
+
+
+def test_flipped_row_node_on_a_down_replica_stays_as_it_is():
+    """A put commits on the replicas that hear it; the one that is down
+    keeps its rows, the flipped word included."""
+    st = _flip_row_node(_row_state(), peer=1, idx=2048 // 16)
+    up = jnp.ones((RE, RM), bool).at[:, 1].set(False)
+    st2, res = _row_op(st, eng.OP_PUT, 2048, 99, up=up)
+    assert bool(res.committed.all())
+    assert np.array_equal(st2.tree_rows[:, 1], st.tree_rows[:, 1])
+    assert not np.array_equal(st2.tree_rows[:, 0], st.tree_rows[:, 0])
+    assert np.asarray(eng.verify_trees(st2)[0])[:, 1].all()
+
+
+@pytest.mark.parametrize("heal", ["rebuild_trees", "exchange_step",
+                                  "reset_rows"])
+def test_flipped_row_node_healed_off_the_path(heal):
+    st = _flip_row_node(_row_state(), peer=0, idx=255)
+    node_bad, _ = eng.verify_trees(st)
+    assert np.asarray(node_bad)[:, 0].all()
+    if heal == "rebuild_trees":
+        st2 = eng.rebuild_trees(st, node_bad)
+    elif heal == "exchange_step":
+        st2, diverged, synced = eng.exchange_step(
+            st, jnp.ones((RE,), bool), jnp.ones((RE, RM), bool))
+        assert bool(synced.all())
+        dv = np.asarray(diverged)
+        assert dv[:, 0].all() and not dv[:, 1:].any()
+    else:
+        st2 = eng.reset_rows(st, jnp.asarray([True, False]),
+                             jnp.ones((RE, RM), bool))
+        assert not np.asarray(st2.obj_seq[0]).any()
+        bad = np.asarray(eng.verify_trees(st2)[0])
+        assert not bad[0].any() and bad[1, 0]
+        st2 = eng.rebuild_trees(st2, jnp.asarray(bad))
+    assert not np.asarray(eng.verify_trees(st2)[0]).any()
+    _assert_plain_trees(st2)
+
+
+def test_sliced_launch_over_a_row_plane_equals_the_full_width_one():
+    """E = 256, S = 2,048 (one row level of one row): the sliced step
+    takes the active ensembles' rows out of ``tree_rows`` and puts them
+    back, and leaves what the full-width step leaves."""
+    e, m, s, k = 256, 3, 2048, 2
+    assert eng.tree_layout(s).rows == 8
+    rng = np.random.default_rng(44)
+    active = np.array([3, 17, 100, 255], np.int32)
+    elect = np.zeros(e, bool)
+    elect[active] = True
+    cand = np.zeros(e, np.int32)
+    lease = np.zeros(e, bool)
+    kind = np.zeros((k, e), np.int32)
+    kind[:, active] = eng.OP_PUT
+    slot = rng.integers(0, s, (k, e)).astype(np.int32)
+    val = rng.integers(1, 1 << 20, (k, e)).astype(np.int32)
+    planes = (kind, slot, val, None, None)
+    up = jnp.ones((e, m), bool)
+    state = eng.init_state(e, m, s)
+    want = eng.full_step_slab(
+        state, jnp.asarray(eng.pack_op_slab(e, k, elect, cand, lease,
+                                            planes)), up)
+    a = 8
+    aidx = np.full(a, e, np.int32)
+    aidx[:len(active)] = active
+    got = eng.full_step_sliced_slab(
+        state, jnp.asarray(eng.pack_op_slab(a, k, elect, cand, lease,
+                                            planes, active, aidx)), up)
+    assert np.asarray(want[2].committed)[:, active].all()
+    assert got[0].tree_rows.shape == (e, m, 8, eng.ROW_WORDS)
+    assert not np.array_equal(got[0].tree_rows, state.tree_rows)
+    for f, g, w in zip(state._fields, got[0], want[0]):
+        assert np.array_equal(g, w), f
+    np.testing.assert_array_equal(np.asarray(got[1])[:len(active)],
+                                  np.asarray(want[1])[active])
+    assert not np.asarray(eng.verify_trees(got[0])[0]).any()

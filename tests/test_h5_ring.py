@@ -158,7 +158,21 @@ def test_served_path_over_five_levels(n_ens, n_slots, n_keys):
     leaf_want = np.asarray(hashk.obj_leaf_hash(
         st.obj_epoch, st.obj_seq, st.obj_val))
     leaf = np.asarray(st.tree_leaf)
-    node = np.asarray(st.tree_node)
+    # the upper levels as stored (those of 128 nodes and more as rows
+    # of 128 nodes, the top ones flat) and as the flat plane the plain
+    # build gives
+    lay = eng.tree_layout(n_slots)
+    assert (lay.row_levels, lay.rows, lay.tail_nodes) == {
+        131072: (2, 72, 32 + 2 + 1), 1048576: (3, 552, 16 + 1)}[n_slots]
+    assert svc.stats()["tree"] == {
+        "row_levels": lay.row_levels, "rows": lay.rows,
+        "tail_nodes": lay.tail_nodes,
+        "rows_per_round": n_ens * 3 * lay.row_levels}
+    assert st.tree_rows.shape == (n_ens, 3, lay.rows, eng.ROW_WORDS)
+    assert st.tree_node.shape[2] == lay.tail_nodes
+    import jax.numpy as jnp
+    node = np.asarray(jnp.concatenate(
+        eng.rows_to_levels(st.tree_rows, st.tree_node, n_slots), axis=-2))
     assert np.array_equal(leaf, leaf_want)
     offs, total = eng._tree_offsets(n_slots)
     assert node.shape[2] == total
@@ -181,6 +195,38 @@ def test_served_path_over_five_levels(n_ens, n_slots, n_keys):
                 assert np.array_equal(node[e, m, offs[0]:offs[1]],
                                       np.asarray(levels[-2]))
         assert touched.count(None) == n_keys // 3   # deleted, recycled
+
+    # a flipped word in a row node, at each row level of one path: the
+    # read through it says so, of that replica alone, and repairs it;
+    # a read under another parent does not see it; the sweep does
+    node_bad, leaf_bad = svc.engine.verify_trees(st)
+    assert not np.asarray(node_bad).any() and not np.asarray(leaf_bad).any()
+    e = n_ens - 1
+    live = [k for k in keys[e] if (e, k) in model
+            and model[(e, k)][0] is not NOTFOUND]
+    svc.set_fast_reads(False)
+    for level in range(lay.row_levels):
+        key = live[level]
+        at = svc.key_slot[e][key]
+        idx = at // 16 ** (level + 1)
+        # (a path checks each parent against its 16 children: a key
+        # whose path holds a SIBLING of the node would see it too)
+        other = next(k for k in live if svc.key_slot[e][k]
+                     // 16 ** (level + 2) != idx // 16)
+        row = lay.row_offs[level] + idx // eng.ROW_NODES
+        word = 3 * eng.ROW_NODES + idx % eng.ROW_NODES
+        was = svc.state.tree_rows
+        svc.state = svc.state._replace(
+            tree_rows=was.at[e, 1, row, word].set(was[e, 1, row, word] ^ 1))
+        bad = np.asarray(svc.engine.verify_trees(svc.state)[0])
+        assert bad[e, 1] and bad.sum() == 1
+        seen = svc.stats()["corruptions_detected"]
+        read(e, [other])
+        assert svc.stats()["corruptions_detected"] == seen
+        read(e, [key])
+        assert svc.stats()["corruptions_detected"] == seen + 1
+        assert not np.asarray(svc.engine.verify_trees(svc.state)[0]).any()
+        assert np.array_equal(svc.state.tree_rows, was)
     svc.stop()
 
 

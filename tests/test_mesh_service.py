@@ -79,6 +79,9 @@ def _mixed_stream(svc, phase, rows):
 
 def _assert_device_state_equal(a, b):
     for name, xa, xb in zip(a.state._fields, a.state, b.state):
+        if xa is None or xb is None:    # no row plane at this shape
+            assert xa is None and xb is None, name
+            continue
         np.testing.assert_array_equal(np.asarray(xa), np.asarray(xb),
                                       err_msg=f"state.{name}")
     np.testing.assert_array_equal(a.leader_np, b.leader_np)
@@ -249,7 +252,9 @@ def _sliced_arms():
 
 
 def _state_rows(svc, rows=slice(None)):
-    return [np.asarray(x)[rows] for x in svc.state]
+    """Every plane the state holds (no row plane at 128 slots: the
+    field is None and no leaf)."""
+    return [np.asarray(x)[rows] for x in jax.tree.leaves(svc.state)]
 
 
 def _launches(svc, n0):

@@ -222,14 +222,16 @@ def _random_state(e, m, s, seed):
 
 @pytest.mark.parametrize("a", [8, 64])
 @pytest.mark.parametrize("shape", [(300, 5, 128), (64, 3, 16),
-                                   (512, 5, 256)], ids=str)
+                                   (512, 5, 256), (260, 3, 2048)], ids=str)
 def test_sliced_edges_match_take_and_set(shape, a):
     """The sliced step's gather and scatter address the object planes
     as rows of their ``[M * E, S]`` view (ISSUE 40): bit-equal to
     ``jnp.take(x, idx, 0)`` / ``x.at[idx].set(s, mode="drop")`` on
-    every plane, padding indices (= E) clipped by the one and dropped
+    every plane (the row plane of a state of 2,048 slots among them:
+    ISSUE 44), padding indices (= E) clipped by the one and dropped
     by the other."""
     e, m, s = shape
+    assert (eng.init_state(1, 1, s).tree_rows is None) == (s < 2048)
     rng = np.random.default_rng(40 + a)
     real = rng.choice(e, a - 5, replace=False).astype(np.int32)
     aidx = np.full((a,), e, np.int32)       # 5 pads, not all at the end
@@ -252,7 +254,7 @@ def test_sliced_edges_match_take_and_set(shape, a):
         state, stepped))
     # the pads wrote nothing, the real rows hold the stepped values
     idle = np.setdiff1d(np.arange(e), real)
-    for g, x, new in zip(got, state, stepped):
+    for g, x, new in zip(*map(jax.tree.leaves, (got, state, stepped))):
         np.testing.assert_array_equal(np.asarray(g)[idle],
                                       np.asarray(x)[idle])
         np.testing.assert_array_equal(np.asarray(g)[aidx[aidx < e]],
