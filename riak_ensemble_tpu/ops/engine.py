@@ -700,6 +700,20 @@ def _write_path(tree_leaf: jax.Array, tree_node: jax.Array,
     return tree_leaf, node[:, :, 0], tree_rows
 
 
+def init_view_mask(n_peers: int, n_views: int = 2,
+                   views: Optional[Sequence[Sequence[int]]] = None
+                   ) -> np.ndarray:
+    """The ``[V, M]`` view mask every ensemble of a fresh state starts
+    with (:func:`init_state`'s ``views``): default one view of all
+    peers."""
+    if views is None:
+        vm = np.zeros((n_views, n_peers), dtype=bool)
+        vm[0, :] = True
+        return vm
+    assert len(views) <= n_views
+    return views_to_mask(views, n_views, n_peers)
+
+
 def init_state(n_ensembles: int, n_peers: int, n_slots: int,
                n_views: int = 2,
                views: Optional[Sequence[Sequence[int]]] = None) -> EngineState:
@@ -710,12 +724,7 @@ def init_state(n_ensembles: int, n_peers: int, n_slots: int,
     applied to every ensemble; default one view of all peers.
     """
     e, m, s, v = n_ensembles, n_peers, n_slots, n_views
-    if views is None:
-        vm = np.zeros((v, m), dtype=bool)
-        vm[0, :] = True
-    else:
-        assert len(views) <= v
-        vm = views_to_mask(views, v, m)
+    vm = init_view_mask(m, v, views)
     zero = jnp.zeros((), jnp.int32)
     empty_leaf = hashk.obj_leaf_hash(zero, zero, zero)           # [LANES]
     leaves = jnp.broadcast_to(empty_leaf, (s, hashk.LANES))

@@ -218,19 +218,32 @@ def _backend_mem_bytes() -> float:
     return float(stats.get("bytes_in_use", float("nan")))
 
 
-def _backend_mem_bytes_per_device() -> Dict[str, float]:
+def _backend_mem_bytes_per_device(field: str = "bytes_in_use"
+                                  ) -> Dict[str, float]:
     """Per-device form of :func:`_backend_mem_bytes` for mesh-sharded
     engines: bytes in use on EVERY local device, keyed by device id.
     An 'ens'-shard imbalance (one shard's slabs growing past its
-    siblings) is invisible in the default-device gauge."""
+    siblings) is invisible in the default-device gauge.  ``field``
+    names another of the allocator's numbers (``peak_bytes_in_use``:
+    the most a device has held since the process began)."""
     import jax
     out: Dict[str, float] = {}
     for d in jax.local_devices():
         stats = d.memory_stats()
-        out[str(d.id)] = (float(stats.get("bytes_in_use",
-                                          float("nan")))
+        out[str(d.id)] = (float(stats.get(field, float("nan")))
                           if stats else float("nan"))
     return out
+
+
+def _state_bytes_per_device(state) -> List[int]:
+    """Bytes of the engine state each device holds, by device id:
+    summed over the addressable shards of every plane."""
+    held: Dict[int, int] = {}
+    for plane in jax.tree.leaves(state):
+        for shard in plane.addressable_shards:
+            held[shard.device.id] = (held.get(shard.device.id, 0)
+                                     + shard.data.nbytes)
+    return [held[d] for d in sorted(held)]
 
 
 def mesh_ens_shards(engine) -> int:
@@ -856,12 +869,23 @@ class BatchedEnsembleService:
         #: per-ens-shard blocks and active-column compaction computes
         #: its |A| bucket PER SHARD (compaction-aware sharding)
         self._mesh_shards = mesh_ens_shards(self.engine)
+        #: unified observability plane (riak_ensemble_tpu.obs): a
+        #: per-service metrics registry + flight recorder, plus
+        #: per-tenant accounting vectorized over ensemble rows.
+        #: ``RETPU_OBS=0`` short-circuits every hot-path record; the
+        #: answer is cached here so the gate is one attribute test.
+        self._obs = obs.enabled()
+        #: the span primitive (obs.spans): every mark of a flush's
+        #: record is stamped through it, and with RETPU_OBS on each
+        #: is a profiler annotation ``svc.<mark>`` as well
+        self.spans = obs.spans.SpanRecorder(annotate=self._obs)
         # the device's planes and their first tree, built where they
-        # will live; waited for, so that the stamp is the build's
+        # will live (a mesh engine's: every device its own block);
+        # waited for on every device, so that the span is the build's
         # seconds (the first launch would have waited for them)
-        t_start = time.perf_counter()
-        self.state = jax.block_until_ready(
-            self.engine.init_state(n_ens, n_peers, n_slots))
+        with self.spans.span("state_init", {}) as state_init:
+            self.state = jax.block_until_ready(
+                self.engine.init_state(n_ens, n_peers, n_slots))
         t_state = time.perf_counter()
         #: ``stats()["tree"]``: where the shape puts the Merkle upper
         #: levels (``engine.tree_layout``) and the rows of the row
@@ -1218,16 +1242,6 @@ class BatchedEnsembleService:
                      "hash_format": hashk.HASH_FORMAT}, protocol=4))
             self._wal = ServiceWAL.open_gen(
                 data_dir, self._current_ckpt(data_dir), wal_sync)
-        #: unified observability plane (riak_ensemble_tpu.obs): a
-        #: per-service metrics registry + flight recorder, plus
-        #: per-tenant accounting vectorized over ensemble rows.
-        #: ``RETPU_OBS=0`` short-circuits every hot-path record; the
-        #: answer is cached here so the gate is one attribute test.
-        self._obs = obs.enabled()
-        #: the span primitive (obs.spans): every mark of a flush's
-        #: record is stamped through it, and with RETPU_OBS on each
-        #: is a profiler annotation ``svc.<mark>`` as well
-        self.spans = obs.spans.SpanRecorder(annotate=self._obs)
         #: the record flush() opened for the launch it is packing
         self._enq_rec: Optional[Dict[str, Any]] = None
         if self._wal is not None:
@@ -1373,11 +1387,20 @@ class BatchedEnsembleService:
         self._autotune = self.controller.enabled
         self._register_obs_metrics()
         #: the start, stamped once (``stats()["startup"]``): seconds
-        #: building the device's planes and their first tree, and
-        #: seconds building everything this constructor holds on the
-        #: host
-        self.startup = {"state_init_s": t_state - t_start,
-                        "host_init_s": time.perf_counter() - t_state}
+        #: building the device's planes and their first tree, seconds
+        #: building everything this constructor holds on the host,
+        #: the state's bytes on each device and, where the platform
+        #: keeps allocator stats, the most any device has held by now
+        #: (one copy of the state: a start that builds on one device
+        #: and then places reads twice that)
+        self.startup = {
+            "state_init_s": state_init.seconds,
+            "host_init_s": time.perf_counter() - t_state,
+            "state_bytes_per_device": _state_bytes_per_device(self.state)}
+        peak = max(_backend_mem_bytes_per_device(
+            "peak_bytes_in_use").values())
+        if not np.isnan(peak):      # no allocator stats (a CPU)
+            self.startup["device_peak_bytes"] = int(peak)
         self._schedule()
 
     # -- dynamic ensemble lifecycle ----------------------------------------
@@ -4268,6 +4291,9 @@ class BatchedEnsembleService:
         shaped = self.launches_sliced + self.launches_gathered
         return {"mesh": {
             "shards": self._mesh_shards,
+            "e_loc": self.n_ens // self._mesh_shards,
+            "state_bytes_per_shard": max(
+                self.startup["state_bytes_per_device"]),
             "launches_sliced": self.launches_sliced,
             "launches_pack_gathered": self.launches_gathered,
             "launches_full_grid": (self.launches_unsliced

@@ -239,6 +239,8 @@ class ShardedEngine:
         # force a first-flush recompile after warmup.
         self._canon = smap(lambda st: st, (_STATE_SPECS,),
                            _STATE_SPECS)
+        #: init_state's programs by the shard's shape and views
+        self._init_programs: dict = {}
 
     def _make_step(self, jitted):
         """Wrap a shard_map'd fused-step program in the serving-step
@@ -265,17 +267,51 @@ class ShardedEngine:
         return int(self.mesh.shape["peer"])
 
     def shard_state(self, state: eng.EngineState) -> eng.EngineState:
-        """Place a host-built state onto the mesh with engine specs —
-        via the identity program, so the placement's cache key matches
-        the step outputs' bit for bit (see ``_canon`` above)."""
+        """Place a host-built state (a restored checkpoint, a test's)
+        onto the mesh with engine specs — via the identity program, so
+        the placement's cache key matches the step outputs' bit for
+        bit (see ``_canon`` above)."""
         return self._canon(state)
 
+    def init_program(self, n_ensembles: int, n_peers: int, n_slots: int,
+                     n_views: int = 2,
+                     views: Optional[Sequence[Sequence[int]]] = None):
+        """The jitted program :meth:`init_state` runs: no operand,
+        :func:`engine.init_state` for a shard's own block as its body,
+        the state under the engine's specs as its result."""
+        n_e, n_p = self.n_ens_shards, self.n_peer_shards
+        assert n_ensembles % n_e == 0
+        assert n_peers % n_p == 0
+        e_loc, m_loc = n_ensembles // n_e, n_peers // n_p
+        vm = eng.init_view_mask(n_peers, n_views, views)       # [V, M]
+        key = (e_loc, m_loc, n_slots, n_views, vm.tobytes())
+        if key not in self._init_programs:
+            def body():
+                st = eng.init_state(e_loc, m_loc, n_slots, n_views)
+                # a shard's own peers of the views (all of them where
+                # the 'peer' axis is not sharded)
+                mine = jax.lax.dynamic_slice_in_dim(
+                    jnp.asarray(vm), jax.lax.axis_index("peer") * m_loc,
+                    m_loc, axis=1)
+                return st._replace(view_mask=jnp.broadcast_to(
+                    mine, (e_loc, n_views, m_loc)))
+
+            self._init_programs[key] = jax.jit(jax.shard_map(
+                body, mesh=self.mesh, in_specs=(),
+                out_specs=_STATE_SPECS, check_vma=False))
+        return self._init_programs[key]
+
     def init_state(self, n_ensembles: int, n_peers: int, n_slots: int,
-                   **kw) -> eng.EngineState:
-        assert n_ensembles % self.mesh.shape["ens"] == 0
-        assert n_peers % self.mesh.shape["peer"] == 0
-        return self.shard_state(
-            eng.init_state(n_ensembles, n_peers, n_slots, **kw))
+                   n_views: int = 2,
+                   views: Optional[Sequence[Sequence[int]]] = None
+                   ) -> eng.EngineState:
+        """Fresh state, BUILT where it lives: every device allocates
+        its own block of every plane once, and no device ever holds a
+        whole one (a ring that needs the mesh is one that a single
+        device cannot build).  The result carries the sharding the
+        step programs emit, as :meth:`shard_state`'s does."""
+        return self.init_program(n_ensembles, n_peers, n_slots, n_views,
+                                 views)()
 
     # -- steps -------------------------------------------------------------
 

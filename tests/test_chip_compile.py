@@ -440,3 +440,85 @@ def test_sliced_mesh_step_moves_no_object_plane(topo, record_property):
     record_property("mesh_k1a8_temp_bytes", temp)
     print(f"mesh_k1a8 temp_bytes {temp}")
     assert temp < 16e6
+
+
+#: `ycsb-a.ring256-n3-h5-mesh4` (ISSUE 45): Riak ring size 256 over the
+#: synctree's own 1M segments, 23.4 GB sharded along 'ens' over the
+#: 2x2 host, each chip `ring64-n3-h5`'s 64 x 3 x 1,048,576
+RING256 = (256, 3, 1_048_576)
+
+
+def test_mesh_builds_a_ring_no_chip_can_hold_in_place(topo,
+                                                      record_property):
+    """The mesh engine's `init_state` program at 256 x 3 x 1,048,576:
+    no operand (nothing is built on one device and then placed), no
+    temporaries, and each device's result its own 5.85 GB of the
+    23.4 GB."""
+    from riak_ensemble_tpu.parallel.mesh import mesh_engine
+
+    e, m, s = RING256
+    engine = mesh_engine(4, devices=topo.devices)
+    compiled = engine.init_program(e, m, s).lower().compile()
+    mem = compiled.memory_analysis()
+    whole = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(
+        jax.eval_shape(lambda: eng.init_state(e, m, s))))
+    for name, value in (("output_bytes", mem.output_size_in_bytes),
+                        ("temp_bytes", mem.temp_size_in_bytes),
+                        ("state_bytes", whole)):
+        record_property(f"ring256_init_{name}", value)
+        print(f"ring256_init {name} {value}")
+    assert whole > 16e9                     # one chip cannot hold it
+    assert mem.argument_size_in_bytes == 0
+    assert whole / 4 <= mem.output_size_in_bytes < whole / 4 * 1.001
+    assert mem.temp_size_in_bytes < 100e6
+    assert not _COLLECTIVES.search(compiled.as_text())
+
+
+@pytest.mark.parametrize("k", [1, 2], ids=["k1", "k2"])
+def test_mesh_round_over_a_sharded_deep_ring_moves_no_plane(
+        topo, record_property, k):
+    """The donated full-grid mesh step every flush of that ring
+    launches (a shard holds 64 < `SLICE_MIN_E` ensembles): under
+    `shard_map` the `[M * E, S]` and `[E * M * R, 512]` views are
+    bitcasts of a LOCAL block, and each chip's program stays what
+    `ring64-n3-h5`'s is on one chip: no collective, no move of an
+    object plane, of `tree_leaf` or of the row plane, under 100 MB of
+    temporaries, arguments and temporaries under half a chip."""
+    from riak_ensemble_tpu.parallel.mesh import mesh_engine
+
+    e, m, s = RING256
+    engine = mesh_engine(4, devices=topo.devices)
+    state = _placed(jax.eval_shape(lambda: eng.init_state(e, m, s)),
+                    eng.state_sharding(engine.mesh))
+    compiled = engine.full_step_slab_donate.lower(
+        state,
+        jax.ShapeDtypeStruct((3 + 5 * k, e), jnp.int32,
+                             sharding=engine.slab_sharding),
+        jax.ShapeDtypeStruct((e, m), jnp.bool_,
+                             sharding=engine.up_sharding)).compile()
+    text = compiled.as_text()
+    assert (" while(" in text) == (k > 1)
+    assert not _COLLECTIVES.search(text), _COLLECTIVES.findall(text)
+    e_loc, r = e // 4, state.tree_rows.shape[2]
+    objects = _whole_plane_moves(
+        text, (f"s32[{e_loc},{m},{s}]", f"s32[{m},{e_loc},{s}]",
+               f"s32[{e_loc * m},{s}]"))
+    leaf = _whole_plane_moves(
+        text, (f"u32[{e_loc},{m},{s},{hashk.LANES}]",))
+    rows = _row_plane_results(
+        text, (f"u32[{e_loc},{m},{r},{eng.ROW_WORDS}]",
+               f"u32[{e_loc * m * r},{eng.ROW_WORDS}]"))
+    mem = compiled.memory_analysis()
+    temp, args = mem.temp_size_in_bytes, mem.argument_size_in_bytes
+    for name, value in (("object_plane_moves", objects),
+                        ("tree_leaf_moves", leaf),
+                        ("node_row_results", [h for _, h in rows]),
+                        ("temp_bytes", temp), ("argument_bytes", args)):
+        record_property(f"ring256_k{k}_{name}", value)
+        print(f"ring256_k{k} {name} {value}")
+    assert not objects, objects
+    assert not leaf, leaf
+    # the row plane: one scatter into the donated block, nothing else
+    assert [op for op, _ in rows] == ["scatter"], rows
+    assert temp < 100e6
+    assert 5.8e9 < args and args + temp < 8e9

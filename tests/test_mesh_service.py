@@ -362,3 +362,216 @@ def test_warmup_covers_the_sliced_mesh_programs():
         assert svc._c_compile.labels("serve").value == serve0, leaked
     finally:
         svc.stop()
+
+
+# -- a mesh builds its state where it lives (ISSUE 45) ------------------------
+
+
+@pytest.mark.parametrize("devices,n_peer,shape,kw", [
+    (8, 1, (16, 3, 128), {}),            # no row plane
+    (4, 1, (8, 3, 4096), {}),            # one row level
+    (4, 1, (4, 3, 131072), {}),          # two, five levels in all
+    (4, 1, (8, 4, 64), {"views": [[0, 1, 2], [1, 2, 3]]}),
+    (8, 2, (8, 4, 4096), {"n_views": 3,  # a sharded 'peer' axis
+                          "views": [[0, 1], [2, 3], [0, 3]]}),
+], ids=["flat", "rows1", "rows2", "views", "peer2-views"])
+def test_mesh_init_state_is_the_placed_host_built_state(devices, n_peer,
+                                                        shape, kw):
+    """``ShardedEngine.init_state`` builds every shard's block on its
+    own device (one program, no operand): plane by plane and sharding
+    by sharding what ``shard_state`` makes of the state one device
+    builds."""
+    from riak_ensemble_tpu.ops import engine as eng
+
+    engine = mesh_engine(devices, n_peer=n_peer)
+    built = engine.init_state(*shape, **kw)
+    placed = engine.shard_state(eng.init_state(*shape, **kw))
+    rows = eng.tree_layout(shape[2]).row_levels
+    assert (built.tree_rows is None) == (rows == 0)
+    for name, a, b in zip(built._fields, built, placed):
+        if b is None:
+            assert a is None, name
+            continue
+        assert (a.shape, a.dtype) == (b.shape, b.dtype), name
+        assert a.sharding == b.sharding, (name, a.sharding, b.sharding)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=name)
+        # a device holds its block of the ensembles and no more
+        block = a.sharding.shard_shape(a.shape)
+        assert block[0] == a.shape[0] * n_peer // devices, name
+        assert {s.data.shape for s in a.addressable_shards} == {block}
+    # one program a shape, met again on the next call
+    assert engine.init_state(*shape, **kw).epoch.sharding == \
+        built.epoch.sharding
+    assert len(engine._init_programs) == 1
+
+
+def test_mesh_service_says_what_each_device_holds():
+    """``stats()["startup"]`` / ``["mesh"]`` of a mesh service: the
+    state's bytes device by device (equal blocks along 'ens'), the
+    ensembles a shard holds; no allocator peak on a CPU."""
+    svc = BatchedEnsembleService(WallRuntime(), 8, 3, 4096, tick=None,
+                                 engine=mesh_engine(4))
+    try:
+        st = svc.stats()
+        held = st["startup"]["state_bytes_per_device"]
+        total = sum(x.nbytes for x in jax.tree.leaves(svc.state))
+        assert held == [total // 4] * 4
+        assert st["startup"]["state_init_s"] > 0.0
+        assert "device_peak_bytes" not in st["startup"]
+        assert st["mesh"]["e_loc"] == 2
+        assert st["mesh"]["state_bytes_per_shard"] == total // 4
+    finally:
+        svc.stop()
+
+
+H5_ENS, H5_SLOTS, H5_KEYS = 4, 131072, 20
+
+
+def _h5_stream(svc):
+    """A seeded stream over five-level trees through the normal queue
+    and flush (``tests/test_h5_ring.py``'s, every ensemble at once so
+    that a flush spans the shards): loads, versioned reads from device
+    rounds, overwrites, deletes, leased reads.  Returns every reply,
+    the plain model (a dict ordered by the versions the service
+    acknowledged), the keys and the slots they were given."""
+    from test_h5_ring import distant_slots, settle
+    from riak_ensemble_tpu.parallel.batched_host import _FreeSlots
+    from riak_ensemble_tpu.types import NOTFOUND
+
+    rng = np.random.default_rng([45, H5_SLOTS])
+    ens = range(H5_ENS)
+    slots = [distant_slots(rng, H5_SLOTS, H5_KEYS) for _ in ens]
+    for e in ens:
+        svc.free_slots[e] = _FreeSlots(0, slots[e][::-1])
+    keys = [[f"user{e}.{i}" for i in range(H5_KEYS)] for e in ens]
+    model, replies = {}, []
+
+    def put(ks, tag):
+        vals = [[f"{tag}.{e}.{k}".encode() * 4 for k in ks[e]]
+                for e in ens]
+        got = settle(svc, [svc.kput_many(e, ks[e], vals[e]) for e in ens])
+        replies.append(got)
+        for e in ens:
+            for k, v, r in zip(ks[e], vals[e], got[e]):
+                assert r[0] == "ok", (e, k, r)
+                old = model.get((e, k))
+                assert old is None or tuple(r[1]) > old[1]
+                model[(e, k)] = (v, tuple(r[1]))
+
+    def read(ks):
+        got = settle(svc, [svc.kget_many(e, ks[e], want_vsn=True)
+                           for e in ens])
+        replies.append(got)
+        for e in ens:
+            for k, r in zip(ks[e], got[e]):
+                want = model.get((e, k))
+                if want is None or want[0] is NOTFOUND:
+                    assert r[:2] == ("ok", NOTFOUND), (e, k, r)
+                else:
+                    assert r == ("ok", want[0], want[1]), (e, k, r)
+
+    put(keys, "load")
+    assert all([svc.key_slot[e][k] for k in keys[e]] == slots[e]
+               for e in ens)
+    svc.set_fast_reads(False)       # every read a device round
+    read(keys)
+    order = [[keys[e][i] for i in rng.permutation(H5_KEYS)] for e in ens]
+    third = H5_KEYS // 3
+    put([o[:third] for o in order], "again")
+    gone = [o[third:2 * third] for o in order]
+    got = settle(svc, [svc.kdelete_many(e, gone[e]) for e in ens])
+    replies.append(got)
+    for e in ens:
+        for k, r in zip(gone[e], got[e]):
+            assert r[0] == "ok", (e, k, r)
+            model[(e, k)] = (NOTFOUND, None)
+    read([ks + ["never.written"] for ks in keys])
+    svc.set_fast_reads(True)
+    live = [[k for k in keys[e] if model[(e, k)][0] is not NOTFOUND][:2]
+            for e in ens]
+    put(live, "leased")
+    hits = svc.read_fastpath_hits
+    got = settle(svc, [svc.kget(e, k) for e in ens for k in live[e]])
+    replies.append(got)
+    assert got == [("ok", model[(e, k)][0]) for e in ens for k in live[e]]
+    assert svc.read_fastpath_hits == hits + 2 * H5_ENS
+    assert svc.stats()["corruptions_detected"] == 0
+    return replies, model, keys, slots
+
+
+@pytest.fixture(scope="module")
+def h5_arms():
+    """The same seeded stream served by ``mesh_engine(4)`` (an
+    ensemble a shard, every flush the full-grid mesh step over row
+    planes under ``shard_map``) and by one device, at 4 x 3 x 131,072:
+    ``ring256-n3-h5-mesh4``'s height and shard count."""
+    from test_h5_ring import WALL_CONFIG
+
+    arms = {}
+    for name, engine in (("mesh", mesh_engine(4)), ("one", None)):
+        svc = BatchedEnsembleService(WallRuntime(), H5_ENS, 3, H5_SLOTS,
+                                     tick=None, config=WALL_CONFIG,
+                                     engine=engine)
+        arms[name] = (svc, *_h5_stream(svc))
+    yield arms
+    for svc, *_ in arms.values():
+        svc.stop()
+
+
+def test_mesh_served_path_over_five_levels_is_the_plain_models(h5_arms):
+    """Every reply was the dict's (asserted as the stream ran), every
+    launch was a full-grid mesh launch (a shard of one ensemble never
+    slices), and the device holds what the model holds."""
+    from riak_ensemble_tpu.ops import engine as eng
+    from riak_ensemble_tpu.types import NOTFOUND
+
+    svc, replies, model, keys, slots = h5_arms["mesh"]
+    assert len(eng.tree_sizes(H5_SLOTS)) == 5
+    m = svc.stats()["mesh"]
+    assert (m["shards"], m["e_loc"]) == (4, 1)
+    assert m["launches_sliced"] == 0
+    assert m["launches_pack_gathered"] + m["launches_full_grid"] > 0
+    assert svc.state.tree_rows.sharding == \
+        svc.engine.init_state(H5_ENS, 3, H5_SLOTS).tree_rows.sharding
+    seq = np.asarray(svc.state.obj_seq)
+    for e in range(H5_ENS):
+        for k, s in zip(keys[e], slots[e]):
+            value, vsn = model[(e, k)]
+            if value is NOTFOUND:
+                assert k not in svc.key_slot[e]
+            else:       # the acknowledged version, on every replica
+                assert (seq[e, :, s] == vsn[1]).all(), (e, k)
+
+
+def test_mesh_trees_over_five_levels_are_the_plain_build(h5_arms):
+    """Every replica's leaves are the hashes of its own objects and
+    its interior nodes, rows and tail, the plain bottom-up build over
+    them, on every shard."""
+    import jax.numpy as jnp
+    from test_h5_ring import plain_uppers
+    from riak_ensemble_tpu.ops import engine as eng
+    from riak_ensemble_tpu.ops import hash as hashk
+
+    svc = h5_arms["mesh"][0]
+    st = svc.state
+    leaf = np.asarray(st.tree_leaf)
+    assert np.array_equal(leaf, np.asarray(hashk.obj_leaf_hash(
+        st.obj_epoch, st.obj_seq, st.obj_val)))
+    node = np.asarray(jnp.concatenate(
+        eng.rows_to_levels(st.tree_rows, st.tree_node, H5_SLOTS),
+        axis=-2))
+    for e in range(H5_ENS):
+        for m in range(3):
+            assert np.array_equal(node[e, m], plain_uppers(leaf[e, m]))
+    node_bad, leaf_bad = svc.engine.verify_trees(st)
+    assert not np.asarray(node_bad).any()
+    assert not np.asarray(leaf_bad).any()
+
+
+def test_mesh_and_one_device_agree_over_five_levels(h5_arms):
+    """The same replies and the same state, planes and host mirrors,
+    for the same seeded stream."""
+    (mesh, r_mesh, *_), (one, r_one, *_) = h5_arms["mesh"], h5_arms["one"]
+    assert r_mesh == r_one
+    _assert_state_equal(one, mesh)
