@@ -26,7 +26,7 @@ from __future__ import annotations
 
 import time
 from collections import deque
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, Optional
 
 __all__ = ["CompileWatch", "COMPILE_EVENTS", "signature"]
 
@@ -37,27 +37,31 @@ COMPILE_EVENTS: "deque[Dict[str, Any]]" = deque(maxlen=256)
 
 
 def signature(args: tuple, kwargs: dict) -> str:
-    """Compact shape signature of a call's array arguments, e.g.
-    ``"f32[4,64];i32[4,64]"`` truncated to the first few leaves —
-    enough to name the (K, A) bucket that compiled.  Computed only on
-    a cache miss."""
+    """Compact signature of a call, one part an argument: an array's
+    ``dtype[shape]``, a pytree's first leaf and its leaf count
+    (``u32[64,3]..x14``: an engine state), a static scalar as it is
+    (``want_vsn=True``), e.g.
+    ``"u32[64,3]..x14;int32[13,64];bool[64,3];want_vsn=True;gather=8"``
+    — enough to name the (K, A) bucket that compiled.  Computed only
+    on a cache miss."""
+    def one(x: Any) -> str:
+        if isinstance(x, (bool, int, float, str, type(None))):
+            return repr(x)
+        leaves = [leaf for leaf in jax.tree_util.tree_leaves(x)
+                  if getattr(leaf, "shape", None) is not None]
+        if not leaves:
+            return "?"
+        dt = getattr(leaves[0], "dtype", None)
+        dt = getattr(dt, "name", str(dt)) if dt is not None else "?"
+        part = f"{dt}[{','.join(map(str, leaves[0].shape))}]"
+        return part if len(leaves) == 1 else f"{part}..x{len(leaves)}"
+
     try:
         import jax
-        leaves = jax.tree_util.tree_leaves((args, kwargs))
+        return ";".join([one(a) for a in args]
+                        + [f"{k}={one(v)}" for k, v in kwargs.items()])
     except Exception:
-        leaves = list(args)
-    parts: List[str] = []
-    for leaf in leaves:
-        shape = getattr(leaf, "shape", None)
-        if shape is None:
-            continue
-        dt = getattr(leaf, "dtype", None)
-        dt = getattr(dt, "name", str(dt)) if dt is not None else "?"
-        parts.append(f"{dt}[{','.join(map(str, shape))}]")
-        if len(parts) >= 6:
-            parts.append("...")
-            break
-    return ";".join(parts)
+        return "?"  # telemetry must never fail the launch
 
 
 class CompileWatch:

@@ -88,15 +88,16 @@ DERIVED_MARKS = ("enqueue", "resolve_native", "resolve_fallback",
                  "between_flushes", "gc", "obs",
                  # the enqueue half's inside: the slab's device_put
                  # alone (inside h2d; on a mesh the per-shard
-                 # placement) and the two jit calls apart (inside
+                 # placement) and the launch's one program call (inside
                  # dispatch; the rest of it is the d2h copy's start)
-                 "h2d_put", "dispatch_step", "dispatch_pack")
+                 "h2d_put", "dispatch_step")
 
 #: per-flush SHAPE fields, counts and not seconds: rounds, uploads,
-#: whether the step ran sliced, whether an arrival started the flush,
-#: and the launch's shape: the pow2 width it packed at (``a``; 0 on
-#: the full grid), its real columns, the busiest shard's, the shards
-SHAPE_FIELDS = ("k", "uploads", "sliced", "arrival",
+#: device programs called, whether the step ran sliced, whether an
+#: arrival started the flush, and the launch's shape: the pow2 width
+#: it packed at (``a``; 0 on the full grid), its real columns, the
+#: busiest shard's, the shards
+SHAPE_FIELDS = ("k", "uploads", "calls", "sliced", "arrival",
                 "a", "cols", "cols_max", "shards")
 
 #: per-flush record fields that are shape/identity metadata or

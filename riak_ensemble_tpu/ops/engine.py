@@ -1837,25 +1837,33 @@ def _full_step_sliced_body(state: EngineState, active_idx: jax.Array,
 #   row 1            cand        [W]
 #   row 2            lease_ok    [W]   bool as 0/1, ONE row: the program
 #                                      broadcasts it to [K, W]
-#   row 3            active_idx  [W]   SLICED launches only (pad = E)
+#   row 3            active_idx  [W]   launches that gather only: a
+#                                      SLICED launch's active columns
+#                                      (pad = E), or the first A columns
+#                                      of a full-width PACK-GATHER
+#                                      launch's (pad = 0; A < W is the
+#                                      condition for gathering, so the
+#                                      row always fits)
 #   then K rows each kind, slot, val, exp_epoch, exp_seq   ([K, W] each)
 #
-# R = head + 5 K with head 3 (4 sliced), so the shape alone carries K
-# and W and a (K, A) bucket stays one program.  Under ``shard_map`` the
+# R = head + 5 K with head 3 (4 with an index row), so the shape alone
+# carries K and W and a (K, A) bucket is one program.  Under ``shard_map`` the
 # slab is sharded ``P(None, 'ens')`` like the op planes it replaces: a
 # row of the local block is that shard's ``P('ens')`` vector.  A SLICED
 # mesh slab is ``n_shards`` blocks of one width ``a_loc`` side by side
 # (W = n_shards * a_loc), and each shard's local block is an ordinary
 # sliced slab of its own: its columns gathered to the block's front,
-# its index row LOCAL (pad = the rows a shard holds, E / n_shards).
+# its index row LOCAL (pad = the rows a shard holds, E / n_shards).  A
+# full-width mesh slab's index row is LOCAL too: the first A columns of
+# each shard's block hold that shard's own pack-gather indices.
 
 SLAB_ELECT, SLAB_CAND, SLAB_LEASE, SLAB_ACTIVE_IDX = 0, 1, 2, 3
 #: the per-round planes, K rows each, in slab order
 SLAB_PLANES = ("kind", "slot", "val", "exp_epoch", "exp_seq")
 
 
-def _slab_head(sliced: bool) -> int:
-    return SLAB_ACTIVE_IDX + 1 if sliced else SLAB_ACTIVE_IDX
+def _slab_head(indexed: bool) -> int:
+    return SLAB_ACTIVE_IDX + 1 if indexed else SLAB_ACTIVE_IDX
 
 
 def pack_op_slab(width: int, k: int, elect, cand, lease_ok, planes,
@@ -1865,14 +1873,16 @@ def pack_op_slab(width: int, k: int, elect, cand, lease_ok, planes,
 
     ``planes`` are the five ``[K, E]`` host planes in
     :data:`SLAB_PLANES` order (None = all zero, the absent CAS
-    versions).  Full width: ``width`` is E and everything is copied
-    whole.  Sliced (``active_idx`` given, ``[width]``, pad = E):
-    ``active`` names the real columns, gathered to the slab's first
-    ``len(active)`` columns; padding columns stay NOOP/zero.  A mesh's
-    sliced slab (the per-shard blocks above) names with ``at`` the slab
-    column each of ``active`` goes to: the front of its shard's block."""
-    sliced = active_idx is not None
-    head = _slab_head(sliced)
+    versions).  Full width (``active`` None): ``width`` is E and
+    everything is copied whole; ``active_idx`` (``[width]``), where
+    given, is the pack-gather's index row.  Sliced (``active`` names
+    the real columns, ``active_idx`` is ``[width]`` with pad = E):
+    the real columns are gathered to the slab's first ``len(active)``
+    columns; padding columns stay NOOP/zero.  A mesh's sliced slab (the
+    per-shard blocks above) names with ``at`` the slab column each of
+    ``active`` goes to: the front of its shard's block."""
+    sliced = active is not None
+    head = _slab_head(active_idx is not None)
     slab = np.zeros((head + len(SLAB_PLANES) * k, width), np.int32)
     if at is None:
         at = slice(len(active) if sliced else width)
@@ -1884,7 +1894,7 @@ def pack_op_slab(width: int, k: int, elect, cand, lease_ok, planes,
     slab[SLAB_ELECT, at] = cols(elect)
     slab[SLAB_CAND, at] = cols(cand)
     slab[SLAB_LEASE, at] = cols(lease_ok)
-    if sliced:
+    if active_idx is not None:
         slab[SLAB_ACTIVE_IDX] = active_idx
     if k:
         body = slab[head:].reshape(len(SLAB_PLANES), k, width)
@@ -1894,12 +1904,12 @@ def pack_op_slab(width: int, k: int, elect, cand, lease_ok, planes,
     return slab
 
 
-def split_op_slab(slab: jax.Array, sliced: bool = False):
+def split_op_slab(slab: jax.Array, indexed: bool = False):
     """Program half: ``(elect, cand, lease_ok[K, W], kind, slot, val,
     exp_epoch, exp_seq)`` — the step bodies' operands in their call
-    order — at static offsets of the slab (a sliced slab's index row
-    is read by the caller)."""
-    head = _slab_head(sliced)
+    order — at static offsets of the slab (``indexed``: the slab has
+    an index row, which the caller reads)."""
+    head = _slab_head(indexed)
     n_p = len(SLAB_PLANES)
     w = slab.shape[1]
     k = (slab.shape[0] - head) // n_p
@@ -1911,10 +1921,13 @@ def split_op_slab(slab: jax.Array, sliced: bool = False):
 
 def _full_step_slab_body(state: EngineState, slab: jax.Array,
                          up: jax.Array,
-                         axis_name: Optional[str] = None
+                         axis_name: Optional[str] = None,
+                         indexed: bool = False
                          ) -> Tuple[EngineState, jax.Array, KvResult]:
-    """:func:`_full_step_body` fed by one op slab (layout above)."""
-    elect, cand, lease, kind, slot, val, xe, xs = split_op_slab(slab)
+    """:func:`_full_step_body` fed by one op slab (layout above;
+    ``indexed``: it carries a pack-gather's index row)."""
+    elect, cand, lease, kind, slot, val, xe, xs = split_op_slab(
+        slab, indexed)
     return _full_step_body(state, elect, cand, kind, slot, val, lease,
                            up, axis_name=axis_name, exp_epoch=xe,
                            exp_seq=xs)
@@ -1928,25 +1941,121 @@ def _full_step_sliced_slab_body(state: EngineState, slab: jax.Array,
     """:func:`_full_step_sliced_body` fed by one A-width op slab whose
     row :data:`SLAB_ACTIVE_IDX` is the active-column index vector."""
     elect, cand, lease, kind, slot, val, xe, xs = split_op_slab(
-        slab, sliced=True)
+        slab, indexed=True)
     return _full_step_sliced_body(
         state, slab[SLAB_ACTIVE_IDX], elect, cand, kind, slot, val,
         lease, up, axis_name=axis_name, exp_epoch=xe, exp_seq=xs)
 
 
-#: the served step programs: ``(state, slab, up)``, plain and donated
-#: (see :data:`full_step_donate` for the aliasing contract; the sliced
-#: step's scatter back into the donated object planes and ``tree_leaf``
-#: is an in-place A-row update on the chip, ``tree_node`` and the small
-#: planes pass through a relayouted copy: "The sliced step's two
-#: edges" above)
-full_step_slab = jax.jit(_full_step_slab_body,
-                         static_argnames=("axis_name",))
-full_step_slab_donate = jax.jit(_full_step_slab_body,
-                                static_argnames=("axis_name",),
+# ---------------------------------------------------------------------------
+# The result pack: what a launch hands back to the host, ONE vector
+
+
+def pack_results(won: jax.Array, res: KvResult, want_vsn: bool,
+                 active_idx: Optional[jax.Array] = None) -> jax.Array:
+    """Flatten a launch's results into ONE uint8 vector on device.
+
+    The host needs ~7 result arrays per launch; fetching them
+    separately costs a device round trip each.  And the d2h payload
+    is paid on every launch, so the six boolean planes travel
+    BIT-PACKED (32x smaller than int32) and only the genuinely
+    integer planes ride at full
+    width, bitcast into the same buffer: one fused pack, one
+    transfer, ~3.6x less data than the all-int32 layout.
+
+    ACTIVE-COLUMN COMPACTION: ``active_idx [A]`` (A pow2-bucketed,
+    padding repeats index 0) gathers the per-round client planes down
+    to the columns the flush actually scheduled ops into
+    (:func:`gather_result_columns`), so the payload scales
+    ``O(K·A)`` instead of ``O(K·E)`` — decoupled from the launch
+    grid.  The election/lease/corruption planes stay full width: the
+    host's lease renewal and scrub path see every column, active or
+    not.  ``None`` keeps the full-width layout.
+
+    Layout: packbits([won E | quorum_ok E | corrupt E*M |
+    committed K*A | get_ok K*A | found K*A]) ++ bitcast_u8(
+    [value K*A | (vsn_epoch K*A | vsn_seq K*A)])  (A = E when
+    uncompacted).  ``batched_host.packed_nbytes`` /
+    ``unpack_results`` / ``unpack_results_sharded`` and
+    ``native/resolvekernel.cc`` are the host's half of it.
+    """
+    with jax.named_scope("result_pack"):
+        if active_idx is not None:
+            res = gather_result_columns(res, active_idx)
+        flags = jnp.concatenate([
+            won.ravel(),
+            res.quorum_ok.any(0).ravel(),
+            res.tree_corrupt.any(0).ravel(),
+            res.committed.ravel(),
+            res.get_ok.ravel(),
+            res.found.ravel(),
+        ]).astype(bool)
+        ints = [res.value.ravel()]
+        if want_vsn:
+            ints += [res.obj_vsn[..., 0].ravel(), res.obj_vsn[..., 1].ravel()]
+        ints_u8 = jax.lax.bitcast_convert_type(
+            jnp.concatenate(ints), jnp.uint8).ravel()
+        return jnp.concatenate([jnp.packbits(flags), ints_u8])
+
+
+def pack_gather_index(slab: jax.Array, gather: int
+                      ) -> Optional[jax.Array]:
+    """The pack-gather's index vector of a full-width slab (or of a
+    shard's block of one): the first ``gather`` columns of row
+    :data:`SLAB_ACTIVE_IDX`; ``gather`` 0 = the launch packs at full
+    width and its slab has no index row."""
+    return slab[SLAB_ACTIVE_IDX, :gather] if gather else None
+
+
+# ---------------------------------------------------------------------------
+# The served programs: ONE program a launch, (state, slab, up) -> (state, flat)
+
+
+def _slab_step_body(state: EngineState, slab: jax.Array, up: jax.Array,
+                    sliced: bool = False, gather: int = 0,
+                    axis_name: Optional[str] = None
+                    ) -> Tuple[EngineState, jax.Array, KvResult]:
+    """The step body a launch runs over its slab: the sliced one, or
+    the full-width one (its slab carries an index row where the pack
+    gathers, ``gather`` > 0)."""
+    if sliced:
+        return _full_step_sliced_slab_body(state, slab, up,
+                                           axis_name=axis_name)
+    return _full_step_slab_body(state, slab, up, axis_name=axis_name,
+                                indexed=bool(gather))
+
+
+def _launch_body(state: EngineState, slab: jax.Array, up: jax.Array,
+                 want_vsn: bool, gather: int = 0, sliced: bool = False,
+                 axis_name: Optional[str] = None
+                 ) -> Tuple[EngineState, jax.Array]:
+    """What a launch asks of the device: :func:`_slab_step_body`, then
+    :func:`pack_results` of what it returned: a sliced launch's
+    A-width results as they are, a full-width launch's gathered down
+    to the ``gather`` columns the slab's index row names (0 = packed
+    at full width)."""
+    state, won, res = _slab_step_body(state, slab, up, sliced, gather,
+                                      axis_name)
+    return state, pack_results(won, res, want_vsn,
+                               pack_gather_index(slab, gather))
+
+
+#: the served programs, plain and donated (see :data:`full_step_donate`
+#: for the aliasing contract; the sliced step's scatter back into the
+#: donated object planes and ``tree_leaf`` is an in-place A-row update
+#: on the chip, ``tree_node`` and the small planes pass through a
+#: relayouted copy: "The sliced step's two edges" above).  ``want_vsn``
+#: and the pack-gather's width are static, so a (K, A) bucket is ONE
+#: program: the step and the pack of its results.
+_FULL_STATIC = ("want_vsn", "gather", "axis_name")
+full_step_slab = jax.jit(_launch_body, static_argnames=_FULL_STATIC)
+full_step_slab_donate = jax.jit(_launch_body,
+                                static_argnames=_FULL_STATIC,
                                 donate_argnums=(0,))
-full_step_sliced_slab = jax.jit(_full_step_sliced_slab_body,
-                                static_argnames=("axis_name",))
-full_step_sliced_slab_donate = jax.jit(_full_step_sliced_slab_body,
-                                       static_argnames=("axis_name",),
+_launch_sliced_body = functools.partial(_launch_body, sliced=True)
+_SLICED_STATIC = ("want_vsn", "axis_name")
+full_step_sliced_slab = jax.jit(_launch_sliced_body,
+                                static_argnames=_SLICED_STATIC)
+full_step_sliced_slab_donate = jax.jit(_launch_sliced_body,
+                                       static_argnames=_SLICED_STATIC,
                                        donate_argnums=(0,))

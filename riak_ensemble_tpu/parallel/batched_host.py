@@ -12,7 +12,7 @@ host *service* that multiplexes thousands of engine-backed ensembles —
   of E leader processes × worker pools).  There is ONE way into the
   step: every launch (a flush, an election-only round, ``execute()``,
   a replica's apply) uploads one op slab and calls one program,
-  ``(state, op slab, up) -> (state, won, result)``, sliced where a
+  ``(state, op slab, up) -> (state, packed results)``, sliced where a
   device can slice and full width otherwise (``_launch_enqueue``);
 - the host side keeps what consensus doesn't need on-device: the
   key→slot assignment per ensemble, the payload store (device arrays
@@ -81,7 +81,6 @@ docs/ARCHITECTURE.md §9 "Lease-protected reads".
 from __future__ import annotations
 
 import errno
-import functools
 import operator
 import os
 import random
@@ -127,85 +126,6 @@ _OP_SLO_FIELDS = operator.attrgetter("kind", "n", "t_rx", "t_sub",
 
 
 
-def _pack_results_body(won, res: eng.KvResult, want_vsn: bool,
-                       active_idx=None):
-    """Flatten a launch's results into ONE uint8 vector on device.
-
-    The host needs ~7 result arrays per launch; fetching them
-    separately costs a device round trip each.  And the d2h payload
-    is paid on every launch, so the six boolean planes travel
-    BIT-PACKED (32x smaller than int32) and only the genuinely
-    integer planes ride at full
-    width, bitcast into the same buffer: one fused pack, one
-    transfer, ~3.6x less data than the all-int32 layout.
-
-    ACTIVE-COLUMN COMPACTION: ``active_idx [A]`` (A pow2-bucketed,
-    padding repeats index 0) gathers the per-round client planes down
-    to the columns the flush actually scheduled ops into
-    (:func:`engine.gather_result_columns`), so the payload scales
-    ``O(K·A)`` instead of ``O(K·E)`` — decoupled from the launch
-    grid.  The election/lease/corruption planes stay full width: the
-    host's lease renewal and scrub path see every column, active or
-    not.  ``None`` keeps the historical full-width layout.
-
-    Layout: packbits([won E | quorum_ok E | corrupt E*M |
-    committed K*A | get_ok K*A | found K*A]) ++ bitcast_u8(
-    [value K*A | (vsn_epoch K*A | vsn_seq K*A)])  (A = E when
-    uncompacted).
-    """
-    with jax.named_scope("result_pack"):
-        if active_idx is not None:
-            res = eng.gather_result_columns(res, active_idx)
-        flags = jnp.concatenate([
-            won.ravel(),
-            res.quorum_ok.any(0).ravel(),
-            res.tree_corrupt.any(0).ravel(),
-            res.committed.ravel(),
-            res.get_ok.ravel(),
-            res.found.ravel(),
-        ]).astype(bool)
-        ints = [res.value.ravel()]
-        if want_vsn:
-            ints += [res.obj_vsn[..., 0].ravel(), res.obj_vsn[..., 1].ravel()]
-        ints_u8 = jax.lax.bitcast_convert_type(
-            jnp.concatenate(ints), jnp.uint8).ravel()
-        return jnp.concatenate([jnp.packbits(flags), ints_u8])
-
-
-_pack_results = jax.jit(_pack_results_body,
-                        static_argnames=("want_vsn",))
-
-
-@functools.partial(jax.jit, static_argnames=("want_vsn", "sharding"))
-def _pack_results_gathered(won, res: eng.KvResult, want_vsn: bool,
-                           sharding, active_idx=None):
-    """Mesh-aware pack: a sharded step's result planes leave the
-    kernel with MIXED shardings ('ens'-sharded [K, E] planes with E
-    minor, peer-sharded corrupt masks, replicated scalars).  Raveling
-    and concatenating those directly leaves GSPMD no expressible
-    output sharding, so it falls back to involuntary full
-    rematerialization (replicate-then-repartition) per operand — the
-    exact ``spmd_partitioner`` warnings MULTICHIP_r04 recorded, and a
-    real ICI/HBM tax on the per-flush d2h critical path at scale.  The
-    packed vector is fetched to the host anyway, so gather explicitly:
-    one ``with_sharding_constraint`` to fully-replicated per input
-    turns the implicit remats into ordinary all-gathers riding ICI,
-    and the pack itself runs replicated (no further resharding).
-    ``sharding`` is the mesh's fully-replicated NamedSharding
-    (static: hashable and compile-time constant).  The active-column
-    index vector is constrained replicated too — the column gather
-    then runs on the already-replicated planes instead of forcing a
-    resharding of its own.
-    """
-    def con(x):
-        return jax.lax.with_sharding_constraint(x, sharding)
-
-    return _pack_results_body(con(won), jax.tree.map(con, res),
-                              want_vsn,
-                              None if active_idx is None
-                              else con(active_idx))
-
-
 def _backend_mem_bytes() -> float:
     """Live device-memory gauge read (export time only): bytes in
     use on the default jax device; NaN when the backend keeps no
@@ -247,85 +167,12 @@ def _state_bytes_per_device(state) -> List[int]:
 
 
 def mesh_ens_shards(engine) -> int:
-    """Number of 'ens'-axis shards the SHARD-WISE pack path applies
-    to: >1 only for a mesh engine whose 'peer' axis is unsharded
-    (each device then holds complete [e_loc, M, ...] rows, so the
-    per-shard pack needs no cross-device traffic at all).  A sharded
-    peer axis keeps the gathered pack (`_pack_results_gathered`) —
-    the corrupt plane spans peer shards there.  0 = not shard-wise
-    (single-device engines included)."""
-    mesh = getattr(engine, "mesh", None)
-    if mesh is None or int(mesh.shape.get("peer", 1)) != 1:
-        return 0
-    n = int(mesh.shape["ens"])
-    return n if n > 1 else 0
-
-
-def _make_shardwise_packer(mesh):
-    """Compaction-aware SHARD-WISE pack for an 'ens'-sharded mesh
-    (peer axis unsharded): ``_pack_results_body`` runs PER ENS-SHARD
-    under shard_map — each device bit-packs its own [K, e_loc] result
-    block with its own LOCAL active-column gather, and the packed d2h
-    payload leaves each device without any all-gather (the gathered
-    pack's replication step is exactly the cross-device tax this
-    removes).  The active-column index operand is ``[n_sh, A_loc]``
-    LOCAL indices (one row per shard, pad 0 — ignored by the host
-    unpack), sharded P('ens', None) so row s lands on shard s.  The
-    output is the per-shard flat vectors concatenated in shard order
-    — :func:`unpack_results_sharded` inverts it.
-
-    Returns a wrapper with the ``(won, res, want_vsn, active_idx)``
-    packer signature, ``_cache_size`` summed over the member programs
-    (CompileWatch), and ``_shardwise`` = the shard count (the service
-    keys its launch records off it).
-    """
-    from jax.sharding import PartitionSpec as P
-
-    res_specs = eng.scan_result_specs()
-    programs: Dict[Tuple[bool, bool], Any] = {}
-
-    def program(want_vsn: bool, has_active: bool):
-        prog = programs.get((want_vsn, has_active))
-        if prog is None:
-            if has_active:
-                def body(won, res, aidx):
-                    return _pack_results_body(won, res, want_vsn,
-                                              aidx.ravel())
-                in_specs = (P("ens"), res_specs, P("ens", None))
-            else:
-                def body(won, res):
-                    return _pack_results_body(won, res, want_vsn)
-                in_specs = (P("ens"), res_specs)
-            prog = jax.jit(jax.shard_map(
-                body, mesh=mesh, in_specs=in_specs,
-                out_specs=P("ens"), check_vma=False))
-            programs[(want_vsn, has_active)] = prog
-        return prog
-
-    def pack(won, res, want_vsn, active_idx=None):
-        if active_idx is None:
-            return program(bool(want_vsn), False)(won, res)
-        return program(bool(want_vsn), True)(won, res, active_idx)
-
-    pack._cache_size = lambda: sum(p._cache_size()
-                                   for p in programs.values())
-    pack._shardwise = int(mesh.shape["ens"])
-    return pack
-
-
-def _select_packer(engine):
-    """The pack program matching the engine's placement: plain jit for
-    single-device engines, the shard-wise form for 'ens'-sharded
-    meshes with an unsharded peer axis, the gathered form for the
-    rest."""
-    mesh = getattr(engine, "mesh", None)
-    if mesh is None:
-        return _pack_results
-    if mesh_ens_shards(engine):
-        return _make_shardwise_packer(mesh)
-    from jax.sharding import NamedSharding, PartitionSpec
-    rep = NamedSharding(mesh, PartitionSpec())
-    return functools.partial(_pack_results_gathered, sharding=rep)
+    """Number of 'ens'-axis shards the engine's SHARD-WISE result pack
+    runs over (``ShardedEngine.pack_shards``: >1 only for a mesh whose
+    'peer' axis is unsharded): the packed payload is then per-shard
+    blocks and the launch buckets its columns per shard.  0 = not
+    shard-wise (single-device engines included)."""
+    return int(getattr(engine, "pack_shards", 0))
 
 
 #: smallest active-column bucket the pack compiles: below 8 columns
@@ -347,7 +194,7 @@ SLICE_MIN_E = 256
 
 def packed_nbytes(e: int, m: int, k: int, want_vsn: bool,
                   a_width: Optional[int] = None) -> int:
-    """Size in bytes of one :func:`_pack_results` payload — the
+    """Size in bytes of one ``engine.pack_results`` payload — the
     per-flush d2h transfer.  ``a_width`` is the compacted column
     count (None = full width E); used for the ``payload_bytes``
     accounting and the bench's full-width-vs-compacted A/B."""
@@ -359,7 +206,7 @@ def packed_nbytes(e: int, m: int, k: int, want_vsn: bool,
 def unpack_results(flat: np.ndarray, e: int, m: int, k: int,
                    want_vsn: bool, active: Optional[np.ndarray] = None,
                    a_width: int = 0, sliced: bool = False):
-    """Invert :func:`_pack_results`: one packed uint8 vector →
+    """Invert ``engine.pack_results``: one packed uint8 vector →
     ``(won, quorum_ok, corrupt, committed, get_ok, found, value,
     vsn)`` host arrays (the k == 0 planes are None).  Module-level so
     the replica side of the replication group
@@ -443,8 +290,8 @@ def unpack_results_sharded(flat: np.ndarray, e: int, m: int, k: int,
                            shard_active: Optional[List[np.ndarray]]
                            = None, a_width: int = 0,
                            sliced: bool = False):
-    """Invert the shard-wise packer (:func:`_make_shardwise_packer`):
-    the payload is ``n_shards`` :func:`_pack_results` blocks in shard
+    """Invert a shard-wise mesh engine's pack (``mesh.ShardedEngine``):
+    the payload is ``n_shards`` ``engine.pack_results`` blocks in shard
     order, each covering a contiguous ``e_loc = E/n_shards`` column
     slice, compacted per shard through its LOCAL active index list
     (``shard_active[s]``, ≤ ``a_width`` entries; None = every shard
@@ -730,11 +577,14 @@ class _BatchAccum:
 
 
 class _StepFns(NamedTuple):
-    """The two step programs a launch can dispatch, both ``(state, op
-    slab, up)`` (``engine.pack_op_slab`` has the layout), donated when
-    the service donates: ``slab`` at full width, ``sliced_slab`` on
-    the gathered active columns (None = the launch cannot slice on
-    this engine)."""
+    """The two programs a launch can call, both ``(state, op slab, up)
+    -> (state, packed results)`` (``engine.pack_op_slab`` has the
+    slab's layout, ``engine.pack_results`` the vector's), donated when
+    the service donates: ``slab`` at full width (static ``want_vsn``
+    and ``gather``, the pack-gather's width), ``sliced_slab`` on the
+    gathered active columns (static ``want_vsn``; None = the launch
+    cannot slice on this engine).  ONE of them is all a launch asks of
+    the device: the step and the pack of its results."""
 
     slab: Any
     sliced_slab: Any
@@ -861,10 +711,6 @@ class BatchedEnsembleService:
         self.tick = tick
         self.max_k = max_ops_per_tick
         self.engine = engine if engine is not None else _LocalEngine()
-        #: result packer matched to the engine's placement (mesh
-        #: engines pack per ens-shard or gather explicitly — see
-        #: _make_shardwise_packer / _pack_results_gathered)
-        self._pack = _select_packer(self.engine)
         #: >0 = the shard-wise mesh pack path: packed payloads are
         #: per-ens-shard blocks and active-column compaction computes
         #: its |A| bucket PER SHARD (compaction-aware sharding)
@@ -1126,6 +972,12 @@ class BatchedEnsembleService:
         #: carries its own ``sliced`` 0/1 beside ``uploads``)
         self.launches_sliced = 0
         self.launches_unsliced = 0
+        #: what the launches handed the device and asked of it: host
+        #: arrays uploaded and device programs called, summed over
+        #: every launch (``stats()["launch"]``; one of each a launch,
+        #: one upload more where the failure detector changed ``up``)
+        self.launch_uploads = 0
+        self.launch_calls = 0
         #: the unsliced launches that pack-gathered (the rest stepped
         #: and packed the full grid), and over every launch that
         #: packed at a width (``a`` > 0) the sums of the busiest
@@ -1350,9 +1202,7 @@ class BatchedEnsembleService:
             "retpu_compile_ms_total",
             "wall ms spent inside watched calls that compiled",
             label_name="phase")
-        if self._obs:
-            self._pack = self._watched("pack", self._pack)
-        #: the launch's step programs, bound once (see _bind_step_fns)
+        #: the launch's programs, bound once (see _bind_step_fns)
         self._fns = self._bind_step_fns()
         #: per-tenant attribution planes [E] (a tenant is an ensemble
         #: row; named tenants via _row_name / set_tenant_label):
@@ -3581,15 +3431,16 @@ class BatchedEnsembleService:
         return out
 
     def _bind_step_fns(self) -> _StepFns:
-        """The engine's two launch programs, resolved ONCE (the
-        constructor calls this when the engine and ``_donate`` are
-        set): the donated twins when the service donates.  The service
-        trusts the engine it was given; a test that injects a fault
-        wraps EVERY program of its engine
-        (``testing.wrap_engine_steps``).  With obs on each reports its
-        executable-cache misses (ARCHITECTURE §11) as ``step`` /
-        ``step_sliced``: what a served flush launches, as the names
-        always meant (the benchmark's warm-up lines group by them)."""
+        """The engine's two launch programs (step and result pack,
+        one program each), resolved ONCE (the constructor calls this
+        when the engine and ``_donate`` are set): the donated twins
+        when the service donates.  The service trusts the engine it
+        was given; a test that injects a fault wraps EVERY program of
+        its engine (``testing.wrap_engine_steps``).  With obs on each
+        reports its executable-cache misses (ARCHITECTURE §11) as
+        ``step`` / ``step_sliced``: what a served flush launches, as
+        the names always meant (the benchmark's warm-up lines group by
+        them)."""
         e = self.engine
         twin = "_donate" if self._donate else ""
         sliced = getattr(e, "full_step_sliced_slab" + twin, None)
@@ -3633,16 +3484,20 @@ class BatchedEnsembleService:
                         cand: Optional[np.ndarray] = None,
                         lease_ok: Optional[np.ndarray] = None
                         ) -> _InFlightLaunch:
-        """ENQUEUE half of a launch: build + upload the inputs,
-        dispatch the fused step, the result pack, and the packed d2h
-        transfer — all asynchronous — and return the in-flight record.
+        """ENQUEUE half of a launch: ONE upload (everything the step
+        reads from the host, the pack-gather's index rows included, as
+        one op slab) and ONE program call (the fused step and the pack
+        of its results, ``(state, slab, up) -> (state, flat)``), then
+        the packed vector's d2h transfer is started — all asynchronous
+        — and the in-flight record returned.  The flush record says so:
+        ``uploads`` (1; 2 on the launch after the failure detector
+        changed ``up``) and ``calls`` (device programs called: 1).
         No host read of device data happens here, so while batch N's
         packed vector is in flight the host is free to enqueue batch
         N+1 against the new (not yet materialized) ``EngineState`` —
         the overlap :meth:`flush` exploits at ``pipeline_depth`` > 1.
         """
         del entries  # base launch doesn't need them (subclass hook)
-        jnp = self._jnp
         if elect is None:
             elect, cand = self._election_inputs()
         now = self.runtime.now
@@ -3674,10 +3529,12 @@ class BatchedEnsembleService:
         #   row is LOCAL: the gather runs inside shard_map.
         # - PACK-GATHER (small/mid grids, or a_loc above e_loc/4):
         #   the step keeps the full grid; only the packed result
-        #   gathers down to [K, a_loc] (the d2h cut alone).
+        #   gathers down to [K, a_loc] (the d2h cut alone), in the
+        #   same program, by the index row of each shard's own block
+        #   of the slab.
         # Buckets ride the pow2 A ladder (mirroring the K ladder's
         # compile-reuse discipline).
-        active = aidx_np = shard_active = at = None
+        active = idx_rows = shard_active = at = None
         a_width = cols_max = 0
         sliced = False
         n_sh = self._mesh_shards or 1
@@ -3693,17 +3550,22 @@ class BatchedEnsembleService:
                 active = cols.astype(np.int32)
                 a_width = a_loc
                 sliced = self._slices(a_loc)
-                # sliced pads aim OUT OF RANGE (the local row count)
-                # so the state scatter drops them; the pack gather
-                # pads with column 0 (ignored by the host unpack)
-                aidx_np = np.full((n_sh, a_loc), e_loc if sliced else 0,
-                                  np.int32)
+                # the slab's index row, a block a shard, each shard's
+                # LOCAL columns at its block's front.  Sliced: blocks
+                # of a_loc, pads aim OUT OF RANGE (the local row
+                # count) so the state scatter drops them; the pack
+                # gather reads the first a_loc columns of full-width
+                # blocks and pads with column 0 (ignored by the host
+                # unpack)
+                idx_rows = (np.full((n_sh, a_loc), e_loc, np.int32)
+                            if sliced
+                            else np.zeros((n_sh, e_loc), np.int32))
                 for si, p in enumerate(per_shard):
-                    aidx_np[si, :p.size] = p
+                    idx_rows[si, :p.size] = p
                 if self._mesh_shards:
                     shard_active = per_shard
                     if sliced:  # each column's place in its block
-                        at = np.flatnonzero(aidx_np.ravel() < e_loc)
+                        at = np.flatnonzero(idx_rows.ravel() < e_loc)
         # EVERY input upload belongs to the h2d mark — an upload
         # inlined into the step call would bill its (synchronous)
         # transfer to 'dispatch' and make the async-enqueue number
@@ -3712,25 +3574,20 @@ class BatchedEnsembleService:
         # changed it (sliced launches gather it on device).
         uploads = int(self._up_dev is None)
         up_j = self._up_device()
-        # ONE upload: everything the step reads from the host as one
-        # op slab (engine.pack_op_slab has the row layout; the program
-        # takes it apart), put where the step wants it.  A sliced
-        # launch's index rows are a slab row; a pack-gather's
-        # (another width) are the packer's operand and a second upload.
+        # ONE upload: everything the program reads from the host as
+        # one op slab (engine.pack_op_slab has the row layout; the
+        # program takes it apart), put where the step wants it; the
+        # index rows are a slab row.
         slab_np = eng.pack_op_slab(
             n_sh * a_width if sliced else self.n_ens, k, elect, cand,
             lease_ok, (kind, slot, val, exp_e, exp_s),
             active if sliced else None,
-            aidx_np.ravel() if sliced else None, at)
+            None if idx_rows is None else idx_rows.ravel(), at)
         with self.spans.span("h2d_put", rec):   # inside h2d
             slab_j = self._put(slab_np, "slab")
         uploads += 1
-        aidx_j = None
-        if aidx_np is not None and not sliced:
-            aidx_j = (self._shard_aidx(aidx_np) if self._mesh_shards
-                      else jnp.asarray(aidx_np[0]))
-            uploads += 1
         rec["uploads"] = uploads
+        rec["calls"] = 1
         rec["sliced"] = int(sliced)
         rec["arrival"] = int(self._by_arrival)
         # the launch's shape: the pow2 width it packed at (0: the
@@ -3743,6 +3600,8 @@ class BatchedEnsembleService:
         rec["shards"] = n_sh
         self.launches_sliced += sliced
         self.launches_unsliced += not sliced
+        self.launch_uploads += uploads
+        self.launch_calls += rec["calls"]
         if a_width:
             self.launches_gathered += not sliced
             self._busiest_sum += cols_max / n_cols
@@ -3762,16 +3621,18 @@ class BatchedEnsembleService:
         lease_snapshot = self.lease_until.copy()
         dispatch = self.spans.span("dispatch", rec).begin()
         try:
-            # the two jit calls apart (inside dispatch; what is
-            # left of it is the d2h copy's start)
-            step = fns.sliced_slab if sliced else fns.slab
+            # the ONE program call (inside dispatch; what is left of
+            # it is the d2h copy's start).  A sliced launch's result
+            # planes are ALREADY A-width; a pack-gather's width is the
+            # full-width program's static ``gather``
             with self.spans.span("dispatch_step", rec):
-                self.state, won, res = step(self.state, slab_j, up_j)
-            # a sliced launch's result planes are ALREADY A-width;
-            # pack-gather mode hands the pack the index vector
-            with self.spans.span("dispatch_pack", rec):
-                flat = self._pack(won, res, want_vsn,
-                                  active_idx=None if sliced else aidx_j)
+                if sliced:
+                    self.state, flat = fns.sliced_slab(
+                        self.state, slab_j, up_j, want_vsn=want_vsn)
+                else:
+                    self.state, flat = fns.slab(
+                        self.state, slab_j, up_j, want_vsn=want_vsn,
+                        gather=a_width)
             # Kick the packed vector's d2h transfer off NOW — the
             # resolve half (possibly a full flush later) only blocks
             # on its completion, so the transfer rides under the next
@@ -3808,17 +3669,6 @@ class BatchedEnsembleService:
         e_loc = self.n_ens // (self._mesh_shards or 1)
         return (self._fns.sliced_slab is not None
                 and e_loc >= SLICE_MIN_E and a_loc * 4 <= e_loc)
-
-    def _shard_aidx(self, pad: np.ndarray):
-        """Place a ``[n_shards, A_loc]`` per-shard local active-index
-        matrix so row s lands on ens-shard s (the shard-wise packer's
-        P('ens', None) operand) — an uncommitted upload would leave
-        the placement to GSPMD and could round-trip through a
-        replicate step."""
-        from jax.sharding import NamedSharding, PartitionSpec
-        return jax.device_put(
-            pad, NamedSharding(self.engine.mesh,
-                               PartitionSpec("ens", None)))
 
     def _put(self, x: np.ndarray, what: str):
         """ONE host→device transfer of a step operand, committed where
@@ -4176,6 +4026,10 @@ class BatchedEnsembleService:
                                if self._occ_launches else 1.0),
             "launches_sliced": self.launches_sliced,
             "launches_unsliced": self.launches_unsliced,
+            "launch": {"launches": (self.launches_sliced
+                                    + self.launches_unsliced),
+                       "uploads": self.launch_uploads,
+                       "calls": self.launch_calls},
             "flush_triggers": dict(self.flush_triggers),
             # WAL-compaction pauses (deferred off the hot path; the
             # svc_compaction latency mark carries the same numbers
@@ -4975,26 +4829,27 @@ class BatchedEnsembleService:
         and on a replication-group replica, diverge it from its
         group).
 
-        Flush depths are pow2-bucketed and the packed-result program
-        is additionally keyed by the active-column bucket AND the
-        static want_vsn flag, so the grid is (K, A) × {vsn, no-vsn}:
-        K in {0, 1, 2, ..., max_k} × A in the pow2 ladder below E
-        plus full width.  The small-K buckets double as the get-only
-        / read-miss flush shapes the read fast path's fallback
-        produces, and the version-less packs are what execute /
+        Flush depths are pow2-bucketed and a launch's ONE program
+        (the step and the pack of its results) is keyed by the
+        active-column bucket AND the static want_vsn flag, so the grid
+        is (K, A) × {vsn, no-vsn}: K in {0, 1, 2, ..., max_k} × A in
+        the pow2 ladder below E plus full width; per bucket the
+        program the launch would call there (the sliced one where its
+        rule slices, else the full-grid one gathering its pack at A).
+        The small-K buckets double as the get-only / read-miss flush
+        shapes the read fast path's fallback produces, and the
+        version-less programs (full width only) are what execute /
         execute_async dispatch — all pre-compiled here so none of
         them pays a first-use compile inside a client's latency
         window.  Without this, the first
         flush at each new (K, A) bucket pays its compile in the
         middle of serving — the dispatch p99 blip the steady-state
-        breakdown can't show.  The pack programs warm on the step's
-        REAL outputs so mesh-sharded result placements compile the
-        executables the live flush dispatches.
+        breakdown can't show.
 
         ``buckets``: optional iterable of ``(k, a_width)`` pairs
-        (a_width None = full width) restricting the PACK grid — the
-        step ladder always warms in full.  bench.py and svcnode share
-        the default full grid.  Compile events recorded during
+        (a_width None = full width) restricting the grid to those
+        (and the election-only K 0 launch).  bench.py and svcnode
+        share the default full grid.  Compile events recorded during
         warmup land under ``phase="warmup"``.
         """
         self._in_warmup = True
@@ -5004,9 +4859,7 @@ class BatchedEnsembleService:
             self._in_warmup = False
 
     def _warmup(self, buckets) -> None:
-        jnp = self._jnp
         e, m, s = self.n_ens, self.n_peers, self.n_slots
-        pack = self._pack
         by_k: Optional[Dict[int, List[Optional[int]]]] = None
         if buckets is not None:
             by_k = {}
@@ -5020,16 +4873,16 @@ class BatchedEnsembleService:
                 return by_k.get(k, [])
             return self._a_ladder()
 
-        # Warm the programs the launch path actually dispatches — with
+        # Warm the programs the launch path actually calls — with
         # donation on, the donated executables (donation changes the
         # compiled program's aliasing, so the plain warm wouldn't cover
         # it).  The throwaway state is THREADED through the calls: a
         # donated call consumes its input state.  Per (K, A) bucket
-        # the launch dispatches EITHER the sliced step (A <= E/4 of
-        # what a shard holds: step + plain pack at A-width) OR the
-        # full-grid step with the gathering pack — warm exactly that,
-        # with operands placed as _launch_enqueue places them
-        # (placement is part of a program's cache key).
+        # the launch calls EITHER the sliced program (A <= E/4 of
+        # what a shard holds) OR the full-grid one with its pack
+        # gathered at A — warm exactly that, with operands placed and
+        # static arguments spelled as _launch_enqueue does (both are
+        # part of a program's cache key).
         fns = self._fns
         st = self.engine.init_state(e, m, s)
         up = self._put(np.ones((e, m), bool), "up")
@@ -5038,53 +4891,47 @@ class BatchedEnsembleService:
         n_sh = self._mesh_shards or 1
         e_loc = e // n_sh
 
-        def zero_slab(k: int, width: int, sliced: bool):
+        def zero_slab(k: int, width: int, idx_row=None, sliced=False):
             """An all-NOOP op slab; sliced: all-pad index rows
             (gathers clip harmlessly, the scatter drops everything —
             state untouched, program compiled)."""
             z = np.zeros((width,), np.int32)
             return self._put(eng.pack_op_slab(
                 width, k, z, z, z, no_planes,
-                z[:0] if sliced else None,
-                z + e_loc if sliced else None), "slab")
+                z[:0] if sliced else None, idx_row), "slab")
 
-        def warm_sliced(k: int, aw: int) -> bool:
-            """One (K, A) bucket's sliced program, where the launch
-            path would slice there (its rule, read per shard)."""
+        def warm(k: int, aw: Optional[int], want_vsn: bool = True):
+            """One (K, A) bucket's program: the sliced one where the
+            launch path would slice there (its rule, read per shard),
+            else the full-grid one, its pack gathered at ``aw`` (by
+            all-pad index rows, column 0) or at full width (None)."""
             nonlocal st
-            if not self._slices(aw):
-                return False
-            slab = zero_slab(k, n_sh * aw, True)
-            st, won, res = fns.sliced_slab(st, slab, up)
-            np.asarray(pack(won, res, True, active_idx=None))
-            return True
+            if aw is not None and self._slices(aw):
+                width = n_sh * aw
+                st, flat = fns.sliced_slab(
+                    st, zero_slab(k, width,
+                                  np.full((width,), e_loc, np.int32),
+                                  sliced=True),
+                    up, want_vsn=want_vsn)
+            else:
+                st, flat = fns.slab(
+                    st, zero_slab(k, e, None if aw is None
+                                  else np.zeros((e,), np.int32)),
+                    up, want_vsn=want_vsn, gather=aw or 0)
+            np.asarray(flat)
 
         k = 0
         while True:
-            slab = zero_slab(k, e, False)
-            st, won, res = fns.slab(st, slab, up)
             # The flush path (the read fast path's get-only/read-miss
             # fallback batches included) always packs WITH versions —
-            # the (K, A) ladder covers those.  The version-less pack
-            # is what WAL-less execute/execute_async dispatch: warmed
-            # at FULL WIDTH per K bucket, which covers a dense bulk
-            # batch without doubling the whole warm grid.
+            # the (K, A) ladder covers those.  The version-less
+            # program is what WAL-less execute/execute_async call:
+            # warmed at FULL WIDTH per K bucket, which covers a dense
+            # bulk batch without doubling the whole warm grid.
             for aw in a_widths(k):
+                warm(k, aw)
                 if aw is None:
-                    # active_idx spelled out as the launch spells it:
-                    # a keyword's presence is part of a jitted
-                    # program's cache key
-                    np.asarray(pack(won, res, True, active_idx=None))
-                    np.asarray(pack(won, res, False, active_idx=None))
-                elif not warm_sliced(k, aw):
-                    # pack-gather on the full-grid result; shard-wise:
-                    # the [n_shards, A_loc] local pad-0 index matrix
-                    # (the live flush's operand form)
-                    aidx = (self._shard_aidx(np.zeros(
-                        (self._mesh_shards, aw), np.int32))
-                        if self._mesh_shards
-                        else jnp.zeros((aw,), jnp.int32))
-                    np.asarray(pack(won, res, True, active_idx=aidx))
+                    warm(k, None, want_vsn=False)
             if k >= self.max_k:
                 break
             k = 1 if k == 0 else k * 2

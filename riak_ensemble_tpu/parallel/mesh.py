@@ -26,6 +26,7 @@ a peer axis use spec ('ens', 'peer'), per-ensemble vectors use
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Sequence, Tuple
 
 import jax
@@ -119,17 +120,18 @@ class ShardedEngine:
 
     The fused steps are INSTANCE attributes: ``full_step_slab`` and
     ``full_step_sliced_slab`` (and their ``_donate`` twins), the
-    jitted ``(state, op slab, up)`` programs the service launches, and
-    the per-plane ``full_step`` / ``full_step_donate`` the slab form
-    is compared against (plain wrappers that default absent CAS planes
-    and forward ``_cache_size`` so ``CompileWatch`` sees mesh
-    compiles).  The SLICED step gathers INSIDE ``shard_map``: every
-    shard takes its own local rows by the local indices of its own
-    block of the slab (``ops/engine.py`` "The op slab"), steps
-    ``[K, a_loc]`` and scatters back, so no row crosses a chip and
-    nothing is resharded; ``won`` and the result planes come back
-    ``n_shards * a_loc`` wide, one block per shard, which
-    ``batched_host``'s shard-wise packer packs as they are.
+    jitted ``(state, op slab, up) -> (state, packed vector)`` programs
+    the service launches (the step and the pack of its results, ONE
+    program a launch), and the per-plane ``full_step`` /
+    ``full_step_donate`` the slab form is compared against (plain
+    wrappers that default absent CAS planes and forward
+    ``_cache_size`` so ``CompileWatch`` sees mesh compiles).  The
+    SLICED step gathers INSIDE ``shard_map``: every shard takes its
+    own local rows by the local indices of its own block of the slab
+    (``ops/engine.py`` "The op slab"), steps ``[K, a_loc]`` and
+    scatters back, so no row crosses a chip and nothing is resharded;
+    ``won`` and the result planes are ``n_shards * a_loc`` wide, one
+    block per shard, and each shard packs its own block as it is.
     """
 
     def __init__(self, mesh: Mesh) -> None:
@@ -168,32 +170,73 @@ class ShardedEngine:
         self._full_donate = smap(_full_body, _full_in, _full_out,
                                  donate=True)
 
-        def _slab_body(st, slab, up):
-            # the local block of the op slab (engine.split_op_slab has
-            # the layout): each shard takes its own columns apart
-            el, ca, lz, k, sl, v, xe, xs = eng.split_op_slab(slab)
-            return _full_body(st, el, ca, k, sl, v, lz, up, xe, xs)
-
         #: where the served launch puts its two host operands: the
         #: step's own specs, so the dispatch places nothing
         self.slab_sharding = NamedSharding(mesh, P(None, "ens"))
         self.up_sharding = NamedSharding(mesh, P("ens", "peer"))
         _slab_in = (_STATE_SPECS, P(None, "ens"), P("ens", "peer"))
-        # (state, slab, up): the jitted programs as they are (no CAS
-        # planes to default, and `_cache_size` is their own)
-        self.full_step_slab = smap(_slab_body, _slab_in, _full_out)
-        self.full_step_slab_donate = smap(_slab_body, _slab_in,
-                                          _full_out, donate=True)
+        rep = NamedSharding(mesh, P())
+        shard_wise = bool(self.pack_shards)
 
-        def _sliced_slab_body(st, slab, up):
-            # one ordinary sliced slab a shard (see class docstring)
-            return eng._full_step_sliced_slab_body(st, slab, up,
-                                                   axis_name=ax)
+        def served(sliced: bool, donate: bool):
+            """One served program, ``(state, slab, up, want_vsn[,
+            gather]) -> (state, flat)``: engine's step body over each
+            shard's block of the slab under shard_map, and
+            ``engine.pack_results`` of what it returned in the SAME
+            jitted program (``want_vsn`` and the pack-gather's width
+            ``gather`` static, as in engine's own).
 
-        self.full_step_sliced_slab = smap(_sliced_slab_body, _slab_in,
-                                          _full_out)
-        self.full_step_sliced_slab_donate = smap(
-            _sliced_slab_body, _slab_in, _full_out, donate=True)
+            SHARD-WISE ('peer' unsharded): the pack runs per shard
+            under the same shard_map: each device bit-packs its own
+            block's results with its own LOCAL gather (the index row
+            of its own block of the slab), and the vector is the
+            per-shard vectors in shard order
+            (``batched_host.unpack_results_sharded`` inverts it);
+            nothing crosses 'ens'.  GATHERED (the rest): the step's
+            result planes leave shard_map with MIXED shardings
+            ('ens'-sharded [K, E] planes, peer-sharded corrupt masks);
+            raveling those directly leaves GSPMD no expressible output
+            sharding and it rematerializes per operand
+            (MULTICHIP_r04's ``spmd_partitioner`` warnings), so each is
+            constrained fully replicated first (ordinary all-gathers
+            over ICI; the vector is fetched to the host anyway) and
+            the pack runs replicated."""
+            def program(st, slab, up, want_vsn, gather=0):
+                block = dict(sliced=sliced, gather=gather, axis_name=ax)
+
+                def over_shards(body, out_specs):
+                    return jax.shard_map(
+                        body, mesh=mesh, in_specs=_slab_in,
+                        out_specs=out_specs, check_vma=False)(st, slab, up)
+
+                if shard_wise:
+                    return over_shards(
+                        functools.partial(eng._launch_body,
+                                          want_vsn=want_vsn, **block),
+                        (_STATE_SPECS, P("ens")))
+                st, won, res = over_shards(
+                    functools.partial(eng._slab_step_body, **block),
+                    _full_out)
+
+                def con(x):
+                    return jax.lax.with_sharding_constraint(x, rep)
+
+                aidx = eng.pack_gather_index(slab, gather)
+                return st, eng.pack_results(
+                    con(won), jax.tree.map(con, res), want_vsn,
+                    None if aidx is None else con(aidx))
+
+            return jax.jit(
+                program,
+                static_argnames=(("want_vsn",) if sliced
+                                 else ("want_vsn", "gather")),
+                donate_argnums=(0,) if donate else ())
+
+        # the jitted programs as they are (`_cache_size` is their own)
+        self.full_step_slab = served(False, donate=False)
+        self.full_step_slab_donate = served(False, donate=True)
+        self.full_step_sliced_slab = served(True, donate=False)
+        self.full_step_sliced_slab_donate = served(True, donate=True)
         # the per-plane reference steps (see class docstring)
         self.full_step = self._make_step(self._full)
         self.full_step_donate = self._make_step(self._full_donate)
@@ -265,6 +308,19 @@ class ShardedEngine:
     def n_peer_shards(self) -> int:
         """Number of shards along the 'peer' mesh axis."""
         return int(self.mesh.shape["peer"])
+
+    @property
+    def pack_shards(self) -> int:
+        """Number of 'ens' shards the SHARD-WISE result pack runs
+        over: >1 only where the 'peer' axis is unsharded (each device
+        then holds complete ``[e_loc, M, ...]`` rows, so a shard packs
+        its own results with no cross-device traffic at all).  0 = the
+        gathered pack (a sharded 'peer' axis: the corrupt plane spans
+        peer shards there; or one 'ens' shard)."""
+        if self.n_peer_shards != 1:
+            return 0
+        n = self.n_ens_shards
+        return n if n > 1 else 0
 
     def shard_state(self, state: eng.EngineState) -> eng.EngineState:
         """Place a host-built state (a restored checkpoint, a test's)

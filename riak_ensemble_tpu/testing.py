@@ -17,6 +17,7 @@ Fault-injection surface (SURVEY §4 parity):
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, List, Optional, Sequence
 
 from riak_ensemble_tpu import peer as peerlib
@@ -356,8 +357,9 @@ class ManagedCluster:
 
 # -- the batched service's step programs -------------------------------------
 
-#: every program a ``BatchedEnsembleService`` launch can dispatch
-#: (``(state, op slab, up) -> (state, won, KvResult)``)
+#: every program a ``BatchedEnsembleService`` launch can call
+#: (``(state, op slab, up) -> (state, packed results)``, the static
+#: ``want_vsn`` and, full width, ``gather`` by keyword)
 STEP_PROGRAMS = ("full_step_slab", "full_step_slab_donate",
                  "full_step_sliced_slab", "full_step_sliced_slab_donate")
 
@@ -376,8 +378,11 @@ class _SteppedEngine:
 
     @staticmethod
     def _through(around, inner, sliced: bool):
-        def step(state, slab, up):
-            return around(inner, state, slab, up, sliced=sliced)
+        def step(state, slab, up, **static):
+            # the launch's static arguments are bound here, so the
+            # hook calls ``inner(state, slab, up)``
+            return around(functools.partial(inner, **static),
+                          state, slab, up, sliced=sliced)
         cache_size = getattr(inner, "_cache_size", None)
         if cache_size is not None:
             step._cache_size = cache_size  # obs.CompileWatch's probe
@@ -391,12 +396,69 @@ def wrap_engine_steps(engine, around):
     """An engine like ``engine`` whose EVERY step program (full width
     and sliced, donated and not: whichever of :data:`STEP_PROGRAMS` it
     has) runs through ``around(inner, state, slab, up, sliced=...)``,
-    which returns what the launch gets: ``(state, won, KvResult)``.
+    which returns what the launch gets: ``(state, flat)``, the new
+    state and the packed result vector (``ops.engine.pack_results``;
+    ``batched_host.unpack_results`` reads it).  ``inner(state, slab,
+    up)`` is the engine's program with the launch's static arguments
+    (``want_vsn``; full width also ``gather``, the pack-gather's
+    width) already bound: ``inner.keywords`` has them and
+    ``inner.func`` is the engine's own.
 
     This is how a test injects a launch fault, counts launches or
     tampers with operands or results, on the path that serves: the
-    service dispatches whichever program fits the flush, so an
-    injector on one of them would be bypassed by its twins.  The op
-    planes of ``slab`` are ``ops.engine.split_op_slab(slab, sliced)``.
+    service calls whichever program fits the flush, so an injector on
+    one of them would be bypassed by its twins.  The op planes of
+    ``slab`` are ``ops.engine.split_op_slab(slab, indexed)``,
+    ``indexed`` where the slab carries an index row (a sliced launch,
+    or ``gather`` > 0).
     """
     return _SteppedEngine(engine, around)
+
+
+def launch_apart(engine, state, slab, up, want_vsn: bool, gather: int = 0,
+                 sliced: bool = False):
+    """A launch as TWO programs, each jitted apart: the step body
+    over the slab, then ``ops.engine.pack_results`` of what it
+    returned (a mesh engine: both under its own ``shard_map``, the
+    pack per shard where the engine packs shard-wise, gathered
+    replicated otherwise).  The reference the served ONE-program
+    launch (``engine.full_step_slab(state, slab, up, want_vsn=...,
+    gather=...)`` and its twins) is held to, bit for bit: returns
+    ``(state, won, KvResult, flat)``.  ``state`` is not donated."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from riak_ensemble_tpu.ops import engine as eng
+
+    mesh = getattr(engine, "mesh", None)
+    ax = ("peer" if mesh is not None and mesh.shape["peer"] > 1
+          else None)
+
+    step = functools.partial(eng._slab_step_body, sliced=sliced,
+                             gather=gather, axis_name=ax)
+
+    def pack(won, res, slab):
+        return eng.pack_results(won, res, want_vsn,
+                                eng.pack_gather_index(slab, gather))
+
+    if mesh is None:
+        state, won, res = jax.jit(step)(state, slab, up)
+        return state, won, res, jax.jit(pack)(won, res, slab)
+
+    slab_spec = P(None, "ens")
+    res_specs = (P("ens"), eng.scan_result_specs())
+    state, won, res = jax.jit(jax.shard_map(
+        step, mesh=mesh,
+        in_specs=(eng.state_specs(), slab_spec, P("ens", "peer")),
+        out_specs=(eng.state_specs(),) + res_specs,
+        check_vma=False))(state, slab, up)
+    if engine.pack_shards:
+        flat = jax.jit(jax.shard_map(
+            pack, mesh=mesh, in_specs=res_specs + (slab_spec,),
+            out_specs=P("ens"), check_vma=False))(won, res, slab)
+    else:
+        rep = NamedSharding(mesh, P())
+        flat = jax.jit(lambda *xs: pack(*jax.tree.map(
+            lambda x: jax.lax.with_sharding_constraint(x, rep),
+            xs)))(won, res, slab)
+    return state, won, res, flat

@@ -95,11 +95,14 @@ def test_pack_unpack_roundtrip_active(cols, a_width):
     cols = np.asarray(cols, np.int32)
     won, res = _random_result(rng, k, e, m, cols)
 
-    full_flat = np.asarray(bh._pack_results(won, res, True))
+    # the pack as a program of its own (a launch runs it inside its
+    # step program: tests/test_op_slab.py holds that one to this)
+    pack = jax.jit(eng.pack_results, static_argnames=("want_vsn",))
+    full_flat = np.asarray(pack(won, res, True))
     pad = np.zeros((a_width,), np.int32)
     pad[:len(cols)] = cols
     comp_flat = np.asarray(
-        bh._pack_results(won, res, True, active_idx=jnp.asarray(pad)))
+        pack(won, res, True, active_idx=jnp.asarray(pad)))
     assert comp_flat.nbytes < full_flat.nbytes
     assert comp_flat.nbytes == bh.packed_nbytes(e, m, k, True, a_width)
 

@@ -322,7 +322,8 @@ def test_service_flush_depth_buckets_to_pow2():
     seen = []
 
     def record(inner, state, slab, up, sliced):
-        seen.append(int(split_op_slab(slab, sliced)[3].shape[0]))
+        indexed = sliced or bool(inner.keywords.get("gather"))
+        seen.append(int(split_op_slab(slab, indexed)[3].shape[0]))
         return inner(state, slab, up)
 
     runtime = Runtime(seed=50)
@@ -675,14 +676,14 @@ def test_async_launch_failure_rolls_back_state():
     poison_next = []
 
     def poison(inner, state, slab, up, sliced):
-        state, won, res = inner(state, slab, up)
+        state, flat = inner(state, slab, up)
         if poison_next:
             poison_next.clear()
             # The returned state LOOKS fine (it replaces svc.state),
             # but the result fetch blows up — the async-dispatch
             # failure shape.
-            res = res._replace(value="poisoned-not-an-array")
-        return state, won, res
+            flat = "poisoned-not-an-array"
+        return state, flat
 
     runtime = Runtime(seed=50)
     svc = BatchedEnsembleService(

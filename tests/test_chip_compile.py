@@ -131,7 +131,7 @@ def _sliced_step(one_chip, k, a):
     place = _on(one_chip)
     return eng.full_step_sliced_slab_donate.lower(
         state, place("slab", (4 + 5 * k, a), jnp.int32),
-        place("up", (E, M), jnp.bool_)).compile()
+        place("up", (E, M), jnp.bool_), want_vsn=True).compile()
 
 
 def test_sliced_donated_step_compiles_at_headline_shape(one_chip):
@@ -273,9 +273,12 @@ def test_deep_ring_round_leaves_the_planes_where_they_lie(
                     one_chip)
     u = state.tree_node.shape[2]
     place = _on(one_chip)
+    # as the cell's window launches it: the pack gathered at A 8 by
+    # the slab's index row, in the step's own program (ISSUE 46)
     compiled = eng.full_step_slab_donate.lower(
-        state, place("slab", (3 + 5 * k, e), jnp.int32),
-        place("up", (e, m), jnp.bool_)).compile()
+        state, place("slab", (4 + 5 * k, e), jnp.int32),
+        place("up", (e, m), jnp.bool_), want_vsn=True,
+        gather=8).compile()
     text = compiled.as_text()
     assert (" while(" in text) == (k > 1)
     node = list(_plane_moves(text, (f"u32[{e},{m},{u},{hashk.LANES}]",)))
@@ -361,11 +364,13 @@ def test_mesh_step_compiles_on_four_chips_without_ens_collectives(topo):
                     eng.state_sharding(mesh))
     compiled = engine.full_step_slab_donate.lower(
         state,
-        jax.ShapeDtypeStruct((3 + 5 * k, e), jnp.int32,
+        jax.ShapeDtypeStruct((4 + 5 * k, e), jnp.int32,
                              sharding=engine.slab_sharding),
         jax.ShapeDtypeStruct((e, M), jnp.bool_,
-                             sharding=engine.up_sharding)).compile()
+                             sharding=engine.up_sharding),
+        want_vsn=True, gather=1024).compile()
     text = compiled.as_text()
+    # the step, and the per-shard pack with its local gather behind it
     assert not _COLLECTIVES.search(text), _COLLECTIVES.findall(text)
     # each device holds its quarter of the state, not all of it
     full = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(state))
@@ -389,17 +394,17 @@ def test_sliced_mesh_step_compiles_on_four_chips_without_ens_collectives(
     up = jax.ShapeDtypeStruct((e, M), jnp.bool_,
                               sharding=engine.up_sharding)
 
-    def compile_(program, head, width):
+    def compile_(program, head, width, **static):
         t0 = time.perf_counter()
         compiled = program.lower(
             state, jax.ShapeDtypeStruct((head + 5 * k, width), jnp.int32,
                                         sharding=engine.slab_sharding),
-            up).compile()
+            up, want_vsn=True, **static).compile()
         return compiled, time.perf_counter() - t0
 
     sliced, sliced_s = compile_(engine.full_step_sliced_slab_donate, 4,
                                 4 * a_loc)
-    grid, grid_s = compile_(engine.full_step_slab_donate, 3, e)
+    grid, grid_s = compile_(engine.full_step_slab_donate, 3, e, gather=0)
     text = sliced.as_text()
     assert not _COLLECTIVES.search(text), _COLLECTIVES.findall(text)
     mem, grid_mem = sliced.memory_analysis(), grid.memory_analysis()
@@ -433,7 +438,8 @@ def test_sliced_mesh_step_moves_no_object_plane(topo, record_property):
         jax.ShapeDtypeStruct((4 + 5 * k, 4 * a_loc), jnp.int32,
                              sharding=engine.slab_sharding),
         jax.ShapeDtypeStruct((e, M), jnp.bool_,
-                             sharding=engine.up_sharding)).compile()
+                             sharding=engine.up_sharding),
+        want_vsn=True).compile()
     _assert_object_planes_stay_put(compiled.as_text(), e // 4,
                                    record_property, "mesh_k1a8")
     temp = compiled.memory_analysis().temp_size_in_bytes
@@ -490,12 +496,15 @@ def test_mesh_round_over_a_sharded_deep_ring_moves_no_plane(
     engine = mesh_engine(4, devices=topo.devices)
     state = _placed(jax.eval_shape(lambda: eng.init_state(e, m, s)),
                     eng.state_sharding(engine.mesh))
+    # as the cell's window launches it: each shard's pack gathered at
+    # a_loc 8 by its own block's index row, in the step's own program
     compiled = engine.full_step_slab_donate.lower(
         state,
-        jax.ShapeDtypeStruct((3 + 5 * k, e), jnp.int32,
+        jax.ShapeDtypeStruct((4 + 5 * k, e), jnp.int32,
                              sharding=engine.slab_sharding),
         jax.ShapeDtypeStruct((e, m), jnp.bool_,
-                             sharding=engine.up_sharding)).compile()
+                             sharding=engine.up_sharding),
+        want_vsn=True, gather=8).compile()
     text = compiled.as_text()
     assert (" while(" in text) == (k > 1)
     assert not _COLLECTIVES.search(text), _COLLECTIVES.findall(text)

@@ -553,13 +553,13 @@ def test_sliced_launch_over_a_row_plane_equals_the_full_width_one():
     planes = (kind, slot, val, None, None)
     up = jnp.ones((e, m), bool)
     state = eng.init_state(e, m, s)
-    want = eng.full_step_slab(
+    want = jax.jit(eng._full_step_slab_body)(
         state, jnp.asarray(eng.pack_op_slab(e, k, elect, cand, lease,
                                             planes)), up)
     a = 8
     aidx = np.full(a, e, np.int32)
     aidx[:len(active)] = active
-    got = eng.full_step_sliced_slab(
+    got = jax.jit(eng._full_step_sliced_slab_body)(
         state, jnp.asarray(eng.pack_op_slab(a, k, elect, cand, lease,
                                             planes, active, aidx)), up)
     assert np.asarray(want[2].committed)[:, active].all()

@@ -29,8 +29,10 @@ import pytest
 jax = pytest.importorskip("jax")
 
 from riak_ensemble_tpu import funref  # noqa: E402
+from riak_ensemble_tpu.ops import engine as eng  # noqa: E402
 from riak_ensemble_tpu.parallel.batched_host import (  # noqa: E402
     SLICE_MIN_E, BatchedEnsembleService, WallRuntime, mesh_ens_shards,
+    packed_nbytes,
 )
 from riak_ensemble_tpu.parallel.mesh import mesh_engine  # noqa: E402
 
@@ -110,11 +112,27 @@ def _wal_bytes(data_dir):
 
 
 def test_shardwise_pack_selected():
+    """An 'ens'-sharded mesh with the 'peer' axis whole packs per
+    shard, inside its step program: the packed vector is one block a
+    shard, sharded along 'ens' as the state is."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
     svc = _mk(16, mesh=True)
     try:
-        assert mesh_ens_shards(svc.engine) == 8
+        engine = svc.engine
+        assert engine.pack_shards == mesh_ens_shards(engine) == 8
         assert svc._mesh_shards == 8
-        assert getattr(svc._pack, "fn", svc._pack)
+        assert mesh_engine(8, n_peer=2).pack_shards == 0
+        k, z = 1, np.zeros((16,), np.int32)
+        _st, flat = engine.full_step_slab(
+            engine.init_state(16, 3, 8),
+            svc._put(eng.pack_op_slab(16, k, z, z, z, (None,) * 5),
+                     "slab"),
+            svc._put(np.ones((16, 3), bool), "up"),
+            want_vsn=True, gather=0)
+        assert flat.shape == (8 * packed_nbytes(2, 3, k, True),)
+        assert flat.sharding.is_equivalent_to(
+            NamedSharding(engine.mesh, P("ens")), 1)
     finally:
         svc.stop()
 
@@ -283,10 +301,12 @@ def test_sliced_mesh_launch_matches_full_grid_and_one_chip(case, k):
                 futs += [svc.kget(c, "k0") for c in cols]
             replies.append(_drive(svc, futs))
             got = _launches(svc, n0)
+            # one upload whichever way: a pack-gather's index rows
+            # ride in the slab as a sliced launch's do
             if svc is arms[0]:    # the mesh as it serves
-                assert got == [(k, 1, 1) if slices else (k, 2, 0)], got
+                assert got == [(k, 1, int(slices))], got
             elif svc is arms[1]:  # held to the full grid: pack-gather
-                assert got == [(k, 2, 0)], got
+                assert got == [(k, 1, 0)], got
             else:                 # one chip slices all four cases
                 assert got == [(k, 1, 1)], got
         assert replies[0] == replies[1] == replies[2]

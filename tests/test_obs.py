@@ -811,7 +811,7 @@ SESSION_SPANS = ("svc.wal", "svc.wal_encode", "svc.wal_append",
                  "svc.h2d", "svc.dispatch", "svc.device_d2h",
                  "svc.unpack", "svc.pack", "svc.obs", "svc.fe_decode",
                  "svc.fe_dispatch", "svc.fe_reply", "py.gc",
-                 "svc.h2d_put", "svc.dispatch_step", "svc.dispatch_pack")
+                 "svc.h2d_put", "svc.dispatch_step")
 
 
 @pytest.fixture(scope="module")
@@ -888,26 +888,22 @@ def _lowered(program):
     import jax.numpy as jnp
 
     from riak_ensemble_tpu.ops import engine as eng
-    from riak_ensemble_tpu.parallel import batched_host
 
     e, m, s, k, a = 512, 3, 16, 2, 16
     st = eng.init_state(e, m, s)
     up = jnp.ones((e, m), bool)
     if program == "step_sliced":
         low = eng.full_step_sliced_slab.lower(
-            st, jnp.zeros((4 + 5 * k, a), jnp.int32), up)
+            st, jnp.zeros((4 + 5 * k, a), jnp.int32), up, want_vsn=True)
+    elif program == "step":     # full width, its pack gathered at a
+        low = eng.full_step_slab.lower(
+            st, jnp.zeros((4 + 5 * k, e), jnp.int32), up, want_vsn=True,
+            gather=a)
     else:
         z = jnp.zeros((k, e), jnp.int32)
-        args = (st, jnp.zeros((e,), bool), jnp.zeros((e,), jnp.int32),
-                z, z, z, jnp.zeros((k, e), bool), up)
-        if program == "step":
-            low = eng.full_step.lower(*args, exp_epoch=z, exp_seq=z)
-        else:
-            _st, won, res = jax.eval_shape(
-                lambda *xs: eng.full_step(*xs), *args)
-            low = batched_host._pack_results.lower(
-                won, res, want_vsn=True,
-                active_idx=jnp.arange(a, dtype=jnp.int32))
+        low = eng.full_step.lower(
+            st, jnp.zeros((e,), bool), jnp.zeros((e,), jnp.int32),
+            z, z, z, jnp.zeros((k, e), bool), up, exp_epoch=z, exp_seq=z)
     return low.as_text(debug_info=True)
 
 
@@ -919,7 +915,10 @@ STEP_SCOPES = ("elect", "quorum", "slot_gather", "merkle_verify",
     *[("step_sliced", s) for s in STEP_SCOPES + ("slice_columns",
                                                  "scatter_columns")],
     *[("step", s) for s in STEP_SCOPES],
-    ("pack", "result_pack"),
+    # the pack is inside the served programs, the per-plane reference
+    # step has none
+    ("step", "result_pack"), ("step_sliced", "result_pack"),
+    *[("per_plane", s) for s in STEP_SCOPES[:1]],
 ])
 def test_lowered_step_names_every_scope(program, scope):
     """``jax.named_scope`` on the step's phases reaches the lowered

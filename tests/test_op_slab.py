@@ -1,18 +1,26 @@
-"""The op slab: one upload per launch (ISSUE 31, 32).
+"""The op slab: one upload and one program call per launch (ISSUE 31,
+32, 46).
 
 Everything a launch reads from the host travels as ONE int32
 array (``engine.pack_op_slab`` has the row layout) in one transfer,
 placed where the step wants it, and is taken apart inside the compiled
-program.  Pinned here:
+program, which returns the state and ONE packed result vector (the
+step and ``engine.pack_results`` of what it returned, one program).
+Pinned here:
 
-- the slab programs are BIT-identical to the per-plane programs on the
-  same operands (full width, sliced, the 'ens'-sharded mesh step; with
-  and without elections; K 1 and 4);
-- a served flush records its transfers (``uploads``) and whether its
-  step sliced (``sliced``): 1 upload on a sliced launch, one chip's or
-  a mesh's, at most 2 on a pack-gather launch (a mesh whose shards
-  hold too few rows to slice among them), and no eager device op
-  inside the ``h2d`` span;
+- the served programs are BIT-identical, state and packed vector, to
+  the per-plane programs on the same operands followed by the pack
+  called apart (full width, sliced, the 'ens'-sharded mesh step; with
+  and without elections; K 1 and 4), and to the step body over the
+  slab followed by the pack, each jitted apart
+  (``testing.launch_apart``), on one chip and on a four-shard mesh,
+  full width with the pack-gather and sliced;
+- a served flush records its transfers (``uploads``), the device
+  programs it called (``calls``) and whether its step sliced
+  (``sliced``): 1 upload and 1 call on every launch that carries
+  operations, sliced or pack-gather, one chip's or a mesh's (2
+  uploads only where the failure detector changed ``up``), and no
+  device op of ``jnp`` inside the ``h2d`` span;
 - every way into the step (keyed ops of each kind, an election-only
   launch, ``execute()`` from host or ``jax.Array`` planes, a replica's
   apply) is one such launch;
@@ -34,8 +42,11 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from riak_ensemble_tpu.ops import engine as eng  # noqa: E402
 from riak_ensemble_tpu.parallel.batched_host import (  # noqa: E402
-    SLICE_MIN_E, BatchedEnsembleService, WallRuntime, _LocalEngine)
-from riak_ensemble_tpu.parallel.mesh import mesh_engine  # noqa: E402
+    SLICE_MIN_E, BatchedEnsembleService, WallRuntime, _LocalEngine,
+    unpack_results, unpack_results_sharded)
+from riak_ensemble_tpu.parallel.mesh import (  # noqa: E402
+    mesh_engine, shard_active_columns)
+from riak_ensemble_tpu.testing import launch_apart  # noqa: E402
 
 E, M, S, A = 64, 3, 8, 8
 
@@ -78,6 +89,19 @@ def _led_state(engine):
     return st
 
 
+def _pack_apart(engine, won, res, want_vsn=True):
+    """``engine.pack_results`` of a step's results as a program of its
+    own (per shard on the mesh, as its served program packs)."""
+    def pack(won, res):
+        return eng.pack_results(won, res, want_vsn)
+    mesh = getattr(engine, "mesh", None)
+    if mesh is not None:
+        pack = jax.shard_map(
+            pack, mesh=mesh, in_specs=(P("ens"), eng.scan_result_specs()),
+            out_specs=P("ens"), check_vma=False)
+    return jax.jit(pack)(won, res)
+
+
 def _assert_same(a, b):
     la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
     assert len(la) == len(lb)
@@ -118,7 +142,8 @@ def test_slab_program_matches_per_plane_program(form, elections, k):
         slab = eng.pack_op_slab(A, k, elect, cand, lease, planes,
                                 active, aidx)
         assert slab.shape == (4 + 5 * k, A) and slab.dtype == np.int32
-        got = engine.full_step_sliced_slab(st, jnp.asarray(slab), up)
+        got = engine.full_step_sliced_slab(st, jnp.asarray(slab), up,
+                                           want_vsn=True)
     else:
         kind, slot, val, xe, xs = (jnp.asarray(p) for p in planes)
         lease_j = jnp.broadcast_to(jnp.asarray(lease), (k, E))
@@ -129,11 +154,12 @@ def test_slab_program_matches_per_plane_program(form, elections, k):
         assert slab.shape == (3 + 5 * k, E) and slab.dtype == np.int32
         slab_j = (jax.device_put(slab, engine.slab_sharding)
                   if form == "mesh4" else jnp.asarray(slab))
-        got = engine.full_step_slab(st, slab_j, up)
+        got = engine.full_step_slab(st, slab_j, up, want_vsn=True,
+                                    gather=0)
     assert np.asarray(want[2].committed).any(), "nothing committed"
     if elections:
         assert np.asarray(want[1]).any(), "no election won"
-    _assert_same(got, want)
+    _assert_same(got, (want[0], _pack_apart(engine, *want[1:])))
 
 
 def _shard_blocks(active, n_sh):
@@ -172,7 +198,7 @@ def test_mesh_sliced_slab_program_matches_one_chips(elections, k):
 
     aidx = np.full((A,), E, np.int32)
     aidx[:active.size] = active
-    want = eng.full_step_sliced_slab(
+    want = jax.jit(eng._full_step_sliced_slab_body)(
         _led_state(_LocalEngine()),
         jnp.asarray(eng.pack_op_slab(A, k, elect, cand, lease, planes,
                                      active, aidx)), up)
@@ -185,9 +211,10 @@ def test_mesh_sliced_slab_program_matches_one_chips(elections, k):
     assert slab.shape == (4 + 5 * k, n_sh * a_loc)
     np.testing.assert_array_equal(
         slab[eng.SLAB_ACTIVE_IDX].reshape(n_sh, a_loc), rows)
-    got = engine.full_step_sliced_slab(
-        _led_state(engine), jax.device_put(slab, engine.slab_sharding),
-        jax.device_put(up_np, engine.up_sharding))
+    got = launch_apart(
+        engine, _led_state(engine),
+        jax.device_put(slab, engine.slab_sharding),
+        jax.device_put(up_np, engine.up_sharding), True, sliced=True)
 
     assert np.asarray(want[2].committed).any(), "nothing committed"
     if elections:
@@ -282,15 +309,17 @@ def test_sliced_step_matches_full_width_step(form, k):
         return (jax.device_put(slab, engine.slab_sharding)
                 if form == "mesh4" else jnp.asarray(slab))
 
-    want = engine.full_step_slab(
-        _led_state(engine),
-        placed(eng.pack_op_slab(E, k, elect, cand, lease, planes)), up)
+    want = launch_apart(
+        engine, _led_state(engine),
+        placed(eng.pack_op_slab(E, k, elect, cand, lease, planes)), up,
+        True)
 
     rows, at, a_loc = _shard_blocks(active, n_sh)
-    got = engine.full_step_sliced_slab(
-        _led_state(engine),
+    got = launch_apart(
+        engine, _led_state(engine),
         placed(eng.pack_op_slab(n_sh * a_loc, k, elect, cand, lease,
-                                planes, active, rows.ravel(), at)), up)
+                                planes, active, rows.ravel(), at)), up,
+        True, sliced=True)
 
     assert np.asarray(want[2].committed).any(), "nothing committed"
     assert np.asarray(want[1]).any(), "no election won"
@@ -303,10 +332,101 @@ def test_sliced_step_matches_full_width_step(form, k):
                                       err_msg=name)
 
 
-@pytest.mark.parametrize("sliced", [False, True], ids=["full", "sliced"])
-def test_split_returns_what_pack_was_given(sliced):
-    """Layout round trip, absent CAS planes included (None = zeros)."""
+@pytest.mark.parametrize("k", [1, 4])
+@pytest.mark.parametrize("form", ["full", "pack-gather", "sliced",
+                                  "mesh4-pack-gather", "mesh4-sliced"])
+def test_one_program_launch_matches_step_then_pack_apart(form, k):
+    """The served program (step and pack, ONE program) against the
+    step body over the same slab followed by ``engine.pack_results``,
+    each jitted apart (``testing.launch_apart``: what a launch called
+    until ISSUE 46): the packed vector and the final state bit-equal,
+    on one chip and on a four-shard mesh, full width (packed whole,
+    and gathered by the slab's index row) and sliced, donated and not;
+    and the host's unpack of that vector returns the step's own
+    planes at the launch's columns."""
+    mesh = form.startswith("mesh4")
+    n_sh = 4 if mesh else 1
+    e_loc = E // n_sh
+    engine = mesh_engine(4) if mesh else _LocalEngine()
+    elect, cand, lease, planes = _operands(k, True, seed=46 + k)
+    active = np.array([2, 5, 11, 40, 63], np.int32)
+    idle = np.setdiff1d(np.arange(E), active)
+    elect[idle] = False
+    elect[[2, 63]], cand[[2, 63]] = True, 0
+    planes[0][:, idle] = eng.OP_NOOP
+    up_np = np.ones((E, M), bool)
+    up_np[::5, 1] = False
+    per_shard, a_loc = shard_active_columns(active, E, n_sh, 2)
+    sliced = form.endswith("sliced")
+    gather = a_loc if form.endswith("pack-gather") else 0
+    if sliced:
+        rows, at, _ = _shard_blocks(active, n_sh)
+        slab = eng.pack_op_slab(n_sh * a_loc, k, elect, cand, lease,
+                                planes, active, rows.ravel(), at)
+    else:
+        row = None
+        if gather:      # each shard's LOCAL indices, pad 0
+            row = np.zeros((n_sh, e_loc), np.int32)
+            for sh, p in enumerate(per_shard):
+                row[sh, :p.size] = p
+            row = row.ravel()
+        slab = eng.pack_op_slab(E, k, elect, cand, lease, planes,
+                                None, row)
+
+    def placed():
+        if not mesh:
+            return jnp.asarray(slab), jnp.asarray(up_np)
+        return (jax.device_put(slab, engine.slab_sharding),
+                jax.device_put(up_np, engine.up_sharding))
+
+    static = {"want_vsn": True} if sliced else {"want_vsn": True,
+                                                "gather": gather}
+    name = "full_step_sliced_slab" if sliced else "full_step_slab"
+    st_ref, won, res, flat_ref = launch_apart(
+        engine, _led_state(engine), *placed(), True, gather, sliced)
+    assert np.asarray(res.committed).any(), "nothing committed"
+    assert np.asarray(won).any(), "no election won"
+    for twin in ("", "_donate"):
+        st, flat = getattr(engine, name + twin)(
+            _led_state(engine), *placed(), **static)
+        _assert_same(st, st_ref)
+        assert flat.dtype == jnp.uint8
+        np.testing.assert_array_equal(np.asarray(flat),
+                                      np.asarray(flat_ref))
+
+    shaped = sliced or gather
+    if mesh:
+        out = unpack_results_sharded(
+            np.asarray(flat), E, M, k, True, n_sh,
+            per_shard if shaped else None, a_loc if shaped else 0,
+            sliced)
+    else:
+        out = unpack_results(np.asarray(flat), E, M, k, True,
+                             active if shaped else None,
+                             a_loc if shaped else 0, sliced)
+    u_won, u_quorum, _, committed, get_ok, found, value, vsn = out
+    won, res = np.asarray(won), jax.tree.map(np.asarray, res)
+    # where each launch column's results sit in the step's planes
+    src = at if sliced else active
+    np.testing.assert_array_equal(u_won[active], won[src])
+    np.testing.assert_array_equal(u_quorum[active],
+                                  res.quorum_ok.any(0)[src])
+    for name, got, want in (("committed", committed, res.committed),
+                            ("get_ok", get_ok, res.get_ok),
+                            ("found", found, res.found),
+                            ("value", value, res.value),
+                            ("vsn", vsn, res.obj_vsn)):
+        np.testing.assert_array_equal(got[:, active], want[:, src],
+                                      err_msg=name)
+
+
+@pytest.mark.parametrize("form", ["full", "sliced", "pack-gather"])
+def test_split_returns_what_pack_was_given(form):
+    """Layout round trip, absent CAS planes included (None = zeros);
+    a full-width slab carries a pack-gather's index row as a sliced
+    one carries its own, and is otherwise the slab without it."""
     k = 3
+    sliced = form == "sliced"
     elect, cand, lease, planes = _operands(k, True, seed=7)
     planes = planes[:3] + (None, None)
     if sliced:
@@ -321,9 +441,22 @@ def test_split_returns_what_pack_was_given(sliced):
     else:
         slab = eng.pack_op_slab(E, k, elect, cand, lease, planes)
         w, take = E, lambda x: x
+        if form == "pack-gather":
+            row = np.zeros((E,), np.int32)
+            row[:A] = [1, 9, 33, 0, 0, 0, 0, 0]
+            plain, slab = slab, eng.pack_op_slab(
+                E, k, elect, cand, lease, planes, None, row)
+            assert slab.shape == (4 + 5 * k, E)
+            np.testing.assert_array_equal(
+                np.delete(slab, eng.SLAB_ACTIVE_IDX, axis=0), plain)
+            np.testing.assert_array_equal(
+                np.asarray(eng.pack_gather_index(jnp.asarray(slab), A)),
+                row[:A])
+            assert eng.pack_gather_index(jnp.asarray(plain), 0) is None
+    indexed = form != "full"
     el, ca, lz, kind, slot, val, xe, xs = jax.jit(
-        eng.split_op_slab, static_argnames="sliced")(
-            jnp.asarray(slab), sliced=sliced)
+        eng.split_op_slab, static_argnames="indexed")(
+            jnp.asarray(slab), indexed=indexed)
     assert el.dtype == bool and lz.dtype == bool and lz.shape == (k, w)
     np.testing.assert_array_equal(np.asarray(el), take(elect))
     np.testing.assert_array_equal(np.asarray(ca), take(cand))
@@ -442,26 +575,28 @@ def _drive_execute_jax(svc):
     assert get_ok.all() and found.all() and (value == 5).all()
 
 
-#: (service shape, drive, most uploads on a launch that carries ops)
+#: (service shape, drive, most uploads on a launch: 1 on every launch
+#: that carries operations, the slab; 2 only on the k == 0 launch that
+#: first uploads a changed ``up`` mask)
 CASES = {
     "sliced": ("sliced", _drive_kput, 1),
-    "pack_gather": ("pack_gather", _drive_kput, 2),
-    "mesh": ("mesh", _drive_kput, 2),
+    "pack_gather": ("pack_gather", _drive_kput, 1),
+    "mesh": ("mesh", _drive_kput, 1),
     "mesh_sliced": ("mesh_sliced", _drive_kput, 1),
     "mesh_sliced_kupdate": ("mesh_sliced", _drive_kupdate, 1),
     "kupdate": ("sliced", _drive_kupdate, 1),
     "kmodify": ("sliced", _drive_kmodify, 1),
-    "kdelete": ("pack_gather", _drive_kdelete, 2),
+    "kdelete": ("pack_gather", _drive_kdelete, 1),
     "kget_no_lease": ("sliced", _drive_kget_no_lease, 1),
     "election_only": ("sliced", _drive_election_only, 2),
     "execute_host": ("pack_gather", _drive_execute_host, 1),
     "execute_jax": ("mesh", _drive_execute_jax, 1),
     # the launch's shape in the record (SHAPES below)
     "shape_sliced": ("sliced", _drive_kput, 1),
-    "shape_pack_gather": ("pack_gather", _drive_kput, 2),
+    "shape_pack_gather": ("pack_gather", _drive_kput, 1),
     "shape_election_full_grid": ("sliced", _drive_election_only, 2),
     "shape_mesh_sliced": ("mesh_sliced", _drive_kput, 1),
-    "shape_mesh_pack_gather": ("mesh", _drive_kput, 2),
+    "shape_mesh_pack_gather": ("mesh", _drive_kput, 1),
 }
 
 #: (a, cols, cols_max, shards) of every launch the drive makes: the
@@ -485,14 +620,18 @@ def _assert_slab_launches(svc, recs, shape, most):
     slices = shape in ("sliced", "mesh_sliced")
     for r in recs:
         assert 1 <= r["uploads"] <= most, r
+        assert r["calls"] == 1, r
         assert r["sliced"] == int(slices and r["k"] > 0), r
         if r["sliced"]:
-            assert r["uploads"] == 1 and r["a"] > 0, r
+            assert r["a"] > 0, r
         assert r["shards"] == (svc._mesh_shards or 1), r
         assert r["cols_max"] <= r["cols"] <= svc.n_ens, r
         assert r["a"] == 0 or r["cols_max"] <= r["a"], r
     assert svc.stats()["launches_sliced"] >= sum(
         r["sliced"] for r in recs)
+    launch = svc.stats()["launch"]
+    assert launch["calls"] == launch["launches"] >= len(recs), launch
+    assert launch["uploads"] >= launch["launches"], launch
     assert {e["fn"] for e in svc._compile_log
             if e["fn"].startswith("step")} <= STEP_PROGRAMS, \
         list(svc._compile_log)
@@ -514,13 +653,11 @@ def test_served_flush_counts_its_uploads(case):
             assert {(r["a"], r["cols"], r["cols_max"], r["shards"])
                     for r in recs} == {SHAPES[case]}
         for r in recs:
-            # no eager device op inside the h2d span: all the launch
-            # takes from jnp there is the index vector's upload
+            # no device op of jnp inside the h2d span, eager or an
+            # upload: the slab's device_put is all of it
             t0 = r["starts"]["h2d"]
             inside = {n for n, t in spy.taken if t0 <= t <= t0 + r["h2d"]}
-            assert inside <= {"asarray"}, inside
-            if shape != "pack_gather":
-                assert not inside, inside
+            assert not inside, inside
     finally:
         svc.stop()
 
@@ -528,8 +665,7 @@ def test_served_flush_counts_its_uploads(case):
 def test_a_replicas_apply_is_a_slab_launch(tmp_path):
     """Three hosts: the first settled round elects every column, so it
     ships full-plane and each replica RE-EXECUTES it through the same
-    door (slab, the packer's index vector, and ``up``'s first
-    upload)."""
+    door (the slab and ``up``'s first upload)."""
     from riak_ensemble_tpu.config import fast_test_config
     from riak_ensemble_tpu.parallel import repgroup
 
@@ -549,7 +685,7 @@ def test_a_replicas_apply_is_a_slab_launch(tmp_path):
         for s in srvs + [svc]:
             lane = getattr(s, "svc", s)
             recs = [r for r in lane.lat_records if "uploads" in r]
-            _assert_slab_launches(lane, recs, "pack_gather", 3)
+            _assert_slab_launches(lane, recs, "pack_gather", 2)
             assert any(r["k"] for r in recs), "no op-carrying apply"
     finally:
         svc.stop()
@@ -564,9 +700,9 @@ def test_mesh_slab_is_committed_to_the_steps_sharding():
     def spy(name):
         inner = getattr(engine, name)
 
-        def step(state, slab, up):
+        def step(state, slab, up, **static):
             seen.append((slab, up))
-            return inner(state, slab, up)
+            return inner(state, slab, up, **static)
         setattr(engine, name, step)
 
     spy("full_step_slab")
@@ -615,7 +751,11 @@ def test_a_wrapped_engines_step_is_what_runs(n_ens, program):
                        if "uploads" in r)
         assert len(calls) - n0 == launches > 0
         twin = program + ("_donate" if svc._donate else "")
-        assert all(inner is getattr(base, twin)
+        # ``inner`` is the engine's program with the launch's static
+        # arguments bound
+        assert all(inner.func is getattr(base, twin)
+                   and inner.keywords["want_vsn"] is True
+                   and ("gather" in inner.keywords) != sliced
                    and sliced == ("sliced" in program)
                    for inner, sliced in calls[n0:]), calls[n0:]
     finally:

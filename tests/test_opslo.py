@@ -218,7 +218,7 @@ def test_injected_slow_op_tail_attribution(monkeypatch):
 
 
 def test_compile_events_catch_unwarmed_bucket():
-    """Acceptance: a deliberately un-warmed (K, A) pack bucket pays
+    """Acceptance: a deliberately un-warmed (K, A) bucket pays
     its first-use compile at SERVE time — and the compile-event hook
     names it (``retpu_compile_events_total{phase="serve"}``) instead
     of leaving a dispatch-p99 mystery.  E=24 is unique to this test
@@ -226,9 +226,8 @@ def test_compile_events_catch_unwarmed_bucket():
     deterministic."""
     svc = BatchedEnsembleService(WallRuntime(), 24, 3, 8, tick=None,
                                  max_ops_per_tick=4)
-    # warm ONLY the k=1 pack bucket: the step ladder always warms in
-    # full, so the k=2 flush below hits a warmed step but an
-    # un-warmed pack program
+    # warm ONLY the k=1 bucket (and the election-only k=0 launch): the
+    # k=2 flush below hits an un-warmed program
     svc.warmup(buckets=[(1, None)])
     assert svc._c_compile.labels("warmup").value > 0, \
         "warmup compiles must be counted under phase=warmup"
@@ -240,10 +239,12 @@ def test_compile_events_catch_unwarmed_bucket():
     assert served >= 1, "un-warmed bucket compile not caught"
     ev = [e for e in svc._compile_log if e["phase"] == "serve"]
     assert ev, "serve-phase compile left no log entry"
-    assert ev[-1]["fn"] == "pack", ev[-1]
+    assert ev[-1]["fn"] == "step", ev[-1]
     assert ev[-1]["compile_ms"] > 0
-    # the un-warmed bucket's shape signature is recorded (K=2 rows)
-    assert "[2," in ev[-1]["shapes"], ev[-1]
+    # the un-warmed bucket's shape signature is recorded: its slab
+    # (K=2: 3 + 5 * 2 rows of 24 columns) and its static arguments
+    assert "[13,24]" in ev[-1]["shapes"].replace(" ", ""), ev[-1]
+    assert "want_vsn" in ev[-1]["shapes"], ev[-1]
     # and the events ride the flight-dump extras section
     extras = svc._flight_extras()
     assert extras["compile_events"], extras
