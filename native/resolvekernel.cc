@@ -146,15 +146,19 @@ extern "C" {
 
 // Build-smoke / ABI handshake for utils/native.py and the tests.
 // 2 = commutative-lane fold (retpu_comm_fold) added.
-int retpu_resolve_version() { return 2; }
+// 3 = a sliced launch's quorum plane is e wide (retpu_resolve_unpack).
+int retpu_resolve_version() { return 3; }
 
 // ---------------------------------------------------------------------
 // 1) Packed-result unpack: one pass over the flat d2h payload.
 //
-// Layout (batched_host._pack_results_body): packbits([won hw |
-// quorum hw | corrupt hw*m | committed k*aw | get_ok k*aw |
+// Layout (ops/engine.py pack_results): packbits([won hw |
+// quorum e | corrupt hw*m | committed k*aw | get_ok k*aw |
 // found k*aw]) ++ int32le[value k*aw | (vsn_e k*aw | vsn_s k*aw)],
 // hw = aw when `sliced` else e, aw = a_width when compacted else e.
+// The quorum plane is e wide in every layout and is read where it
+// lies: a sliced launch reports the epoch check of the columns it
+// did not step too, so the host's lease renewal sees every column.
 // Outputs are caller-zeroed full-width planes; only real (non-pad)
 // active columns are written — bit-identical to unpack_results'
 // scatter.  Returns 0, or -1 when flat_len can't hold the layout.
@@ -168,7 +172,7 @@ int retpu_resolve_unpack(
     int32_t* value, int32_t* vsn) {
   const int64_t aw = active ? a_width : e;
   const int64_t hw = (sliced && active) ? aw : e;
-  const int64_t nbits = 2 * hw + hw * m + 3 * k * aw;
+  const int64_t nbits = hw + e + hw * m + 3 * k * aw;
   const int64_t hdr = (nbits + 7) / 8;
   const int64_t need = hdr + 4 * k * aw * (want_vsn ? 3 : 1);
   if (flat_len < need || e <= 0 || m < 0 || k < 0) return -1;
@@ -181,16 +185,14 @@ int retpu_resolve_unpack(
     for (int64_t i = 0; i < e; i++) quorum[i] = get_bit(flat, b++);
     for (int64_t i = 0; i < e * m; i++) corrupt[i] = get_bit(flat, b++);
   } else {
-    // Sliced launch: rows are A-width, scattered through the active
-    // index list; pad rows (i >= n_active) are dropped.
+    // Sliced launch: the won and corrupt rows are A-width, scattered
+    // through the active index list; pad rows (i >= n_active) are
+    // dropped.  The quorum plane between them is full width.
     for (int64_t i = 0; i < hw; i++) {
       int v = get_bit(flat, b++);
       if (i < n_active) won[active[i]] = static_cast<uint8_t>(v);
     }
-    for (int64_t i = 0; i < hw; i++) {
-      int v = get_bit(flat, b++);
-      if (i < n_active) quorum[active[i]] = static_cast<uint8_t>(v);
-    }
+    for (int64_t i = 0; i < e; i++) quorum[i] = get_bit(flat, b++);
     for (int64_t i = 0; i < hw; i++) {
       for (int64_t j = 0; j < m; j++) {
         int v = get_bit(flat, b++);

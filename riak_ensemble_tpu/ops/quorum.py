@@ -111,13 +111,15 @@ def reduce_peers(x: jax.Array, axis_name) -> jax.Array:
     return s
 
 
-@functools.partial(jax.jit, static_argnames=("required", "axis_name"))
+@functools.partial(jax.jit,
+                   static_argnames=("required", "axis_name", "met_only"))
 def quorum_met_batch(valid: jax.Array,
                      nack: jax.Array,
                      view_mask: jax.Array,
                      self_idx: jax.Array,
                      required: str = "quorum",
-                     axis_name: Optional[str] = None) -> jax.Array:
+                     axis_name: Optional[str] = None,
+                     met_only: bool = False) -> jax.Array:
     """Batched quorum predicate.
 
     Args:
@@ -135,6 +137,11 @@ def quorum_met_batch(valid: jax.Array,
                   it).  Sharded callers must pass ``self_idx=-1`` and
                   fold their own vote into ``valid`` (a global index
                   cannot be matched against a local peer slice).
+      met_only:   the caller reads nothing but ``== MET`` (static): a
+                  NACK is then reported as UNDECIDED, and the gather
+                  that tells the two apart (one element a row: 0.09 ms
+                  of a launch at 10,000 rows on a v5e, PERF.md
+                  section 6, PR 47) is not traced.
 
     Returns int8 ``[...]`` of MET / UNDECIDED / NACK.
     """
@@ -174,6 +181,8 @@ def quorum_met_batch(valid: jax.Array,
     nack_v = nack_v & active
 
     all_met = met_v.all(-1)
+    if met_only:
+        return jnp.where(all_met, MET, UNDECIDED).astype(jnp.int8)
     # First unmet view, in order — matches the reference's recursion,
     # which only reports NACK if every earlier view already met.
     first_unmet = jnp.argmin(met_v.astype(jnp.int32), axis=-1)  # [...]

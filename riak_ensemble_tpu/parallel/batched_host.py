@@ -193,13 +193,18 @@ SLICE_MIN_E = 256
 
 
 def packed_nbytes(e: int, m: int, k: int, want_vsn: bool,
-                  a_width: Optional[int] = None) -> int:
+                  a_width: Optional[int] = None,
+                  sliced: bool = False) -> int:
     """Size in bytes of one ``engine.pack_results`` payload — the
     per-flush d2h transfer.  ``a_width`` is the compacted column
-    count (None = full width E); used for the ``payload_bytes``
-    accounting and the bench's full-width-vs-compacted A/B."""
+    count (None = full width E), ``sliced`` a launch whose step ran
+    on the gathered grid (its won/corrupt planes ``a_width`` wide,
+    its quorum plane E wide all the same); used for the
+    ``payload_bytes`` accounting and the bench's
+    full-width-vs-compacted A/B."""
     aw = e if a_width is None else a_width
-    nbits = 2 * e + e * m + 3 * k * aw
+    hw = aw if sliced else e
+    nbits = hw + e + hw * m + 3 * k * aw
     return (nbits + 7) // 8 + 4 * k * aw * (3 if want_vsn else 1)
 
 
@@ -221,13 +226,16 @@ def unpack_results(flat: np.ndarray, e: int, m: int, k: int,
     for them, so every downstream consumer (resolve loops, WAL,
     replica CRC) is layout-blind.  ``sliced`` marks a
     launch whose step itself ran on the gathered grid: then the
-    won/quorum_ok/corrupt planes are A-width too and scatter the
-    same way (inactive columns won nothing, renewed nothing and
-    flagged nothing — exactly what the full grid reports for
-    columns no round touched)."""
+    won/corrupt planes are A-width too and scatter the same way
+    (inactive columns won nothing and flagged nothing — exactly what
+    the full grid reports for columns no round touched).  The
+    ``quorum_ok`` plane is E wide in EVERY layout and is read where
+    it lies: a sliced launch reports the epoch check of the columns
+    it did not step too (``engine._sliced_quorum``), so the caller's
+    lease renewal sees every ensemble, as on a full-width launch."""
     aw = e if active is None else a_width
-    hw = aw if sliced else e  # election/quorum/corrupt plane width
-    nbits = 2 * hw + hw * m + 3 * k * aw
+    hw = aw if sliced else e  # election/corrupt plane width
+    nbits = hw + e + hw * m + 3 * k * aw
     bits = np.unpackbits(flat[:(nbits + 7) // 8],
                          count=nbits).astype(bool)
     ints = flat[(nbits + 7) // 8:].copy().view(np.int32)
@@ -246,7 +254,7 @@ def unpack_results(flat: np.ndarray, e: int, m: int, k: int,
         return out.reshape(shape) if shape is not None else out
 
     won = take_bits(hw)
-    quorum_ok = take_bits(hw)
+    quorum_ok = take_bits(e)
     corrupt = take_bits(hw * m, (hw, m))
     if sliced and active is not None:
         a = len(active)
@@ -256,7 +264,6 @@ def unpack_results(flat: np.ndarray, e: int, m: int, k: int,
             out[active] = c[:a]
             return out
         won = scat_cols(won, (e,))
-        quorum_ok = scat_cols(quorum_ok, (e,))
         corrupt = scat_cols(corrupt, (e, m))
     if k:
         committed = take_bits(k * aw, (k, aw))
@@ -301,10 +308,12 @@ def unpack_results_sharded(flat: np.ndarray, e: int, m: int, k: int,
     stays layout-blind, exactly as with the gathered
     pack.  ``sliced``: every shard's STEP ran on its gathered
     ``[K, a_width]`` grid, so each block is a sliced launch's
-    (its won/quorum/corrupt planes ``a_width`` wide too)."""
+    (its won/corrupt planes ``a_width`` wide too, its quorum plane
+    the shard's whole ``e_loc``)."""
     e_loc = e // n_shards
-    nb = packed_nbytes(a_width if sliced else e_loc, m, k, want_vsn,
-                       a_width if shard_active is not None else None)
+    nb = packed_nbytes(e_loc, m, k, want_vsn,
+                       a_width if shard_active is not None else None,
+                       sliced)
     parts = []
     for s in range(n_shards):
         act = None if shard_active is None else shard_active[s]
@@ -616,8 +625,9 @@ class _InFlightLaunch:
     #: list (None = full-width pack) and the pow2-bucketed packed
     #: column count — the resolve half scatters the compact [K, A]
     #: planes back through these.  ``sliced`` marks a launch whose
-    #: STEP ran on the gathered [K, A] grid (then the won/quorum/
-    #: corrupt planes are A-width too, not just the client planes).
+    #: STEP ran on the gathered [K, A] grid (then the won/corrupt
+    #: planes are A-width too, not just the client planes; the
+    #: quorum plane is E wide on every launch).
     active: Any = None
     a_width: int = 0
     sliced: bool = False
@@ -978,6 +988,13 @@ class BatchedEnsembleService:
         #: one upload more where the failure detector changed ``up``)
         self.launch_uploads = 0
         self.launch_calls = 0
+        #: lease renewals launches made OUTSIDE their active set: the
+        #: columns a launch carried no operation and no election for
+        #: and renewed all the same, from the epoch check every launch
+        #: reports at full width (a sliced launch's too:
+        #: ``engine._sliced_quorum``); a launch over the whole grid
+        #: has no such columns (``stats()["lease_renewals_idle"]``)
+        self.lease_renewals_idle = 0
         #: the unsliced launches that pack-gathered (the rest stepped
         #: and packed the full grid), and over every launch that
         #: packed at a width (``a`` > 0) the sums of the busiest
@@ -3623,7 +3640,8 @@ class BatchedEnsembleService:
         try:
             # the ONE program call (inside dispatch; what is left of
             # it is the d2h copy's start).  A sliced launch's result
-            # planes are ALREADY A-width; a pack-gather's width is the
+            # planes are ALREADY A-width (its quorum plane E wide, as
+            # on every launch); a pack-gather's width is the
             # full-width program's static ``gather``
             with self.spans.span("dispatch_step", rec):
                 if sliced:
@@ -3808,9 +3826,15 @@ class BatchedEnsembleService:
             # leader confirmed its epoch with a quorum — the
             # leader_tick renewal (peer.erl:1092-1095), which covers
             # read-only leaders (reads ride the epoch-check round),
-            # not just committers.
+            # not just committers — and idle ones: quorum_ok is E wide
+            # on every launch, sliced or not, so a launch renews the
+            # ensembles it carried nothing for as well.
             renew = won_np | quorum_ok
             self.lease_until[renew] = fl.now + self.config.lease()
+            if fl.active is not None:
+                self.lease_renewals_idle += int(
+                    np.count_nonzero(renew)
+                    - np.count_nonzero(renew[fl.active]))
 
             # Device-detected integrity failures -> anti-entropy
             # exchange for the affected ensembles (the tree_corrupted
@@ -4026,6 +4050,7 @@ class BatchedEnsembleService:
                                if self._occ_launches else 1.0),
             "launches_sliced": self.launches_sliced,
             "launches_unsliced": self.launches_unsliced,
+            "lease_renewals_idle": self.lease_renewals_idle,
             "launch": {"launches": (self.launches_sliced
                                     + self.launches_unsliced),
                        "uploads": self.launch_uploads,
@@ -4473,6 +4498,10 @@ class BatchedEnsembleService:
             "retpu_lease_valid_fraction": fam(
                 "gauge", "live rows holding a margin-valid lease",
                 round(self._lease_valid_fraction(), 4)),
+            "retpu_lease_renewals_idle_total": fam(
+                "counter", "lease renewals launches made outside "
+                "their active set (rows they carried no operation "
+                "for)", self.lease_renewals_idle),
             "retpu_grid_occupancy": fam(
                 "gauge", "mean packed-grid occupancy (a_width / E)",
                 round(occ, 4)),

@@ -84,11 +84,16 @@ def _random_result(rng, k, e, m, cols):
     return won, res
 
 
-@pytest.mark.parametrize("cols,a_width", [
+_ROUNDTRIP_COLS = [
     ([2, 7, 8, 21], 4),       # exact pow2 fit
     ([0, 3, 9, 20, 30], 8),   # padded bucket (pad repeats index 0)
     ([31], 1),                # single hot column
-])
+]
+_PLANES = ("won", "quorum", "corrupt", "committed", "get_ok", "found",
+           "value", "vsn")
+
+
+@pytest.mark.parametrize("cols,a_width", _ROUNDTRIP_COLS)
 def test_pack_unpack_roundtrip_active(cols, a_width):
     rng = np.random.default_rng(7)
     k, e, m = 5, 32, 3
@@ -109,10 +114,75 @@ def test_pack_unpack_roundtrip_active(cols, a_width):
     o_full = bh.unpack_results(full_flat, e, m, k, True)
     o_comp = bh.unpack_results(comp_flat, e, m, k, True,
                                active=cols, a_width=a_width)
-    for name, a, b in zip(("won", "quorum", "corrupt", "committed",
-                           "get_ok", "found", "value", "vsn"),
-                          o_full, o_comp):
+    for name, a, b in zip(_PLANES, o_full, o_comp):
         np.testing.assert_array_equal(a, b, err_msg=name)
+
+
+@pytest.mark.parametrize("want_vsn", [True, False],
+                         ids=["vsn", "no-vsn"])
+@pytest.mark.parametrize("cols,a_width", _ROUNDTRIP_COLS)
+def test_pack_unpack_roundtrip_sliced(cols, a_width, want_vsn):
+    """A SLICED launch's pack: ``won``, the corrupt mask and the
+    client planes A wide (pads included), the quorum plane ONE row E
+    wide (``engine._sliced_quorum``).  The numpy unpack and the native
+    one give the same full-width planes: the A-wide ones scattered
+    through the active list, the quorum row as it was packed, set
+    bits of idle columns included."""
+    from riak_ensemble_tpu.parallel import resolve_native
+
+    rng = np.random.default_rng(11)
+    k, e, m = 5, 32, 3
+    cols = np.asarray(cols, np.int32)
+    n = len(cols)
+
+    def noise(shape, dtype=bool):
+        if dtype is bool:
+            return rng.random(shape) < 0.5
+        return rng.integers(0, 1 << 20, shape).astype(dtype)
+
+    res = eng.KvResult(
+        committed=noise((k, a_width)), get_ok=noise((k, a_width)),
+        found=noise((k, a_width)),
+        value=noise((k, a_width), np.int32),
+        obj_vsn=noise((k, a_width, 2), np.int32),
+        quorum_ok=noise((1, e)),
+        tree_corrupt=noise((k, a_width, m)))
+    won = noise((a_width,))
+    assert res.quorum_ok[0, np.setdiff1d(np.arange(e), cols)].any()
+
+    pack = jax.jit(eng.pack_results, static_argnames=("want_vsn",))
+    flat = np.asarray(pack(jnp.asarray(won),
+                           jax.tree.map(jnp.asarray, res), want_vsn))
+    assert flat.nbytes == bh.packed_nbytes(e, m, k, want_vsn, a_width,
+                                           sliced=True)
+
+    def wide(x, axis=0):
+        """The A-wide plane's real columns at their places in E."""
+        shape = list(x.shape)
+        shape[axis] = e
+        out = np.zeros(shape, x.dtype)
+        out[(slice(None),) * axis + (cols,)] = np.take(
+            x, np.arange(n), axis=axis)
+        return out
+
+    want = (wide(won), res.quorum_ok[0], wide(res.tree_corrupt.any(0)),
+            wide(res.committed, 1), wide(res.get_ok, 1),
+            wide(res.found, 1), wide(res.value, 1),
+            wide(res.obj_vsn, 1) if want_vsn else None)
+    arms = {"numpy": bh.unpack_results(flat, e, m, k, want_vsn,
+                                       active=cols, a_width=a_width,
+                                       sliced=True)}
+    nr = resolve_native.get()
+    if nr is not None:
+        arms["native"] = nr.unpack(flat, e, m, k, want_vsn, cols,
+                                   a_width, True)
+        assert arms["native"] is not None
+    for arm, got in arms.items():
+        for name, g, w in zip(_PLANES, got, want):
+            if w is None:
+                assert g is None, (arm, name)
+                continue
+            np.testing.assert_array_equal(g, w, err_msg=f"{arm} {name}")
 
 
 # -- the skew-load equivalence sweep ----------------------------------------

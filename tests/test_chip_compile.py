@@ -205,6 +205,28 @@ def _assert_object_planes_stay_put(text, e, record_property, tag):
     assert all(" parameter(" in s for s in sources), sources
 
 
+def _assert_idle_quorum_moves_no_plane(text, e, n_slots, record_property,
+                                       tag):
+    """The sliced program's full-width epoch check
+    (``engine._sliced_quorum``, ISSUE 47) is in the compiled program
+    and reads the small ballot planes alone: beside the object planes
+    (above) ``tree_leaf`` does not move either, the check holds no
+    gather (``quorum_met_batch``'s per-row one took 0.09 ms of a launch
+    at 10,000 rows on the chip: ``met_only``), and what the program
+    moves of the ``[E]`` / ``[E, M]`` / ``[E, V, M]`` planes is
+    recorded (the same moves as before ISSUE 47 at both buckets)."""
+    checks = [line for line in text.splitlines() if "idle_quorum" in line]
+    assert checks
+    assert not [line for line in checks if " gather(" in line]
+    leaf = _whole_plane_moves(text, (f"u32[{e},{M},{n_slots},4]",))
+    small = _whole_plane_moves(
+        text, (f"[{e}]", f"[{e},{M}]", f"[{e},{V},{M}]", f"[{e},{V}]"))
+    record_property(f"{tag}_tree_leaf_moves", leaf)
+    record_property(f"{tag}_small_plane_moves", small)
+    print(f"{tag} tree_leaf moves {leaf} small-plane moves {small}")
+    assert not leaf, leaf
+
+
 @pytest.mark.parametrize("k,a", [(1, 8), (16, 256)], ids=["k1a8", "k16a256"])
 def test_sliced_step_moves_no_object_plane(one_chip, record_property, k, a):
     """`ycsb-a.ring10k-n5`'s window flush (K 1, A 8) and the headline
@@ -219,6 +241,26 @@ def test_sliced_step_moves_no_object_plane(one_chip, record_property, k, a):
     print(f"k{k}a{a} temp_bytes {temp}")
     if (k, a) == (1, 8):        # 125.6 MB before ISSUE 40
         assert temp < 16e6
+
+
+@pytest.mark.parametrize("k,a", [(1, 8), (16, 256)], ids=["k1a8", "k16a256"])
+def test_sliced_step_checks_every_epoch_and_moves_no_plane(
+        one_chip, record_property, k, a):
+    """The same two programs since ISSUE 47: the epoch check of all
+    10,000 ensembles rides in the sliced step, its result E bits of
+    the packed vector, and it moves no plane of the keyspace's size."""
+    from riak_ensemble_tpu.parallel.batched_host import packed_nbytes
+
+    compiled = _sliced_step(one_chip, k, a)
+    text = compiled.as_text()
+    _assert_idle_quorum_moves_no_plane(text, E, S, record_property,
+                                       f"k{k}a{a}")
+    # the packed vector: won and corrupt A wide, the quorum plane E
+    flat = packed_nbytes(E, M, k, True, a, sliced=True)
+    assert flat == (a + E + a * M + 3 * k * a + 7) // 8 + 12 * k * a
+    root = [line for line in text[text.index("\nENTRY "):].splitlines()
+            if line.lstrip().startswith("ROOT ")][0]
+    assert f"u8[{flat}]" in root, root
 
 
 #: Riak's default ring with a deep keyspace: `ycsb-a.ring64-n3-deep`
@@ -442,6 +484,10 @@ def test_sliced_mesh_step_moves_no_object_plane(topo, record_property):
         want_vsn=True).compile()
     _assert_object_planes_stay_put(compiled.as_text(), e // 4,
                                    record_property, "mesh_k1a8")
+    # ... and the per-shard epoch check of a shard's 10,240 rows too
+    _assert_idle_quorum_moves_no_plane(compiled.as_text(), e // 4, S,
+                                       record_property, "mesh_k1a8")
+    assert not _COLLECTIVES.search(compiled.as_text())
     temp = compiled.memory_analysis().temp_size_in_bytes
     record_property("mesh_k1a8_temp_bytes", temp)
     print(f"mesh_k1a8 temp_bytes {temp}")

@@ -334,6 +334,77 @@ def test_sliced_mesh_launch_matches_full_grid_and_one_chip(case, k):
             svc.stop()
 
 
+# -- a sliced launch renews every shard's leases (ISSUE 47) -------------------
+
+
+def _leased_arms():
+    """(the mesh as it serves, one chip) at E_SL ensembles on a
+    VIRTUAL clock, so a lease lapses when the test says so."""
+    from riak_ensemble_tpu.config import fast_test_config
+    from riak_ensemble_tpu.runtime import Runtime
+
+    def mk(engine):
+        rt = Runtime(seed=47)
+        return rt, BatchedEnsembleService(rt, E_SL, 3, 8, tick=None,
+                                          config=fast_test_config(),
+                                          engine=engine)
+    return mk(mesh_engine(N_SH)), mk(None)
+
+
+@pytest.mark.parametrize("down", [None, "followers", "everyone",
+                                  "leader-and-follower"])
+def test_sliced_mesh_launch_renews_every_shards_leases(down):
+    """One operation on ONE shard (the other three blocks all pad):
+    each shard's block of the packed vector carries the epoch check of
+    all of that shard's own rows, so the launch renews the lease of
+    every ensemble on every shard and a read of an idle ensemble of
+    ANOTHER shard is answered from the mirror.  Not where the device's
+    check fails: an idle ensemble whose leader is down, or whose up
+    members are short of a quorum, keeps its lapsed lease and its read
+    takes the device round.  The mesh and one chip agree on every
+    ensemble's lease."""
+    busy, idle = 5, 2 * E_LOC + 7           # shards 0 and 2
+    seen = []
+    for rt, svc in _leased_arms():
+        try:
+            _drive(svc, [svc.kput(idle, "b", b"v")])
+            lead = int(svc.leader_np[idle])
+            others = [p for p in range(3) if p != lead]
+            gone = {None: [], "followers": others,
+                    "everyone": [lead] + others,
+                    "leader-and-follower": [lead, others[0]]}[down]
+            for p in gone:
+                svc.set_peer_up(idle, p, False)
+            rt.run_for(svc.config.lease() * 3)
+            assert svc.stats()["lease_valid_fraction"] == 0.0
+            idle0, n0 = svc.lease_renewals_idle, len(svc.lat_records)
+            assert _drive(svc, [svc.kput(busy, "a", b"x")])[0][0] == "ok"
+            # one sliced launch; the failed election of the third case
+            # rides in it as an active column of its own shard
+            assert [x[2] for x in _launches(svc, n0)] == [1]
+            active = 1 + (down == "leader-and-follower")
+            kept = down is not None
+            assert svc.lease_renewals_idle - idle0 == E_SL - active - (
+                kept and active == 1)
+            assert svc.stats()["lease_valid_fraction"] == (
+                E_SL - kept) / E_SL
+            flushes = svc.flushes
+            g = svc.kget(idle, "b")
+            if kept:
+                assert not g.done and svc.lease_until[idle] <= rt.now
+                assert _drive(svc, [g]) != [("ok", b"v")]
+                assert svc.flushes > flushes
+            else:
+                assert g.done and g.value == ("ok", b"v")
+                assert svc.flushes == flushes
+            seen.append((svc.lease_until > rt.now + svc._read_margin,
+                         svc.lease_renewals_idle))
+        finally:
+            svc.stop()
+    np.testing.assert_array_equal(seen[0][0], seen[1][0])
+    assert seen[0][1] == seen[1][1]
+
+
 def test_election_only_launch_on_a_mesh_that_slices():
     """K = 0 carries no op planes to compact: the mesh steps the full
     grid, as one chip does, and the re-elected row then serves through

@@ -35,7 +35,7 @@ needs_kernel = pytest.mark.skipif(
 
 def _pack_reference(won, quorum, corrupt, committed, get_ok, found,
                     value, vsn, want_vsn):
-    """Host-side replica of _pack_results_body's layout (the d2h
+    """Host-side replica of ``engine.pack_results``' layout (the d2h
     payload the kernel unpacks)."""
     flags = np.concatenate(
         [won.ravel(), quorum.ravel(), corrupt.ravel(),
@@ -57,7 +57,9 @@ def _pack_reference(won, quorum, corrupt, committed, get_ok, found,
 def test_unpack_fuzz_equivalence(seed):
     """Random packed planes through native vs Python unpack: every
     returned plane bit-identical across full-width, compacted
-    (pack-gather) and sliced [K, A] layouts, want_vsn on and off."""
+    (pack-gather) and sliced [K, A] layouts, want_vsn on and off.
+    The quorum plane is E wide in all three (a sliced launch reports
+    the columns it did not step too) and comes back as packed."""
     nr = resolve_native.get()
     rng = np.random.default_rng(seed)
     for trial in range(60):
@@ -79,7 +81,7 @@ def test_unpack_fuzz_equivalence(seed):
             sliced = mode == 2
         hw = aw if (sliced and active is not None) else e
         won = rng.integers(0, 2, hw).astype(bool)
-        quorum = rng.integers(0, 2, hw).astype(bool)
+        quorum = rng.integers(0, 2, e).astype(bool)
         corrupt = rng.integers(0, 2, (hw, m)).astype(bool)
         committed = rng.integers(0, 2, (k, aw)).astype(bool)
         get_ok = rng.integers(0, 2, (k, aw)).astype(bool)
@@ -102,6 +104,49 @@ def test_unpack_fuzz_equivalence(seed):
                 continue
             assert np.array_equal(np.asarray(a), np.asarray(b)), \
                 (seed, trial, name, mode)
+        assert np.array_equal(nat[1], quorum), (seed, trial, mode)
+
+
+@needs_kernel
+@pytest.mark.parametrize("e,na,aw,k", [
+    (10, 1, 8, 1),      # one hot column, a byte-straddling quorum row
+    (300, 5, 8, 1),     # the benchmark's shape: K 1, A 8
+    (257, 33, 64, 3),   # E not a multiple of 8
+    (64, 16, 16, 0),    # election-only: control planes alone
+])
+def test_unpack_sliced_quorum_full_width(e, na, aw, k):
+    """A sliced launch's payload: ``won`` and the corrupt mask A wide
+    and scattered through the active list, the quorum plane between
+    them E wide and returned as packed, idle columns' bits included;
+    native and numpy bit-identical."""
+    nr = resolve_native.get()
+    rng = np.random.default_rng(e + na)
+    m = 3
+    active = np.sort(rng.choice(e, na, replace=False)).astype(np.int32)
+    won = rng.integers(0, 2, aw).astype(bool)
+    quorum = rng.integers(0, 2, e).astype(bool)
+    quorum[np.setdiff1d(np.arange(e), active)[:2]] = True
+    corrupt = rng.integers(0, 2, (aw, m)).astype(bool)
+    bits = [rng.integers(0, 2, (k, aw)).astype(bool) for _ in range(3)]
+    value = rng.integers(-2**31, 2**31, (k, aw)).astype(np.int32)
+    vsn = rng.integers(0, 2**31, (k, aw, 2)).astype(np.int32)
+    flat = _pack_reference(won, quorum, corrupt, *bits, value, vsn, True)
+    ref = unpack_results(flat, e, m, k, True, active=active,
+                         a_width=aw, sliced=True)
+    nat = nr.unpack(flat, e, m, k, True, active, aw, True)
+    assert nat is not None
+    for name, a, b in zip(("won", "quorum", "corrupt", "committed",
+                           "get_ok", "found", "value", "vsn"), ref, nat):
+        if a is None:
+            assert b is None, name
+            continue
+        assert np.array_equal(np.asarray(a), np.asarray(b)), name
+    assert np.array_equal(nat[1], quorum)
+    want_won = np.zeros(e, bool)
+    want_won[active] = won[:na]
+    assert np.array_equal(nat[0], want_won)
+    # one bit short of the layout is refused, not misread
+    assert nr.unpack(flat[:-1], e, m, k, True, active, aw, True) is None
 
 
 @needs_kernel

@@ -913,7 +913,8 @@ STEP_SCOPES = ("elect", "quorum", "slot_gather", "merkle_verify",
 
 @pytest.mark.parametrize("program,scope", [
     *[("step_sliced", s) for s in STEP_SCOPES + ("slice_columns",
-                                                 "scatter_columns")],
+                                                 "scatter_columns",
+                                                 "idle_quorum")],
     *[("step", s) for s in STEP_SCOPES],
     # the pack is inside the served programs, the per-plane reference
     # step has none

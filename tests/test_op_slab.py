@@ -226,6 +226,13 @@ def test_mesh_sliced_slab_program_matches_one_chips(elections, k):
                                   np.asarray(want[1])[:n])
     for name, g, w in zip(want[2]._fields, got[2], want[2]):
         g, w = np.asarray(g), np.asarray(w)
+        if name == "quorum_ok":
+            # ONE row at full width on both: each shard's epoch check
+            # of all its own rows, side by side, is one chip's
+            assert g.shape == w.shape == (1, E)
+            assert w[0, active].all() and w.sum() > n
+            np.testing.assert_array_equal(g, w, err_msg=name)
+            continue
         assert g.shape[1] == n_sh * a_loc, (name, g.shape)
         np.testing.assert_array_equal(g[:, at], w[:, :n], err_msg=name)
         if name in ("committed", "get_ok", "found"):
@@ -289,12 +296,17 @@ def test_sliced_edges_match_take_and_set(shape, a):
 
 
 @pytest.mark.parametrize("k", [1, 4])
+@pytest.mark.parametrize("peers", ["all-up", "some-down"])
 @pytest.mark.parametrize("form", ["one-chip", "mesh4"])
-def test_sliced_step_matches_full_width_step(form, k):
+def test_sliced_step_matches_full_width_step(form, peers, k):
     """The same operations through the sliced program and through the
     full-width program: the same state, and the same ``won`` and
-    result columns at each active column's place (healthy ensembles:
-    an idle column's full-width NOOP round changes nothing)."""
+    result columns at each active column's place (an idle column's
+    full-width NOOP round changes nothing).  The QUORUM plane agrees
+    over all E columns: the sliced program's one full-width row is
+    what the full-width program's rounds report, for the columns it
+    stepped and for those it did not: also where an idle ensemble's
+    leader is down or its up members are short of a quorum."""
     n_sh = 4 if form == "mesh4" else 1
     engine = mesh_engine(n_sh) if form == "mesh4" else _LocalEngine()
     elect, cand, lease, planes = _operands(k, True, seed=40 + k)
@@ -303,7 +315,15 @@ def test_sliced_step_matches_full_width_step(form, k):
     elect[idle], lease[idle] = False, False
     elect[[2, 63]], cand[[2, 63]] = True, 0
     planes[0][:, idle] = eng.OP_NOOP
-    up = jnp.ones((E, M), bool)
+    up_np = np.ones((E, M), bool)
+    no_quorum = []
+    if peers == "some-down":
+        up_np[::5, 1] = False       # one follower: a quorum stands
+        up_np[[7, 33], 0] = False   # idle, the leader itself down
+        up_np[[9, 50], 1:] = False  # idle, the leader alone
+        up_np[11, 1:] = False       # active, the leader alone
+        no_quorum = [7, 9, 11, 33, 50]
+    up = jnp.asarray(up_np)
 
     def placed(slab):
         return (jax.device_put(slab, engine.slab_sharding)
@@ -327,8 +347,15 @@ def test_sliced_step_matches_full_width_step(form, k):
     np.testing.assert_array_equal(np.asarray(got[1])[at],
                                   np.asarray(want[1])[active])
     for name, g, w in zip(want[2]._fields, got[2], want[2]):
-        np.testing.assert_array_equal(np.asarray(g)[:, at],
-                                      np.asarray(w)[:, active],
+        g, w = np.asarray(g), np.asarray(w)
+        if name == "quorum_ok":
+            assert g.shape == (1, E)
+            np.testing.assert_array_equal(g[0], w.any(0), err_msg=name)
+            ok = np.ones(E, bool)
+            ok[no_quorum] = False
+            np.testing.assert_array_equal(g[0], ok, err_msg=name)
+            continue
+        np.testing.assert_array_equal(g[:, at], w[:, active],
                                       err_msg=name)
 
 
@@ -409,8 +436,10 @@ def test_one_program_launch_matches_step_then_pack_apart(form, k):
     # where each launch column's results sit in the step's planes
     src = at if sliced else active
     np.testing.assert_array_equal(u_won[active], won[src])
-    np.testing.assert_array_equal(u_quorum[active],
-                                  res.quorum_ok.any(0)[src])
+    # the quorum plane is E wide whatever the launch's shape
+    assert u_quorum.shape == (E,)
+    assert u_quorum.sum() > active.size
+    np.testing.assert_array_equal(u_quorum, res.quorum_ok.any(0))
     for name, got, want in (("committed", committed, res.committed),
                             ("get_ok", get_ok, res.get_ok),
                             ("found", found, res.found),

@@ -118,6 +118,12 @@ class TestBatchedDifferential:
             assert got == expect, (
                 f"trial={trial} views={views_idx} self={self_i} "
                 f"valid={valid} nack={nack} expect={expect} got={got}")
+            # met_only: the same MET, and a NACK told as UNDECIDED
+            met = int(quorum_met_batch(valid, nack, mask,
+                                       np.int32(self_i), required,
+                                       met_only=True))
+            assert met == (MET if expect == MET else UNDECIDED), (
+                trial, expect, met)
 
     def test_vmapped_batch_shape(self):
         E, V, M = 32, 2, 5
@@ -129,6 +135,10 @@ class TestBatchedDifferential:
         self_idx = np.zeros(E, np.int32)
         out = quorum_met_batch(valid, nack, mask, self_idx)
         assert out.shape == (E,)
+        met = quorum_met_batch(valid, nack, mask, self_idx, met_only=True)
+        np.testing.assert_array_equal(np.asarray(met) == MET,
+                                      np.asarray(out) == MET)
+        assert NACK in np.asarray(out) and NACK not in np.asarray(met)
         for e in range(E):
             peers = [P(i) for i in range(M)]
             replies = [(peers[i], "ok") for i in range(M) if valid[e, i]]

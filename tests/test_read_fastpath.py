@@ -116,6 +116,127 @@ def test_lease_expiry_and_margin_misses():
     assert svc.read_fastpath_miss_reasons["no_lease"] == 2
 
 
+#: a ring wide enough for a SLICED launch (``SLICE_MIN_E`` rows, the
+#: smallest bucket at most a quarter of them), the ensemble the
+#: launches below carry operations for, and one they carry nothing for
+WIDE, BUSY, IDLE = 256, 5, 200
+
+
+def make_wide(**kw):
+    return make(n_ens=WIDE, **kw)
+
+
+def test_lease_lapses_on_a_service_that_never_launches():
+    """Nothing but a launch renews a lease: with no flush at all the
+    lease runs out on the clock and stays out, whatever the width of
+    the ring (a ring wide enough to slice: nothing renews it behind
+    the service's back)."""
+    runtime, svc = make_wide()
+    cfg = svc.config
+    assert settle(runtime, svc, svc.kput(IDLE, "b", b"v"))[0] == "ok"
+    assert svc.kget(IDLE, "b").done
+    flushes, idle = svc.flushes, svc.lease_renewals_idle
+    runtime.run_for(float(svc.lease_until[IDLE]) - runtime.now
+                    - cfg.read_margin() * 0.5)
+    assert not svc._try_fast(IDLE, svc.key_slot[IDLE]["b"], False)[0]
+    runtime.run_for(cfg.lease() * 3)
+    assert not svc._try_fast(IDLE, svc.key_slot[IDLE]["b"], False)[0]
+    assert svc.read_fastpath_miss_reasons["no_lease"] == 2
+    assert svc.stats()["lease_valid_fraction"] == 0.0
+    assert (svc.flushes, svc.lease_renewals_idle) == (flushes, idle)
+
+
+def _lapse_all(runtime, svc):
+    runtime.run_for(svc.config.lease() * 3)
+    assert svc.stats()["lease_valid_fraction"] == 0.0
+
+
+def _sliced_put(runtime, svc, electing=0):
+    """One settled kput on ``BUSY`` alone; the launch that carried it
+    sliced (its record says so), its active set that one column and
+    the ``electing`` ones."""
+    before = svc.launches_sliced
+    assert settle(runtime, svc, svc.kput(BUSY, "a", b"x"))[0] == "ok"
+    assert svc.launches_sliced == before + 1
+    rec = [r for r in svc.lat_records if r.get("k")][-1]
+    assert (rec["sliced"], rec["cols"], rec["a"]) == (1, 1 + electing, 8)
+
+
+def test_sliced_launch_renews_an_idle_ensembles_lease():
+    """ISSUE 47: a sliced launch carries the epoch check of EVERY
+    ensemble, so the lease of one it had no operation for is renewed
+    and the next read of that ensemble is answered from the mirror:
+    no flush, no device round."""
+    runtime, svc = make_wide()
+    assert settle(runtime, svc, svc.kput(IDLE, "b", b"v"))[0] == "ok"
+    _lapse_all(runtime, svc)
+    assert svc._fast_read_ok(IDLE, runtime.now) == "no_lease"
+    idle0 = svc.stats()["lease_renewals_idle"]
+
+    stamp = runtime.now
+    _sliced_put(runtime, svc)
+    # every ensemble but the busy one, renewed outside the active set,
+    # and none beyond the launch's own pre-upload stamp + lease
+    st = svc.stats()
+    assert st["lease_renewals_idle"] - idle0 == WIDE - 1
+    assert st["lease_valid_fraction"] == 1.0
+    assert np.all(svc.lease_until <= runtime.now + svc.config.lease())
+    assert np.all(svc.lease_until >= stamp + svc.config.lease())
+    flushes, hits = svc.flushes, svc.read_fastpath_hits
+    g = svc.kget(IDLE, "b")
+    assert g.done and g.value == ("ok", b"v")
+    assert (svc.flushes, svc.read_fastpath_hits) == (flushes, hits + 1)
+    assert svc.obs_registry.snapshot()[
+        "retpu_lease_renewals_idle_total"] == st["lease_renewals_idle"]
+    assert "retpu_lease_renewals_idle_total %d" % (
+        st["lease_renewals_idle"]) in svc.obs_registry.render_prometheus()
+
+
+@pytest.mark.parametrize("down", [
+    "followers",            # the leader alone: short of a quorum
+    "everyone",             # leader down, nobody to elect
+    "leader-and-follower",  # leader down, the election cannot win
+])
+def test_sliced_launch_renews_no_lease_without_the_epoch_check(down):
+    """...and ONLY from the device's own check: an idle ensemble whose
+    leader is down, or whose up members are short of a quorum, is not
+    renewed by another ensemble's launch; its read misses and takes
+    the device round (which cannot succeed either).  Peers back up,
+    the next launch renews it."""
+    runtime, svc = make_wide()
+    assert settle(runtime, svc, svc.kput(IDLE, "b", b"v"))[0] == "ok"
+    lead = int(svc.leader_np[IDLE])
+    others = [p for p in range(3) if p != lead]
+    gone = {"followers": others, "everyone": [lead] + others,
+            "leader-and-follower": [lead, others[0]]}[down]
+    for p in gone:
+        svc.set_peer_up(IDLE, p, False)
+    _lapse_all(runtime, svc)
+    idle0 = svc.lease_renewals_idle
+
+    # (the third case puts IDLE in the active set: its election runs,
+    # and loses, inside the same launch)
+    _sliced_put(runtime, svc, electing=down == "leader-and-follower")
+    assert svc.lease_until[IDLE] <= runtime.now
+    assert svc.lease_renewals_idle - idle0 == WIDE - 2
+    assert svc.stats()["lease_valid_fraction"] == (WIDE - 1) / WIDE
+    flushes = svc.flushes
+    g = svc.kget(IDLE, "b")
+    assert not g.done
+    reason = "no_lease" if down == "followers" else "no_leader"
+    assert svc.read_fastpath_miss_reasons[reason] == 1
+    assert settle(runtime, svc, g) != ("ok", b"v")
+    assert svc.flushes > flushes
+    assert svc.lease_until[IDLE] <= runtime.now
+
+    for p in gone:
+        svc.set_peer_up(IDLE, p, True)
+    _sliced_put(runtime, svc)   # the old leader is back: no election
+    assert svc.stats()["lease_valid_fraction"] == 1.0
+    g = svc.kget(IDLE, "b")
+    assert g.done and g.value == ("ok", b"v")
+
+
 def test_leader_down_then_reelection_revalidates_vsn_mirror():
     runtime, svc = make()
     assert settle(runtime, svc, svc.kput(0, "a", b"v"))[0] == "ok"
@@ -273,14 +394,18 @@ def test_kget_many_mixed_fast_and_fallback():
     assert m2.done and m2.value == [("ok", b"2x"), ("ok", b"1")]
 
 
-def test_equivalence_random_ops_fast_vs_device():
+@pytest.mark.parametrize("ring", ["narrow", "sliced"])
+def test_equivalence_random_ops_fast_vs_device(ring):
     """After a random keyed workload, every key's fast answer equals
-    its forced device-round answer (value AND version)."""
+    its forced device-round answer (value AND version): on a ring of
+    three ensembles, and on three ensembles of a ring wide enough that
+    every launch slices and the others' leases ride along."""
     rng = np.random.default_rng(42)
-    runtime, svc = make(n_ens=3)
+    ens = [0, 1, 2] if ring == "narrow" else [BUSY, 100, IDLE]
+    runtime, svc = make(n_ens=3 if ring == "narrow" else WIDE)
     keys = [f"k{i}" for i in range(4)]
     for _ in range(30):
-        e = int(rng.integers(3))
+        e = ens[int(rng.integers(3))]
         key = keys[int(rng.integers(4))]
         r = rng.random()
         if r < 0.5:
@@ -297,7 +422,9 @@ def test_equivalence_random_ops_fast_vs_device():
     while any(svc.queues):
         svc.flush()
     svc.flush()
-    for e in range(3):
+    if ring == "sliced":
+        assert svc.launches_sliced > 5 and svc.lease_renewals_idle > WIDE
+    for e in ens:
         for key in keys + [f"c{k}" for k in keys]:
             fast = svc.kget_vsn(e, key)
             assert fast.done  # hit or immediate NOTFOUND

@@ -31,6 +31,10 @@ N_ENS = 6
 N_PEERS = 5
 N_KEYS = 3
 ROUNDS = 35
+#: the same six ensembles as columns of a ring wide enough that every
+#: launch SLICES (``SLICE_MIN_E`` rows): the other 250 carry nothing
+WIDE_ENS = 256
+WIDE_COLS = (3, 40, 77, 130, 201, 255)
 
 
 def _drain(svc, runtime, pending, max_flushes=10, tolerate=None,
@@ -74,13 +78,15 @@ def _apply_outcomes(pending):
             # 'failed' read returned nothing: no model event
 
 
-def _submit_batch(rng, svc, models, vals, vsns, seed):
+def _submit_batch(rng, svc, models, vals, vsns, seed,
+                  cols=range(N_ENS)):
     """One round of the concurrent workload, shared by every sweep:
     puts, CAS updates on the last acked vsn (sometimes stale — then
-    they must fail cleanly), reads, and deletes."""
+    they must fail cleanly), reads, and deletes (on the service's
+    ensembles ``cols``)."""
     pending = []
     for _ in range(int(rng.integers(2, 8))):
-        e = int(rng.integers(N_ENS))
+        e = cols[int(rng.integers(N_ENS))]
         k = int(rng.integers(N_KEYS))
         m = models[(e, k)]
         key = f"key{k}"
@@ -133,16 +139,34 @@ def test_service_linearizable_under_nemesis_pipelined(seed):
     _nemesis_sweep(seed, pipeline_depth=2, max_k=4)
 
 
-def _nemesis_sweep(seed, pipeline_depth, max_k=8):
+@pytest.mark.parametrize("seed,depth", [(721, 1), (722, 2)])
+def test_service_linearizable_under_nemesis_on_a_ring_that_slices(
+        seed, depth):
+    """The SAME sweep on six ensembles of a 256-wide ring: every op
+    launch is a sliced one, whose quorum plane renews the lease of
+    EVERY ensemble whose leader holds an epoch quorum of up members
+    (ISSUE 47), so most reads of the sweep are leased replies that
+    race the leader kills, the membership churn and the jumps past the
+    lease, and the model still finds no stale or lost value."""
+    svc = _nemesis_sweep(seed, pipeline_depth=depth,
+                         max_k=8 if depth == 1 else 4,
+                         n_ens=WIDE_ENS, cols=WIDE_COLS)
+    assert svc.launches_sliced >= ROUNDS // 2
+    assert svc.lease_renewals_idle > WIDE_ENS
+    assert svc.read_fastpath_hits > 0
+
+
+def _nemesis_sweep(seed, pipeline_depth, max_k=8, n_ens=N_ENS,
+                   cols=range(N_ENS)):
     rng = np.random.default_rng(seed)
     runtime = Runtime(seed=seed)
     config = fast_test_config()
-    svc = BatchedEnsembleService(runtime, N_ENS, N_PEERS, n_slots=8,
+    svc = BatchedEnsembleService(runtime, n_ens, N_PEERS, n_slots=8,
                                  tick=None, max_ops_per_tick=max_k,
                                  config=config,
                                  pipeline_depth=pipeline_depth)
     models = {(e, k): KeyModel(f"{e}/key{k}")
-              for e in range(N_ENS) for k in range(N_KEYS)}
+              for e in cols for k in range(N_KEYS)}
     vals = itertools.count(1)
     down = {}  # ens -> peer index currently down
     #: last vsn seen in a write ack per (ens, key) — CAS ops use it
@@ -160,7 +184,7 @@ def _nemesis_sweep(seed, pipeline_depth, max_k=8):
             # kill the CURRENT LEADER of a random ensemble right
             # before the flush that carries this round's ops — the
             # election folds into the same launch (mid-flush kill)
-            e = int(rng.integers(N_ENS))
+            e = cols[int(rng.integers(N_ENS))]
             if e not in down and svc.leader_np[e] >= 0:
                 p = int(svc.leader_np[e])
                 svc.set_peer_up(e, p, False)
@@ -170,8 +194,8 @@ def _nemesis_sweep(seed, pipeline_depth, max_k=8):
             # random up-and-running ensemble by one member (or restore
             # the full view), keys must survive the joint-consensus
             # transition
-            e = int(rng.integers(N_ENS))
-            sel = np.zeros((N_ENS,), bool)
+            e = cols[int(rng.integers(N_ENS))]
+            sel = np.zeros((n_ens,), bool)
             sel[e] = True
             nv = svc.member_np.copy()
             if nv[e].sum() == N_PEERS:
@@ -182,7 +206,8 @@ def _nemesis_sweep(seed, pipeline_depth, max_k=8):
                 nv[e] = True
             svc.update_members(sel, nv)
 
-        pending = _submit_batch(rng, svc, models, vals, vsns, seed)
+        pending = _submit_batch(rng, svc, models, vals, vsns, seed,
+                                cols)
 
         # -- lease expiry race: sometimes jump virtual time past the
         #    lease before flushing, so leased reads race renewal ------
@@ -207,6 +232,7 @@ def _nemesis_sweep(seed, pipeline_depth, max_k=8):
     # Sanity floor, not equality: a round whose ops all resolve
     # pre-flush (absent-key gets/deletes) never launches.
     assert svc.flushes >= ROUNDS // 2
+    return svc
 
 
 @pytest.mark.parametrize("seed", conftest.soak_seeds([801, 802, 803, 804]))
