@@ -425,7 +425,7 @@ class FaultPlan:
     def describe(self) -> Dict[str, Any]:
         """Plain-data (wire-encodable) snapshot of rules + counters —
         the health verb's ``injected`` section, the flight-recorder
-        dump section, and the bench's embedded fault config."""
+        dump section, and a run's embedded fault config."""
         with self._lock:
             return {
                 "active": self._active_locked(),

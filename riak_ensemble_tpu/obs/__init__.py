@@ -78,7 +78,7 @@ stack reports into:
   export surfaces.  ``RETPU_WATCHDOG=0`` disarms the standing pull.
 
 Knobs: ``RETPU_OBS=0`` disables hot-path recording (instruments stay
-constructed; record calls short-circuit — the bench's A/B arm);
+constructed; record calls short-circuit — the tests' OFF arm);
 ``RETPU_OBS_DUMP_DIR`` directs flight-recorder dumps (unset keeps
 them in memory only).  Stores are PER PROCESS: in-process replica
 servers share the span store with their leader, subprocess replicas

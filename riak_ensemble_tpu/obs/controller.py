@@ -35,7 +35,7 @@ the ``retpu_autotune_*`` gauge family, the ``health()``
 ``controller`` section, the flight-dump ``controller_decisions``
 section, and Chrome-trace instants via ``tools/trace_export.py``.
 :func:`replay` reconstructs the final knob state from the journal
-alone — the bench ASSERTS that reconstruction against the live knobs,
+alone — the tests ASSERT that reconstruction against the live knobs,
 so "the journal explains every knob change" is a tested property,
 not a hope.
 
@@ -141,7 +141,7 @@ def replay(events, initial: Dict[str, Any]) -> Dict[str, Any]:
     every knob-bearing decision's ``old -> new`` over ``initial``,
     checking each transition's ``old`` against the folded state (a
     mismatch means the journal does NOT explain the knob history —
-    the bench's reconstruction assertion fails loudly, not softly).
+    a reconstruction assertion fails loudly, not softly).
     """
     state = dict(initial)
     for ev in events:

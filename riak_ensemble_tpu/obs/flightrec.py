@@ -102,7 +102,7 @@ SHAPE_FIELDS = ("k", "uploads", "calls", "sliced", "arrival",
 
 #: per-flush record fields that are shape/identity metadata or
 #: derived marks, not additive latency components — shared with
-#: bench's tail attribution so the two dominant-mark argmaxes can
+#: every tail attribution so two dominant-mark argmaxes can
 #: never drift apart
 META_FIELDS = SHAPE_FIELDS + ("total",) + DERIVED_MARKS + (
     "flush_id", "t", "a_width", "payload_bytes", "queued_rounds",

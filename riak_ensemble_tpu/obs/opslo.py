@@ -72,7 +72,7 @@ STAGES: Tuple[str, ...] = ("rx", "assign", "queue_wait", "flush", "ack")
 def ring_capacity(default: int = 4096) -> int:
     """``RETPU_SLO_RING`` rounded up to a power of two (floor 64).
     ``0`` disables per-op tracing alone (the rest of the obs plane
-    stays live — the bench's op-trace A/B arm) and returns 0."""
+    stays live — the tests' untraced arm) and returns 0."""
     try:
         n = int(os.environ.get("RETPU_SLO_RING", default))
     except ValueError:

@@ -17,8 +17,8 @@ share the process).  :func:`timeline` answers the joined record —
 the obs API a test or bench asks "where did flush N's time go,
 end to end?".
 
-Per-process scope: in-process replica servers (tests, the bench
-smoke shape) share this store with their leader, so the join is
+Per-process scope: in-process replica servers (the tests'
+threaded hosts) share this store with their leader, so the join is
 immediate.  Subprocess replicas record into their own process's
 store; the leader's id still names their spans, and the join happens
 wherever both exports land.

@@ -53,8 +53,8 @@ REPLICA_SPANS = ("validate", "apply", "scatter", "rebuild", "wal_sync")
 def enabled() -> bool:
     """Whether the standing fleet pull + anomaly walk is armed
     (``RETPU_WATCHDOG``, default on; leader-with-links only either
-    way).  Services cache the answer at construction — the bench's
-    ``fleet_obs_overhead`` off arm."""
+    way).  Services cache the answer at construction: the off arm
+    is a service built under ``RETPU_WATCHDOG=0``."""
     return os.environ.get("RETPU_WATCHDOG", "1") != "0"
 
 

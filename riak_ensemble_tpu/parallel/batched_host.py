@@ -200,8 +200,8 @@ def packed_nbytes(e: int, m: int, k: int, want_vsn: bool,
     count (None = full width E), ``sliced`` a launch whose step ran
     on the gathered grid (its won/corrupt planes ``a_width`` wide,
     its quorum plane E wide all the same); used for the
-    ``payload_bytes`` accounting and the bench's
-    full-width-vs-compacted A/B."""
+    ``payload_bytes`` accounting and the tests'
+    full-width-vs-compacted comparison."""
     aw = e if a_width is None else a_width
     hw = aw if sliced else e
     nbits = hw + e + hw * m + 3 * k * aw
@@ -357,7 +357,7 @@ def _u8view(x: np.ndarray) -> np.ndarray:
 def warmup_kernels(svc: "BatchedEnsembleService") -> None:
     """Back-compat wrapper for
     :meth:`BatchedEnsembleService.warmup` (the (K, A)-grid
-    pre-compile bench.py and svcnode share)."""
+    pre-compile every entry point shares)."""
     svc.warmup()
 
 
@@ -1135,7 +1135,7 @@ class BatchedEnsembleService:
         #: native single-pass resolve kernel (RETPU_NATIVE_RESOLVE=0
         #: or a missing toolchain pins the pure-Python fallback — the
         #: oracle arm; docs/ARCHITECTURE.md §12).  Resolved at
-        #: construction like RETPU_OBS so the bench A/B can hold one
+        #: construction like RETPU_OBS so an A/B test can hold one
         #: arm per live service.
         self._native_resolve = resolve_native.get()
         self.native_resolve_flushes = 0
@@ -1463,7 +1463,7 @@ class BatchedEnsembleService:
             return fut
         accum = _BatchAccum(n)
         # hot path (the keyed ceiling is per-key host Python —
-        # VERDICT r3 weak #3), vectorized per ARCHITECTURE §12b rung
+        # review r3 weak #3), vectorized per ARCHITECTURE §12b rung
         # 1: key→slot is ONE dict pass whose loop body is dict work
         # alone; handle allocation is one slab operation
         # (_alloc_handles), the payload store one bulk update, the
@@ -3586,7 +3586,7 @@ class BatchedEnsembleService:
         # EVERY input upload belongs to the h2d mark — an upload
         # inlined into the step call would bill its (synchronous)
         # transfer to 'dispatch' and make the async-enqueue number
-        # read milliseconds of jitter it doesn't have (VERDICT r3 #4).
+        # read milliseconds of jitter it doesn't have (review r3 #4).
         # The up mask uploads only when the failure detector actually
         # changed it (sliced launches gather it on device).
         uploads = int(self._up_dev is None)
@@ -3984,7 +3984,7 @@ class BatchedEnsembleService:
         launch records would both hide the pause (p99 = 0) and
         inject zero samples into every launch component.  This is
         what makes the BASELINE p99 target analyzable before and
-        after a platform change (VERDICT r2)."""
+        after a platform change (review r2)."""
         recs = list(self.lat_records)
         out: Dict[str, Dict[str, float]] = {}
         events = [r for r in recs if "svc_compaction" in r]
@@ -4877,7 +4877,7 @@ class BatchedEnsembleService:
 
         ``buckets``: optional iterable of ``(k, a_width)`` pairs
         (a_width None = full width) restricting the grid to those
-        (and the election-only K 0 launch).  bench.py and svcnode
+        (and the election-only K 0 launch).  svcnode and the smoke
         share the default full grid.  Compile events recorded during
         warmup land under ``phase="warmup"``.
         """
@@ -5670,7 +5670,7 @@ class BatchedEnsembleService:
         # Finish the breakdown the launch recorded: oldest-op queue
         # wait, WAL append+sync, per-future resolve.  Per-component
         # percentiles over these records are what makes a p99 target
-        # analyzable (VERDICT r2 weak #2).
+        # analyzable (review r2 weak #2).
         t_wal = rec["starts"]["wal"]
         oldest = min((op.t_enq for _e, ops in taken for op in ops
                       if op.t_enq), default=t_wal)

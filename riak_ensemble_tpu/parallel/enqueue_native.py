@@ -14,8 +14,7 @@ Knob discipline mirrors :mod:`.resolve_native` exactly:
 - ``RETPU_NATIVE_ENQUEUE=0`` opts the service out of the whole
   slab-resident enqueue path — per-entry plane pack and per-op future
   fan-out run as before (the oracle arm of
-  ``tests/test_native_enqueue.py`` and the bench's
-  ``enqueue_native_speedup`` A/B).
+  ``tests/test_native_enqueue.py``).
 - Knob on but no toolchain / stale .so: the slab path still runs, with
   the plane pack through numpy fancy indexing (the ``enqueue_fallback``
   arm) — graceful degradation, never a crash.
@@ -40,7 +39,7 @@ _instance_tried = False
 def enabled() -> bool:
     """The ``RETPU_NATIVE_ENQUEUE`` knob (default on): ``0`` pins the
     historical per-entry pack + per-op future fan-out — the oracle arm
-    of the equivalence tests and the bench A/B."""
+    of the equivalence tests."""
     return os.environ.get("RETPU_NATIVE_ENQUEUE", "1") != "0"
 
 

@@ -197,7 +197,7 @@ class ShardedEngine:
             ('ens'-sharded [K, E] planes, peer-sharded corrupt masks);
             raveling those directly leaves GSPMD no expressible output
             sharding and it rematerializes per operand
-            (MULTICHIP_r04's ``spmd_partitioner`` warnings), so each is
+            (``spmd_partitioner`` warns of each), so each is
             constrained fully replicated first (ordinary all-gathers
             over ICI; the vector is fetched to the host anyway) and
             the pack runs replicated."""

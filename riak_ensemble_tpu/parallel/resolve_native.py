@@ -32,8 +32,8 @@ _instance_tried = False
 
 def enabled() -> bool:
     """The ``RETPU_NATIVE_RESOLVE`` knob (default on): ``0`` pins the
-    pure-Python resolve path — the fallback arm of the bench A/B and
-    the oracle of the equivalence tests."""
+    pure-Python resolve path — the oracle of the equivalence
+    tests."""
     return os.environ.get("RETPU_NATIVE_RESOLVE", "1") != "0"
 
 
@@ -41,7 +41,7 @@ def get() -> Optional["NativeResolve"]:
     """The loaded kernel wrapper, or None when the knob is off or the
     toolchain can't build it (callers use the Python fallback).  The
     knob is re-read per call so a service constructed under
-    ``RETPU_NATIVE_RESOLVE=0`` (the bench's fallback arm) never picks
+    ``RETPU_NATIVE_RESOLVE=0`` (the tests' fallback arm) never picks
     the kernel up; the library handle itself is built once."""
     global _instance, _instance_tried
     if not enabled():

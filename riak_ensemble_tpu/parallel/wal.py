@@ -384,7 +384,7 @@ class ServiceWAL:
             # durability, and a destroy's kv deletions sitting in the
             # userspace stdio buffer would die with the process — the
             # destroyed tenant's records would replay into a recycled
-            # row (ADVICE r3).
+            # row (advice r3).
             self._barrier()
 
     def records(self) -> List[Tuple[Any, Any]]:

@@ -1,4 +1,4 @@
-"""Consensus-managed membership for the scale plane (VERDICT r3 #3).
+"""Consensus-managed membership for the scale plane (review r3 #3).
 
 The reference's cluster story is one loop: every mutation flows
 through the root ensemble's kmodify (``riak_ensemble_root.erl:38-45``),
@@ -331,7 +331,7 @@ class ServiceReconciler:
         Entries are (key, payload, (epoch, seq)).
 
         Versions are the per-slot MAX (epoch, seq) across the UP
-        member lanes (ADVICE r5): on a leaderless row, lane 0 can
+        member lanes (advice r5): on a leaderless row, lane 0 can
         lag a quorum-committed write (e.g. it was down when the write
         committed), and exporting its stale version would pair the
         newest payload with an old (epoch, seq) — CAS tokens minted

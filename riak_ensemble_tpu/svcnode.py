@@ -332,7 +332,7 @@ class ServiceServer:
         # Per-connection op budget: the read loop blocks once
         # _MAX_INFLIGHT ops are unresolved, so a pipelining client
         # can't grow the queues/pending maps without bound (the
-        # VERDICT/advisor backpressure finding).
+        # review/advisor backpressure finding).
         inflight = asyncio.Semaphore(_MAX_INFLIGHT)
         svc = self.svc
         bp = svc.svc_backpressure
@@ -889,7 +889,7 @@ async def serve(n_ens: int, n_peers: int, n_slots: int,
         # one: restore() treats any present 'dynamic' kwarg as an
         # explicit choice and fails loudly on mismatch, so forwarding
         # an unasserted default would crash every restart of a
-        # --dynamic-persisted data_dir (ADVICE r3).  An explicit
+        # --dynamic-persisted data_dir (advice r3).  An explicit
         # True OR False still forwards, keeping the loud error for
         # genuinely contradictory assertions in both directions.
         dyn_kw = {} if dynamic is None else {"dynamic": bool(dynamic)}

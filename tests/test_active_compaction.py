@@ -418,7 +418,7 @@ def test_warmup_covers_ka_grid():
     assert svc._a_ladder() == [None, 8, 16, 32]
     svc.warmup()  # full (K, A) grid; must not raise or touch state
     assert svc.flushes == 0 and not np.asarray(svc.state.obj_seq).any()
-    # restricted bucket list (the bench/svcnode sharing surface)
+    # restricted bucket list (the surface entry points share)
     svc.warmup(buckets=[(4, 8), (4, None), (1, 8)])
     f = svc.kput(3, "k", 1)
     while any(svc.queues):

@@ -162,7 +162,7 @@ def test_service_heals_device_corruption():
 
 def test_service_composes_with_sharded_engine():
     """The same service runs over a ShardedEngine on the virtual
-    8-device mesh (the scale-out path, VERDICT round-1 item 3)."""
+    8-device mesh (the scale-out path, review round-1 item 3)."""
     from riak_ensemble_tpu.parallel.mesh import ShardedEngine, make_mesh
 
     if jax.device_count() < 8:
@@ -964,7 +964,7 @@ def test_restore_of_an_image_without_the_tree_stamp_rebuilds(tmp_path,
 
 
 def test_restore_rebuilds_trees_on_hash_format_change(tmp_path):
-    """Hash-format migration (round-5 ADVICE): a checkpoint written
+    """Hash-format migration (round-5 advice): a checkpoint written
     under a different device fold persists tree_leaf/tree_node that
     mismatch the running code's hashes.  Restore must detect the
     stamped format and rebuild every replica tree from the object

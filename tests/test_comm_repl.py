@@ -396,7 +396,7 @@ def test_comm_on_off_equivalence_and_metrics(tmp_path):
 def test_wire_coalescing_and_early_ack(tmp_path):
     """A hot-slot storm of SEPARATE scalar kmodifys queued into one
     flush ships fewer merge cells than committed ops (the wire-level
-    coalescing the bench meters) and settles through early acks on
+    coalescing ``repl_merge_cells`` counts) and settles through early acks on
     every replica — pure-merge frames ack after the WAL sync, before
     the device scatter."""
     svc, srvs = _group(tmp_path)
@@ -427,6 +427,47 @@ def test_wire_coalescing_and_early_ack(tmp_path):
         _assert_lanes_equal(svc, srvs)
     finally:
         _stop(svc, srvs)
+
+
+def test_merge_stream_undercuts_the_ordered_stream_per_entry(tmp_path):
+    """A hot-slot counter storm (two keys, each four times a batch,
+    every ensemble): the comm lane counts merge entries and early
+    acks, ships fewer bytes an entry than the ordered delta stream
+    does for the same storm, and both arms end in the same K/V
+    state."""
+    storm, fun = ["ctr0", "ctr1"] * 4, funref.ref("rmw:add", 1)
+    per_entry, finals = {}, {}
+    for arm, comm in (("comm", True), ("ordered", False)):
+        svc, srvs = _group(tmp_path / arm)
+        svc._comm_repl = comm
+        try:
+            warm = [svc.kmodify_many(e, storm, fun)
+                    for e in range(N_ENS)]
+            _settle(svc, warm)  # slots and elections, full-plane
+            g0 = dict(svc.stats()["group"])
+            for _ in range(4):
+                _settle(svc, [svc.kmodify_many(e, storm, fun)
+                              for e in range(N_ENS)])
+            g = svc.stats()["group"]
+            assert g["quorum_failures"] == 0, g
+            entries = (g["repl_delta_entries"] + g["repl_full_entries"]
+                       - g0["repl_delta_entries"]
+                       - g0["repl_full_entries"])
+            assert entries > 0, g
+            per_entry[arm] = (g["repl_bytes_sections"]
+                              - g0["repl_bytes_sections"]) / entries
+            merged = g["repl_merge_entries"] - g0["repl_merge_entries"]
+            early = g["repl_early_acks"] - g0["repl_early_acks"]
+            assert (merged > 0 and early > 0) if comm \
+                else (merged, early) == (0, 0), g
+            finals[arm] = [_counter_val(r) for r in _settle(
+                svc, [svc.kget(e, k) for e in range(N_ENS)
+                      for k in storm[:2]])]
+            _assert_lanes_equal(svc, srvs)
+        finally:
+            _stop(svc, srvs)
+    assert finals["comm"] == finals["ordered"] == [20] * (2 * N_ENS)
+    assert per_entry["comm"] < per_entry["ordered"], per_entry
 
 
 # -- kmodify_many enqueue-side coalescing ------------------------------------

@@ -266,6 +266,38 @@ def test_admission_token_bucket_caps_flush_take():
         svc.stop()
 
 
+def test_guard_caps_a_hot_tenant_on_a_live_service():
+    """The two halves joined: on a live service whose `hot` tenant
+    sends nearly all the traffic, the armed controller journals a
+    `tenant_guard` decision on the observed share and throttles that
+    tenant's rows, and the quiet tenant's ops still commit."""
+    svc = BatchedEnsembleService(WallRuntime(), 8, 1, 8, tick=None,
+                                 max_ops_per_tick=8)
+    try:
+        svc.set_autotune(True)
+        svc.controller.cadence = 4
+        svc.controller.guard.min_ops = 16
+        for e in range(4):
+            svc.set_tenant_label(e, "hot")
+        svc.set_tenant_label(4, "quiet")
+        keys = [f"k{j}" for j in range(4)]
+        for _ in range(12):
+            futs = [svc.kput_many(e, keys, [b"v"] * 4)
+                    for e in range(4)]
+            futs.append(svc.kput(4, "q", b"qv"))
+            while not all(f.done for f in futs):
+                svc.flush()
+            assert futs[-1].value[0] == "ok"
+        evs = [ev for ev in svc.controller.journal.snapshot()
+               if ev["actuator"] == "tenant_guard"]
+        assert evs, "guard armed but never decided"
+        assert evs[0]["cause"] == "tenant_ops_share"
+        assert evs[0]["observed"] >= 0.7
+        assert svc.controller.guard.throttled.get("hot")
+    finally:
+        svc.stop()
+
+
 # -- the chaos gate -----------------------------------------------------------
 
 def test_soak_schedule_virtual_clock():

@@ -15,7 +15,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 #: source roots scanned for knob reads (tests excluded: a test may
 #: reference hypothetical knobs in strings)
-SOURCE_ROOTS = ("riak_ensemble_tpu", "bench.py", "chip_smoke.py",
+SOURCE_ROOTS = ("riak_ensemble_tpu", "chip_smoke.py",
                 "__graft_entry__.py")
 
 KNOB_RE = re.compile(r"RETPU_[A-Z0-9_]+")

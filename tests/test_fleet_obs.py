@@ -175,42 +175,31 @@ def test_flight_dump_rotation_bounds_dir(tmp_path, monkeypatch):
                 if p.endswith(".json")]) == 4
 
 
-# -- bench-trend box grouping ------------------------------------------------
+# -- the standing pull --------------------------------------------------------
 
-def test_bench_trend_never_ratchets_across_fingerprints(tmp_path):
-    """Two synthetic fingerprints: the newest round on a NEW box must
-    never be ratcheted against the old box's best (cross-box
-    absolute-ms comparisons are weather), while a same-box regression
-    still trips — and the table draws the boundary explicitly."""
-    from tools import bench_trend
+@pytest.mark.parametrize("armed", [True, False])
+def test_standing_pull_runs_only_while_armed(tmp_path, monkeypatch,
+                                             armed):
+    """A leading three-host group with the watchdog armed posts obsq
+    pulls on its links, evaluates them and refreshes the links' clock
+    estimates; one built under ``RETPU_WATCHDOG=0`` pulls nothing."""
+    from test_repl_delta import _group, _settle, _stop
 
-    box_a = {"cpu_count": 2, "jax": "j", "jaxlib": "jl",
-             "platform": "cpu"}
-    box_b = {"cpu_count": 96, "jax": "j", "jaxlib": "jl",
-             "platform": "tpu"}
-    (tmp_path / "BENCH_r01.json").write_text(
-        json.dumps({"parsed": {"value": 1000.0, "box": box_a}}))
-    (tmp_path / "BENCH_r02.json").write_text(
-        json.dumps({"parsed": {"value": 900.0, "box": box_a}}))
-    # a 100x "regression" on a DIFFERENT box: not comparable, passes
-    (tmp_path / "BENCH_r03.json").write_text(
-        json.dumps({"parsed": {"value": 10.0, "box": box_b}}))
-    rep = bench_trend.check(str(tmp_path), tolerance=0.5)
-    assert rep["comparable_rounds"] == 0
-    assert rep["best_same_box_ops_per_sec"] is None
-    # same box again, out-of-band: trips
-    (tmp_path / "BENCH_r04.json").write_text(
-        json.dumps({"parsed": {"value": 400.0, "box": box_a}}))
-    with pytest.raises(bench_trend.TrendError):
-        bench_trend.check(str(tmp_path), tolerance=0.5)
-    # grouping + explicit boundary rendering
-    rows = bench_trend.trajectory(
-        bench_trend.load_rounds(str(tmp_path)))
-    groups = bench_trend.box_groups(rows)
-    assert [len(g) for _k, g in groups] == [2, 1, 1]
-    table = bench_trend.render_table(rows)
-    assert table.count("box change") == 2
-    assert "cpu2 -> cpu96" in table
+    monkeypatch.setenv("RETPU_WATCHDOG", "1" if armed else "0")
+    svc, srvs = _group(tmp_path)
+    try:
+        svc.watchdog.cadence = 2
+        for rnd in range(8):
+            _settle(svc, [svc.kput(e, "k", b"v%d" % rnd)
+                          for e in range(4)])
+        wd = svc.watchdog
+        if armed:
+            assert wd.pulls > 0 and wd.evals > 0
+            assert sum(ln.clock.samples for ln in svc._links) > 0
+        else:
+            assert (wd.pulls, wd.evals) == (0, 0)
+    finally:
+        _stop(svc, srvs)
 
 
 # -- watchdog pending-pull expiry -------------------------------------------

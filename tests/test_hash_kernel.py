@@ -209,7 +209,7 @@ def test_fold_collision_smoke():
 
 
 def test_fold_compensated_swap_no_collision():
-    """Regression (round-5 ADVICE): format 2's fold pre-mixed children
+    """Regression (round-5 advice): format 2's fold pre-mixed children
     LINEARLY (child*C1 + pos*C2 + lane), so replacing children (a, b)
     at positions (p, q) with (b+d, a-d), d = (q-p)*C2*C1^-1 mod 2^32,
     preserved the pre-mix multiset and collided deterministically.

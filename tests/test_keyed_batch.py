@@ -1,6 +1,6 @@
 """Vectorized keyed client path (kput_many/kget_many).
 
-VERDICT r2 #5: the scalar keyed path is bounded by per-op Python
+review r2 #5: the scalar keyed path is bounded by per-op Python
 (futures, op objects, per-op resolve).  The batch API keeps keyed
 semantics — arbitrary keys, per-key results in order, slot recycling,
 WAL durability — while packing/resolving through array slices.

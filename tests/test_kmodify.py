@@ -1,4 +1,4 @@
-"""kmodify on the batched service (VERDICT r3 #6): server-side
+"""kmodify on the batched service (review r3 #6): server-side
 read→fn→CAS retry with the actor plane's funref/MFA discipline
 (riak_ensemble_peer.erl:303-317, do_modify_fsm :1404-1416;
 riak_ensemble_root.erl:74-90 runs all cluster ops through it)."""

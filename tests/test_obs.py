@@ -933,3 +933,48 @@ def test_lowered_step_names_every_scope(program, scope):
     # an operation inside the scope is located as "<scope>/<op>"
     assert re.search(r'loc\("(?:[^"]*/)?%s/' % scope,
                      _lowered(program))
+
+
+# -- the stats-schema ratchet -------------------------------------------------
+
+def test_obs_metric_names_documented():
+    """The test_env_knobs pattern applied to metric names: every
+    metric a service registry can export is listed in
+    docs/ARCHITECTURE.md §11, and every `retpu_*` name the §11 tables
+    document still exists — so a new metric can't ship undocumented
+    and a renamed one can't haunt the docs."""
+    import re
+
+    from riak_ensemble_tpu.parallel.repgroup import ReplicatedService
+    from riak_ensemble_tpu.utils.trace import Tracer
+
+    svc = BatchedEnsembleService(WallRuntime(), 2, 1, 4, tick=None,
+                                 max_ops_per_tick=2)
+    grp = ReplicatedService(WallRuntime(), 2, 1, 4, group_size=1)
+
+    # the tracer's registry-fold names register on first use
+    class _RT:
+        now = 0.0
+        trace = None
+    tr = Tracer(_RT(), registry=svc.obs_registry).install()
+    tr._on_event("probe", {})
+    tr.finish(tr.begin("probe", 0), "ok")
+    code_names = set(svc.obs_registry.names()) \
+        | set(grp.obs_registry.names())
+    svc.stop()
+    grp.stop()
+    assert code_names, "metric-name scan found nothing"
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "docs", "ARCHITECTURE.md"),
+              encoding="utf-8") as fh:
+        arch = fh.read()
+    documented = set(re.findall(r"`(retpu_[a-z0-9_]+)`", arch))
+    missing = code_names - documented
+    assert not missing, (
+        f"undocumented metric name(s) {sorted(missing)}: add them to "
+        "docs/ARCHITECTURE.md §11 'Observability plane'")
+    stale = documented - code_names
+    assert not stale, (
+        f"ARCHITECTURE.md documents removed metric(s) "
+        f"{sorted(stale)}: drop the row or restore the metric")

@@ -145,7 +145,7 @@ def test_engine_flag_gated_pallas_equivalence(interpreted_engine_gate):
 
 
 def test_quorum_met_wide_pallas_3dim_view_mask(interpreted_engine_gate):
-    """Regression (round-5 ADVICE): the wide Pallas branch of
+    """Regression (round-5 advice): the wide Pallas branch of
     engine._quorum_met must accept a 3-dim [E, V, Ml] view_mask with
     W > 1 — broadcasting it per lane — not just a caller-pre-widened
     4-dim mask."""

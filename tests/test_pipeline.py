@@ -9,8 +9,7 @@ transfer riding a slow link): at depth 1 every flush eats the full
 delay; at depth 2 the delay of batch N runs under batch N+1's
 enqueue + dwell, roughly halving wall time.  A regression that
 silently serializes the pipeline (settle-before-enqueue) collapses
-the ratio to ~1 and fails fast — the tier-1 guard the bench's
-``serial_ops_per_sec`` A/B mirrors at full shapes.
+the ratio to ~1 and fails fast.
 """
 
 import time

@@ -11,8 +11,12 @@ retries transparently, and the reconnect satellite on
 """
 
 import asyncio
+import json
+import os
 import socket
 import struct
+import subprocess
+import sys
 import time
 
 import pytest
@@ -264,5 +268,112 @@ def test_proxy_rides_out_leader_step_down(tmp_path):
 
         asyncio.run(scenario())
     finally:
+        for s in srvs:
+            s.stop()
+
+
+# -- the serving plane as processes ------------------------------------------
+
+#: the client's process: `wire` and sockets only.  Writes and reads a
+#: slab batch a round through the proxy, then reads every key straight
+#: off each follower, and prints one tally
+_CLIENT = r'''
+import json, socket, struct, sys, time
+from riak_ensemble_tpu import wire
+cfg, HDR = json.loads(sys.argv[1]), struct.Struct(">I")
+ROUNDS, N_ENS = 3, 2
+keys = ["k%d" % j for j in range(4)]
+lens = struct.pack("<4i", *map(len, keys))
+arena = "".join(keys).encode()
+tally = dict(writes=0, reads=0, follower_reads=0, errors=0)
+def ask(sock, *frame):
+    payload = wire.encode(frame)
+    sock.sendall(HDR.pack(len(payload)) + payload)
+    buf = b""
+    while len(buf) < 4 or len(buf) < 4 + HDR.unpack(buf[:4])[0]:
+        got = sock.recv(1 << 16)
+        assert got, "closed"
+        buf += got
+    return wire.decode(buf[4:])[1]
+def oks(res, vals=None):
+    good = isinstance(res, list) and all(r[0] == "ok" for r in res) \
+        and (vals is None or [r[1] for r in res] == vals)
+    tally["errors"] += not good
+    return len(res) if good else 0
+px = socket.create_connection(tuple(cfg["proxy"]), timeout=120)
+for rnd in range(ROUNDS):
+    vals = [b"v%d.%d" % (rnd, j) for j in range(4)]
+    for ens in range(N_ENS):
+        tally["writes"] += oks(ask(
+            px, rnd, "kput_slab", ens, lens, arena,
+            struct.pack("<4i", *map(len, vals)), b"".join(vals)))
+        tally["reads"] += oks(ask(px, rnd, "kget_slab", ens, lens, arena),
+                              vals)
+for addr in cfg["followers"]:
+    s = socket.create_connection(tuple(addr), timeout=120)
+    end = time.monotonic() + 60
+    while ask(s, 0, "kget", 0, "k0") == ("error", "not-leader"):
+        assert time.monotonic() < end, "follower lease never arrived"
+        time.sleep(0.1)
+    for ens in range(N_ENS):
+        tally["follower_reads"] += oks(
+            ask(s, 1, "kget_slab", ens, lens, arena), vals)
+print(json.dumps(tally), flush=True)
+'''
+
+
+def test_promoted_group_behind_a_proxy_process(tmp_path):
+    """A promoted three-host group with follower reads, a proxy in a
+    process of its own and a client in another: no write or read
+    fails through the hop, the followers answer from their mirrors,
+    and ONE ``("fleet", "metrics")`` pull off the leader carries the
+    replicas' own count of the reads they served."""
+    from test_follower_reads import _ask
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=repo)
+    srvs = [repgroup.ReplicaServer(
+        2, 3, 8, data_dir=str(tmp_path / f"r{i}"), config=_CFG,
+        follower_reads=True) for i in range(3)]
+    hosts = [("127.0.0.1", s.client_port) for s in srvs]
+    px = None
+    try:
+        resp = _control(srvs[0].repl_port, (
+            "promote", [("127.0.0.1", s.repl_port) for s in srvs[1:]]))
+        assert resp[0] == "ok", resp
+        px = subprocess.Popen(
+            [sys.executable, "-m", "riak_ensemble_tpu.proxy",
+             "--port", "0", "--discover-timeout", "120", "--upstream",
+             ",".join(f"{h}:{p}" for h, p in hosts)],
+            stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
+            text=True, env=env)
+        line = px.stdout.readline()
+        assert line.startswith("proxy serving on "), line
+        host, _, port = line.split()[3].rpartition(":")
+        # the serving gate: the first write rides out the fresh
+        # leader's host-quorum heal
+        end = time.monotonic() + 120.0
+        while _ask(int(port), 0, "kput", 0, "gate", b"g",
+                   timeout=120.0)[0] != "ok":
+            assert time.monotonic() < end, "group never served"
+            time.sleep(0.25)
+        out = subprocess.run(
+            [sys.executable, "-c", _CLIENT, json.dumps(dict(
+                proxy=[host, int(port)], followers=hosts[1:]))],
+            capture_output=True, text=True, env=env, timeout=240)
+        assert out.returncode == 0, out.stderr[-800:]
+        tally = json.loads(out.stdout.strip().splitlines()[-1])
+        assert tally == dict(writes=24, reads=24, follower_reads=16,
+                             errors=0), tally
+        fm = _ask(srvs[0].client_port, 1, "fleet", "metrics",
+                  timeout=120.0)
+        assert len(fm["hosts"]) == 3, sorted(fm["hosts"])
+        served = [snap.get("retpu_group_follower_reads_served", 0)
+                  for snap in fm["hosts"].values()]
+        assert sum(served) >= 16, served
+    finally:
+        if px is not None:
+            px.kill()
+            px.wait(timeout=10)
         for s in srvs:
             s.stop()

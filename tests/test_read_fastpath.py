@@ -435,6 +435,35 @@ def test_equivalence_random_ops_fast_vs_device(ring):
             assert fast.value == dev.value, (e, key)
 
 
+def test_disjoint_read_and_write_keys_hit_over_nine_in_ten():
+    """A 90/10 mix whose reads and writes never share a key: the
+    mirror answers over 0.9 of the reads while every round's writes
+    launch, and the only miss reason on record is the one this test
+    provokes, a read racing a queued write of its own key."""
+    runtime, svc = make()
+    rkeys, wkeys = ["r0", "r1", "r2"], ["w0", "w1", "w2"]
+    for e in range(4):
+        settle(runtime, svc, svc.kput_many(e, rkeys + wkeys, [b"0"] * 6))
+    svc.read_fastpath_hits = svc.read_fastpath_misses = 0
+    svc.read_fastpath_miss_reasons.clear()
+    for rnd in range(10):
+        reads = [svc.kget_many(e, rkeys * 3) for e in range(4)]
+        write = svc.kput(rnd % 4, wkeys[rnd % 3], b"v%d" % rnd)
+        assert all(f.done and f.value == [("ok", b"0")] * 9
+                   for f in reads)
+        assert settle(runtime, svc, write)[0] == "ok"
+    assert svc.read_fastpath_misses == 0
+    assert svc.read_fastpath_miss_reasons == {}
+    p = svc.kput(0, "r0", b"1")
+    g = svc.kget(0, "r0")
+    settle(runtime, svc, g)
+    assert p.value[0] == "ok" and g.value == ("ok", b"1")
+    st = svc.stats()
+    assert st["read_fastpath_miss_reasons"] == {"pending_write": 1}
+    hits, misses = st["read_fastpath_hits"], st["read_fastpath_misses"]
+    assert hits == 360 and hits / (hits + misses) > 0.9
+
+
 def test_stats_surface():
     runtime, svc = make()
     assert settle(runtime, svc, svc.kput(0, "a", b"v"))[0] == "ok"

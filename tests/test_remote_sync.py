@@ -1,5 +1,5 @@
 """Streamed Merkle exchange across a REAL process boundary at 1M
-segments (VERDICT r3 #7) — the ``test/synctree_remote.erl:24-38``
+segments (review r3 #7) — the ``test/synctree_remote.erl:24-38``
 analog: two OS processes, each holding a 1M-segment device tree, a
 level-by-level descent over the wire, and an asserted traffic ledger:
 O(width · height · diffs), never O(keys)."""

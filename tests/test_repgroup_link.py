@@ -1,5 +1,5 @@
 """Unit tests for the leader↔replica link and catch-up codecs
-(ADVICE r5 regressions): idle-socket timeouts must not tear quiet
+(advice r5 regressions): idle-socket timeouts must not tear quiet
 links down, and a tree-patch's control-plane meta must validate
 before — and apply after — everything else.
 """
@@ -63,7 +63,7 @@ def _make_link():
 
 
 def test_idle_timeout_with_empty_awaiting_keeps_link():
-    """ADVICE r5: a 120 s idle-socket timeout on a link with NOTHING
+    """advice r5: a 120 s idle-socket timeout on a link with NOTHING
     outstanding is benign — dropping it forced a full re-sync
     reconnect per idle period on quiet links (stepped-down
     ex-leaders, idle leaders)."""
@@ -157,7 +157,7 @@ def _mk_svc(dynamic=False):
 
 
 def test_install_meta_validates_mode_before_mutating():
-    """ADVICE r5: a lifecycle-mode mismatch must fail BEFORE the
+    """advice r5: a lifecycle-mode mismatch must fail BEFORE the
     leader's control-plane vectors land — a half-applied meta leaves
     the replica campaigning with mixed state."""
     src = _mk_svc(dynamic=True)

@@ -416,7 +416,7 @@ def test_delta_across_resync_and_install_barrier(tmp_path):
 
 def test_delta_off_knob_full_plane_equivalence(tmp_path):
     """The RETPU_REPL_DELTA=0 arm: every entry ships full-plane and
-    the lanes still converge (the A/B baseline the bench runs)."""
+    the lanes still converge (the full-plane baseline)."""
     svc, srvs = _group(tmp_path)
     try:
         svc._repl_delta = False  # what RETPU_REPL_DELTA=0 pins
@@ -427,6 +427,34 @@ def test_delta_off_knob_full_plane_equivalence(tmp_path):
         g = svc.stats()["group"]
         assert g["repl_delta_entries"] == 0, g
         assert g["repl_full_entries"] > 0, g
+        _assert_lanes_equal(svc, srvs)
+    finally:
+        _stop(svc, srvs)
+
+
+def test_skewed_write_set_ships_a_fraction_of_the_full_plane(tmp_path):
+    """The stream's cost follows what committed, not the grid: with
+    writes rotating over one of the four columns a round, an entry
+    ships under 25% of its full-plane equivalent's bytes, and every
+    replica lane still ends bit-equal."""
+    n_ens, keys = N_ENS, [f"key{j}" for j in range(4)]
+    svc, srvs = _group(tmp_path)
+    try:
+        # dense warm round: every column allocates its slots and
+        # elects (full-plane entries) before the meter starts
+        _settle(svc, [svc.kput_many(e, keys, [b"w"] * 4)
+                      for e in range(n_ens)])
+        g0 = dict(svc.stats()["group"])
+        for rnd in range(8):
+            _settle(svc, [svc.kput_many(e, keys, [b"v%d" % rnd] * 4)
+                          for e in range(rnd % 4, n_ens, 4)])
+        g = svc.stats()["group"]
+        entries = g["repl_delta_entries"] - g0["repl_delta_entries"]
+        assert entries > 0 and g["quorum_failures"] == 0, g
+        assert g["repl_full_entries"] == g0["repl_full_entries"], g
+        shipped = g["repl_bytes_sections"] - g0["repl_bytes_sections"]
+        full = g["repl_bytes_full_equiv"] - g0["repl_bytes_full_equiv"]
+        assert 0 < shipped < 0.25 * full, (shipped, full, entries)
         _assert_lanes_equal(svc, srvs)
     finally:
         _stop(svc, srvs)

@@ -1,4 +1,4 @@
-"""Dynamic replication-group host membership (VERDICT r4 missing #1).
+"""Dynamic replication-group host membership (review r4 missing #1).
 
 The reference reconfigures an ensemble's member set across machines at
 runtime via joint consensus — add/remove/replace with multi-view

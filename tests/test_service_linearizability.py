@@ -252,7 +252,7 @@ def test_service_linearizable_across_launch_failures(seed):
     # usual ~15%), so the firing gate below measures the system's
     # rollback behavior, never the dice — a purely random schedule can
     # legitimately draw zero injections on a quiet seed and abort a
-    # soak (VERDICT r3 weak #5 / directive #8).
+    # soak (review r3 weak #5 / directive #8).
     forced_launch = 1 + int(inject_rng.integers(6))
     launch_no = 0
 
@@ -330,7 +330,7 @@ def test_service_linearizable_across_launch_failures(seed):
 
 @pytest.mark.parametrize("seed", conftest.soak_seeds([901, 902, 903, 904]))
 def test_service_linearizable_under_corruption_nemesis(seed):
-    """Device-state corruption joins the nemesis (VERDICT r3 #9): the
+    """Device-state corruption joins the nemesis (review r3 #9): the
     sweep flips object/tree-leaf/tree-node lanes on a minority of
     replicas MID-RUN — concurrent with client load, leader kills and
     lease races — and the history must stay linearizable: the

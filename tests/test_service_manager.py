@@ -1,4 +1,4 @@
-"""Consensus-managed scale-plane membership (VERDICT r3 #3): tenants
+"""Consensus-managed scale-plane membership (review r3 #3): tenants
 flow through the root ensemble + gossip, placement derives from the
 svcnode directory, and reconciliation loops converge every node's
 batched service — joining a new svcnode rebalances tenants via gossip
@@ -239,7 +239,7 @@ def test_all_false_views_rejected_and_contained():
 
 
 def test_versions_survive_tenant_handoff():
-    """VERDICT r4 missing #2 / directive #4: a placement move carries
+    """review r4 missing #2 / directive #4: a placement move carries
     {epoch, seq} with the values (replace_members_test.erl:26-30
     semantics — consensus moves, objects keep their versions).  A CAS
     token read BEFORE a reconciler-driven move must work AFTER it,
@@ -306,7 +306,7 @@ def test_versions_survive_tenant_handoff():
 
 
 def test_leaderless_export_pairs_payload_with_committed_version():
-    """ADVICE r5 regression: _export on a LEADERLESS row must not read
+    """advice r5 regression: _export on a LEADERLESS row must not read
     versions from lane 0 — lane 0 can lag a quorum-committed write
     (it was down when the write committed), and pairing the newest
     payload with its stale (epoch, seq) voids every CAS token minted

@@ -303,7 +303,7 @@ def test_svcnode_slab_verbs_and_fallback():
 
 
 def test_svcnode_restart_adopts_persisted_dynamic_mode(tmp_path):
-    """ADVICE r3 (medium): restarting a --dynamic-persisted data_dir
+    """advice r3 (medium): restarting a --dynamic-persisted data_dir
     WITHOUT re-passing --dynamic must adopt the persisted mode (the
     restore docstring's 'persisted lifecycle mode WINS'), not crash at
     startup; an explicitly contradictory flag still fails loudly."""

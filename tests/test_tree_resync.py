@@ -1,5 +1,5 @@
 """Incremental (Merkle) catch-up for diverged repgroup replicas
-(VERDICT r4 missing #3).
+(review r4 missing #3).
 
 The reference heals peer divergence by tree exchange — cost
 O(width·height·diffs), never O(keys) (synctree.erl:372-417,

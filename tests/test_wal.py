@@ -516,7 +516,7 @@ def test_crash_point_fuzz_no_acked_write_lost(tmp_path, seed):
 
 def test_buffer_mode_delete_reaches_kernel_before_ack(tmp_path,
                                                       monkeypatch):
-    """ADVICE r3: delete() must honor buffer mode's process-crash
+    """advice r3: delete() must honor buffer mode's process-crash
     floor exactly like log() — a destroy's kv deletions sitting in the
     userspace stdio buffer would die with the process and replay the
     destroyed tenant's records into a recycled row."""

@@ -1,7 +1,7 @@
 """Restricted wire codec: roundtrips, allowlist rejection, hostile
 frames.  The codec replaces pickle on the TCP transport so a peer that
 can reach the node port can inject at worst a protocol message, never
-code (disterl's property, ADVICE r1)."""
+code (disterl's property, advice r1)."""
 
 import pytest
 

@@ -1,1 +1,1 @@
-# repo tooling (tools.bench_trend et al.) — importable from tests
+# repo tooling (trace_export, trace_scopes) — importable from tests
