@@ -11,6 +11,15 @@ last line of stdout.  ``--sweep`` (one set-up, a ladder of rates) and
 ``--rehearse`` (a CPU, cut shapes, exit 3, no result line) are for
 builders; the driver passes neither.
 
+A deployment is data: ``configs/<name>.json`` may carry a
+``riak_ensemble`` object (the reference's own application settings,
+``config.Config``'s fields), which goes to ``server.py`` as one
+argument where the key is present and not at all where it is not; a
+traffic file may carry ``insertproportion`` and ``requestdistribution:
+"latest"`` (``ycsb.schedule``).  A ``--benchmark`` file may name a
+directory of its own (``"data"``, relative to the file) whose
+``configs/`` and ``traffic/`` are looked through before this one's.
+
 No process this run starts outlives it.  ``server.py`` asks the kernel
 for SIGKILL at this process's death (so SIGKILL here, which runs no
 handler, leaves nothing either); SIGTERM, SIGINT and SIGHUP print one
@@ -78,9 +87,14 @@ class Run:
     """The cell, its files and what every printed line carries."""
 
     def __init__(self, args) -> None:
-        with open(args.benchmark
-                  or os.path.join(ROOT, "BENCHMARK.json")) as f:
+        path = args.benchmark or os.path.join(ROOT, "BENCHMARK.json")
+        with open(path) as f:
             self.bench = json.load(f)
+        #: where a cell's files are looked for, by name, in order
+        self.dirs = [HERE]
+        if "data" in self.bench:
+            self.dirs.insert(0, os.path.join(
+                os.path.dirname(os.path.abspath(path)), self.bench["data"]))
         cells = {w["name"]: w for w in self.bench["workloads"]}
         if args.workload not in cells:
             raise SystemExit(f"no cell {args.workload!r} in BENCHMARK.json")
@@ -98,14 +112,21 @@ class Run:
             where = self.traffic if key in self.traffic else self.cfg
             where[key] = json.loads(val)
         self.recordcount = self.cfg["records_per_ens"] * self.cfg["n_ens"]
+        #: inserts scheduled so far: the next takes key number
+        #: ``recordcount + inserted``
+        self.inserted = 0
+        #: the settings as given, on every line that states the run
+        self.settings = ({"riak_ensemble": self.cfg["riak_ensemble"]}
+                         if "riak_ensemble" in self.cfg else {})
         self.out = os.path.join(ROOT, ".bench_out", self.cell["name"])
         self.device: dict = {}
         self.tag = f"{os.getpid()}.{time.time_ns()}"
         self.phase = "starting"    # the last ``what`` said
 
-    @staticmethod
-    def _json(kind: str, name: str) -> dict:
-        with open(os.path.join(HERE, kind, name + ".json")) as f:
+    def _json(self, kind: str, name: str) -> dict:
+        paths = [os.path.join(base, kind, name + ".json")
+                 for base in self.dirs]
+        with open(next(filter(os.path.exists, paths), paths[-1])) as f:
             return json.load(f)
 
     def say(self, what: str, **fields) -> None:
@@ -166,7 +187,10 @@ class Child:
         self.run = run
         self.proc = None
 
-    async def start(self) -> None:
+    def command(self) -> list:
+        """``server.py``'s command line: the ring's shape, the engine
+        and, only where the configuration has the key, its
+        ``riak_ensemble`` settings as one argument."""
         r, cfg = self.run, self.run.cfg
         cmd = [sys.executable, os.path.join(HERE, "server.py"),
                "--n-ens", str(cfg["n_ens"]), "--n-peers",
@@ -177,13 +201,18 @@ class Child:
             cmd.append("--rehearse")
         if r.args.control:
             cmd += ["--control", r.args.control]
+        if "riak_ensemble" in cfg:
+            cmd += ["--riak-ensemble", json.dumps(cfg["riak_ensemble"])]
+        return cmd
+
+    async def start(self) -> None:
         # (from this thread, the main one: the kernel ties the child's
         # request to the thread that forked it)
-        cmd += ["--parent-pid", str(os.getpid())]
+        cmd = self.command() + ["--parent-pid", str(os.getpid())]
         self.proc = await asyncio.create_subprocess_exec(
             *cmd, stdin=asyncio.subprocess.PIPE,
             stdout=asyncio.subprocess.PIPE, limit=256 << 20, cwd=ROOT,
-            env=dict(os.environ, **{RUN_TAG: r.tag}))
+            env=dict(os.environ, **{RUN_TAG: self.run.tag}))
 
     async def event(self, want: str) -> dict:
         """The next line of the child, which must be ``want``."""
@@ -256,7 +285,7 @@ def per_layer(run: Run, facts: dict) -> dict:
     for m in run.bench["per_layer"]:
         if "workloads" in m and run.cell["name"] not in m["workloads"]:
             continue
-        spec = Run._json("layers", m["name"])
+        spec = run._json("layers", m["name"])
         name = spec["reader"]
         if name not in readers:
             mod_spec = importlib.util.spec_from_file_location(
@@ -281,9 +310,14 @@ async def phase(run: Run, client, records, stream: int, seconds: float,
     due, is_read, keynum = ycsb.schedule(
         run.args.seed, stream, pileup or t["rate"], seconds,
         run.recordcount,
-        t["readproportion"], t["requestdistribution"])
+        t["readproportion"], t["requestdistribution"],
+        t.get("insertproportion", 0.0), run.inserted)
     if pileup:
         due = np.zeros_like(due)
+    # the phase's inserts: the write keys past those there were
+    run.inserted += int((~is_read & (
+        keynum >= run.recordcount + run.inserted)).sum())
+    records.grow(run.recordcount + run.inserted)
 
     async def tracer() -> None:
         span = min(TRACE_S, seconds / 2.0)
@@ -348,7 +382,8 @@ async def warm_up(run: Run, child: Child, client, records, next_wid: int,
     rng = np.random.default_rng([int(run.args.seed), 0x47524944])
     # one record of every ensemble, to aim a column at
     first_of = np.full(run.cfg["n_ens"], -1, np.int64)
-    first_of[records.ens[::-1]] = np.arange(run.recordcount)[::-1]
+    first_of[records.ens[:run.recordcount][::-1]] = np.arange(
+        run.recordcount)[::-1]
     aimable = first_of[first_of >= 0]
     await quiet("load")     # what the load itself met, since the start
     bursts = []
@@ -446,7 +481,8 @@ async def main_async(run: Run) -> int:
         t_serving = time.perf_counter()
         run.say("serving", seconds_since_start=t_serving - T_START,
                 compile_cache=serving["compile_cache"],
-                control=serving["control"])
+                control=serving["control"],
+                **{k: serving[k] for k in run.settings})
 
         records = ycsb.Records(args.seed, run.recordcount,
                                run.cfg["n_ens"])
@@ -475,7 +511,13 @@ async def main_async(run: Run) -> int:
         window_end_unix = time.time()
         run.say("window", seconds=args.seconds,
                 drained_seconds=log.t_end - log.t_last_due,
-                **step_summary(log, args.seconds))
+                **step_summary(log, args.seconds),
+                reads=int(log.is_read.sum()),
+                keys=int(np.unique(log.keynum).size),
+                inserts=int((~log.is_read
+                             & (log.keynum >= run.recordcount)).sum()),
+                reads_before_insert=check.reads_before_insert(
+                    log, run.recordcount))
 
         # read-back: every key written since the load, and a seeded
         # sample of untouched ones, from device rounds
@@ -517,8 +559,10 @@ async def main_async(run: Run) -> int:
         shutil.rmtree(run.out, ignore_errors=True)
 
     v = check.verdict(run.recordcount, load_vsn, logs, got, dump,
-                      run.device, mesh=run.cfg.get("engine") == "mesh")
-    run.say("checked", **v, processes_left=processes_left)
+                      run.device, mesh=run.cfg.get("engine") == "mesh",
+                      cfg=run.cfg)
+    run.say("checked", **run.settings, **v,
+            processes_left=processes_left)
     if processes_left:
         return 1                # killed by now, but it was there
     facts = {"dump": dump, "log": log, "cfg": run.cfg,
@@ -561,7 +605,20 @@ async def main_async(run: Run) -> int:
         device["window_s"] = red["window_s"]
         result["breakdown"] = {"device_ops": red["device_ops"],
                                "idle_gaps": red["idle_gaps"]}
+    result.update(run.settings)
+    # each number compared beside its limit (and each guarantee as the
+    # run was found to keep it): last in the line, and the last lines
+    # of stderr
+    result["guarantees"] = v["guarantees"]
+    result["compared"] = {c["name"]: [c["value"], c["limit"]]
+                          for c in v["compared"]}
     print(json.dumps(result), flush=True)
+    for name, kept in v["guarantees"].items():
+        print(f"guarantee {name}: {'kept' if kept else 'BROKEN'}",
+              file=sys.stderr)
+    for name, (value, limit) in result["compared"].items():
+        print(f"{name}: {value} (limit {limit})", file=sys.stderr)
+    sys.stderr.flush()
     return 0
 
 
@@ -581,10 +638,18 @@ async def sweep(run: Run, child: Child, client, records,
         marks = {m: float(np.median([r.get(m, 0.0) for r in recs]) * 1e3)
                  for m in ("queue_wait", "h2d", "dispatch", "device_d2h",
                            "wal", "resolve", "total")} if recs else {}
+        since = dump["since_mark"]
         row = dict(rate=rate, **step_summary(log, SWEEP_STEP_S),
-                   flushes=dump["since_mark"]["flushes"],
-                   ops_served=dump["since_mark"]["ops_served"],
-                   fast_hits=dump["since_mark"]["read_fastpath_hits"],
+                   flushes=since["flushes"],
+                   ops_served=since["ops_served"],
+                   fast_hits=since["read_fastpath_hits"],
+                   # the rung's own readings of per-layer metrics
+                   # (``mean_k`` is its ``rounds_per_flush``)
+                   ops_per_flush=since["ops_served"]
+                   / max(since["flushes"], 1),
+                   step_wait_p50_ms=float(np.median(
+                       [r.get("device_d2h", 0.0) + r.get("inflight_wait", 0.0)
+                        for r in recs]) * 1e3) if recs else None,
                    mean_k=float(np.mean([r["k"] for r in recs]))
                    if recs else None,
                    max_k=max((r["k"] for r in recs), default=None),

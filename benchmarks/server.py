@@ -5,8 +5,16 @@ protocol on stdin (one JSON line on stdout per event or answer).
 Events, in order: ``device`` (what JAX found; anything but a TPU ends
 the process with code 2 unless ``--rehearse``), ``native`` (the four
 native halves, built from source when stale), ``serving`` (host, port,
-compile cache).  Commands: ``mark``, ``trace_start``, ``trace_stop``,
-``fast_reads_off``, ``dump``, ``quit`` — see ``README.md``.
+compile cache, the settings).  Commands: ``mark``, ``trace_start``,
+``trace_stop``, ``fast_reads_off``, ``dump``, ``quit`` — see
+``README.md``.
+
+``--riak-ensemble <json>`` is the deployment's ``riak_ensemble``
+object: the reference's application settings by their own names
+(``config.Config``'s fields).  The service is then started with
+``config=Config(**settings)`` and nothing else changes; a name
+``Config`` does not have ends the process with an ``error`` line and
+code 2 before JAX is imported.
 
 This process cannot outlive the ``run.py`` that started it: before it
 imports JAX it asks the kernel for SIGKILL at its parent's death
@@ -16,7 +24,9 @@ whole.
 
 ``--control <name>`` (never passed by the driver) serves with one
 stated guarantee broken, to show that the check fails such a run:
-``stale_read``, ``lost_write``, ``wal_buffer``, ``python_resolve``.
+``stale_read``, ``lost_write``, ``wal_buffer``, ``python_resolve``,
+``leased_read`` (the lease mirror answers reads again under a
+deployment that set ``trust_lease`` false).
 """
 
 from __future__ import annotations
@@ -24,6 +34,7 @@ from __future__ import annotations
 import argparse
 import asyncio
 import ctypes
+import dataclasses
 import json
 import os
 import shutil
@@ -137,12 +148,18 @@ def break_guarantee(control: str, server) -> None:
             nth = count[0]
 
             def acked(result) -> None:
-                if before is None or result == "failed":
+                if result == "failed":
                     return
-                settled[key] = (before, time.monotonic())
                 if control == "lost_write" and nth % 50 == 0:
-                    # the acknowledged write is quietly undone
-                    svc.kput(ens, key, before)
+                    # the acknowledged write is quietly undone (the
+                    # first this wrapper saw of its key, an insert
+                    # among them: the key is gone with it)
+                    if before is None:
+                        svc.kdelete(ens, key)
+                    else:
+                        svc.kput(ens, key, before)
+                if before is not None:
+                    settled[key] = (before, time.monotonic())
             fut.add_waiter(acked)
             return fut
         if op == "kget" and control == "stale_read":
@@ -167,14 +184,24 @@ async def serve(args, device: dict):
         import functools
         svcnode.BatchedEnsembleService = functools.partial(
             svcnode.BatchedEnsembleService, wal_sync="buffer")
+    said = {}
+    if args.settings is not None:
+        from riak_ensemble_tpu.config import Config
+        kw["config"] = Config(**args.settings)
+        kw["config"].validate()
+        said["riak_ensemble"] = args.settings
     data_dir = os.path.join(args.out, "data")
     shutil.rmtree(data_dir, ignore_errors=True)
     server = await svcnode.serve(args.n_ens, args.n_peers, args.n_slots,
                                  data_dir=data_dir, **kw)
     if args.control in ("stale_read", "lost_write"):
         break_guarantee(args.control, server)
+    if args.control == "leased_read":
+        # what ``set_fast_reads(True)`` refuses under trust_lease false
+        server.svc._assert_read_margin()
+        server.svc._fast_reads = True
     say("serving", host=server.host, port=server.port,
-        compile_cache=cache_dir, control=args.control, **device)
+        compile_cache=cache_dir, control=args.control, **said, **device)
     return server
 
 
@@ -256,10 +283,16 @@ async def commands(args, server, device: dict) -> None:
             elif word == "dump":
                 now = counters()
                 rb = state["read_back"]
+                # (the window's end: where the read-back began, else now)
+                end = rb or now
                 out = {
                     "stats": svc.stats(),
                     "since_mark": {c: now[c] - state["mark"].get(c, 0)
                                    for c in COUNTERS},
+                    "window_counters": {
+                        c: end[c] - state["mark"].get(c, 0)
+                        for c in COUNTERS},
+                    "leased_reads": end["read_fastpath_hits"],
                     "lat_records": recorder.take(),
                     "compile_events": [
                         e for e in list(COMPILE_EVENTS)
@@ -309,10 +342,22 @@ def main(argv=None) -> int:
                          "longer the parent")
     ap.add_argument("--control", default=None,
                     choices=("stale_read", "lost_write", "wal_buffer",
-                             "python_resolve"))
+                             "python_resolve", "leased_read"))
+    ap.add_argument("--riak-ensemble", default=None, metavar="JSON",
+                    help="the deployment's riak_ensemble settings")
     args = ap.parse_args(argv)
     die_with_parent(signal.SIGKILL, args.parent_pid)    # before JAX
     sys.path.insert(0, ROOT)
+    args.settings = None
+    if args.riak_ensemble is not None:
+        from riak_ensemble_tpu.config import Config     # imports no JAX
+        args.settings = json.loads(args.riak_ensemble)
+        unknown = sorted(set(args.settings)
+                         - {f.name for f in dataclasses.fields(Config)})
+        if unknown:
+            say("error", what="riak_ensemble: config.Config has no "
+                              f"setting {', '.join(unknown)}")
+            return 2
     if args.control == "python_resolve":
         os.environ["RETPU_NATIVE_RESOLVE"] = "0"
 

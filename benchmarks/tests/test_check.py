@@ -132,3 +132,103 @@ def test_a_run_under_a_weaker_guarantee_is_not_correct(path, bad):
 
 def test_no_tpu_is_not_correct():
     assert not run(clean_rows(), device={"platform": "cpu"})["correct"]
+
+
+# -- inserts: a key that was never loaded starts absent --------------------
+
+def insert_rows():
+    # key 12 was never loaded (RECORDS is 10); write 100 inserts it
+    return [
+        (True, 12, 0.5, 0.6, -2, None, OK),          # before the insert
+        (False, 12, 1.0, 2.0, None, (1, 40), OK),   # wid 101: the insert
+        (True, 12, 1.5, 1.6, -2, None, OK),          # sent before its ack
+        (True, 12, 1.5, 1.7, 101, None, OK),         # or sees it already
+        (True, 12, 2.1, 2.2, 101, None, OK),         # after its ack
+    ]
+
+
+def test_reads_of_an_inserted_key_are_held_to_its_acknowledgement():
+    v = run(insert_rows(), read_back={12: 101, 7: 7})
+    assert v["correct"], v
+    assert v["reads_before_insert"] == 2
+    rows = insert_rows()
+    rows[4] = (True, 12, 2.1, 2.2, -2, None, OK)    # notfound after the ack
+    v = run(rows, read_back={12: 101, 7: 7})
+    assert not v["correct"] and value(v, "stale_reads") == 1
+    assert v["examples"][0]["needed"] == (1, 40)
+    rows[4] = (True, 12, 2.1, 2.2, 7, None, OK)     # a loaded key's bytes
+    assert value(run(rows, read_back={12: 101}), "fabricated_reads") == 1
+
+
+def test_a_lost_insert_fails_and_an_unanswered_one_may_stand():
+    v = run(insert_rows(), read_back={12: -2, 7: 7})  # acknowledged, gone
+    assert not v["correct"] and value(v, "lost_writes") == 1
+    rows = insert_rows()[:1] + [(False, 12, 1.0, np.nan, None, None, 0)]
+    for got in (-2, 101):                 # never answered: either stands
+        v = run(rows, read_back={12: got})
+        assert value(v, "lost_writes") == 0
+        assert value(v, "failed_or_unanswered") == 1
+    # never sent at all (the connection was lost first): absent
+    rows = [(True, 13, 0.5, 0.6, -2, None, OK)]
+    assert run(rows, read_back={13: -2})["correct"]
+    assert value(run(rows, read_back={13: 5}), "lost_writes") == 1
+
+
+def test_an_insert_into_a_full_ensemble_is_a_failed_request():
+    rows = insert_rows()
+    rows[1] = (False, 12, 1.0, 2.0, None, None, FAILED)
+    rows[3] = rows[4] = (True, 12, 2.1, 2.2, -2, None, OK)
+    v = run(rows, read_back={12: -2})
+    assert not v["correct"] and value(v, "failed_or_unanswered") == 1
+    assert value(v, "stale_reads") == 0 and value(v, "lost_writes") == 0
+
+
+# -- the guarantee follows the setting -------------------------------------
+
+UNLEASED = {"riak_ensemble": {"trust_lease": False},
+            "guarantees": {"reads": check.READS_UNLEASED}}
+
+
+def under(cfg, leased_reads):
+    log = transcript(clean_rows())
+    return check.verdict(RECORDS, LOAD_VSN, [log],
+                         {3: 100, 5: 106, 7: 7, 8: 8},
+                         dict(DUMP, leased_reads=leased_reads), DEVICE,
+                         cfg=cfg)
+
+
+def test_no_lease_trusted_means_no_read_from_the_mirror():
+    v = under(UNLEASED, 0)
+    assert v["correct"]
+    assert [c["name"] for c in v["compared"]][-1] == "leased_reads"
+    v = under(UNLEASED, 3)                # three reads the mirror answered
+    assert not v["correct"] and value(v, "leased_reads") == 3
+    assert all(c["limit"] == 0 for c in v["compared"])
+
+
+def test_the_configuration_has_to_state_what_it_sets():
+    leased_words = {"riak_ensemble": {"trust_lease": False},
+                    "guarantees": {"reads": "leased reads only inside the "
+                                            "lease margin, else a device "
+                                            "round"}}
+    v = under(leased_words, 0)
+    assert not v["correct"]
+    assert v["guarantees"]["reads_stated_unleased"] is False
+
+
+@pytest.mark.parametrize("cfg", [
+    None, {}, {"guarantees": {"reads": "leased"}},
+    {"riak_ensemble": {"trust_lease": True}},
+    {"riak_ensemble": {"ensemble_tick": 0.25}},
+], ids=["none", "empty", "no_settings", "trusted", "another_setting"])
+def test_where_the_lease_is_trusted_nothing_changes(cfg):
+    v = under(cfg, 12_345)
+    assert v["correct"]
+    assert [c["name"] for c in v["compared"]] == [
+        "fabricated_reads", "stale_reads", "lost_writes",
+        "failed_or_unanswered"]
+    assert "reads_stated_unleased" not in v["guarantees"]
+    # and a dump from before PR 49 (no ``leased_reads``) still checks
+    log = transcript(clean_rows())
+    assert check.verdict(RECORDS, LOAD_VSN, [log], {3: 100}, DUMP, DEVICE,
+                         cfg=cfg)["correct"]

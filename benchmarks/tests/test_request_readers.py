@@ -20,8 +20,7 @@ NEW_METRICS = (
     "op_residence_update_p50_ms", "op_residence_read_p50_ms",
     "rx_hold_p50_ms", "outside_server_update_mean_ms",
     "outside_server_read_mean_ms", "launch_pad_share",
-    "launch_width_mean", "dispatch_step_p50_ms", "dispatch_pack_p50_ms",
-    "h2d_put_p50_ms")
+    "launch_width_mean", "dispatch_step_p50_ms", "h2d_put_p50_ms")
 
 
 def facts(strip=()):
@@ -117,7 +116,7 @@ def test_new_layer_files_name_readers_that_exist():
         assert callable(reader(spec["reader"]))
         assert name in by
     assert by["busiest_shard_share"]["workloads"] == [
-        "ycsb-a.ring40k-n5-mesh4"]
+        "ycsb-a.ring40k-n5-mesh4", "ycsb-a.ring256-n3-h5-mesh4"]
     assert all("workloads" not in by[n] for n in NEW_METRICS)
 
 

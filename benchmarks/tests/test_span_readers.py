@@ -155,16 +155,55 @@ def test_window_mean(records, expect):
 
 
 def test_layer_files_name_readers_that_exist():
+    """Every per-layer metric of ``BENCHMARK.json``, wherever it stands
+    in the list, has a ``layers/`` file that names a reader there is;
+    no ``layers/`` file is left without its entry, but for the file of
+    ``dispatch_pack_p50_ms`` (its entry went with PR 49; the file stays
+    while ``docs/ARCHITECTURE.md``, which a benchmark PR may not edit,
+    names it and tier-1's ``test_docs_paths`` looks for it)."""
     with open(os.path.join(os.path.dirname(BENCH),
                            "BENCHMARK.json")) as f:
         bench = json.load(f)
     names = [m["name"] for m in bench["per_layer"]]
-    assert names[-6:] == [*SPAN_METRICS, "idle_named_share",
-                          "rounds_per_flush"]
-    for name in names[-6:]:
+    assert {*SPAN_METRICS, "idle_named_share", "rounds_per_flush"} <= set(
+        names)
+    for name in names:
         with open(os.path.join(BENCH, "layers", name + ".json")) as f:
             spec = json.load(f)
-        assert callable(reader(spec["reader"]))
+        assert callable(reader(spec["reader"])), name
+    assert sorted(names + ["dispatch_pack_p50_ms"]) == sorted(
+        n[:-len(".json")] for n in os.listdir(os.path.join(BENCH, "layers")))
+
+
+def test_counter_ratio_reads_the_window_or_the_whole_run():
+    """``read_fastpath_hit_share`` since PR 49: the counters between
+    ``mark`` and the window's end (``window_counters``), so that the
+    read-back's device reads leave the denominator; ``since_mark`` (to
+    the ``dump``) is what ``ops_per_flush`` still reads."""
+    dump = {"since_mark": {"read_fastpath_hits": 44_000,
+                           "read_fastpath_misses": 35_000,
+                           "flushes": 9_000, "ops_served": 150_000},
+            "window_counters": {"read_fastpath_hits": 44_000,
+                                "read_fastpath_misses": 600,
+                                "flushes": 8_000, "ops_served": 90_000}}
+    kw = {"num": ["read_fastpath_hits"],
+          "den": ["read_fastpath_hits", "read_fastpath_misses"],
+          "scale": 100.0}
+    read = reader("counter_ratio")
+    assert read({"dump": dump}, **kw) == (pytest.approx(55.696, rel=1e-4),
+                                          79_000)
+    assert read({"dump": dump}, over="window_counters", **kw) == (
+        pytest.approx(98.655, rel=1e-4), 44_600)
+    # a child from before PR 49 has no such reading: nothing to read
+    del dump["window_counters"]
+    assert read({"dump": dump}, over="window_counters", **kw) is None
+    with open(os.path.join(BENCH, "layers",
+                           "read_fastpath_hit_share.json")) as f:
+        assert json.load(f)["args"]["over"] == "window_counters"
+    # no attempt in the window: nothing to read, never a 0
+    dump["window_counters"] = {"read_fastpath_hits": 0,
+                               "read_fastpath_misses": 0}
+    assert read({"dump": dump}, over="window_counters", **kw) is None
 
 
 def test_rehearsal_prints_the_span_metrics_a_cpu_can_read():

@@ -1,6 +1,10 @@
 """The generator against YCSB's own constants, and the schedule as a
 function of the seed alone."""
 
+import hashlib
+import json
+import os
+
 import numpy as np
 import pytest
 
@@ -89,3 +93,112 @@ def test_records_round_trip_and_reject_altered_bytes():
     groups = r.by_ensemble(np.arange(100))
     assert sorted(k for ks in groups.values() for k in ks) == list(range(100))
     assert all(int(r.ens[k]) == e for e, ks in groups.items() for k in ks)
+
+
+# -- the accepted cells' schedules are pinned; inserts and ``latest`` ------
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+BENCH = os.path.dirname(HERE)
+with open(os.path.join(BENCH, "testdata", "schedule_hashes.json")) as _f:
+    #: "<traffic>/<seed>/<stream>" -> [arrivals, sha256 of due + is_read
+    #: + keynum], from the ``ycsb.schedule`` of before PR 49 (stream 2
+    #: over 45 s is the window, stream 100 the first steady warm-up)
+    PINNED = json.load(_f)
+
+
+def _cells():
+    with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    out = {}
+    for w in bench["workloads"]:
+        with open(os.path.join(BENCH, "configs", w["config"] + ".json")) as f:
+            cfg = json.load(f)
+        with open(os.path.join(BENCH, "traffic",
+                               w["traffic"] + ".json")) as f:
+            out[w["traffic"]] = (cfg["records_per_ens"] * cfg["n_ens"],
+                                 json.load(f))
+    return out
+
+
+@pytest.mark.parametrize("pinned", sorted(PINNED))
+def test_accepted_traffic_keeps_its_schedule_bit_for_bit(pinned):
+    traffic, seed, stream = pinned.split("/")
+    recordcount, t = _cells()[traffic]
+    assert "insertproportion" not in t
+    seconds = 45.0 if stream == "2" else float(t["warm_seconds"])
+    due, is_read, keynum = ycsb.schedule(
+        int(seed), int(stream), t["rate"], seconds, recordcount,
+        t["readproportion"], t["requestdistribution"],
+        t.get("insertproportion", 0.0), 0)
+    digest = hashlib.sha256(due.tobytes() + is_read.tobytes()
+                            + keynum.astype(np.int64).tobytes())
+    assert [int(due.size), digest.hexdigest()] == PINNED[pinned]
+
+
+def _plain_zipfian(u, items, theta=0.99):
+    """``ZipfianGenerator.nextLong(itemcount)`` as the Java reads, one
+    draw, zeta summed afresh."""
+    zetan = sum(1.0 / i ** theta for i in range(1, items + 1))
+    zeta2 = 1.0 + 0.5 ** theta
+    alpha = 1.0 / (1.0 - theta)
+    eta = (1 - (2.0 / items) ** (1 - theta)) / (1 - zeta2 / zetan)
+    uz = u * zetan
+    if uz < 1.0:
+        return 0
+    if uz < 1.0 + 0.5 ** theta:
+        return 1
+    return int(items * (eta * u - eta + 1) ** alpha)
+
+
+def test_inserts_take_the_next_key_numbers_in_due_order():
+    rc, done = 1_000, 7
+    due, is_read, keynum = ycsb.schedule(11, 2, 400, 5.0, rc, 0.95,
+                                         "latest", 0.05, done)
+    n = due.size
+    assert n == 2_000 and int(is_read.sum()) == 1_900
+    ins = ~is_read & (keynum >= rc + done)
+    assert int(ins.sum()) == 100 and not (~is_read & ~ins).any()
+    assert keynum[ins].tolist() == list(range(rc + done, rc + done + 100))
+    assert (np.diff(due) >= 0).all()
+    # YCSB-B with 5% inserts on top: updates stay updates of loaded keys
+    due, is_read, keynum = ycsb.schedule(11, 2, 400, 5.0, rc, 0.5,
+                                         "zipfian", 0.1, 0)
+    ins = ~is_read & (keynum >= rc)
+    assert int(ins.sum()) == 200 and int((~is_read & ~ins).sum()) == 800
+    assert keynum[~ins].max() < rc
+
+
+def test_latest_is_the_newest_due_key_less_a_zipfian_rank():
+    """``SkewedLatestGenerator`` restated plainly: every request takes
+    ``newest - ZipfianGenerator.nextLong(newest)``, where ``newest`` is
+    the last key number due so far (``recordcount - 1`` and then the
+    inserts) and the Zipfian's zeta grows with the keys."""
+    rc, done = 300, 4
+    rng = np.random.default_rng([5, 0x4C4F4144, 9])   # schedule's own
+    n = 600
+    rng.random(n)                                      # the arrivals
+    u = rng.random(n)
+    due, is_read, keynum = ycsb.schedule(5, 9, 200, 3.0, rc, 0.9,
+                                         "latest", 0.1, done)
+    newest = rc - 1 + done
+    for i in range(n):
+        if not is_read[i]:
+            newest += 1
+            assert keynum[i] == newest
+        else:
+            assert keynum[i] == newest - _plain_zipfian(u[i], newest)
+            assert 0 < keynum[i] <= newest
+    reads = keynum[is_read]
+    age = (rc - 1 + done + np.cumsum(~is_read))[is_read] - reads
+    assert float((age == 0).mean()) > 0.1      # the newest key is hot
+
+
+def test_records_grow_names_and_places_inserted_keys():
+    r = ycsb.Records(3, 100, 8)
+    r.grow(130)
+    r.grow(120)                                 # never shrinks
+    assert r.recordcount == 100 and len(r.keys) == 130 == r.ens.size
+    assert r.keys == ycsb.key_names(130)
+    assert r.ens.tolist() == [ycsb.fnv1a64_bytes(k.encode()) % 8
+                              for k in r.keys]
+    assert set(r.by_ensemble([5, 125, 129])) <= set(range(8))

@@ -7,7 +7,12 @@ What follows the source: ``recordcount`` records named
 ``ScrambledZipfianGenerator`` (a Zipfian with constant 0.99 over
 10**10 items whose published zeta is ``ZETAN``, folded into the record
 range by ``fnvhash64``), 10 fields of 100 bytes per record, and the
-read/update proportions of the traffic file.
+read/update/insert proportions of the traffic file.  An insert takes
+the next key number after the loaded ones (``CoreWorkload``'s
+``transactioninsertkeysequence``: ``recordcount``, ``recordcount + 1``,
+...), named and placed as every key is; ``requestdistribution:
+"latest"`` is ``SkewedLatestGenerator``: the newest key number less a
+Zipfian 0.99 rank over the keys there are (workload D).
 
 ``assumed`` (what the source leaves to its database binding):
 
@@ -19,7 +24,13 @@ read/update proportions of the traffic file.
   preflist; never Python's salted ``hash``);
 - the first 8 bytes of every record written carry a write id unique
   in the run, so a value that comes back names the one write that
-  carried it; the other 992 bytes are seeded and are checked too.
+  carried it; the other 992 bytes are seeded and are checked too;
+- under ``latest`` the newest key is the last insert DUE before the
+  request, not YCSB's last ACKNOWLEDGED one
+  (``AcknowledgedCounterGenerator``): an open loop fixes its schedule
+  before it sends anything, so what the server has acknowledged by
+  then is not knowable; a read that overtakes its key's insert reads
+  notfound and the check counts it (``reads_before_insert``).
 
 This module imports no JAX and nothing of the program.
 """
@@ -111,26 +122,39 @@ def scrambled_zipfian(u: np.ndarray, recordcount: int) -> np.ndarray:
     return fnvhash64(zipfian(u, ITEM_COUNT, ZETAN)) % recordcount
 
 
-def key_names(recordcount: int) -> list:
-    """``CoreWorkload.buildKeyName`` with hashed insert order."""
-    return [f"user{h}" for h in fnvhash64(np.arange(recordcount)).tolist()]
+def key_names(recordcount: int, first: int = 0) -> list:
+    """``CoreWorkload.buildKeyName`` with hashed insert order, for the
+    key numbers ``first`` to ``recordcount - 1``."""
+    return [f"user{h}"
+            for h in fnvhash64(np.arange(first, recordcount)).tolist()]
 
 
 class Records:
     """The data set of one run: key names, each key's ensemble, and the
     bytes of every write by its id.  Ids 0..recordcount-1 are the
-    loaded records; later writes take the ids after them."""
+    loaded records; later writes take the ids after them.  Keys
+    0..recordcount-1 are loaded; an insert's key number follows them
+    (:meth:`grow` names and places it before it is sent)."""
 
     def __init__(self, seed: int, recordcount: int, n_ens: int) -> None:
         self.recordcount = recordcount
         self.n_ens = n_ens
-        self.keys = key_names(recordcount)
-        self.ens = (fnv1a64_keys(self.keys)
-                    % np.uint64(n_ens)).astype(np.int64)
+        self.keys: list = []
+        self.ens = np.zeros(0, np.int64)
+        self.grow(recordcount)
         rng = np.random.default_rng([int(seed), 0x59435342])
         #: every record's tail is a 992-byte slice of this pool
         self._pool = rng.bytes(1 << 20)
         self._span = len(self._pool) - (RECORD_BYTES - ID_BYTES)
+
+    def grow(self, n_keys: int) -> None:
+        """Name and place the key numbers up to ``n_keys`` (inserts)."""
+        if n_keys <= len(self.keys):
+            return
+        new = key_names(n_keys, len(self.keys))
+        self.keys += new
+        self.ens = np.concatenate([self.ens, (
+            fnv1a64_keys(new) % np.uint64(self.n_ens)).astype(np.int64)])
 
     def value(self, write_id: int, stub: bool = False) -> bytes:
         """The bytes write ``write_id`` carries.  A ``stub`` is the id
@@ -163,28 +187,59 @@ class Records:
         return out
 
 
+def latest(u: np.ndarray, newest: np.ndarray) -> np.ndarray:
+    """``SkewedLatestGenerator.nextValue`` for uniform draws ``u``:
+    ``newest`` (the last key number there is, per draw) less a Zipfian
+    rank over ``newest`` items, whose zeta grows with them as
+    ``ZipfianGenerator.nextLong(itemcount)`` recomputes it."""
+    newest = np.asarray(newest, np.int64)
+    top = int(newest.max()) if newest.size else 0
+    zetas = np.concatenate(([0.0], np.cumsum(
+        1.0 / np.arange(1, top + 1, dtype=np.float64)
+        ** ZIPFIAN_CONSTANT)))
+    return newest - zipfian(u, newest, zetas[newest])
+
+
 def schedule(seed: int, stream: int, rate: float, seconds: float,
              recordcount: int, read_share: float,
-             distribution: str = "zipfian"):
+             distribution: str = "zipfian", insert_share: float = 0.0,
+             inserted: int = 0):
     """The open-loop schedule of one phase, a function of its
     arguments alone: Poisson arrivals at ``rate`` for ``seconds``
-    (``due``, seconds from the phase's start), each a read or an
-    update (``is_read``) of one record (``keynum``, drawn by YCSB's
-    ``requestdistribution``: ``zipfian`` or ``uniform``).  The process is
-    conditioned on its count — exactly ``rate * seconds`` arrivals at
-    sorted uniform instants, exactly ``read_share`` of them reads in
-    a seeded order — so every seed offers the same amount of work.
-    ``stream`` keeps the warm-up's draws apart from the window's."""
+    (``due``, seconds from the phase's start), each a read or a write
+    (``is_read``) of one record (``keynum``, drawn by YCSB's
+    ``requestdistribution``: ``zipfian``, ``uniform`` or ``latest``).
+    The process is conditioned on its count — exactly ``rate *
+    seconds`` arrivals at sorted uniform instants, exactly
+    ``read_share`` of them reads and ``insert_share`` of them inserts
+    in a seeded order — so every seed offers the same amount of work.
+    A write whose ``keynum`` is ``recordcount + inserted`` or more is
+    an insert: the run's ``inserted``-th and on, in due order (the
+    phases before this one made ``inserted``).  Under ``latest`` a
+    request takes the newest key DUE before it less a Zipfian rank.
+    ``stream`` keeps the warm-up's draws apart from the window's.
+    Without inserts and ``latest`` nothing is drawn that was not drawn
+    before there were any: an old traffic file keeps its schedule."""
     rng = np.random.default_rng([int(seed), 0x4C4F4144, int(stream)])
     n = int(round(rate * seconds))
     due = np.sort(rng.random(n)) * seconds
+    u = rng.random(n)
     if distribution == "zipfian":
-        keynum = scrambled_zipfian(rng.random(n), recordcount)
+        keynum = scrambled_zipfian(u, recordcount)
     elif distribution == "uniform":
-        keynum = (rng.random(n) * recordcount).astype(np.int64)
-    else:
+        keynum = (u * recordcount).astype(np.int64)
+    elif distribution != "latest":
         raise ValueError(f"requestdistribution {distribution!r}")
     is_read = np.zeros(n, bool)
     is_read[:int(round(n * read_share))] = True
     rng.shuffle(is_read)
+    is_insert = np.zeros(n, bool)
+    if insert_share > 0:
+        writes = np.flatnonzero(~is_read)
+        n_ins = min(int(round(n * insert_share)), writes.size)
+        is_insert[writes[rng.permutation(writes.size)[:n_ins]]] = True
+    made = inserted + np.cumsum(is_insert)
+    if distribution == "latest":
+        keynum = latest(u, recordcount - 1 + made)
+    keynum = np.where(is_insert, recordcount - 1 + made, keynum)
     return due, is_read, keynum
